@@ -123,6 +123,8 @@ def main() -> None:
     parser.add_argument('--smoke', action='store_true',
                         help='fewer steps; assert the <10%% async bar')
     args = parser.parse_args()
+    from skypilot_tpu import compile_cache
+    compile_cache.enable()
     steps = 8 if args.smoke else args.steps
     results = run_bench(steps=steps, save_interval=args.save_interval,
                         batch_size=args.batch_size, seq_len=args.seq_len,

@@ -14,6 +14,7 @@ CHECKPOINT_DIR), this script consumes it:
 from __future__ import annotations
 
 import argparse
+import json
 import time
 
 
@@ -64,6 +65,7 @@ def main() -> None:
     import jax
     import jax.numpy as jnp
 
+    from skypilot_tpu import compile_cache
     from skypilot_tpu import parallel
     from skypilot_tpu.callbacks import base as callbacks
     from skypilot_tpu.data import checkpoints
@@ -73,12 +75,16 @@ def main() -> None:
     from skypilot_tpu.models.train import jit_train_step
     from skypilot_tpu.parallel.sharding import token_batch_sharding
 
+    compile_cache.enable()
     parallel.initialize_from_env()
     mesh = parallel.build_mesh(
         parallel.MeshConfig(data=-1, fsdp=args.fsdp,
                             sequence=args.sequence, tensor=args.tensor),
         num_slices=parallel.distributed.num_slices())
-    print(f'mesh: {dict(mesh.shape)} over {jax.device_count()} devices')
+    dev = jax.devices()[0]
+    print(f'mesh: {dict(mesh.shape)} over {jax.device_count()} devices '
+          f'(platform={dev.platform} device_kind={dev.device_kind!r} '
+          f'jax={jax.__version__})')
 
     if args.preflight:
         from skypilot_tpu.parallel import preflight
@@ -174,6 +180,13 @@ def main() -> None:
     if mgr is not None:
         mgr.close()  # wait-on-exit: drain in-flight saves
     cb.flush()
+    # Where the state ended up: every device should hold its share, not
+    # device 0 everything (None where the backend reports no stats).
+    stats = {d.id: d.memory_stats() or {} for d in jax.local_devices()}
+    print('device memory:', json.dumps([
+        {'id': i, 'bytes_in_use': s.get('bytes_in_use'),
+         'peak_bytes_in_use': s.get('peak_bytes_in_use')}
+        for i, s in stats.items()]))
     print('done', time.strftime('%X'))
 
 

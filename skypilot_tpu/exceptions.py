@@ -1,6 +1,6 @@
 """Typed exceptions for the framework.
 
-Capability parity with the reference's error taxonomy
+Capability parity with the reference's error classes
 (/root/reference/sky/exceptions.py:1-298), redesigned around TPU slices:
 provisioning failures carry a failover history over (tpu_type, zone,
 capacity_type) triples rather than VM launchables.

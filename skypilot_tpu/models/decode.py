@@ -149,11 +149,13 @@ def _norm(x, scale, eps, plus_one: bool = False):
 
 
 def _layer_forward(x, lp, cfg, positions, k_cache, v_cache,
-                   *, use_flash: bool):
+                   *, use_flash: bool, mesh=None):
     """One decoder layer against an explicit KV cache slice.
 
     x [b, s, d]; k_cache/v_cache [b, h_kv, max_len, hd] already contain
     this call's k/v written at [positions].  Returns the layer output.
+    `mesh` (the mesh the params and cache are sharded over, if any)
+    goes to the attention kernels, which run per shard under it.
     """
     h = _norm(x, lp['attn_norm']['scale'], cfg.norm_eps,
               cfg.norm_scale_plus_one)
@@ -167,7 +169,7 @@ def _layer_forward(x, lp, cfg, positions, k_cache, v_cache,
         # lengths — query token j of slot b sits at lengths[b] + j.
         out = paged_attention_ops.paged_attention(
             q, k_cache.leaf, v_cache.leaf, k_cache.tables,
-            k_cache.lengths, sm_scale=cfg.head_dim ** -0.5)
+            k_cache.lengths, sm_scale=cfg.head_dim ** -0.5, mesh=mesh)
         out = out.astype(x.dtype)
     elif use_flash:
         # Prefill from index 0: the valid cache region is exactly the
@@ -175,7 +177,7 @@ def _layer_forward(x, lp, cfg, positions, k_cache, v_cache,
         # requires.  (Chunks at index>0 take the masked path instead.)
         s = q.shape[2]
         out = flash_attention(q, k_cache[:, :, :s],
-                              v_cache[:, :, :s], causal=True)
+                              v_cache[:, :, :s], causal=True, mesh=mesh)
     else:
         # Masked decode: grouped einsums against the cache — GQA
         # q-heads fold into a `rep` axis per kv-head, so the repeated
@@ -223,7 +225,8 @@ def _embed(cfg, params, tokens):
 
 def _scan_layers_and_unembed(cfg, params, x, positions, cache_k, cache_v,
                              write_fn, *, use_flash: bool,
-                             view_fn=None, all_positions: bool = False):
+                             view_fn=None, all_positions: bool = False,
+                             mesh=None):
     """The shared per-layer loop: project+rope k/v, write them into the
     cache via `write_fn(k_cache, k_new) -> k_cache`, run the layer, then
     final-norm + unembed the last position.  Single-sequence decode and
@@ -255,7 +258,8 @@ def _scan_layers_and_unembed(cfg, params, x, positions, cache_k, cache_v,
         k_cache = write_fn(k_cache, k)
         v_cache = write_fn(v_cache, v)
         x = _layer_forward(x, lp, cfg, positions, view_fn(k_cache),
-                           view_fn(v_cache), use_flash=use_flash)
+                           view_fn(v_cache), use_flash=use_flash,
+                           mesh=mesh)
         return x, (k_cache, v_cache)
 
     x, (new_k, new_v) = jax.lax.scan(
@@ -271,7 +275,8 @@ def _scan_layers_and_unembed(cfg, params, x, positions, cache_k, cache_v,
     return logits, new_k, new_v
 
 
-def _forward_with_cache(cfg, params, tokens, cache, *, use_flash: bool):
+def _forward_with_cache(cfg, params, tokens, cache, *, use_flash: bool,
+                        mesh=None):
     """Shared prefill/step body: embeds tokens at cache['index'],
     updates every layer's cache, returns (logits_last, new_cache)."""
     _, s = tokens.shape
@@ -285,13 +290,15 @@ def _forward_with_cache(cfg, params, tokens, cache, *, use_flash: bool):
 
     logits, new_k, new_v = _scan_layers_and_unembed(
         cfg, params, _embed(cfg, params, tokens), positions,
-        cache['k'], cache['v'], write, use_flash=use_flash)
+        cache['k'], cache['v'], write, use_flash=use_flash, mesh=mesh)
     return logits, {'k': new_k, 'v': new_v, 'index': cache_len}
 
 
-def prefill(cfg: ModelConfig, params, tokens, *, max_len: int):
+def prefill(cfg: ModelConfig, params, tokens, *, max_len: int,
+            mesh=None):
     """Process the prompt [b, s] into a FRESH cache; returns
-    (last-token logits [b, V], cache).  Flash-kernel attention.
+    (last-token logits [b, V], cache).  Flash-kernel attention (per
+    shard under `mesh`, when the params are sharded over one).
 
     Builds the cache itself: the flash path is only correct from
     index 0 (it attends over the static [0, s) window), so accepting a
@@ -299,7 +306,7 @@ def prefill(cfg: ModelConfig, params, tokens, *, max_len: int):
     """
     cache = init_cache(cfg, tokens.shape[0], max_len)
     return _forward_with_cache(cfg, params, tokens, cache,
-                               use_flash=True)
+                               use_flash=True, mesh=mesh)
 
 
 def decode_step(cfg: ModelConfig, params, token, cache):
@@ -428,8 +435,9 @@ def _sample(logits, rng, temperature, *, greedy: bool, top_k: int):
 
 
 def _generate_impl(cfg, params, prompt, rng, temperature,
-                   max_new_tokens, max_len, greedy, top_k):
-    logits, cache = prefill(cfg, params, prompt, max_len=max_len)
+                   max_new_tokens, max_len, greedy, top_k, mesh):
+    logits, cache = prefill(cfg, params, prompt, max_len=max_len,
+                            mesh=mesh)
     rng, first_rng = jax.random.split(rng)
     first = _sample(logits, first_rng, temperature, greedy=greedy,
                     top_k=top_k)
@@ -456,13 +464,13 @@ def _generate_impl(cfg, params, prompt, rng, temperature,
 _generate_jit = jax.jit(
     _generate_impl,
     static_argnames=('cfg', 'max_new_tokens', 'max_len', 'greedy',
-                     'top_k'))
+                     'top_k', 'mesh'))
 
 
 def generate(cfg: ModelConfig, params, prompt, *, max_new_tokens: int,
              max_len: Optional[int] = None,
              sampling: Optional[SamplingConfig] = None,
-             rng: Optional[jax.Array] = None
+             rng: Optional[jax.Array] = None, mesh=None
              ) -> Tuple[jax.Array, jax.Array]:
     """Greedy/temperature generation.  prompt [b, s] -> (tokens
     [b, s+max_new_tokens], new token slice [b, max_new_tokens]).
@@ -482,7 +490,7 @@ def generate(cfg: ModelConfig, params, prompt, *, max_new_tokens: int,
         cfg, params, prompt, rng,
         jnp.asarray(max(sampling.temperature, 1e-6), jnp.float32),
         max_new_tokens, max_len, sampling.temperature <= 0.0,
-        sampling.top_k)
+        sampling.top_k, mesh)
 
 
 # -------------------------------------------------- slot-batched decoding
@@ -708,7 +716,7 @@ def _dequant_kv(leaf_slice, dtype):
 
 
 def _paged_forward(cfg: ModelConfig, params, tokens, paged, *,
-                   kernel=None, all_positions: bool = False):
+                   kernel=None, all_positions: bool = False, mesh=None):
     """Shared write-then-attend body for paged decode: tokens [B, S]
     land at positions lengths..lengths+S-1, then every query attends
     through the pool.  Returns (logits, new_k, new_v) WITHOUT
@@ -775,11 +783,11 @@ def _paged_forward(cfg: ModelConfig, params, tokens, paged, *,
     return _scan_layers_and_unembed(
         cfg, params, _embed(cfg, params, tokens), positions,
         paged['k'], paged['v'], write, use_flash=False, view_fn=view,
-        all_positions=all_positions)
+        all_positions=all_positions, mesh=mesh)
 
 
 def paged_batched_step(cfg: ModelConfig, params, tokens, paged,
-                       active=None, *, kernel=None):
+                       active=None, *, kernel=None, mesh=None):
     """One decode step across all slots against the page pool; exact
     parity with `batched_step` (same masked attention math — the
     gathered pages in table order ARE the slot's cache with positions
@@ -787,7 +795,7 @@ def paged_batched_step(cfg: ModelConfig, params, tokens, paged,
     the same online-softmax sums without materialising the gather).
     """
     logits, new_k, new_v = _paged_forward(cfg, params, tokens, paged,
-                                          kernel=kernel)
+                                          kernel=kernel, mesh=mesh)
     lengths = paged['lengths']
     advance = (jnp.ones_like(lengths) if active is None
                else active.astype(lengths.dtype))
@@ -796,17 +804,19 @@ def paged_batched_step(cfg: ModelConfig, params, tokens, paged,
 
 
 def paged_engine_step(cfg: ModelConfig, params, state, paged, *,
-                      max_top_k: int = 64, kernel=None):
+                      max_top_k: int = 64, kernel=None, mesh=None):
     """`engine_step` against the page pool: same on-device token
     selection and stop bookkeeping, cache reads/writes through the
     block tables.  Returns (new_state, new_paged, finished [B])."""
     return _select_and_bookkeep(state, *paged_batched_step(
         cfg, params, state['tokens'][:, None], paged,
-        state['active'], kernel=kernel), max_top_k=max_top_k)
+        state['active'], kernel=kernel, mesh=mesh),
+        max_top_k=max_top_k)
 
 
 def paged_spec_engine_step(cfg: ModelConfig, params, state, paged,
-                           drafts, *, max_top_k: int = 64, kernel=None):
+                           drafts, *, max_top_k: int = 64, kernel=None,
+                           mesh=None):
     """Self-speculative verify tick: ONE batched forward checks k
     drafted tokens per slot against the paged cache and the longest
     exact prefix (plus the bonus correction token) is emitted.
@@ -835,7 +845,8 @@ def paged_spec_engine_step(cfg: ModelConfig, params, state, paged,
         [state['tokens'][:, None], jnp.asarray(drafts, jnp.int32)],
         axis=1)                                    # [B, S]
     logits, new_k, new_v = _paged_forward(
-        cfg, params, tokens, paged, kernel=kernel, all_positions=True)
+        cfg, params, tokens, paged, kernel=kernel, all_positions=True,
+        mesh=mesh)
 
     # Per-slot key chain: position j samples with exactly the key a
     # plain tick would use at that step; carries[j] is the post-split

@@ -517,25 +517,46 @@ def _assert_complete(params: Dict[str, Any], expect: Any,
 # --------------------------------------------------------------------------
 
 
+def save_converted(out_dir: str, params: Dict[str, Any],
+                   cfg: configs.ModelConfig) -> None:
+    """Write the converted-checkpoint layout every consumer reads
+    (`--model auto` servers, `--init-from` trainers):
+
+      <out>/0/...              orbax step-0 checkpoint of
+                               {'params': tree} (what
+                               checkpoints.restore_params reads and
+                               what finetune resume starts from)
+      <out>/model_config.json  ModelConfig for the tree's shapes
+
+    `params` is the unboxed param tree (numpy or jax arrays); its
+    stored dtype is what a server restores — `cfg.param_dtype` only
+    says what a trainer casts to.
+    """
+    import orbax.checkpoint as ocp  # pylint: disable=import-outside-toplevel
+    os.makedirs(out_dir, exist_ok=True)
+    mgr = ocp.CheckpointManager(
+        os.path.abspath(out_dir),
+        options=ocp.CheckpointManagerOptions(max_to_keep=1, create=True))
+    mgr.save(0, args=ocp.args.PyTreeSave({'params': params}))
+    mgr.wait_until_finished()
+    mgr.close()
+    with open(os.path.join(out_dir, MODEL_CONFIG_FILENAME), 'w',
+              encoding='utf-8') as f:
+        json.dump(cfg.to_json_dict(), f, indent=1)
+
+
 def convert(src_dir: str, out_dir: str,
             dtype: Optional[str] = None) -> configs.ModelConfig:
-    """Convert an HF safetensors checkpoint to our orbax layout.
-
-    Output dir contents:
-      <out>/0/...            orbax step-0 checkpoint of {'params': tree}
-                             (what checkpoints.restore_params reads and
-                             what finetune resume starts from)
-      <out>/model_config.json  ModelConfig for the converted shapes
-      <out>/tokenizer.*        copied from src when present
-    """
+    """Convert an HF safetensors checkpoint to our orbax layout:
+    `save_converted`'s files plus <out>/tokenizer.* copied from src
+    when present."""
     import shutil  # pylint: disable=import-outside-toplevel
     import tempfile  # pylint: disable=import-outside-toplevel
 
-    import orbax.checkpoint as ocp  # pylint: disable=import-outside-toplevel
     os.makedirs(out_dir, exist_ok=True)
-    # Disk-backed staging caps resident memory at ~one layer (VERDICT
-    # r4 weak #7: an 8B f32 import used ~32 GB of heap); orbax then
-    # streams from the memmaps and the scratch dir is removed.
+    # Disk-backed staging caps resident memory at ~one layer (an 8B f32
+    # import used ~32 GB of heap); orbax then streams from the memmaps
+    # and the scratch dir is removed.
     # Sweep scratch left by a killed prior run first — without this a
     # crashed convert leaks tens of GB inside the checkpoint dir that
     # every later rsync/upload of it would drag along.
@@ -547,21 +568,12 @@ def convert(src_dir: str, out_dir: str,
     try:
         params, cfg = load_params(src_dir, dtype=dtype,
                                   scratch_dir=scratch)
-        mgr = ocp.CheckpointManager(
-            os.path.abspath(out_dir),
-            options=ocp.CheckpointManagerOptions(max_to_keep=1,
-                                                 create=True))
-        mgr.save(0, args=ocp.args.PyTreeSave({'params': params}))
-        mgr.wait_until_finished()
-        mgr.close()
+        save_converted(out_dir, params, cfg)
         n_params = sum(
             int(np.prod(a.shape)) for a in _iter_leaves(params))
         del params
     finally:
         shutil.rmtree(scratch, ignore_errors=True)
-    with open(os.path.join(out_dir, MODEL_CONFIG_FILENAME), 'w',
-              encoding='utf-8') as f:
-        json.dump(cfg.to_json_dict(), f, indent=1)
     copied = []
     for fname in _TOKENIZER_FILES:
         src = os.path.join(src_dir, fname)

@@ -56,7 +56,8 @@ def moe_apply(tokens, router_logits, w_gate, w_up, w_down,
     in_capacity = in_capacity.reshape(n_tokens, top_k, n_exp)
 
     # dispatch [N, E, C]: token -> (expert, buffer slot).
-    pos_onehot = jax.nn.one_hot(position, capacity, dtype=jnp.float32)
+    pos_onehot = jax.nn.one_hot(position.astype(jnp.int32), capacity,
+                                dtype=jnp.float32)
     dispatch = jnp.einsum('nke,nkec->nec', choice * in_capacity,
                           pos_onehot * in_capacity[..., None])
     combine = jnp.einsum('nk,nke,nkec->nec', gate_vals,

@@ -159,29 +159,24 @@ def load_pretrained_params(state: TrainState, directory: str) -> TrainState:
     Leaf order pairs the restored plain tree with the state's boxed
     params (boxing preserves traversal order, same invariant
     checkpoints.restore_params relies on); every leaf is shape-checked.
-    Peak memory note: the random-init params exist until replaced —
-    for the largest models prefer a tensor/fsdp mesh so both trees are
-    sharded.
+    Each leaf is restored straight onto its state leaf's sharding, so
+    on a mesh every device reads its own shard and the unsharded tree
+    never sits on one chip.  Peak memory note: the random-init params
+    exist until replaced.
     """
     from skypilot_tpu.data import checkpoints  # pylint: disable=import-outside-toplevel
-    plain = checkpoints.restore_params(directory)
+    old_leaves, treedef = jax.tree_util.tree_flatten(state.params)
+    plain = checkpoints.restore_params(
+        directory, shardings=[leaf.sharding for leaf in old_leaves])
     if plain is None:
         raise FileNotFoundError(f'No checkpoint under {directory}')
-    old_leaves, treedef = jax.tree_util.tree_flatten(state.params)
     new_leaves = jax.tree_util.tree_leaves(plain)
-    if len(old_leaves) != len(new_leaves):
-        raise ValueError(
-            f'Checkpoint has {len(new_leaves)} arrays; model expects '
-            f'{len(old_leaves)} — wrong model_config for this state?')
     placed = []
     for old, new in zip(old_leaves, new_leaves):
         if tuple(old.shape) != tuple(new.shape):
             raise ValueError(f'Shape mismatch: checkpoint {new.shape} '
                              f'vs model {old.shape}')
-        arr = jnp.asarray(new, old.dtype)
-        sharding = getattr(old, 'sharding', None)
-        placed.append(jax.device_put(arr, sharding)
-                      if sharding is not None else arr)
+        placed.append(new.astype(old.dtype))
     return state.replace(
         params=jax.tree_util.tree_unflatten(treedef, placed))
 

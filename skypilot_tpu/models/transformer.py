@@ -163,7 +163,7 @@ class Attention(nn.Module):
                     else ring_attention)
             out = attn(q, k, v, mesh=self.mesh, causal=True)
         else:
-            out = flash_attention(q, k, v, causal=True)
+            out = flash_attention(q, k, v, causal=True, mesh=self.mesh)
 
         out = out.transpose(0, 2, 1, 3)  # [b, s, h, d]
         return nn.DenseGeneral(

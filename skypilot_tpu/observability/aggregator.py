@@ -74,7 +74,8 @@ _M_SERIES = metrics_lib.gauge(
 _INGEST_PREFIX = 'skytpu_'
 
 # Decode-path peak FLOP/s per chip for the MFU estimate; default = TPU
-# v5e bf16 (matches bench.py's fallback).  Serving MFU uses 2*params
+# v5e bf16 (the controller cannot see a replica's device; ROADMAP
+# Speed 10 replaces the default).  Serving MFU uses 2*params
 # FLOPs/token (forward only).
 _DEFAULT_PEAK_FLOPS = 197e12
 

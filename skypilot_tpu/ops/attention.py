@@ -11,10 +11,12 @@ Design (TPU-first):
   materialising the O(seq^2) probability matrix, with causal
   block-skipping.  `delta = rowsum(dO * O)` is a cheap XLA-fused
   pre-pass.
-- On CPU (tests) the same kernels run under Pallas interpret mode when
-  SKYTPU_PALLAS_INTERPRET=1; otherwise a blockwise `lax.scan`
-  implementation with identical online-softmax math is used, and its
-  autodiff is the backward.
+- On the CPU backend (tests) the same kernels run under Pallas
+  interpret mode when SKYTPU_PALLAS_INTERPRET=1 (an error on any other
+  backend); otherwise a blockwise `lax.scan` implementation with
+  identical online-softmax math is used, and its autodiff is the
+  backward.  The choice follows `jax.default_backend()` alone: a TPU
+  backend always takes the compiled kernels.
 
 No reference equivalent: SkyPilot ships no kernels (SURVEY.md §2.1).
 Shapes follow [batch, num_heads, seq, head_dim].
@@ -37,22 +39,52 @@ LSE_PAD = 1e30
 # delta) therefore ride in a broadcast 128-lane trailing dim — the same
 # layout the official JAX TPU flash kernel uses for its l/m residuals.
 _LANES = 128
+# Mosaic's default scoped-VMEM budget for one kernel, and what a v5e
+# core physically has.  The flash kernels hold a whole K/V row (forward,
+# dq) or a whole Q/dO/LSE/delta row (dk/dv) per program, so at long
+# sequences they must ask for more than the default.
+_DEFAULT_SCOPED_VMEM = 16 * 1024 * 1024
+_MAX_SCOPED_VMEM = 100 * 1024 * 1024
+
+
+def _vmem_params(block_bytes: int):
+    """CompilerParams that raise the scoped-VMEM limit when the
+    kernel's double-buffered blocks (`block_bytes` = one copy of every
+    in/out block) plus working room would not fit the default."""
+    from jax.experimental.pallas import tpu as pltpu  # pylint: disable=import-outside-toplevel
+    need = 2 * block_bytes + 8 * 1024 * 1024
+    if need <= _DEFAULT_SCOPED_VMEM:
+        return None
+    return pltpu.CompilerParams(
+        vmem_limit_bytes=min(need, _MAX_SCOPED_VMEM))
 
 
 def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == 'tpu'
-    except Exception:  # pylint: disable=broad-except
+    # A backend that fails to initialise raises here and fails the
+    # process: never a quiet blockwise run on a host that has a chip.
+    return jax.default_backend() == 'tpu'
+
+
+def interpret_mode() -> bool:
+    """SKYTPU_PALLAS_INTERPRET=1: run the Pallas kernels in the
+    interpreter.  A test mode for the CPU backend only; on any other
+    backend it would put interpreted kernels on the accelerator, so it
+    is an error there, not a mode."""
+    if os.environ.get('SKYTPU_PALLAS_INTERPRET', '') != '1':
         return False
-
-
-def _interpret() -> bool:
-    """Run the Pallas kernels in interpret mode (CPU tests)."""
-    return os.environ.get('SKYTPU_PALLAS_INTERPRET', '') == '1'
+    # skytpu: lint-ok[tracer-safety] reason=the backend name is a host string, not a traced value
+    if jax.default_backend() != 'cpu':
+        raise RuntimeError(
+            f'SKYTPU_PALLAS_INTERPRET=1 is a CPU-backend test mode; the '
+            f'JAX backend is {jax.default_backend()!r}.  Unset it to run '
+            f'the compiled kernels.')
+    return True
 
 
 def _use_pallas() -> bool:
-    return _on_tpu() or _interpret()
+    # interpret_mode() first: it must get the chance to refuse a
+    # non-CPU backend before _on_tpu() short-circuits.
+    return interpret_mode() or _on_tpu()
 
 
 def _repeat_kv(q, k, v):
@@ -248,7 +280,10 @@ def _flash_fwd_pallas(q, k, v, *, causal: bool, sm_scale: float,
             jax.ShapeDtypeStruct((b * h, q_len + q_pad, _LANES),
                                  jnp.float32),
         ],
-        interpret=_interpret(),
+        compiler_params=_vmem_params(
+            2 * (k_len + k_pad) * d * k.dtype.itemsize +
+            2 * block_q * d * q.dtype.itemsize + block_q * _LANES * 4),
+        interpret=interpret_mode(),
     )(qp, kp, vp)
     return (out.reshape(b, h, q_len + q_pad, d)[:, :, :q_len],
             lse[:, :, 0].reshape(b, h, q_len + q_pad)[:, :, :q_len])
@@ -424,7 +459,11 @@ def _flash_bwd_pallas(q, k, v, out, lse, g, g_lse, *, causal: bool,
                   q1_spec],
         out_specs=qd_spec,
         out_shape=jax.ShapeDtypeStruct((b * h, qlp, d), q.dtype),
-        interpret=_interpret(),
+        compiler_params=_vmem_params(
+            2 * klp * d * k.dtype.itemsize +
+            3 * block_q * d * q.dtype.itemsize +
+            2 * block_q * _LANES * 4),
+        interpret=interpret_mode(),
     )(qp, kp, vp, dop, lsep, deltap)
 
     kd_in_spec = pl.BlockSpec((1, block_k, d),
@@ -449,7 +488,10 @@ def _flash_bwd_pallas(q, k, v, out, lse, g, g_lse, *, causal: bool,
         out_specs=[kd_out_spec, kd_out_spec],
         out_shape=[jax.ShapeDtypeStruct((b * h, klp, d), k.dtype),
                    jax.ShapeDtypeStruct((b * h, klp, d), v.dtype)],
-        interpret=_interpret(),
+        compiler_params=_vmem_params(
+            2 * qlp * d * q.dtype.itemsize + 2 * qlp * _LANES * 4 +
+            4 * block_k * d * k.dtype.itemsize),
+        interpret=interpret_mode(),
     )(qp, kp, vp, dop, lsep, deltap)
 
     dq = dq.reshape(b, h, qlp, d)[:, :, :q_len]
@@ -509,10 +551,27 @@ _flash_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)
 
 def flash_attention(q, k, v, *, causal: bool = True,
                     sm_scale: Optional[float] = None,
-                    block_q: int = 128, block_k: int = 128):
-    """Flash attention over [batch, heads, seq, head_dim] arrays."""
+                    block_q: int = 128, block_k: int = 128, mesh=None):
+    """Flash attention over [batch, heads, seq, head_dim] arrays.
+
+    Under a `mesh` of more than one device each device runs the kernel
+    on its own batch/head shard (ops/sp_common.py says why); the
+    sequence dim stays whole, so a mesh whose 'sequence' axis shards
+    activations wants ring/ulysses attention, not this."""
     if sm_scale is None:
         sm_scale = float(q.shape[-1]) ** -0.5
+    if mesh is not None and mesh.size > 1:
+        from skypilot_tpu.ops import sp_common  # pylint: disable=import-outside-toplevel
+        batch_axes, head_axes, tp = sp_common.batch_head_axes(
+            mesh, q.shape[0])
+        k, v = sp_common.broadcast_gqa_if_indivisible(q, k, v, tp)
+        spec = jax.sharding.PartitionSpec(batch_axes, head_axes, None,
+                                          None)
+        fn = functools.partial(flash_attention, causal=causal,
+                               sm_scale=sm_scale, block_q=block_q,
+                               block_k=block_k)
+        return sp_common.sp_shard_map(fn, mesh, (spec, spec, spec),
+                                      spec)(q, k, v)
     out, _ = _flash_lse(q, k, v, causal, float(sm_scale), block_q, block_k)
     return out
 

@@ -18,15 +18,16 @@ position `lengths[b] + r % S`), so one kernel serves single-token
 decode (S=1) AND the self-speculative verify step (S=k+1) — drafts
 are verified through the same paged kernel.
 
-int8 pools (PR 7's per-page absmax scales) use a separate kernel body
-with fused dequant on the loaded K/V operand: the int8 bytes are what
-moves from HBM, the multiply happens on the VMEM-resident block.
+int8 pools (PR 7's per-page absmax scales) run the same kernel body
+with the dequant fused into the score and probability tiles: the int8
+bytes are what moves from HBM, the scales multiply VMEM-resident tiles.
 
-Same interpret-mode-on-CPU pattern as ops/attention.py
-(`SKYTPU_PALLAS_INTERPRET=1`); off-TPU without interpret mode a pure
-`jnp` gather reference with identical masking math is used, and
-`SKYTPU_DECODE_KERNEL=pallas|gather` pins the engine's path choice
-(default: pallas wherever Pallas can run, else gather).
+Same interpret-mode pattern as ops/attention.py
+(`SKYTPU_PALLAS_INTERPRET=1`, CPU backend only); off-TPU without
+interpret mode a pure `jnp` gather reference with identical masking
+math is used, and `SKYTPU_DECODE_KERNEL=pallas|gather` pins the
+engine's path choice (default: pallas wherever Pallas can run, else
+gather).
 
 Shapes: q [B, h_q, S, d]; pool leaves [n_pages, h_kv, ps, d] (int8
 pools: {'q': int8, 'scale': f32 [n_pages, h_kv, ps]}); tables [B, P];
@@ -45,8 +46,8 @@ import jax.numpy as jnp
 
 from skypilot_tpu.ops.attention import NEG_INF
 from skypilot_tpu.ops.attention import _LANES
-from skypilot_tpu.ops.attention import _interpret
 from skypilot_tpu.ops.attention import _use_pallas
+from skypilot_tpu.ops.attention import interpret_mode
 
 KERNEL_CHOICES = ('pallas', 'gather')
 
@@ -56,6 +57,7 @@ def decode_kernel_choice() -> str:
     'gather' (the dense page-gather view).  SKYTPU_DECODE_KERNEL pins
     it; default is pallas wherever Pallas can run (TPU, or CPU with
     SKYTPU_PALLAS_INTERPRET=1) and gather otherwise."""
+    interpret_mode()  # refuses a non-CPU backend whatever the pin says
     choice = os.environ.get('SKYTPU_DECODE_KERNEL', '').strip().lower()
     if choice:
         if choice not in KERNEL_CHOICES:
@@ -66,49 +68,35 @@ def decode_kernel_choice() -> str:
     return 'pallas' if _use_pallas() else 'gather'
 
 
-def _dequant_block(vals, scale, dtype):
-    """Fused per-token dequant of one loaded [ps, d] int8 block."""
-    return vals.astype(dtype) * scale.astype(dtype)[:, None]
+def _paged_decode_kernel(tables_ref, lengths_ref, q_ref, *refs,
+                         page_size: int, s_q: int, num_rows: int,
+                         quantized: bool):
+    """One (slot, kv_head, table_row) program streams its pool page
+    through VMEM and folds it into the slot's online softmax.
 
+    Refs: q [1, 1, R, d] pre-scaled f32 (R = rep * s_q); k/v
+    [1, 1, ps, d] in the pool dtype; int8 pools add ks/vs
+    [1, h_kv, ps] f32 per-token scales (the block carries every kv
+    head of the page — a `(1, 1, ps)` block has a second-to-last dim
+    Mosaic cannot tile — and the program reads its own head's row);
+    o [1, 1, R, d].  Scratch acc [R, d], m/l [R, _LANES] (per-row
+    scalars broadcast across lanes for Mosaic tiling, like the flash
+    kernels' LSE layout).
 
-def _paged_kernel_body(i, q, k, v, length, acc_ref, m_ref, l_ref, *,
-                       page_size: int, s_q: int):
-    """Online-softmax update of one (slot, kv_head, table_row)
-    program.  q [R, d] pre-scaled f32 (R = rep * s_q); k/v [ps, d]
-    f32; `length` the slot's pre-write depth.  Scratch acc [R, d],
-    m/l [R, _LANES] (per-row scalars broadcast across lanes for
-    Mosaic tiling, like the flash kernels' LSE layout)."""
-    r = q.shape[0]
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)
-    kpos = i * page_size + jax.lax.broadcasted_iota(
-        jnp.int32, (r, page_size), 1)
-    # Query row r sits at absolute position length + (r % s_q): the
-    # GQA fold keeps the S query tokens of each q-head contiguous.
-    qpos = length + jax.lax.broadcasted_iota(
-        jnp.int32, (r, page_size), 0) % s_q
-    s = jnp.where(kpos <= qpos, s, NEG_INF)
-    m_prev = jnp.max(m_ref[...], axis=-1, keepdims=True)
-    l_prev = jnp.max(l_ref[...], axis=-1, keepdims=True)
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-    corr = jnp.exp(m_prev - m_new)
-    p = jnp.exp(s - m_new)
-    l_new = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    m_ref[...] = jnp.broadcast_to(m_new, (r, _LANES))
-    l_ref[...] = jnp.broadcast_to(l_new, (r, _LANES))
-
-
-def _paged_decode_kernel(tables_ref, lengths_ref, q_ref, k_ref, v_ref,
-                         o_ref, acc_ref, m_ref, l_ref, *,
-                         page_size: int, s_q: int, num_rows: int):
-    """Native-dtype pool kernel: one (slot, kv_head, table_row)
-    program streams its pool page through VMEM."""
+    int8 dequant is fused without ever building the f32 page: with
+    k[t] = kq[t] * ks[t], q.k[t] = (q.kq[t]) * ks[t] scales a COLUMN of
+    the score tile, and sum_t p[t] v[t] = sum_t (p[t] vs[t]) vq[t]
+    scales a column of the probabilities — both are row-vector
+    broadcasts of the [1, ps] scale row as loaded.
+    """
     from jax.experimental import pallas as pl  # pylint: disable=import-outside-toplevel
 
+    if quantized:
+        k_ref, ks_ref, v_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref = refs
+    else:
+        k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref = refs
     b = pl.program_id(0)
+    hh = pl.program_id(1)
     i = pl.program_id(2)
     length = lengths_ref[b]
 
@@ -122,45 +110,33 @@ def _paged_decode_kernel(tables_ref, lengths_ref, q_ref, k_ref, v_ref,
     # computes (kpos 0 <= length), so m is finite from the first page.
     @pl.when(i * page_size <= length + s_q - 1)
     def _compute():  # pylint: disable=unused-variable
-        _paged_kernel_body(
-            i, q_ref[0, 0].astype(jnp.float32),
-            k_ref[0, 0].astype(jnp.float32),
-            v_ref[0, 0].astype(jnp.float32), length,
-            acc_ref, m_ref, l_ref, page_size=page_size, s_q=s_q)
-
-    @pl.when(i == num_rows - 1)
-    def _finish():  # pylint: disable=unused-variable
-        l = jnp.max(l_ref[...], axis=-1, keepdims=True)
-        o_ref[0, 0] = (acc_ref[...] /
-                       jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
-
-
-def _paged_decode_kernel_int8(tables_ref, lengths_ref, q_ref, k_ref,
-                              ks_ref, v_ref, vs_ref, o_ref, acc_ref,
-                              m_ref, l_ref, *, page_size: int, s_q: int,
-                              num_rows: int):
-    """int8 pool kernel: same program shape, with the per-page absmax
-    scales fused into the loaded K/V blocks (dequant on the VMEM
-    operand — int8 is what crossed HBM)."""
-    from jax.experimental import pallas as pl  # pylint: disable=import-outside-toplevel
-
-    b = pl.program_id(0)
-    i = pl.program_id(2)
-    length = lengths_ref[b]
-
-    @pl.when(i == 0)
-    def _init():  # pylint: disable=unused-variable
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-
-    @pl.when(i * page_size <= length + s_q - 1)
-    def _compute():  # pylint: disable=unused-variable
-        k = _dequant_block(k_ref[0, 0], ks_ref[0, 0], jnp.float32)
-        v = _dequant_block(v_ref[0, 0], vs_ref[0, 0], jnp.float32)
-        _paged_kernel_body(
-            i, q_ref[0, 0].astype(jnp.float32), k, v, length,
-            acc_ref, m_ref, l_ref, page_size=page_size, s_q=s_q)
+        q = q_ref[0, 0]
+        r = q.shape[0]
+        s = jax.lax.dot_general(
+            q, k_ref[0, 0].astype(jnp.float32), (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        if quantized:
+            s = s * ks_ref[0, pl.ds(hh, 1), :]
+        kpos = i * page_size + jax.lax.broadcasted_iota(
+            jnp.int32, (r, page_size), 1)
+        # Query row r sits at absolute position length + (r % s_q): the
+        # GQA fold keeps the S query tokens of each q-head contiguous.
+        qpos = length + jax.lax.broadcasted_iota(
+            jnp.int32, (r, page_size), 0) % s_q
+        s = jnp.where(kpos <= qpos, s, NEG_INF)
+        m_prev = jnp.max(m_ref[...], axis=-1, keepdims=True)
+        l_prev = jnp.max(l_ref[...], axis=-1, keepdims=True)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        corr = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)
+        l_new = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
+        if quantized:
+            p = p * vs_ref[0, pl.ds(hh, 1), :]
+        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
+            p, v_ref[0, 0].astype(jnp.float32), (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_ref[...] = jnp.broadcast_to(m_new, (r, _LANES))
+        l_ref[...] = jnp.broadcast_to(l_new, (r, _LANES))
 
     @pl.when(i == num_rows - 1)
     def _finish():  # pylint: disable=unused-variable
@@ -199,8 +175,10 @@ def _paged_attention_pallas(q, k_leaf, v_leaf, tables, lengths, *,
         (1, 1, ps, d),
         lambda bb, hh, ii, tt, ll: (tt[bb, ii], hh, 0, 0),
         memory_space=pltpu.VMEM)
+    # Last two block dims equal the array's (h_kv, ps): see the kernel
+    # docstring.
     scale_spec = pl.BlockSpec(
-        (1, 1, ps), lambda bb, hh, ii, tt, ll: (tt[bb, ii], hh, 0),
+        (1, h_kv, ps), lambda bb, hh, ii, tt, ll: (tt[bb, ii], 0, 0),
         memory_space=pltpu.VMEM)
     out_spec = pl.BlockSpec(
         (1, 1, r, d), lambda bb, hh, ii, tt, ll: (bb, hh, 0, 0),
@@ -209,20 +187,15 @@ def _paged_attention_pallas(q, k_leaf, v_leaf, tables, lengths, *,
                pltpu.VMEM((r, _LANES), jnp.float32),
                pltpu.VMEM((r, _LANES), jnp.float32)]
     if quantized:
-        kernel = functools.partial(
-            _paged_decode_kernel_int8, page_size=ps, s_q=s_q,
-            num_rows=num_rows)
         in_specs = [q_spec, kv_spec, scale_spec, kv_spec, scale_spec]
         operands = (qr, k_leaf['q'], k_leaf['scale'], v_leaf['q'],
                     v_leaf['scale'])
     else:
-        kernel = functools.partial(
-            _paged_decode_kernel, page_size=ps, s_q=s_q,
-            num_rows=num_rows)
         in_specs = [q_spec, kv_spec, kv_spec]
         operands = (qr, k_leaf, v_leaf)
     out = pl.pallas_call(
-        kernel,
+        functools.partial(_paged_decode_kernel, page_size=ps, s_q=s_q,
+                          num_rows=num_rows, quantized=quantized),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=grid,
@@ -230,7 +203,7 @@ def _paged_attention_pallas(q, k_leaf, v_leaf, tables, lengths, *,
             out_specs=out_spec,
             scratch_shapes=scratch),
         out_shape=jax.ShapeDtypeStruct((b, h_kv, r, d), q.dtype),
-        interpret=_interpret(),
+        interpret=interpret_mode(),
     )(tables, lengths, *operands)
     return out.reshape(b, h_kv, rep, s_q, d).reshape(b, h_q, s_q, d)
 
@@ -271,16 +244,32 @@ def _paged_attention_reference(q, k_leaf, v_leaf, tables, lengths, *,
 
 
 def paged_attention(q, k_leaf: Any, v_leaf: Any, tables, lengths, *,
-                    sm_scale: Optional[float] = None):
+                    sm_scale: Optional[float] = None, mesh=None):
     """Paged decode attention over one layer's page pool.
 
     q [B, h_q, S, d] (query token j of slot b at absolute position
     lengths[b] + j, already written into the pool); pool leaves
     [n_pages, h_kv, ps, d] (or int8 {'q','scale'}); tables [B, P];
     lengths [B].  Returns [B, h_q, S, d] in q's dtype.
+
+    Under a `mesh` of more than one device each device runs the kernel
+    on its own heads (ops/sp_common.py says why): q heads and the
+    pool's kv heads are sharded over 'tensor' as
+    parallel/sharding.page_pool_sharding places them; slots, tables
+    and lengths are replicated.
     """
     if sm_scale is None:
         sm_scale = float(q.shape[-1]) ** -0.5
+    if mesh is not None and mesh.size > 1:
+        from skypilot_tpu.ops import sp_common  # pylint: disable=import-outside-toplevel
+        P = jax.sharding.PartitionSpec
+        _, head_axes, _ = sp_common.batch_head_axes(mesh)
+        heads = P(None, head_axes)
+        leaf_spec = jax.tree.map(lambda _: heads, k_leaf)
+        fn = functools.partial(paged_attention, sm_scale=sm_scale)
+        return sp_common.sp_shard_map(
+            fn, mesh, (heads, leaf_spec, leaf_spec, P(), P()),
+            heads)(q, k_leaf, v_leaf, tables, lengths)
     if _use_pallas():
         return _paged_attention_pallas(q, k_leaf, v_leaf, tables,
                                        lengths, sm_scale=sm_scale)

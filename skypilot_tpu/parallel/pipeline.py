@@ -130,8 +130,8 @@ def pipeline_param_shardings(params: Dict[str, Any], mesh):
     }
 
 
-def _pipeline_body(stage_params, x_mb, *, cfg, n_stages: int, remat: bool,
-                   sequence_axis: Optional[str]):
+def _pipeline_body(stage_params, x_mb, *, cfg, mesh, n_stages: int,
+                   remat: bool, sequence_axis: Optional[str]):
     """Per-device GPipe schedule (runs under partial-manual shard_map).
 
     stage_params leaves: [1, layers_per_stage, ...] on the pipeline
@@ -152,7 +152,10 @@ def _pipeline_body(stage_params, x_mb, *, cfg, n_stages: int, remat: bool,
                      jnp.arange(seq))
     else:
         positions = jnp.arange(seq)
-    layer = DecoderLayer(cfg, sequence_axis=sequence_axis)
+    # The mesh lets the flash kernel nest its own shard_map over the
+    # axes this region leaves to GSPMD (a Mosaic kernel cannot sit in a
+    # partly-manual region).
+    layer = DecoderLayer(cfg, mesh, sequence_axis=sequence_axis)
 
     def stage_fn(h):
         def body(carry, lp):
@@ -229,7 +232,8 @@ def pipeline_forward(cfg, params, inputs, *, mesh,
 
     manual_axes = {'pipeline'} | ({'sequence'} if seq_parallel else set())
     act_spec = P(None, None, sequence_axis, None)
-    body = functools.partial(_pipeline_body, cfg=cfg, n_stages=n_stages,
+    body = functools.partial(_pipeline_body, cfg=cfg, mesh=mesh,
+                             n_stages=n_stages,
                              remat=cfg.remat,
                              sequence_axis=sequence_axis)
     out_mb = jax.shard_map(

@@ -31,21 +31,6 @@ DEFAULT_MIN_BANDWIDTH_GBPS = 0.05
 DEFAULT_MAX_LATENCY_MS = 5000.0
 
 
-def _shard_map(fn, mesh, in_specs, out_specs, axis: str):
-    """Capability probe: `jax.shard_map` is the public API from jax
-    0.6+; older jax only ships `jax.experimental.shard_map.shard_map`
-    (different kwargs: `check_rep`, no `axis_names`).  Probe the
-    attribute rather than version-compare — backports exist."""
-    import jax  # pylint: disable=import-outside-toplevel
-    if hasattr(jax, 'shard_map'):
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, axis_names={axis},
-                             check_vma=False)
-    from jax.experimental import shard_map as shard_map_lib  # pylint: disable=import-outside-toplevel
-    return shard_map_lib.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                                   out_specs=out_specs, check_rep=False)
-
-
 def probe_collectives(mesh, *, bandwidth_mb: float = 64.0,
                       repeats: int = 3) -> Dict[str, Dict[str, float]]:
     """Measure per-axis collective latency and bandwidth.
@@ -59,8 +44,8 @@ def probe_collectives(mesh, *, bandwidth_mb: float = 64.0,
     in their target sharding across the timed iterations; each timed
     call returns only a REPLICATED SCALAR
     (the collective's payload never crosses PCIe), synced by a
-    `device_get` of that scalar — airtight on every platform (bench.py's
-    lesson) while keeping the timed region fabric-dominated.
+    `device_get` of that scalar, which keeps the timed region
+    fabric-dominated.
     """
     import jax  # pylint: disable=import-outside-toplevel
     import jax.numpy as jnp  # pylint: disable=import-outside-toplevel
@@ -93,8 +78,11 @@ def probe_collectives(mesh, *, bandwidth_mb: float = 64.0,
             # heuristics are not.
             return jax.make_array_from_callback(shape, sharding, _block)
 
-        probe = jax.jit(_shard_map(_probe_fn, mesh, P(axis), P(),
-                                   axis=axis))
+        # Manual over `axis` only; the probe's collectives run without
+        # the replication checker.
+        probe = jax.jit(jax.shard_map(
+            _probe_fn, mesh=mesh, in_specs=P(axis), out_specs=P(),
+            axis_names={axis}, check_vma=False))
 
         tiny = _sharded((n, 8))
         # Each PARTICIPANT holds bandwidth_mb of payload (per-rank

@@ -199,6 +199,7 @@ class AsyncModelServer:
             'num_hosts': server.num_hosts,
             'draining': server.draining,
             'weight_version': server.weight_version,
+            **server.runtime,
         }
         engine = server._engine  # pylint: disable=protected-access
         code = 200
@@ -270,7 +271,12 @@ class AsyncModelServer:
                     pass
                 raise model_server_lib.ClientDisconnected(
                     'client disconnected mid-generation')
+            # Wait the cancellation out: until the watchdog's read has
+            # actually left the reader, the connection loop's next
+            # readuntil() raises "another coroutine is already waiting"
+            # (met on the chip host on every one-shot /generate).
             watchdog.cancel()
+            await asyncio.gather(watchdog, return_exceptions=True)
             tokens = gen.result()
         else:
             tokens = await gen

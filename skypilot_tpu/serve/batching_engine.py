@@ -345,7 +345,7 @@ class ContinuousBatchingEngine:
             self._step = jax.jit(
                 functools.partial(decode.paged_engine_step, cfg,
                                   max_top_k=self.max_top_k,
-                                  kernel=self.decode_kernel),
+                                  kernel=self.decode_kernel, mesh=mesh),
                 donate_argnums=(2,))
             # Speculative verify tick: same donated-pool discipline as
             # the plain tick, plus the [slots, k] draft batch; the
@@ -354,7 +354,7 @@ class ContinuousBatchingEngine:
             self._spec_step = jax.jit(
                 functools.partial(decode.paged_spec_engine_step, cfg,
                                   max_top_k=self.max_top_k,
-                                  kernel=self.decode_kernel),
+                                  kernel=self.decode_kernel, mesh=mesh),
                 donate_argnums=(2,))
             # Block-table surgery: donated so XLA patches the pool's
             # tiny int32 tables in place.
@@ -395,7 +395,8 @@ class ContinuousBatchingEngine:
         # per admission.
         self._prefill = jax.jit(
             lambda params, toks: decode.prefill(cfg, params, toks,
-                                                max_len=max_len))
+                                                max_len=max_len,
+                                                mesh=mesh))
         # Chunk continuation at index > 0 (masked per-position causal
         # path): one compile per chunk width; the private prefill cache
         # is donated so XLA extends it in place.

@@ -239,7 +239,19 @@ class ModelServer:
 
         from skypilot_tpu.models import configs
         from skypilot_tpu.models.transformer import Transformer
+        from skypilot_tpu.ops import attention as attention_ops
 
+        # What this process runs on, as JAX reports it — served on
+        # GET / so a prober can name the device without touching JAX
+        # itself (a chip belongs to one process).  interpret_mode()
+        # refuses to start an interpreted server on a non-CPU backend.
+        dev = jax.devices()[0]
+        self.runtime: Dict[str, Any] = {
+            'device': {'platform': dev.platform, 'kind': dev.device_kind,
+                       'count': jax.device_count()},
+            'jax_version': jax.__version__,
+            'pallas_interpret': attention_ops.interpret_mode(),
+        }
         if quantize not in (None, 'int8'):
             # Validate BEFORE the (potentially minutes-long) checkpoint
             # restore, not after.
@@ -430,7 +442,9 @@ class ModelServer:
                 params = jax.device_put(params, self._shardings)
         if quantize:
             from skypilot_tpu.models import quantize as quantize_lib
-            params = quantize_lib.quantize_params(params)
+            # quantize_params computes on the host; place the result
+            # once, or every jitted call uploads the weights again.
+            params = jax.device_put(quantize_lib.quantize_params(params))
             report = quantize_lib.quantization_report(params)
             logger.info(
                 f'int8 weight-only quantization: '
@@ -480,6 +494,11 @@ class ModelServer:
                     page_size=page_size, quantize_kv=quantize_kv,
                     prefix_caching=prefix_caching,
                     spec_tokens=spec_tokens)
+        # 'dense': the lock-step generate path (flash prefill + masked
+        # decode), the same word a dense-cache engine reports.
+        self.runtime['decode_kernel'] = (
+            self._engine.decode_kernel if self._engine is not None
+            else 'dense')
         if self._engine is not None:
             # The engine worker thread emits records outside any HTTP
             # request context; it stamps this identity (plus the
@@ -579,8 +598,11 @@ class ModelServer:
             params = checkpoints.restore_params(
                 checkpoint_dir, None, shardings=self._shardings)
             if self._quantize:
+                import jax  # pylint: disable=import-outside-toplevel
+
                 from skypilot_tpu.models import quantize as quantize_lib  # pylint: disable=import-outside-toplevel
-                params = quantize_lib.quantize_params(params)
+                params = jax.device_put(
+                    quantize_lib.quantize_params(params))
             epoch = engine.swap_params(params)
             self.params = params
             self.weight_version = epoch
@@ -737,7 +759,8 @@ class ModelServer:
             tokens, new = decode.generate(
                 self.cfg, self.params, prompt,
                 max_new_tokens=max_new_tokens, max_len=self.max_len,
-                sampling=sampling, rng=jax.random.PRNGKey(seed))
+                sampling=sampling, rng=jax.random.PRNGKey(seed),
+                mesh=self._mesh)
         del tokens
         return new.tolist()
 
@@ -943,7 +966,8 @@ def _make_handler(server: ModelServer):
                        'role': server.role,
                        'num_hosts': server.num_hosts,
                        'draining': server.draining,
-                       'weight_version': server.weight_version}
+                       'weight_version': server.weight_version,
+                       **server.runtime}
             engine = server._engine  # pylint: disable=protected-access
             code = 200
             if engine is not None:  # local bind: close() may race
@@ -1583,6 +1607,8 @@ def main() -> None:
                              'without a thread per connection) or the '
                              'legacy thread-per-connection server.')
     args = parser.parse_args()
+    from skypilot_tpu import compile_cache  # pylint: disable=import-outside-toplevel
+    compile_cache.enable()
     server = ModelServer(args.model, checkpoint_dir=args.checkpoint_dir,
                          max_len=args.max_len, max_batch=args.max_batch,
                          quantize=args.quantize,

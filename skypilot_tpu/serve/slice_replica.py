@@ -619,6 +619,8 @@ def main() -> None:
     parser.add_argument('--sequence', type=int, default=None)
     parser.add_argument('--iters', type=int, default=3)
     args, extra = parser.parse_known_args()
+    from skypilot_tpu import compile_cache  # pylint: disable=import-outside-toplevel
+    compile_cache.enable()
     if args.bench_prefill:
         _bench_prefill(args)
         return

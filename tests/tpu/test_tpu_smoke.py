@@ -1,34 +1,30 @@
-"""Hardware-gated TPU smoke tests: real Mosaic lowering + execution.
+"""Hardware-gated TPU tests: real Mosaic lowering + execution.
 
 Interpret mode skips BlockSpec tiling legality checks, so a kernel can
-be interpret-green yet fail to lower on hardware (VERDICT round-2 weak
-#1: exactly that happened).  This suite runs ONLY on a real TPU:
+be interpret-green yet fail to lower on hardware.  This suite runs ONLY
+on a real TPU:
 
     SKYTPU_TPU_TESTS=1 python -m pytest tests/tpu -q
 
-Under the default hermetic test env (JAX_PLATFORMS=cpu) every test here
-skips, so `pytest tests/` stays green on CPU-only machines.
+With SKYTPU_TPU_TESTS=1 and no TPU backend the run FAILS before
+collection (tests/conftest.py) — a chip suite that skips is a chip
+suite that passed nothing.  Without the variable (the hermetic
+`JAX_PLATFORMS=cpu` env of `pytest tests/`) every test here skips.
 """
 from __future__ import annotations
+
+import os
+import time
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-
-def _on_tpu() -> bool:
-    try:
-        dev = jax.devices()[0]
-    except Exception:  # pylint: disable=broad-except
-        return False
-    return (jax.default_backend() == 'tpu' or
-            'tpu' in getattr(dev, 'device_kind', '').lower())
-
-
 pytestmark = pytest.mark.skipif(
-    not _on_tpu(), reason='requires real TPU (SKYTPU_TPU_TESTS=1 on a '
-    'TPU host); interpret mode cannot validate Mosaic lowering')
+    os.environ.get('SKYTPU_TPU_TESTS') != '1',
+    reason='chip suite: SKYTPU_TPU_TESTS=1 on a TPU host (interpret '
+    'mode cannot validate Mosaic lowering)')
 
 
 def _qkv(b=2, h=4, h_kv=None, s=512, d=128, dtype=jnp.bfloat16, seed=0):
@@ -224,3 +220,131 @@ def test_family_variants_forward_on_tpu():
                                                               tokens)
         assert logits.shape == (1, 64, cfg.vocab_size)
         assert logits.dtype == jnp.float32
+
+
+# ------------------------------------------------- Llama-3-8B widths
+# What chip_smoke.py serves: 32 q heads / 8 kv heads, head_dim 128,
+# 16-token pages.  References run in float32 at 'highest' matmul
+# precision (a TPU f32 matmul otherwise rounds its operands to bf16).
+
+
+def _paged_case(quantized: bool, s_q: int, seed: int = 0):
+    from skypilot_tpu.models.decode import _quant_kv
+    h_q, h_kv, d, ps = 32, 8, 128, 16
+    n_pages, slots, rows = 96, 4, 20
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    q = jax.random.normal(ks[0], (slots, h_q, s_q, d), jnp.bfloat16)
+    k = jax.random.normal(ks[1], (n_pages, h_kv, ps, d), jnp.bfloat16)
+    v = jax.random.normal(ks[2], (n_pages, h_kv, ps, d), jnp.bfloat16)
+    if quantized:
+        kq, kscale = _quant_kv(k)
+        vq, vscale = _quant_kv(v)
+        k = {'q': kq, 'scale': kscale}
+        v = {'q': vq, 'scale': vscale}
+    # Every slot reads its own scattered pages (0 is the null page);
+    # depths cover an empty slot, a mid-page one and a full table.
+    tables = jax.random.permutation(ks[3], jnp.arange(1, n_pages))[
+        :slots * rows].reshape(slots, rows).astype(jnp.int32)
+    lengths = jnp.asarray([0, 37, 160, rows * ps - s_q], jnp.int32)
+    return q, k, v, tables, lengths
+
+
+@pytest.mark.parametrize('s_q', [1, 4])
+@pytest.mark.parametrize('quantized', [False, True])
+def test_paged_attention_matches_reference_at_llama_widths(quantized,
+                                                           s_q):
+    """The paged decode kernel (bf16 and int8 pools; S = 1 decode and
+    S = k+1 speculative verify) lowers and agrees with the gather
+    reference.
+
+    Tolerance: the kernel returns bf16 (q's dtype), 8 mantissa bits,
+    so an output of magnitude up to ~2 (a softmax-weighted mean of
+    N(0,1) values; the deepest slot averages 320 of them, the
+    shallowest attends a single key) carries up to 2 * 2^-8 = 8e-3 of
+    rounding; the reference is rounded the same way once more.  2e-2
+    leaves 2x room and is far under what a wrong page, a wrong scale
+    row or a mask off by one would produce (errors of order 1)."""
+    from skypilot_tpu.ops import paged_attention as pa
+    q, k, v, tables, lengths = _paged_case(quantized, s_q)
+    sm_scale = 128 ** -0.5
+    out = jax.jit(lambda *a: pa._paged_attention_pallas(
+        *a, sm_scale=sm_scale))(q, k, v, tables, lengths)
+    with jax.default_matmul_precision('highest'):
+        ref = pa._paged_attention_reference(q, k, v, tables, lengths,
+                                            sm_scale=sm_scale)
+    assert out.shape == q.shape and out.dtype == q.dtype
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(ref, np.float32), atol=2e-2)
+
+
+def test_flash_forward_backward_at_llama_head_dim_seq_4096():
+    """Flash forward and both backward kernels at head_dim 128, GQA 4:1,
+    seq 4096 — the length at which the dk/dv program's whole-row blocks
+    approach the default scoped-VMEM limit.
+
+    Tolerances: forward as the short-sequence tests above (bf16 output,
+    3e-2).  Gradients are compared after dividing by the reference's
+    largest magnitude; 2e-2 of that scale covers bf16 rounding of
+    dq/dk/dv plus the f32-accumulation-order difference over 4096 keys,
+    and a dropped k block or a wrong GQA group sum would be of order
+    1."""
+    from skypilot_tpu.ops.attention import flash_attention, mha_reference
+    q, k, v = _qkv(b=1, h=8, h_kv=2, s=4096)
+
+    def loss(fn):
+        return lambda q, k, v: jnp.sum(
+            fn(q, k, v).astype(jnp.float32) ** 2)
+
+    out = jax.jit(flash_attention)(q, k, v)
+    g = jax.jit(jax.grad(loss(flash_attention), argnums=(0, 1, 2)))(
+        q, k, v)
+    with jax.default_matmul_precision('highest'):
+        ref = mha_reference(q, k, v)
+        gr = jax.grad(loss(mha_reference), argnums=(0, 1, 2))(q, k, v)
+    np.testing.assert_allclose(
+        np.asarray(out, np.float32), np.asarray(ref, np.float32),
+        atol=3e-2)
+    for a, b in zip(g, gr):
+        scale = max(1.0, float(jnp.max(jnp.abs(b.astype(jnp.float32)))))
+        np.testing.assert_allclose(
+            np.asarray(a, np.float32) / scale,
+            np.asarray(b, np.float32) / scale, atol=2e-2)
+
+
+def test_interpret_mode_is_refused_on_the_chip(monkeypatch):
+    """SKYTPU_PALLAS_INTERPRET=1 on a TPU backend is an error, not a
+    mode: nothing may put interpreted kernels on the chip."""
+    from skypilot_tpu.ops import attention
+    from skypilot_tpu.ops import paged_attention
+    monkeypatch.setenv('SKYTPU_PALLAS_INTERPRET', '1')
+    with pytest.raises(RuntimeError, match='SKYTPU_PALLAS_INTERPRET'):
+        attention.interpret_mode()
+    with pytest.raises(RuntimeError, match='SKYTPU_PALLAS_INTERPRET'):
+        paged_attention.decode_kernel_choice()
+
+
+def test_block_until_ready_waits_for_the_device():
+    """Timed work ends in block_until_ready (bench.py, the trainer):
+    pin that on this backend it returns only when the device is done.
+    256 chained 4096^3 bf16 matmuls are 3.5e13 FLOPs; no TPU generation
+    in bench.py's peak table reaches 1e15 FLOP/s, so a return before
+    35 ms would be one that did not wait, and a fetch of the result
+    afterwards has nothing left to wait for."""
+    n, depth = 4096, 256
+    x = jnp.full((n, n), 1.0 / n, jnp.bfloat16)
+
+    @jax.jit
+    def chain(x):
+        return jax.lax.fori_loop(0, depth, lambda _, y: y @ x, x)
+
+    float(chain(x)[0, 0])          # compile + warm, the fetch included
+    t0 = time.perf_counter()
+    y = chain(x)
+    dispatched = time.perf_counter() - t0
+    y.block_until_ready()
+    blocked = time.perf_counter() - t0
+    t1 = time.perf_counter()
+    float(y[0, 0])
+    fetched = time.perf_counter() - t1
+    assert blocked >= 2.0 * n ** 3 * depth / 1e15, (dispatched, blocked)
+    assert fetched < 0.25 * blocked, (dispatched, blocked, fetched)
