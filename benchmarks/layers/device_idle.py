@@ -1,0 +1,9 @@
+"""Device: share of the traced window in which no operation ran on the
+chip (1 - the union of the device's operation intervals over the
+window, `reduce.py`), averaged over the chips used."""
+
+
+def compute(run):
+    if run.trace is None or run.trace['window_s'] <= 0:
+        return None
+    return 100.0 * (1.0 - run.trace['busy_s'] / run.trace['window_s'])
