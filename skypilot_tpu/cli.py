@@ -1380,9 +1380,11 @@ def serve_profile(service_name, replica_id, export_trace):
 
     Pulls each replica's `GET /profile` payload — the engine's bounded
     ring of per-tick phase timings (admit / prefill-chunk / decode-step
-    / spec-verify / sample / page-scatter / handoff / slice-sync), the
-    recompile sentinel's per-jit-entry compile counts, and
-    device-memory watermarks — and renders per-phase quantiles plus a
+    / spec-verify / device-wait / sample / page-scatter / handoff /
+    slice-sync; the dispatch phases time the host's dispatch,
+    device-wait the wait for the device), the recompile sentinel's
+    per-jit-entry compile counts, and the device-memory watermark — and
+    renders per-phase quantiles plus a
     collapsed-stack summary (pipe into a flamegraph tool)."""
     import json  # pylint: disable=import-outside-toplevel
 

@@ -54,6 +54,22 @@ class SamplingConfig:
     seed: int = 0
 
 
+def bind(fn, *args, **kwargs):
+    """`functools.partial(fn, *args, **kwargs)` under `fn`'s own name.
+    `jax.jit` names the compiled program after `__name__`, and that is
+    the name a device trace shows it by (`jit_prefill_chunk`); a
+    partial or a lambda comes out as `jit__unknown` / `jit__lambda_`,
+    which no reader of the trace can tell apart.  Every jitted entry
+    of the serving engines is bound through here."""
+
+    def call(*a, **k):
+        return fn(*args, *a, **kwargs, **k)
+
+    call.__name__ = fn.__name__
+    call.__qualname__ = fn.__qualname__
+    return call
+
+
 def init_cache(cfg: ModelConfig, batch: int, max_len: int
                ) -> Dict[str, Any]:
     """Zeroed KV cache pytree (per-layer stacked, scan-layout)."""
@@ -167,17 +183,21 @@ def _layer_forward(x, lp, cfg, positions, k_cache, v_cache,
         # the pool by block-table index in-grid (fused int8 dequant on
         # the loaded operand); `positions` is implied by the view's
         # lengths — query token j of slot b sits at lengths[b] + j.
-        out = paged_attention_ops.paged_attention(
-            q, k_cache.leaf, v_cache.leaf, k_cache.tables,
-            k_cache.lengths, sm_scale=cfg.head_dim ** -0.5, mesh=mesh)
+        with jax.named_scope('paged_attention'):
+            out = paged_attention_ops.paged_attention(
+                q, k_cache.leaf, v_cache.leaf, k_cache.tables,
+                k_cache.lengths, sm_scale=cfg.head_dim ** -0.5,
+                mesh=mesh)
         out = out.astype(x.dtype)
     elif use_flash:
         # Prefill from index 0: the valid cache region is exactly the
         # prompt window [0, s) — a STATIC slice (q.shape[2]), as jit
         # requires.  (Chunks at index>0 take the masked path instead.)
         s = q.shape[2]
-        out = flash_attention(q, k_cache[:, :, :s],
-                              v_cache[:, :, :s], causal=True, mesh=mesh)
+        with jax.named_scope('flash_attention'):
+            out = flash_attention(q, k_cache[:, :, :s],
+                                  v_cache[:, :, :s], causal=True,
+                                  mesh=mesh)
     else:
         # Masked decode: grouped einsums against the cache — GQA
         # q-heads fold into a `rep` axis per kv-head, so the repeated
@@ -212,7 +232,8 @@ def _layer_forward(x, lp, cfg, positions, k_cache, v_cache,
     x = x + out
     h = _norm(x, lp['mlp_norm']['scale'], cfg.norm_eps,
               cfg.norm_scale_plus_one)
-    return x + _mlp(h, lp, cfg)
+    with jax.named_scope('mlp'):
+        return x + _mlp(h, lp, cfg)
 
 
 def _embed(cfg, params, tokens):
@@ -255,23 +276,29 @@ def _scan_layers_and_unembed(cfg, params, x, positions, cache_k, cache_v,
         k = _attn_proj(h, lp['attn']['k_proj'])
         v = _attn_proj(h, lp['attn']['v_proj'])
         k = _rope(k, positions, cfg)
-        k_cache = write_fn(k_cache, k)
-        v_cache = write_fn(v_cache, v)
+        with jax.named_scope('kv_write'):
+            k_cache = write_fn(k_cache, k)
+            v_cache = write_fn(v_cache, v)
         x = _layer_forward(x, lp, cfg, positions, view_fn(k_cache),
                            view_fn(v_cache), use_flash=use_flash,
                            mesh=mesh)
         return x, (k_cache, v_cache)
 
-    x, (new_k, new_v) = jax.lax.scan(
-        lambda carry, ls: body(carry, ls),
-        x, (layers, cache_k, cache_v))
-    if all_positions:
-        x = _norm(x, params['final_norm']['scale'], cfg.norm_eps,
-                  cfg.norm_scale_plus_one)
-        return heads.unembed(x, params, cfg), new_k, new_v
-    x = _norm(x[:, -1:], params['final_norm']['scale'], cfg.norm_eps,
-              cfg.norm_scale_plus_one)
-    logits = heads.unembed(x, params, cfg)[:, 0]
+    # The scan slices each layer's share out of the stacked cache and
+    # writes it back: in a device trace those copies are the ops under
+    # `layer_scan` that are under none of the scopes inside the body.
+    with jax.named_scope('layer_scan'):
+        x, (new_k, new_v) = jax.lax.scan(
+            lambda carry, ls: body(carry, ls),
+            x, (layers, cache_k, cache_v))
+    with jax.named_scope('lm_head'):
+        if all_positions:
+            x = _norm(x, params['final_norm']['scale'], cfg.norm_eps,
+                      cfg.norm_scale_plus_one)
+            return heads.unembed(x, params, cfg), new_k, new_v
+        x = _norm(x[:, -1:], params['final_norm']['scale'],
+                  cfg.norm_eps, cfg.norm_scale_plus_one)
+        logits = heads.unembed(x, params, cfg)[:, 0]
     return logits, new_k, new_v
 
 
@@ -631,9 +658,10 @@ def _select_and_bookkeep(state, logits, new_cache, *, max_top_k: int):
     """Shared tick tail for dense and paged steps: on-device token
     selection + stop/countdown bookkeeping (see engine_step docs)."""
     active = state['active']
-    split = jax.vmap(lambda k: jax.random.split(k, 2))(state['keys'])
-    nxt = batched_sample(logits, split[:, 1], state['temperature'],
-                         state['top_k'], max_top_k=max_top_k)
+    with jax.named_scope('sampling'):
+        split = jax.vmap(lambda k: jax.random.split(k, 2))(state['keys'])
+        nxt = batched_sample(logits, split[:, 1], state['temperature'],
+                             state['top_k'], max_top_k=max_top_k)
     nxt = jnp.where(active, nxt.astype(jnp.int32), state['tokens'])
     stopped = jnp.any(nxt[:, None] == state['stop_ids'], axis=1)
     remaining = state['remaining'] - active.astype(jnp.int32)
@@ -859,13 +887,14 @@ def paged_spec_engine_step(cfg: ModelConfig, params, state, paged,
         _, (carries, skeys) = jax.lax.scan(body, key, None, length=s_q)
         return carries, skeys
 
-    carries, skeys = jax.vmap(chain)(state['keys'])   # [B, S, 2] each
-    vocab = logits.shape[-1]
-    toks = batched_sample(
-        logits.reshape(b * s_q, vocab), skeys.reshape(b * s_q, 2),
-        jnp.repeat(state['temperature'], s_q),
-        jnp.repeat(state['top_k'], s_q),
-        max_top_k=max_top_k).reshape(b, s_q).astype(jnp.int32)
+    with jax.named_scope('sampling'):
+        carries, skeys = jax.vmap(chain)(state['keys'])  # [B, S, 2] each
+        vocab = logits.shape[-1]
+        toks = batched_sample(
+            logits.reshape(b * s_q, vocab), skeys.reshape(b * s_q, 2),
+            jnp.repeat(state['temperature'], s_q),
+            jnp.repeat(state['top_k'], s_q),
+            max_top_k=max_top_k).reshape(b, s_q).astype(jnp.int32)
 
     # Longest exact prefix: draft j is accepted iff it equals the
     # model's own output at the previous position AND everything
@@ -964,8 +993,9 @@ def insert_prefill_pages(paged, private_cache, pages_row, *,
                     'scale': pool_leaf['scale'].at[:, ids].set(scale)}
         return pool_leaf.at[:, ids].set(piece.astype(pool_leaf.dtype))
 
-    return dict(paged, k=leaf(paged['k'], private_cache['k']),
-                v=leaf(paged['v'], private_cache['v']))
+    with jax.named_scope('page_scatter'):
+        return dict(paged, k=leaf(paged['k'], private_cache['k']),
+                    v=leaf(paged['v'], private_cache['v']))
 
 
 def paged_seed_private(cfg: ModelConfig, paged, pages_row, *,

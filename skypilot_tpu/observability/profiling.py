@@ -1,26 +1,41 @@
-"""Continuous profiling plane: tick-phase breakdown + recompile
-sentinel for the serving engines.
+"""Continuous profiling plane: tick-phase spans + recompile sentinel
+for the serving engines.
 
-Two always-on, low-overhead instruments (ISSUE 18):
+Two always-on, low-overhead instruments:
 
-- **TickProfiler** — a bounded ring of per-tick phase timings.  The
-  engine worker marks phase boundaries with `lap()`; each lap is ONE
-  monotonic clock read (the previous lap's timestamp is the phase
-  start, so phases are exclusive by construction — nested laps, like
-  the page-scatter inside a prefill finish, subtract themselves from
-  the enclosing phase).  Idle ticks (no recorded phase) never enter
-  the ring.  Each retained tick carries a device-memory watermark when
-  the backend reports one (`memory_stats()` is None on CPU).  Phase
-  durations also feed the process-global
-  `skytpu_engine_tick_phase_seconds{phase}` histogram so the fleet
-  aggregator sees the breakdown without touching `/profile`.
+- **TickProfiler** -- the engine loop's only span recorder.  The worker
+  opens each phase of an iteration where it happens (`with
+  prof.phase('admit'):`); a phase is recorded twice from the same two
+  clock reads: into a bounded ring (host clock, `time.monotonic`, the
+  clock `RequestSpan` uses) and as a `jax.profiler.TraceAnnotation`
+  named `skytpu/<phase>`, which lands on the host plane of the
+  profiler's `.xplane.pb` on the clock the device events are on.  The
+  whole iteration is `skytpu/tick` and carries the iteration's number
+  (`n`), so a ring record is tied to trace time by that number.  With
+  no profiler session open an annotation is a no-op check.  Phases
+  are exclusive: a phase opened inside another (the slice's
+  `slice-sync` inside `decode-step`) takes its time out of the
+  enclosing one.  Iterations that did no work never enter the ring.
+  `decode-step`, `prefill-chunk` and `spec-verify`'s dispatch are
+  asynchronous: those phases time the host's dispatch, and
+  `device-wait` (the blocking read of the tick in flight) is the one
+  phase in which the host waits for the device.  Cumulative totals
+  (`tick_loop()`) are what `stats()` carries; phase durations also
+  feed the process-global `skytpu_engine_tick_phase_seconds{phase}`
+  histogram so the fleet aggregator sees the breakdown without
+  touching `/profile`.
 
-- **RecompileSentinel** — wraps the engine's resolved jit entries
+  The **starvation probe** (`probe_starved`) is asked right before an
+  iteration's first dispatch with a tick in flight: if that tick has
+  already finished, the device has run dry.  The count is exact; the
+  seconds are an estimate (see `probe_starved`).
+
+- **RecompileSentinel** -- wraps the engine's resolved jit entries
   (incl. the Pallas kernel path, a closure constant of the wrapped
   step) and watches `fn._cache_size()` after every call: an increase
   means THIS call compiled.  Compiles during warm-up are expected;
   a compile after `steady_after` quiet calls is the classic silent
-  TPU perf killer — it bumps `skytpu_engine_recompiles_total{fn}` and
+  TPU perf killer -- it bumps `skytpu_engine_recompiles_total{fn}` and
   journals `recompile_detected{fn, shapes}` so the post-mortem names
   the shape that busted the cache.
 
@@ -33,16 +48,21 @@ import collections
 import os
 import threading
 import time
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, List, Optional
 
 from skypilot_tpu.observability import metrics as metrics_lib
 
 # The complete tick-phase vocabulary (docs/observability.md mirrors
-# this table).  A tick records only the phases that ran; decode-step
-# and spec-verify are mutually exclusive per tick, slice-sync appears
-# only on multi-host replicas.
+# this table).  An iteration records only the phases that ran;
+# decode-step and spec-verify are mutually exclusive per iteration,
+# slice-sync appears only on multi-host replicas.
 PHASES = ('admit', 'prefill-chunk', 'decode-step', 'spec-verify',
-          'sample', 'page-scatter', 'handoff', 'slice-sync')
+          'device-wait', 'sample', 'page-scatter', 'handoff',
+          'slice-sync')
+DEVICE_WAIT = 'device-wait'
+# Names on the profiler trace's host plane: `skytpu/tick` (stat `n`)
+# around `skytpu/<phase>` (stats `n`, `count`, `request_id`).
+TRACE_PREFIX = 'skytpu/'
 
 DEFAULT_RING_TICKS = 512
 # Steady-state threshold: a compile after this many quiet calls of the
@@ -61,7 +81,7 @@ _M_RECOMPILES = metrics_lib.counter(
     'after the warm-up window — each one is a served-tick stall).',
     ('fn',))
 # Pre-bound histogram children: .labels() validates and rebuilds the
-# label tuple on every call, which is most of the per-lap cost — the
+# label tuple on every call, which is most of the per-phase cost — the
 # phase vocabulary is closed, so bind once.
 _PHASE_OBSERVERS = {name: _M_PHASE.labels(phase=name)
                     for name in PHASES}
@@ -111,19 +131,93 @@ def _quantile(sorted_vals: List[float], q: float) -> Optional[float]:
     return sorted_vals[idx]
 
 
+class _NullPhase:
+    """What `phase()` hands out under SKYTPU_PROFILE_DISABLE."""
+    record = True
+    count = None
+    dur_s = 0.0
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+
+class _Phase:
+    """One open phase: a context manager that, from one pair of clock
+    reads, appends `[name, start, seconds, count, request_id]` to the
+    iteration's record and closes the `skytpu/<name>` annotation.  Set
+    `record = False` before leaving a phase whose machinery ran but did
+    no work (the trace still shows it; the ring does not), and `count`
+    once what it worked on is known."""
+
+    __slots__ = ('_prof', 'name', 'request_id', 'count', 'record',
+                 'dur_s', '_t0', '_inner_s', '_outer', '_span', '_count0')
+
+    def __init__(self, prof: 'TickProfiler', name: str,
+                 request_id: Optional[str], count: Optional[int]):
+        self._prof = prof
+        self.name = name
+        self.request_id = request_id
+        self.count = count
+        self.record = True
+        self.dur_s = 0.0
+
+    def __enter__(self):
+        prof = self._prof
+        args: Dict[str, Any] = {'n': prof.iteration}
+        if self.count is not None:
+            args['count'] = self.count
+        if self.request_id is not None:
+            args['request_id'] = self.request_id
+        self._count0 = self.count
+        self._span = prof._annotate(TRACE_PREFIX + self.name, **args)  # pylint: disable=protected-access
+        self._outer = prof._open  # pylint: disable=protected-access
+        prof._open = self  # pylint: disable=protected-access
+        self._inner_s = 0.0
+        self._span.__enter__()
+        self._t0 = prof._clock()  # pylint: disable=protected-access
+        return self
+
+    def __exit__(self, *exc):
+        prof = self._prof
+        now = prof._clock()  # pylint: disable=protected-access
+        if self.count != self._count0:
+            self._span.set_metadata(count=self.count)   # known at the end
+        self._span.__exit__(*exc)
+        whole = now - self._t0
+        self.dur_s = whole - self._inner_s
+        prof._open = self._outer  # pylint: disable=protected-access
+        if self._outer is not None:
+            self._outer._inner_s += whole  # pylint: disable=protected-access
+        if self.name == DEVICE_WAIT:
+            prof._wait_returned(now)  # pylint: disable=protected-access
+        if self.record:
+            prof._cur.append(  # pylint: disable=protected-access
+                [self.name, self._t0 - prof._t_tick0, self.dur_s,  # pylint: disable=protected-access
+                 self.count, self.request_id])
+        return False
+
+
+_NULL_PHASE = _NullPhase()
+
+
 class TickProfiler:
-    """Per-tick phase timings in a bounded ring.
+    """Per-iteration phase spans in a bounded ring, and on the
+    profiler trace.
 
     Single-writer (the engine worker thread) / multi-reader
-    (`snapshot()` from HTTP threads): the in-progress tick is thread
-    local to the writer; only the ring append and aggregate updates
-    take the lock.
+    (`snapshot()`, `tick_loop()` from HTTP threads): the iteration in
+    progress belongs to the writer; only the ring append and the
+    cumulative totals take the lock.
     """
 
     def __init__(self, *, ring_ticks: Optional[int] = None,
                  disabled: Optional[bool] = None,
                  memory_cb: Optional[Callable[[], Optional[int]]] = None,
-                 clock: Callable[[], float] = time.monotonic) -> None:
+                 clock: Callable[[], float] = time.monotonic,
+                 annotate: Optional[Callable[..., Any]] = None) -> None:
         self.disabled = (profiling_disabled() if disabled is None
                          else bool(disabled))
         self.ring_ticks = (ring_ticks_default() if ring_ticks is None
@@ -132,96 +226,145 @@ class TickProfiler:
         self._memory_cb = (_default_memory_cb if memory_cb is None
                            else memory_cb)
         self._mem_dead = False   # backend reported nothing; stop asking
+        if annotate is None and not self.disabled:
+            from jax.profiler import TraceAnnotation  # pylint: disable=import-outside-toplevel
+            annotate = TraceAnnotation
+        self._annotate = annotate
+        # Ring records carry both clocks: `t0_s` on `clock` and `ts`,
+        # the same instant as wall time through this one offset.
+        self._wall_offset = time.time() - clock()
         self._lock = threading.Lock()
         self._ring: collections.deque = collections.deque(
             maxlen=self.ring_ticks)
-        self._ticks = 0          # non-idle ticks retained (cumulative)
-        self._laps = 0           # recorded laps (cumulative)
+        self._ticks = 0          # iterations retained (cumulative)
+        self._laps = 0           # recorded phases (cumulative)
+        self._loop_s = 0.0       # their durations (cumulative)
         self._phase_totals: Dict[str, float] = {}
         self._phase_counts: Dict[str, int] = {}
+        self._starved_ticks = 0
+        self._starved_s = 0.0
         self._mem_watermark: Optional[int] = None
-        # Worker-thread state for the in-progress tick.
+        # Worker-thread state for the iteration in progress.
+        self.iteration = 0       # numbers every begin_tick, idle or not
         self._t_tick0 = 0.0
-        self._t_last = 0.0
-        self._cur: List[Tuple[str, float, float]] = []
-        # Self-overhead model: per-lap clock+bookkeeping cost measured
-        # once, multiplied by the cumulative lap count in snapshot().
-        self._per_lap_s = self._calibrate(clock)
+        self._cur: List[List[Any]] = []
+        self._open: Optional[_Phase] = None
+        self._tick_span: Any = None
+        # Starvation probe: when the last device-wait returned, the
+        # last interval between two returns of an unstarved iteration
+        # (the running tick length), and this iteration's findings.
+        self._t_wait_ret: Optional[float] = None
+        self._tick_len: Optional[float] = None
+        self._waited = False
+        self._starved_now: Optional[float] = None
+        # Self-overhead model: one phase's clock reads and annotation,
+        # measured once, multiplied by the cumulative phase count in
+        # snapshot().
+        self._per_lap_s = 0.0 if self.disabled else self._calibrate()
 
-    @staticmethod
-    def _calibrate(clock: Callable[[], float]) -> float:
+    def _calibrate(self) -> float:
         n = 256
         t0 = time.perf_counter()
         for _ in range(n):
-            clock()
-        per_read = (time.perf_counter() - t0) / n
-        # A lap is one clock read plus a tuple append; double the read
-        # cost is a deliberately pessimistic bound.
-        return per_read * 2.0
+            with self._annotate(TRACE_PREFIX + 'calibrate'):
+                self._clock()
+                self._clock()
+        # Twice the measured cost is a deliberately pessimistic bound
+        # on the list append and the bookkeeping around them.
+        return (time.perf_counter() - t0) / n * 2.0
 
     # ---------------------------------------------- worker-thread API
 
     def begin_tick(self) -> None:
+        self.iteration += 1
         if self.disabled:
             return
-        now = self._clock()
-        self._t_tick0 = now
-        self._t_last = now
         self._cur = []
+        self._open = None
+        self._waited = False
+        self._starved_now = None
+        self._tick_span = self._annotate(TRACE_PREFIX + 'tick',
+                                         n=self.iteration)
+        self._tick_span.__enter__()
+        self._t_tick0 = self._clock()
 
-    def lap(self, phase: str, record: bool = True) -> None:
-        """Close the interval since the previous lap.  `record=False`
-        advances the lap clock without attributing the interval (the
-        phase's machinery ran but did no work this tick)."""
+    def phase(self, name: str, *, request_id: Optional[str] = None,
+              count: Optional[int] = None):
+        """Open phase `name` of this iteration (a context manager).
+        `request_id` for a phase that works for one request (admit,
+        chunk and scatter do), `count` for what it works on (live
+        slots at dispatch, chunk width, pages scattered); a count
+        known only at the end is set on the phase before it closes."""
         if self.disabled:
-            return
-        now = self._clock()
-        if record:
-            self._cur.append((phase, self._t_last - self._t_tick0,
-                              now - self._t_last))
-        self._t_last = now
+            return _NULL_PHASE
+        return _Phase(self, name, request_id, count)
+
+    def probe_starved(self, inflight_finished) -> bool:
+        """Ask, right before an iteration's first dispatch, whether the
+        tick in flight has already finished (`is_ready()`, no
+        blocking): if so nothing is queued behind it and the device
+        has run dry.  Counted exactly in `starved_ticks`.  `starved_s`
+        gets an ESTIMATE of for how long: host time since the previous
+        device-wait returned (when the tick in flight started on the
+        device) less the running tick length, floored at 0 -- good to
+        the tick-to-tick variation, and 0 until one unstarved interval
+        has been seen."""
+        if self.disabled or not inflight_finished.is_ready():
+            return False
+        dry = 0.0
+        if self._t_wait_ret is not None and self._tick_len is not None:
+            dry = max(0.0, self._clock() - self._t_wait_ret -
+                      self._tick_len)
+        self._starved_now = dry
+        return True
+
+    def _wait_returned(self, now: float) -> None:
+        if self._t_wait_ret is not None and self._starved_now is None:
+            self._tick_len = now - self._t_wait_ret
+        self._t_wait_ret = now
+        self._waited = True
 
     def end_tick(self) -> None:
-        """Retain the tick if any phase recorded; idle spins of the
-        worker loop never enter the ring."""
+        """Retain the iteration if any phase recorded; idle spins of
+        the worker loop never enter the ring."""
         if self.disabled:
             return
+        now = self._clock()
+        self._tick_span.__exit__(None, None, None)
+        if not self._waited:
+            # No tick was in flight: the next device-wait return does
+            # not follow the last one by a tick.
+            self._t_wait_ret = None
         cur = self._cur
         self._cur = []
         if not cur:
             return
-        mem = self._sample_mem()
+        dur = now - self._t_tick0
         rec = {
-            'ts': time.time(),
-            'dur_s': self._t_last - self._t_tick0,
+            'n': self.iteration,
+            'ts': self._t_tick0 + self._wall_offset,
+            't0_s': self._t_tick0,
+            'dur_s': dur,
             'phases': cur,
-            'mem_bytes': mem,
         }
         with self._lock:
             self._ring.append(rec)
             self._ticks += 1
             self._laps += len(cur)
-            for name, _, dur in cur:
+            self._loop_s += dur
+            for name, _, seconds, _, _ in cur:
                 self._phase_totals[name] = (
-                    self._phase_totals.get(name, 0.0) + dur)
+                    self._phase_totals.get(name, 0.0) + seconds)
                 self._phase_counts[name] = (
                     self._phase_counts.get(name, 0) + 1)
-            if mem is not None and (self._mem_watermark is None or
-                                    mem > self._mem_watermark):
-                self._mem_watermark = mem
-        for name, _, dur in cur:
+            if self._starved_now is not None:
+                self._starved_ticks += 1
+                self._starved_s += self._starved_now
+        for name, _, seconds, _, _ in cur:
             obs = _PHASE_OBSERVERS.get(name)
             if obs is None:
                 obs = _M_PHASE.labels(phase=name)
-            obs.observe(dur)
-
-    def _sample_mem(self) -> Optional[int]:
-        if self._mem_dead:
-            return None
-        mem = self._memory_cb()
-        if mem is None:
-            self._mem_dead = True
-        return mem
+            obs.observe(seconds)
 
     # ------------------------------------------------- reader-side API
 
@@ -230,10 +373,35 @@ class TickProfiler:
         with self._lock:
             return self._ticks
 
+    def tick_loop(self) -> Dict[str, Any]:
+        """Cumulative totals since the engine started, all monotone (a
+        reader takes a difference): iterations that did work, their
+        summed durations, seconds by phase, and the starvation probe's
+        count and estimated seconds."""
+        with self._lock:
+            return {'iterations': self._ticks,
+                    'loop_s': self._loop_s,
+                    'phase_s': dict(self._phase_totals),
+                    'starved_ticks': self._starved_ticks,
+                    'starved_s': self._starved_s}
+
+    def _read_memory(self) -> Optional[int]:
+        """One read of the backend's watermark, on the reader's
+        thread (it only rises, so a per-iteration series said no
+        more)."""
+        if self._mem_dead:
+            return None
+        mem = self._memory_cb()
+        if mem is None:
+            self._mem_dead = True
+        return mem
+
     def snapshot(self) -> Dict[str, Any]:
         """JSON-ready view: ring, per-phase aggregates + quantiles over
-        the ring, memory watermark, and the profiler's own modeled
-        overhead (what the ≤3% budget is asserted against)."""
+        the ring, the cumulative totals, the device-memory watermark,
+        and the profiler's own modeled overhead (what the ≤3% budget is
+        asserted against)."""
+        mem = self._read_memory()
         with self._lock:
             ring = [dict(rec, phases=[list(p) for p in rec['phases']])
                     for rec in self._ring]
@@ -241,11 +409,14 @@ class TickProfiler:
             counts = dict(self._phase_counts)
             ticks = self._ticks
             laps = self._laps
+            if mem is not None and (self._mem_watermark is None or
+                                    mem > self._mem_watermark):
+                self._mem_watermark = mem
             watermark = self._mem_watermark
         durs_by_phase: Dict[str, List[float]] = {}
         for rec in ring:
-            for name, _, dur in rec['phases']:
-                durs_by_phase.setdefault(name, []).append(dur)
+            for entry in rec['phases']:
+                durs_by_phase.setdefault(entry[0], []).append(entry[2])
         phases: Dict[str, Dict[str, Any]] = {}
         for name, total in sorted(totals.items()):
             durs = sorted(durs_by_phase.get(name, ()))
@@ -257,16 +428,15 @@ class TickProfiler:
                 'p99_s': _quantile(durs, 0.99),
                 'max_s': durs[-1] if durs else None,
             }
-        last_mem = next((rec['mem_bytes'] for rec in reversed(ring)
-                         if rec.get('mem_bytes') is not None), None)
         return {
             'enabled': not self.disabled,
             'ring_ticks': self.ring_ticks,
             'ticks': ticks,
             'phases': phases,
             'ring': ring,
+            'tick_loop': self.tick_loop(),
             'device_memory': {'watermark_bytes': watermark,
-                              'last_bytes': last_mem},
+                              'last_bytes': mem},
             'overhead_s': laps * self._per_lap_s,
         }
 
@@ -415,23 +585,22 @@ def chrome_trace(snapshot: Dict[str, Any], *, pid: int = 0,
                  tid: int = 0) -> Dict[str, Any]:
     """Chrome trace-event JSON (`chrome://tracing` / Perfetto) from a
     profiler snapshot's ring: one complete ('X') event per recorded
-    phase, plus a device-memory counter track when watermarks exist."""
+    phase, carrying the iteration's number and the phase's count and
+    request id."""
     events: List[Dict[str, Any]] = []
     for rec in snapshot.get('ring', ()):
         base_us = float(rec.get('ts', 0.0)) * 1e6
         for entry in rec.get('phases', ()):
             name, rel, dur = entry[0], float(entry[1]), float(entry[2])
+            args = {'n': rec.get('n')}
+            if len(entry) > 3 and entry[3] is not None:
+                args['count'] = entry[3]
+            if len(entry) > 4 and entry[4] is not None:
+                args['request_id'] = entry[4]
             events.append({
                 'name': name, 'cat': 'engine-tick', 'ph': 'X',
                 'ts': base_us + rel * 1e6,
                 'dur': max(dur * 1e6, 0.01),
-                'pid': pid, 'tid': tid, 'args': {},
-            })
-        mem = rec.get('mem_bytes')
-        if mem is not None:
-            events.append({
-                'name': 'device_memory', 'cat': 'engine-tick',
-                'ph': 'C', 'ts': base_us, 'pid': pid, 'tid': tid,
-                'args': {'bytes_in_use': int(mem)},
+                'pid': pid, 'tid': tid, 'args': args,
             })
     return {'traceEvents': events, 'displayTimeUnit': 'ms'}

@@ -7,8 +7,16 @@ the batching engine records a `RequestSpan` per request with the
 phase breakdown a serving SLO decomposes into:
 
     queue_wait  — submit() until the engine pops the request
-    prefill     — chunked prompt prefill (count + total seconds)
+    prefill_wall — admission until the slot goes live (seed, chunks,
+                  scatter and every tick that ran between them), with
+                  the engine iterations it took
+    first_token_wait — slot live until the first generated token (the
+                  tick that carries it and the one-behind read)
+    prefill     — chunk count + the HOST'S DISPATCH seconds of those
+                  chunks (the dispatches are asynchronous: this is not
+                  how long prefill took; prefill_wall is)
     ttft        — submit() until the first generated token
+                  (= queue_wait + prefill_wall + first_token_wait)
     itl         — inter-token gaps during decode (count/mean/max)
     total       — submit() until the request finished
 
@@ -86,7 +94,19 @@ class RequestSpan:
         self.submit_wall = time.time()
         self._submit = time.monotonic()
         self.queue_wait_s: Optional[float] = None
+        self._admitted: Optional[float] = None
+        # Admission until the slot went live, the engine iterations
+        # that took (a prefix hit with a one-chunk tail: two, one for
+        # the seed alone), and slot live until the first token.  With
+        # queue_wait_s they add up to ttft_s.
+        self._admit_iteration = 0
+        self._live: Optional[float] = None
+        self.prefill_wall_s: Optional[float] = None
+        self.prefill_iterations: Optional[int] = None
+        self.first_token_wait_s: Optional[float] = None
         self.prefill_chunks = 0
+        # Host seconds spent DISPATCHING the prefill programs (they
+        # run asynchronously): not prefill time, see prefill_wall_s.
         self.prefill_s = 0.0
         # Prompt pages adopted from the engine's prefix cache instead
         # of prefilled (paged-KV engines; 0 = cold / dense engine).
@@ -132,13 +152,29 @@ class RequestSpan:
 
     # ----------------------------------------------- recording (engine)
 
-    def mark_admitted(self) -> None:
+    def mark_admitted(self, iteration: int = 0) -> None:
+        """The engine popped the request, in loop iteration
+        `iteration` (the tick profiler's number)."""
         if self.queue_wait_s is None:
-            self.queue_wait_s = time.monotonic() - self._submit
+            self._admitted = time.monotonic()
+            self._admit_iteration = iteration
+            self.queue_wait_s = self._admitted - self._submit
 
     def mark_prefill_chunk(self, duration_s: float) -> None:
+        """One prefill program dispatched; `duration_s` is what the
+        dispatch took on the host."""
         self.prefill_chunks += 1
         self.prefill_s += duration_s
+
+    def mark_live(self, iteration: int = 0,
+                  now: Optional[float] = None) -> None:
+        """The slot joined the decode batch (or, where the first token
+        comes out of the prefill itself, that token arrived)."""
+        if self._live is not None or self._admitted is None:
+            return
+        self._live = time.monotonic() if now is None else now
+        self.prefill_wall_s = self._live - self._admitted
+        self.prefill_iterations = iteration - self._admit_iteration + 1
 
     def mark_token(self) -> Optional[float]:
         """Record one generated token; returns the inter-token gap in
@@ -148,6 +184,9 @@ class RequestSpan:
         gap: Optional[float] = None
         if self.ttft_s is None:
             self.ttft_s = now - self._submit
+            if self._admitted is not None:
+                self.mark_live(self._admit_iteration, now)
+                self.first_token_wait_s = now - self._live
         elif self._last_token is not None:
             gap = now - self._last_token
             self.itl_count += 1
@@ -178,6 +217,9 @@ class RequestSpan:
             'queue_wait_ms': ms(self.queue_wait_s),
             'prefill_chunks': self.prefill_chunks,
             'prefill_ms': ms(self.prefill_s),
+            'prefill_wall_ms': ms(self.prefill_wall_s),
+            'prefill_iterations': self.prefill_iterations,
+            'first_token_wait_ms': ms(self.first_token_wait_s),
             'prefix_hit_pages': self.prefix_hit_pages,
             'ttft_ms': ms(self.ttft_s),
             'itl_mean_ms': ms(itl_mean),
@@ -210,6 +252,13 @@ class RequestSpan:
                 self.spec_steps, 3)
         return out
 
+    def _prefill_bar_s(self) -> float:
+        """Length of the prefill bar: admission to slot live where the
+        engine marked it, else the chunks' dispatch seconds."""
+        if self.prefill_wall_s is not None:
+            return self.prefill_wall_s if self.prefill_chunks else 0.0
+        return self.prefill_s
+
     def segment(self, identity: Optional[Dict[str, Any]] = None
                 ) -> Dict[str, Any]:
         """This span as a trace segment: the cross-process exchange
@@ -229,11 +278,11 @@ class RequestSpan:
             phases.append({'name': 'queue', 'start': wall0,
                            'duration_ms': round(
                                self.queue_wait_s * 1e3, 3)})
-        if self.prefill_s:
+        if self._prefill_bar_s():
             phases.append({'name': 'prefill',
                            'start': wall0 + (self.queue_wait_s or 0.0),
-                           'duration_ms': round(self.prefill_s * 1e3,
-                                                3)})
+                           'duration_ms': round(
+                               self._prefill_bar_s() * 1e3, 3)})
         if self.ttft_s is not None and self.total_s is not None:
             phases.append({'name': 'decode',
                            'start': wall0 + self.ttft_s,
@@ -255,13 +304,11 @@ class RequestSpan:
             timeline.add_complete_event(f'{base}/queue', wall0,
                                         self.queue_wait_s)
         if self.ttft_s is not None:
-            # Prefill runs between admission and first token; the span
-            # bar shows its aggregate (chunks interleave with ticks, so
-            # a contiguous bar is an approximation labeled as such).
-            if self.prefill_s:
+            if self._prefill_bar_s():
                 timeline.add_complete_event(
                     f'{base}/prefill',
-                    wall0 + (self.queue_wait_s or 0.0), self.prefill_s,
+                    wall0 + (self.queue_wait_s or 0.0),
+                    self._prefill_bar_s(),
                     args={'chunks': self.prefill_chunks})
             decode_s = (self.total_s or self.ttft_s) - self.ttft_s
             timeline.add_complete_event(

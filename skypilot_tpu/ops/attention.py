@@ -284,6 +284,7 @@ def _flash_fwd_pallas(q, k, v, *, causal: bool, sm_scale: float,
             2 * (k_len + k_pad) * d * k.dtype.itemsize +
             2 * block_q * d * q.dtype.itemsize + block_q * _LANES * 4),
         interpret=interpret_mode(),
+        name='flash_fwd',
     )(qp, kp, vp)
     return (out.reshape(b, h, q_len + q_pad, d)[:, :, :q_len],
             lse[:, :, 0].reshape(b, h, q_len + q_pad)[:, :, :q_len])
@@ -464,6 +465,7 @@ def _flash_bwd_pallas(q, k, v, out, lse, g, g_lse, *, causal: bool,
             3 * block_q * d * q.dtype.itemsize +
             2 * block_q * _LANES * 4),
         interpret=interpret_mode(),
+        name='flash_bwd_dq',
     )(qp, kp, vp, dop, lsep, deltap)
 
     kd_in_spec = pl.BlockSpec((1, block_k, d),
@@ -492,6 +494,7 @@ def _flash_bwd_pallas(q, k, v, out, lse, g, g_lse, *, causal: bool,
             2 * qlp * d * q.dtype.itemsize + 2 * qlp * _LANES * 4 +
             4 * block_k * d * k.dtype.itemsize),
         interpret=interpret_mode(),
+        name='flash_bwd_dkv',
     )(qp, kp, vp, dop, lsep, deltap)
 
     dq = dq.reshape(b, h, qlp, d)[:, :, :q_len]

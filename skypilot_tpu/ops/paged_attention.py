@@ -204,6 +204,7 @@ def _paged_attention_pallas(q, k_leaf, v_leaf, tables, lengths, *,
             scratch_shapes=scratch),
         out_shape=jax.ShapeDtypeStruct((b, h_kv, r, d), q.dtype),
         interpret=interpret_mode(),
+        name='paged_decode_attention',
     )(tables, lengths, *operands)
     return out.reshape(b, h_kv, rep, s_q, d).reshape(b, h_q, s_q, d)
 

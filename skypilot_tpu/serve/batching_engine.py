@@ -108,7 +108,6 @@ against it.
 from __future__ import annotations
 
 import collections
-import functools
 import os
 import threading
 import time
@@ -341,20 +340,25 @@ class ContinuousBatchingEngine:
                 self._state, sharding_lib.engine_state_sharding(mesh))
         self._tokens = jnp.zeros((slots, 1), jnp.int32)  # legacy loop
 
+        # Every jitted entry is a function under its own name
+        # (`decode.bind`, never a partial or a lambda): the name is the
+        # program's in a device trace (`jit_paged_engine_step`), and the
+        # benchmark's readers find the tick and the prefill programs by
+        # it.
         if self._kv is not None:
             self._step = jax.jit(
-                functools.partial(decode.paged_engine_step, cfg,
-                                  max_top_k=self.max_top_k,
-                                  kernel=self.decode_kernel, mesh=mesh),
+                decode.bind(decode.paged_engine_step, cfg,
+                            max_top_k=self.max_top_k,
+                            kernel=self.decode_kernel, mesh=mesh),
                 donate_argnums=(2,))
             # Speculative verify tick: same donated-pool discipline as
             # the plain tick, plus the [slots, k] draft batch; the
             # kernel choice is a closure constant, so both ticks hit
             # the same attention path.
             self._spec_step = jax.jit(
-                functools.partial(decode.paged_spec_engine_step, cfg,
-                                  max_top_k=self.max_top_k,
-                                  kernel=self.decode_kernel, mesh=mesh),
+                decode.bind(decode.paged_spec_engine_step, cfg,
+                            max_top_k=self.max_top_k,
+                            kernel=self.decode_kernel, mesh=mesh),
                 donate_argnums=(2,))
             # Block-table surgery: donated so XLA patches the pool's
             # tiny int32 tables in place.
@@ -372,7 +376,7 @@ class ContinuousBatchingEngine:
             # Prefix-hit seeding: cached pages -> the leading positions
             # of a fresh private cache (pool read-only, NOT donated).
             self._seed_private = jax.jit(
-                functools.partial(decode.paged_seed_private, cfg),
+                decode.bind(decode.paged_seed_private, cfg),
                 static_argnames=('priv_len',))
             # KV handoff adoption: imported page contents -> pool pages
             # (quantizing when the pool is int8); pool donated.  The
@@ -384,33 +388,28 @@ class ContinuousBatchingEngine:
                                           donate_argnums=(0,))
         else:
             self._step = jax.jit(
-                functools.partial(decode.engine_step, cfg,
-                                  max_top_k=self.max_top_k),
+                decode.bind(decode.engine_step, cfg,
+                            max_top_k=self.max_top_k),
                 donate_argnums=(2,))
-        self._legacy_step = jax.jit(
-            lambda p, t, c: decode.batched_step(cfg, p, t, c),
-            donate_argnums=(2,))
+        self._legacy_step = jax.jit(decode.bind(decode.batched_step, cfg),
+                                    donate_argnums=(2,))
         # Jitted prefill: one compile per prompt-length bucket (the
         # whole point of the bucket padding), not eager per-op dispatch
         # per admission.
         self._prefill = jax.jit(
-            lambda params, toks: decode.prefill(cfg, params, toks,
-                                                max_len=max_len,
-                                                mesh=mesh))
+            decode.bind(decode.prefill, cfg, max_len=max_len, mesh=mesh))
         # Chunk continuation at index > 0 (masked per-position causal
         # path): one compile per chunk width; the private prefill cache
         # is donated so XLA extends it in place.
         self._prefill_chunk = jax.jit(
-            lambda params, toks, cache: decode.prefill_chunk(
-                cfg, params, toks, cache),
-            donate_argnums=(2,))
+            decode.bind(decode.prefill_chunk, cfg), donate_argnums=(2,))
         # Jitted in-place slot adoption (dense): eager
         # dynamic_update_slice would materialize two full copies of the
         # pool cache per admission; donation lets XLA update in place.
         self._insert = jax.jit(decode.insert_prefill,
                                donate_argnums=(0,))
         # ---- continuous profiling plane (observability/profiling.py).
-        # Tick-phase ring + recompile sentinel; both collapse to no-ops
+        # Tick-phase spans + recompile sentinel; both collapse to no-ops
         # under SKYTPU_PROFILE_DISABLE.  Every resolved jit entry above
         # (incl. the Pallas kernel path, a closure constant of _step)
         # gets the sentinel's O(1) cache-size probe so a steady-state
@@ -962,6 +961,11 @@ class ContinuousBatchingEngine:
                 stats['pages_exhausted_deferrals'] = self._page_deferrals
         rate = round(self._decode_rate(), 3)
         stats['decode_tokens_per_s'] = rate
+        # The worker loop's cumulative totals (iterations, seconds by
+        # phase, the starvation probe): monotone, so a reader takes a
+        # difference.  `starved_s` is an estimate; the ring stays in
+        # profile().
+        stats['tick_loop'] = self._profiler.tick_loop()
         # Per-request phase traces (newest first) — the "why was THIS
         # request slow" answer, keyed by X-SkyTPU-Request-Id.
         stats['recent_spans'] = self._spans.recent()
@@ -979,8 +983,9 @@ class ContinuousBatchingEngine:
 
     def profile(self) -> Dict[str, Any]:
         """Continuous-profiling snapshot (what `GET /profile` serves):
-        the tick-phase ring with per-phase quantiles, device-memory
-        watermarks, the profiler's modeled self-overhead, and the
+        the tick-phase ring with per-phase quantiles, the cumulative
+        `tick_loop` totals, the device-memory watermark (read now, on
+        this thread), the profiler's modeled self-overhead, and the
         recompile sentinel's per-jit-entry compile counts."""
         snap = self._profiler.snapshot()
         snap['recompiles'] = self._sentinel.snapshot()
@@ -1088,7 +1093,7 @@ class ContinuousBatchingEngine:
         plan = self._plan_pages(request)   # may raise PagesExhausted
         if plan is not None:
             self._kv.commit(slot_id, plan)
-        self._queue.record_admission(request)
+        self._queue.record_admission(request, self._profiler.iteration)
         if self.cfg.n_experts > 0 and n > 0:
             # MoE: the capacity dispatch couples EVERY prompt token, so
             # pad tokens, an n-1/last-token split, and chunk boundaries
@@ -1097,11 +1102,11 @@ class ContinuousBatchingEngine:
             # The first generated token therefore comes from the
             # prefill logits (one compile per distinct MoE prompt
             # length), selected with the same key chain a tick uses.
-            t_prefill = time.perf_counter()
+            t_prefill = time.monotonic()
             logits, pre = self._prefill(
                 self.params, jnp.asarray([prompt], jnp.int32))
             request.span.mark_prefill_chunk(
-                time.perf_counter() - t_prefill)
+                time.monotonic() - t_prefill)
             if plan is not None:
                 import numpy as np  # pylint: disable=import-outside-toplevel
                 n_pages = -(-n // self._kv.page_size)
@@ -1189,73 +1194,82 @@ class ContinuousBatchingEngine:
                 self._release_slot_pages(pending.slot_id)
             return True  # pending is finished (slot freed)
         import numpy as np  # pylint: disable=import-outside-toplevel
-        t_chunk0 = time.perf_counter()
         n_target = pending.n_target
         # Fractional-role clamp: a decode-heavy budget shrinks the
         # per-tick piece (floor 1 — prefill slows, never stalls).
         chunk = self._queue.prefill_tokens_per_tick(self.prefill_chunk)
         plan = pending.plan
         reuse_tokens = plan.n_reuse_tokens if plan is not None else 0
-        if pending.cache is None and reuse_tokens > 0:
-            # Prefix hit: seed the private cache from the cached pages
-            # — positions [0, reuse_tokens) appear exactly as if they
-            # had been prefilled here; only the tail chunks run.
-            pending.cache = self._seed_private(
-                self._cache,
-                np.asarray(plan.reuse_pages, np.int32),
-                priv_len=self.max_len)
-            pending.consumed = reuse_tokens
-            request.span.mark_prefill_chunk(
-                time.perf_counter() - t_chunk0)
+        seeding = pending.cache is None and reuse_tokens > 0
+        # The phase and `span.prefill_s` time the host's DISPATCH of
+        # the program (asynchronous), not the program.
+        with self._profiler.phase(
+                'prefill-chunk', request_id=request.request_id) as phase:
+            t_chunk0 = time.monotonic()
+            if seeding:
+                # Prefix hit: seed the private cache from the cached
+                # pages — positions [0, reuse_tokens) appear exactly as
+                # if they had been prefilled here; only the tail chunks
+                # run (count 0: no prompt token is computed).
+                pending.cache = self._seed_private(
+                    self._cache,
+                    np.asarray(plan.reuse_pages, np.int32),
+                    priv_len=self.max_len)
+                pending.consumed = reuse_tokens
+                phase.count = 0
+            elif pending.cache is None:
+                # Chunk 0: flash prefill from index 0 into a fresh
+                # private cache.  Width = the bucket of min(n_target,
+                # chunk) so short prompts keep today's bucket-bounded
+                # compile count; pad keys land at positions >= the real
+                # length where the causal mask hides them (and the
+                # first one is overwritten by the real last token's
+                # step).  Padding is staged in NUMPY: eager
+                # `.at[:n].set` would compile a tiny scatter per
+                # distinct prompt length, right on the admission path.
+                take = min(n_target, chunk)
+                bucket = min(self._bucket(take), self.max_len)
+                padded = np.zeros((1, bucket), np.int32)
+                padded[0, :take] = request.prompt_ids[:take]
+                _, pending.cache = self._prefill(self.params,
+                                                 jnp.asarray(padded))
+                # The padded flash cache advanced index to `bucket`;
+                # chunk continuations must write at the REAL consumed
+                # length.
+                pending.cache = dict(pending.cache,
+                                     index=jnp.asarray(take, jnp.int32))
+                pending.consumed = take
+                phase.count = bucket
+            else:
+                # Chunk i>0: masked per-position-causal continuation at
+                # index = consumed.  Width is the POWER-OF-TWO BUCKET
+                # of the remaining tail capped at `chunk` (bounded
+                # compile count) AND at max_len - start: the write must
+                # fit the private cache — a wider piece would make
+                # dynamic_update_slice clamp its start index and
+                # silently overwrite already-prefilled positions
+                # (reachable when chunk does not divide max_len, and on
+                # every prefix-hit seed whose tail is shorter than one
+                # chunk).  Pad positions are beyond every real query's
+                # causal horizon and each is overwritten by the decode
+                # step that reaches it.
+                start = pending.consumed
+                take = min(n_target - start, chunk)
+                width = min(self._bucket(take), chunk,
+                            self.max_len - start)
+                piece = np.zeros((1, width), np.int32)
+                piece[0, :take] = request.prompt_ids[start:start + take]
+                _, pending.cache = self._prefill_chunk(
+                    self.params, jnp.asarray(piece), pending.cache)
+                pending.cache = dict(
+                    pending.cache,
+                    index=jnp.asarray(start + take, jnp.int32))
+                pending.consumed = start + take
+                phase.count = width
+            request.span.mark_prefill_chunk(time.monotonic() - t_chunk0)
+        if seeding:
             return False
-        if pending.cache is None:
-            # Chunk 0: flash prefill from index 0 into a fresh private
-            # cache.  Width = the bucket of min(n_target, chunk) so
-            # short prompts keep today's bucket-bounded compile count;
-            # pad keys land at positions >= the real length where the
-            # causal mask hides them (and the first one is overwritten
-            # by the real last token's step).  Padding is staged in
-            # NUMPY: eager `.at[:n].set` would compile a tiny scatter
-            # per distinct prompt length, right on the admission path.
-            take = min(n_target, chunk)
-            bucket = min(self._bucket(take), self.max_len)
-            padded = np.zeros((1, bucket), np.int32)
-            padded[0, :take] = request.prompt_ids[:take]
-            _, pending.cache = self._prefill(self.params,
-                                             jnp.asarray(padded))
-            # The padded flash cache advanced index to `bucket`; chunk
-            # continuations must write at the REAL consumed length.
-            pending.cache = dict(pending.cache,
-                                 index=jnp.asarray(take, jnp.int32))
-            pending.consumed = take
-        else:
-            # Chunk i>0: masked per-position-causal continuation at
-            # index = consumed.  Width is the POWER-OF-TWO BUCKET of
-            # the remaining tail capped at `chunk` (bounded compile
-            # count) AND at max_len - start: the write must fit the
-            # private cache — a wider piece would make
-            # dynamic_update_slice clamp its start index and silently
-            # overwrite already-prefilled positions (reachable when
-            # chunk does not divide max_len, and on every prefix-hit
-            # seed whose tail is shorter than one chunk).  Pad
-            # positions are beyond every real query's causal horizon
-            # and each is overwritten by the decode step that reaches
-            # it.
-            start = pending.consumed
-            take = min(n_target - start, chunk)
-            width = min(self._bucket(take), chunk,
-                        self.max_len - start)
-            piece = np.zeros((1, width), np.int32)
-            piece[0, :take] = request.prompt_ids[start:start + take]
-            _, pending.cache = self._prefill_chunk(
-                self.params, jnp.asarray(piece), pending.cache)
-            pending.cache = dict(
-                pending.cache,
-                index=jnp.asarray(start + take, jnp.int32))
-            pending.consumed = start + take
-        request.span.mark_prefill_chunk(time.perf_counter() - t_chunk0)
         self._record_chunk()
-        self._profiler.lap('prefill-chunk')
         if pending.consumed < n_target:
             return False
         return self._finish_prefill(pending)
@@ -1270,34 +1284,37 @@ class ContinuousBatchingEngine:
         request = pending.request
         n_target = pending.n_target
         plan = pending.plan
-        if plan is not None:
-            # Scatter only the FRESH pages (the reused prefix already
-            # lives in the pool — rewriting pages another slot shares,
-            # even with identical values, is what this skips), then
-            # point the block table at the full row and publish the
-            # fresh full pages for the next prefix hit.
-            ps = self._kv.page_size
-            r = len(plan.reuse_pages)
-            n_prompt_pages = -(-n_target // ps)
-            self._cache = self._insert_pages(
-                self._cache, pending.cache,
-                np.asarray(plan.row[r:n_prompt_pages], np.int32),
-                first_page=r)
-            pending.cache = None   # donated to the scatter
-            self._cache = self._admit_paged(
-                self._cache, pending.slot_id,
-                self._pad_row(plan.row), n_target)
-            self._kv.register_prefix(plan)
-        else:
-            self._cache = self._insert(self._cache, pending.slot_id,
-                                       pending.cache, n_target)
-        self._activate(pending.slot_id, request,
-                       int(request.prompt_ids[-1]), n_target,
-                       remaining=request.max_new_tokens,
-                       key=self._jax.random.PRNGKey(request.seed))
         # Cache adoption (page scatter / dense insert) + activation:
         # its own phase so prefill compute and pool surgery separate.
-        self._profiler.lap('page-scatter')
+        with self._profiler.phase('page-scatter',
+                                  request_id=request.request_id) as phase:
+            if plan is not None:
+                # Scatter only the FRESH pages (the reused prefix
+                # already lives in the pool — rewriting pages another
+                # slot shares, even with identical values, is what this
+                # skips), then point the block table at the full row
+                # and publish the fresh full pages for the next prefix
+                # hit.
+                ps = self._kv.page_size
+                r = len(plan.reuse_pages)
+                n_prompt_pages = -(-n_target // ps)
+                phase.count = n_prompt_pages - r
+                self._cache = self._insert_pages(
+                    self._cache, pending.cache,
+                    np.asarray(plan.row[r:n_prompt_pages], np.int32),
+                    first_page=r)
+                pending.cache = None   # donated to the scatter
+                self._cache = self._admit_paged(
+                    self._cache, pending.slot_id,
+                    self._pad_row(plan.row), n_target)
+                self._kv.register_prefix(plan)
+            else:
+                self._cache = self._insert(self._cache, pending.slot_id,
+                                           pending.cache, n_target)
+            self._activate(pending.slot_id, request,
+                           int(request.prompt_ids[-1]), n_target,
+                           remaining=request.max_new_tokens,
+                           key=self._jax.random.PRNGKey(request.seed))
         return True
 
     def _activate(self, slot_id: int, request: scheduler.Request,
@@ -1315,6 +1332,7 @@ class ContinuousBatchingEngine:
         self._state = self._sampler.admit(
             self._state, slot_id, token, remaining, request.stop_ids,
             key, request.temperature, request.top_k)
+        request.span.mark_live(self._profiler.iteration)
 
     def _deactivate(self, slot_ids: List[int]) -> None:
         """Host-forced slot shutdown (cancel): flip active off so the
@@ -1364,63 +1382,68 @@ class ContinuousBatchingEngine:
         decide how many land per dispatch.
         """
         import numpy as np  # pylint: disable=import-outside-toplevel
+        prof = self._profiler
         k = self.spec_tokens
         n_live = len(live)
-        drafts = np.zeros((len(self._slots), k), np.int32)
-        for slot_id in live:
-            drafter = self._slots[slot_id].drafter
-            if drafter is not None:
-                drafts[slot_id] = drafter.propose(k)
-        drafts_dev = self._jnp.asarray(drafts)
-        if self._mesh is not None:
-            from skypilot_tpu.parallel import sharding as sharding_lib  # pylint: disable=import-outside-toplevel
-            drafts_dev = self._jax.device_put(
-                drafts_dev,
-                sharding_lib.spec_drafts_sharding(self._mesh))
-        self._state, self._cache, finished, toks_d, counts_d = (
-            self._dispatch_spec_step(drafts_dev))
-        toks = np.asarray(toks_d)
-        counts = np.asarray(counts_d)
-        fins = np.asarray(finished)
-        pushed = 0
-        accepted = 0
-        slot_ticks = 0
-        for slot_id, request in list(live.items()):
-            if request.done.is_set():
-                continue
-            slot_ticks += 1
-            c = int(counts[slot_id])
-            emitted = [int(t) for t in toks[slot_id, :c]]
-            drafter = self._slots[slot_id].drafter
-            if drafter is not None and emitted:
-                drafter.observe(emitted)
-            for token in emitted:
-                request._push(token)  # pylint: disable=protected-access
-            pushed += c
-            accepted += max(c - 1, 0)
-            span = request.span
-            span.spec_steps += 1
-            span.spec_proposed += k
-            span.spec_accepted += max(c - 1, 0)
-            _M_SPEC_ACCEPT_LEN.observe(float(max(c, 1)))
-            if fins[slot_id]:
-                live.pop(slot_id, None)
-                self._slots[slot_id].request = None
-                self._slots[slot_id].drafter = None
-                self._release_slot_pages(slot_id)
-                request._finish()  # pylint: disable=protected-access
-        if pushed:
-            self._record_tokens(pushed)
-        with self._metrics_lock:
-            self._ticks += 1
-            self._spec_ticks += 1
-            self._spec_slot_ticks += slot_ticks
-            self._spec_proposed += k * n_live
-            self._spec_accepted += accepted
-        _M_TICKS.inc()
-        _M_SPEC_PROPOSED.inc(k * n_live)
-        _M_SPEC_ACCEPTED.inc(accepted)
-        _M_BUSY_SLOTS.set(sum(1 for s in self._slots if s.active))
+        with prof.phase('spec-verify', count=n_live):
+            drafts = np.zeros((len(self._slots), k), np.int32)
+            for slot_id in live:
+                drafter = self._slots[slot_id].drafter
+                if drafter is not None:
+                    drafts[slot_id] = drafter.propose(k)
+            drafts_dev = self._jnp.asarray(drafts)
+            if self._mesh is not None:
+                from skypilot_tpu.parallel import sharding as sharding_lib  # pylint: disable=import-outside-toplevel
+                drafts_dev = self._jax.device_put(
+                    drafts_dev,
+                    sharding_lib.spec_drafts_sharding(self._mesh))
+            self._state, self._cache, finished, toks_d, counts_d = (
+                self._dispatch_spec_step(drafts_dev))
+        with prof.phase('device-wait', count=n_live):
+            toks = np.asarray(toks_d)
+            counts = np.asarray(counts_d)
+            fins = np.asarray(finished)
+        with prof.phase('sample') as phase:
+            pushed = 0
+            accepted = 0
+            slot_ticks = 0
+            for slot_id, request in list(live.items()):
+                if request.done.is_set():
+                    continue
+                slot_ticks += 1
+                c = int(counts[slot_id])
+                emitted = [int(t) for t in toks[slot_id, :c]]
+                drafter = self._slots[slot_id].drafter
+                if drafter is not None and emitted:
+                    drafter.observe(emitted)
+                for token in emitted:
+                    request._push(token)  # pylint: disable=protected-access
+                pushed += c
+                accepted += max(c - 1, 0)
+                span = request.span
+                span.spec_steps += 1
+                span.spec_proposed += k
+                span.spec_accepted += max(c - 1, 0)
+                _M_SPEC_ACCEPT_LEN.observe(float(max(c, 1)))
+                if fins[slot_id]:
+                    live.pop(slot_id, None)
+                    self._slots[slot_id].request = None
+                    self._slots[slot_id].drafter = None
+                    self._release_slot_pages(slot_id)
+                    request._finish()  # pylint: disable=protected-access
+            if pushed:
+                self._record_tokens(pushed)
+            with self._metrics_lock:
+                self._ticks += 1
+                self._spec_ticks += 1
+                self._spec_slot_ticks += slot_ticks
+                self._spec_proposed += k * n_live
+                self._spec_accepted += accepted
+            _M_TICKS.inc()
+            _M_SPEC_PROPOSED.inc(k * n_live)
+            _M_SPEC_ACCEPTED.inc(accepted)
+            _M_BUSY_SLOTS.set(sum(1 for s in self._slots if s.active))
+            phase.count = pushed
 
     # ------------------------------------------------- pipelined worker
 
@@ -1463,38 +1486,41 @@ class ContinuousBatchingEngine:
                 self._queue.expire_stale()
                 # Host ops (KV handoff imports) run between ticks: they
                 # mutate self._cache, which only this thread owns.
-                ran_ops = self._drain_host_ops()
-                prof.lap('handoff', record=bool(ran_ops))
+                if self._host_ops:
+                    with prof.phase('handoff') as phase:
+                        phase.count = self._drain_host_ops()
                 # Cancelled or deadline-expired live requests: freeze
                 # their slots on device before the next dispatch, free
                 # them (and their KV pages) for admission.  Deadline
                 # reaps finish with DeadlineExceeded so the HTTP front
-                # answers 504 instead of a silent truncation.
+                # answers 504 instead of a silent truncation.  (Making
+                # room is part of admitting: the phase is `admit`.)
                 now = time.monotonic()
                 reaped = [(i, r.cancelled) for i, r in live.items()
                           if r.cancelled or r.deadline_exceeded(now)]
                 if reaped:
-                    self._deactivate([i for i, _ in reaped])
-                    for i, was_cancel in reaped:
-                        request = live.pop(i)
-                        self._slots[i].request = None
-                        self._slots[i].drafter = None
-                        self._release_slot_pages(i)
-                        if was_cancel:
-                            request._finish()  # pylint: disable=protected-access
-                        else:
-                            _M_DEADLINE_REAPED.inc()
-                            request._finish(  # pylint: disable=protected-access
-                                scheduler.DeadlineExceeded(
-                                    'request deadline passed '
-                                    'mid-generation'))
-                # Admissions: hand free slots to queued requests.  The
-                # prompt's chunks run interleaved with ticks below.
-                # Page-pool exhaustion DEFERS (the request goes back to
-                # the queue head and waits for pages to free or its
-                # TTL) — it must never fail the engine.
+                    with prof.phase('admit', count=len(reaped)):
+                        self._deactivate([i for i, _ in reaped])
+                        for i, was_cancel in reaped:
+                            request = live.pop(i)
+                            self._slots[i].request = None
+                            self._slots[i].drafter = None
+                            self._release_slot_pages(i)
+                            if was_cancel:
+                                request._finish()  # pylint: disable=protected-access
+                            else:
+                                _M_DEADLINE_REAPED.inc()
+                                request._finish(  # pylint: disable=protected-access
+                                    scheduler.DeadlineExceeded(
+                                        'request deadline passed '
+                                        'mid-generation'))
+                # Admissions: hand free slots to queued requests, one
+                # `admit` phase each.  The prompt's chunks run
+                # interleaved with ticks below.  Page-pool exhaustion
+                # DEFERS (the request goes back to the queue head and
+                # waits for pages to free or its TTL) — it must never
+                # fail the engine.
                 deferred = False
-                admitted = False
                 free = [i for i, s in enumerate(self._slots)
                         if not s.active]
                 occupied = len(self._slots) - len(free)
@@ -1508,16 +1534,18 @@ class ContinuousBatchingEngine:
                     request = self._queue.pop()
                     if request is None:
                         break
-                    admitted = True
                     try:
                         # Bind request identity so engine-worker log
                         # lines land in the structured ring under the
                         # request that triggered them (the worker
                         # thread never sees the HTTP front's context).
-                        with logs_lib.bind(
-                                request_id=request.request_id,
-                                **(getattr(self, 'log_identity', None)
-                                   or {})):
+                        with prof.phase('admit',
+                                        request_id=request.request_id,
+                                        count=len(request.prompt_ids)), \
+                                logs_lib.bind(
+                                    request_id=request.request_id,
+                                    **(getattr(self, 'log_identity',
+                                               None) or {})):
                             pending = self._start_admission(
                                 slot_id, request)
                     except cache_manager.PagesExhausted:
@@ -1532,12 +1560,11 @@ class ContinuousBatchingEngine:
                     elif self._slots[slot_id].request is not None:
                         live[slot_id] = request
                         occupied += 1
-                # The admit phase is everything since the handoff lap:
-                # stale-expiry, reaps, and the admission loop (minus
-                # any page-scatter laps a full-prefix admission took
-                # inside _finish_prefill — laps are exclusive).
-                prof.lap('admit',
-                         record=bool(admitted or deferred or reaped))
+                # Starvation probe, right before this iteration's first
+                # dispatch: a tick in flight that has already finished
+                # left the device with nothing queued.
+                if inflight is not None and (pending_prefills or live):
+                    prof.probe_starved(inflight[1])
                 # At most ONE prefill chunk between ticks — the bound
                 # on the ITL stall an admission can impose.
                 if pending_prefills:
@@ -1550,44 +1577,49 @@ class ContinuousBatchingEngine:
                         pending_prefills.append(pending)
                 # Dispatch tick t+1 BEFORE reading tick t: the host's
                 # token fetch and stream bookkeeping below overlap the
-                # device's compute of this new step.
+                # device's compute of this new step.  `decode-step`
+                # times that (asynchronous) dispatch, not the tick.
                 dispatched = None
                 if live and self.spec_tokens:
                     # Speculative mode: synchronous multi-token verify
                     # ticks (see _spec_tick); `inflight` stays empty.
                     self._spec_tick(live)
-                    prof.lap('spec-verify')
                 elif live:
-                    self._state, self._cache, finished = (
-                        self._dispatch_step())
+                    with prof.phase('decode-step', count=len(live)):
+                        self._state, self._cache, finished = (
+                            self._dispatch_step())
                     dispatched = (self._state, finished,
                                   list(live.items()))
-                    prof.lap('decode-step')
                 if inflight is not None:
                     state_t, finished_t, snapshot = inflight
-                    toks = np.asarray(state_t['tokens'])
-                    fins = np.asarray(finished_t)
-                    pushed = 0
-                    for slot_id, request in snapshot:
-                        if request.done.is_set():
-                            # Finished in an earlier tick (device froze
-                            # the slot); this tick's value is a repeat.
-                            continue
-                        request._push(int(toks[slot_id]))  # pylint: disable=protected-access
-                        pushed += 1
-                        if fins[slot_id]:
-                            live.pop(slot_id, None)
-                            self._slots[slot_id].request = None
-                            self._release_slot_pages(slot_id)
-                            request._finish()  # pylint: disable=protected-access
-                    if pushed:
-                        self._record_tokens(pushed)
-                    with self._metrics_lock:
-                        self._ticks += 1
-                    _M_TICKS.inc()
-                    _M_BUSY_SLOTS.set(
-                        sum(1 for s in self._slots if s.active))
-                    prof.lap('sample')
+                    # The one place the host waits for the device: the
+                    # blocking read of the tick in flight, nothing else.
+                    with prof.phase('device-wait', count=len(snapshot)):
+                        toks = np.asarray(state_t['tokens'])
+                        fins = np.asarray(finished_t)
+                    with prof.phase('sample') as phase:
+                        pushed = 0
+                        for slot_id, request in snapshot:
+                            if request.done.is_set():
+                                # Finished in an earlier tick (device
+                                # froze the slot); this tick's value is
+                                # a repeat.
+                                continue
+                            request._push(int(toks[slot_id]))  # pylint: disable=protected-access
+                            pushed += 1
+                            if fins[slot_id]:
+                                live.pop(slot_id, None)
+                                self._slots[slot_id].request = None
+                                self._release_slot_pages(slot_id)
+                                request._finish()  # pylint: disable=protected-access
+                        if pushed:
+                            self._record_tokens(pushed)
+                        with self._metrics_lock:
+                            self._ticks += 1
+                        _M_TICKS.inc()
+                        _M_BUSY_SLOTS.set(
+                            sum(1 for s in self._slots if s.active))
+                        phase.count = pushed
                 inflight = dispatched
                 prof.end_tick()
                 if (inflight is None and not live and

@@ -530,8 +530,9 @@ class AdmissionQueue:
                 continue
             return request
 
-    def record_admission(self, request: Request) -> None:
-        request.span.mark_admitted()
+    def record_admission(self, request: Request,
+                         iteration: int = 0) -> None:
+        request.span.mark_admitted(iteration)
         wait = time.monotonic() - request.submit_time
         _M_ADMITTED.inc()
         _M_QOS_ADMITTED.labels(

@@ -160,8 +160,6 @@ class SliceReplicaEngine(batching_engine_lib.ContinuousBatchingEngine):
                  mesh=None,
                  rank_channels: Optional[List[Any]] = None,
                  **kwargs) -> None:
-        import functools  # pylint: disable=import-outside-toplevel
-
         import jax  # pylint: disable=import-outside-toplevel
 
         from skypilot_tpu.models import decode  # pylint: disable=import-outside-toplevel
@@ -179,7 +177,7 @@ class SliceReplicaEngine(batching_engine_lib.ContinuousBatchingEngine):
         self._sp_prefills = 0
         # One compile per padded prompt width (the bucket ladder bounds
         # the count, same as the chunked path).
-        self._sp_prefill_jit = jax.jit(functools.partial(
+        self._sp_prefill_jit = jax.jit(decode.bind(
             decode.prefill_sp, cfg, mesh=mesh,
             max_len=kwargs.get('max_len', 512)))
         super().__init__(cfg, params, mesh=mesh, **kwargs)
@@ -196,8 +194,8 @@ class SliceReplicaEngine(batching_engine_lib.ContinuousBatchingEngine):
         SPMD step.  RankDead propagates to the worker loop, which fails
         the replica as a unit — a half-dead slice must never keep
         half-serving."""
-        self._coordinator.tick()
-        self._profiler.lap('slice-sync')
+        with self._profiler.phase('slice-sync'):
+            self._coordinator.tick()
         return super()._dispatch_step()
 
     def _dispatch_spec_step(self, drafts):
@@ -206,10 +204,10 @@ class SliceReplicaEngine(batching_engine_lib.ContinuousBatchingEngine):
         the identical spec step — drafts are rank 0's host-side
         decision, exactly like admissions."""
         import numpy as np  # pylint: disable=import-outside-toplevel
-        self._coordinator.broadcast(
-            coordinator_lib.CMD_TICK,
-            spec=np.asarray(drafts).tolist())
-        self._profiler.lap('slice-sync')
+        with self._profiler.phase('slice-sync'):
+            self._coordinator.broadcast(
+                coordinator_lib.CMD_TICK,
+                spec=np.asarray(drafts).tolist())
         return super()._dispatch_spec_step(drafts)
 
     def _activate(self, slot_id, request, token, length, *,
@@ -290,14 +288,18 @@ class SliceReplicaEngine(batching_engine_lib.ContinuousBatchingEngine):
                  if pending.plan is not None else 0)
         if (pending.cache is None and reuse == 0 and
                 not request.cancelled):
-            t0 = time.perf_counter()
-            cache = self._try_sp_prefill(request.prompt_ids,
-                                         pending.n_target)
+            with self._profiler.phase(
+                    'prefill-chunk', request_id=request.request_id,
+                    count=pending.n_target) as phase:
+                t0 = time.monotonic()
+                cache = self._try_sp_prefill(request.prompt_ids,
+                                             pending.n_target)
+                # Below the threshold: the chunked path records its own.
+                phase.record = cache is not None
             if cache is not None:
                 pending.cache = cache
                 pending.consumed = pending.n_target
-                request.span.mark_prefill_chunk(
-                    time.perf_counter() - t0)
+                request.span.mark_prefill_chunk(time.monotonic() - t0)
                 self._record_chunk()
                 self._coordinator.broadcast(
                     coordinator_lib.CMD_PREFILL,
@@ -378,8 +380,6 @@ class FollowerExecutor:
                  kv_pages: Optional[int] = None, page_size: int = 16,
                  quantize_kv: bool = False, spec_tokens: int = 0,
                  max_top_k: int = 64, max_stop_ids: int = 16) -> None:
-        import functools  # pylint: disable=import-outside-toplevel
-
         import jax  # pylint: disable=import-outside-toplevel
         import jax.numpy as jnp  # pylint: disable=import-outside-toplevel
 
@@ -399,14 +399,12 @@ class FollowerExecutor:
         if self._paged:
             kernel = paged_attention_lib.decode_kernel_choice()
             self._step = jax.jit(
-                functools.partial(decode.paged_engine_step, cfg,
-                                  max_top_k=int(max_top_k),
-                                  kernel=kernel),
+                decode.bind(decode.paged_engine_step, cfg,
+                            max_top_k=int(max_top_k), kernel=kernel),
                 donate_argnums=(2,))
             self._spec_step = jax.jit(
-                functools.partial(decode.paged_spec_engine_step, cfg,
-                                  max_top_k=int(max_top_k),
-                                  kernel=kernel),
+                decode.bind(decode.paged_spec_engine_step, cfg,
+                            max_top_k=int(max_top_k), kernel=kernel),
                 donate_argnums=(2,))
             self._admit_paged = jax.jit(decode.paged_admit_slot,
                                         donate_argnums=(0,))
@@ -424,8 +422,8 @@ class FollowerExecutor:
                 raise ValueError('spec_tokens requires the paged KV '
                                  'engine (kv_pages)')
             self._step = jax.jit(
-                functools.partial(decode.engine_step, cfg,
-                                  max_top_k=int(max_top_k)),
+                decode.bind(decode.engine_step, cfg,
+                            max_top_k=int(max_top_k)),
                 donate_argnums=(2,))
             self._insert = jax.jit(decode.insert_prefill,
                                    donate_argnums=(0,))
@@ -434,12 +432,9 @@ class FollowerExecutor:
         self._state = decode.init_engine_state(int(slots),
                                                int(max_stop_ids))
         self._prefill = jax.jit(
-            lambda p, toks: decode.prefill(cfg, p, toks,
-                                           max_len=self.max_len))
+            decode.bind(decode.prefill, cfg, max_len=self.max_len))
         self._prefill_chunk_jit = jax.jit(
-            lambda p, toks, cache: decode.prefill_chunk(
-                cfg, p, toks, cache),
-            donate_argnums=(2,))
+            decode.bind(decode.prefill_chunk, cfg), donate_argnums=(2,))
 
     def _bucket(self, n: int) -> int:
         for b in batching_engine_lib._PREFILL_BUCKETS:  # pylint: disable=protected-access
@@ -579,8 +574,8 @@ def _bench_prefill(args) -> None:
     tokens = np.zeros((1, width), np.int32)
     tokens[0, :n] = rng.integers(1, cfg.vocab_size - 1, size=n)
     tokens = jnp.asarray(tokens)
-    fn = jax.jit(lambda p, t: decode.prefill_sp(cfg, p, t, mesh=mesh,
-                                                max_len=max_len))
+    fn = jax.jit(decode.bind(decode.prefill_sp, cfg, mesh=mesh,
+                             max_len=max_len))
     cache = fn(params, tokens)             # compile
     jax.block_until_ready(cache)
     times = []

@@ -196,6 +196,42 @@ class TestRequestSpan:
         assert d['total_ms'] is not None
         assert d['status'] == 'ok'
 
+    def test_ttft_is_the_sum_of_its_three_marks(self):
+        """queue_wait + prefill_wall + first_token_wait = ttft, from
+        the marks alone (one clock, no engine)."""
+        span = tracing.RequestSpan('req-2')
+        assert span.prefill_wall_s is None
+        span.mark_live(3)                      # not admitted: ignored
+        assert span.prefill_wall_s is None
+        span.mark_admitted(iteration=7)
+        span.mark_prefill_chunk(0.001)
+        span.mark_live(iteration=8)
+        span.mark_live(iteration=9)            # idempotent
+        span.mark_token()
+        span.mark_token()
+        assert span.prefill_iterations == 2
+        assert (span.queue_wait_s + span.prefill_wall_s +
+                span.first_token_wait_s) == pytest.approx(span.ttft_s,
+                                                          abs=1e-9)
+        d = span.to_dict()
+        assert d['prefill_iterations'] == 2
+        assert d['prefill_wall_ms'] is not None
+        assert d['first_token_wait_ms'] is not None
+        # prefill_ms keeps its name and its meaning: dispatch seconds.
+        assert d['prefill_ms'] == pytest.approx(1.0)
+
+    def test_first_token_before_the_slot_is_live(self):
+        """Where the first token comes out of the prefill itself (the
+        expert models' admission), its arrival is the slot going live:
+        the identity holds with a zero wait."""
+        span = tracing.RequestSpan()
+        span.mark_admitted(iteration=4)
+        span.mark_token()
+        assert span.first_token_wait_s == 0.0
+        assert span.prefill_iterations == 1
+        assert span.queue_wait_s + span.prefill_wall_s == \
+            pytest.approx(span.ttft_s, abs=1e-9)
+
     def test_finish_idempotent(self):
         span = tracing.RequestSpan()
         span.finish('ok')
@@ -218,6 +254,121 @@ class TestRequestSpan:
     def test_ids_unique(self):
         ids = {tracing.new_request_id() for _ in range(100)}
         assert len(ids) == 100
+
+
+# ------------------------------------------- spans on a running engine
+
+
+@pytest.fixture(scope='module')
+def tiny_paged_engine():
+    import flax.linen as nn
+    import jax
+    import jax.numpy as jnp
+
+    from skypilot_tpu.models import configs
+    from skypilot_tpu.models.transformer import Transformer
+    from skypilot_tpu.serve import batching_engine
+    cfg = configs.get_config('tiny')
+    params = nn.meta.unbox(Transformer(cfg).init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))['params'])
+    eng = batching_engine.ContinuousBatchingEngine(
+        cfg, params, max_len=64, slots=2, prefill_chunk=8, kv_pages=48,
+        page_size=8)
+    yield eng
+    eng.stop()
+
+
+# prompt, engine iterations from admission to slot live (chunks of 8,
+# the last prompt token rides the first tick; a prefix hit spends one
+# iteration on the seed alone, then one on the tail chunk).
+_SPAN_CASES = {
+    'plain': (list(range(200, 220)), 3),
+    'prefix-hit': (list(range(200, 217)) + [3, 4, 5], 2),
+    'one-token': ([7], 1),
+}
+
+
+@pytest.mark.parametrize('case', list(_SPAN_CASES))
+def test_engine_span_marks_add_up_to_ttft(tiny_paged_engine, case):
+    """`queue_wait_s + prefill_wall_s + first_token_wait_s == ttft_s`
+    on the `tiny` engine, to within clock reads."""
+    eng = tiny_paged_engine
+    prompt, iterations = _SPAN_CASES[case]
+    if case == 'prefix-hit':
+        eng.generate(_SPAN_CASES['plain'][0], 2, timeout=180)
+    handle = eng.submit(prompt, 3)
+    handle.result(timeout=180)
+    span = handle.span
+    assert (span.prefix_hit_pages > 0) == (case == 'prefix-hit')
+    assert span.prefill_wall_s >= 0 and span.first_token_wait_s > 0
+    assert (span.queue_wait_s + span.prefill_wall_s +
+            span.first_token_wait_s) == pytest.approx(span.ttft_s,
+                                                      abs=1e-6)
+    assert span.prefill_iterations == iterations
+    # What the chunks' dispatch cost the host lies inside the wall.
+    assert span.prefill_s <= span.prefill_wall_s + 1e-6
+    d = eng.span(handle.request_id)
+    assert d['prefill_wall_ms'] == pytest.approx(
+        span.prefill_wall_s * 1e3, abs=1e-3)
+
+
+def test_every_jitted_entry_is_a_named_function(tiny_paged_engine):
+    """The device trace names a program after the jitted function:
+    none may be a lambda or a `functools.partial` (`jit__lambda_`,
+    `jit__unknown`), and the tick's must be `paged_engine_step`, the
+    name `benchmarks/layers/paged_attn_roofline.py` finds it by."""
+    import functools
+
+    from skypilot_tpu.serve import batching_engine
+    eng = tiny_paged_engine
+    dense = batching_engine.ContinuousBatchingEngine(
+        eng.cfg, eng.params, max_len=64, slots=2)
+    try:
+        names = {}
+        for engine in (eng, dense):
+            for attr, entry in vars(engine).items():
+                jitted = getattr(entry, '__wrapped__', None)
+                if not hasattr(jitted, 'lower'):
+                    continue                  # not a sentinel-wrapped jit
+                fn = jitted.__wrapped__
+                assert not isinstance(fn, functools.partial), attr
+                assert fn.__name__ not in ('<lambda>', 'call'), attr
+                names[(engine is eng, attr)] = jitted.__name__
+    finally:
+        dense.stop()
+    assert names[(True, '_step')] == 'paged_engine_step'
+    assert names[(True, '_spec_step')] == 'paged_spec_engine_step'
+    assert names[(True, '_prefill')] == 'prefill'
+    assert names[(True, '_prefill_chunk')] == 'prefill_chunk'
+    assert names[(True, '_seed_private')] == 'paged_seed_private'
+    assert names[(True, '_insert_pages')] == 'insert_prefill_pages'
+    assert names[(True, '_admit_paged')] == 'paged_admit_slot'
+    assert names[(False, '_step')] == 'engine_step'
+    assert names[(False, '_legacy_step')] == 'batched_step'
+    assert len(names) >= 16
+
+
+def test_stats_tick_loop_counts_the_loop(tiny_paged_engine):
+    """`stats()['tick_loop']`: cumulative, with `device-wait` split
+    out of `sample`, and no more seconds in phases than in the loop."""
+    eng = tiny_paged_engine
+    before = eng.stats()['tick_loop']
+    eng.generate(list(range(300, 312)), 6, timeout=180)
+    after = eng.stats()['tick_loop']
+    assert after['iterations'] > before['iterations']
+    assert after['loop_s'] > before['loop_s']
+    for phase in ('admit', 'prefill-chunk', 'page-scatter',
+                  'decode-step', 'device-wait', 'sample'):
+        assert after['phase_s'][phase] > before['phase_s'].get(phase, 0)
+    assert sum(after['phase_s'].values()) <= after['loop_s'] + 1e-9
+    assert after['starved_ticks'] >= before['starved_ticks']
+    assert after['starved_s'] >= before['starved_s']
+    ring = eng.profile()['ring']
+    assert [rec['n'] for rec in ring] == sorted(rec['n'] for rec in ring)
+    chunk = next(p for rec in ring for p in rec['phases']
+                 if p[0] == 'prefill-chunk')
+    # Chunk 0's width is its flash bucket (16), the id its request's.
+    assert chunk[3] == 16 and chunk[4]
 
 
 # ----------------------------------------------------------- timeline fixes

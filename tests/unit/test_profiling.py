@@ -44,63 +44,205 @@ class RecordingJournal:
         self.events.append((name, fields))
 
 
+class RecordingAnnotation:
+    """Stand-in for `jax.profiler.TraceAnnotation`: keeps what the
+    profiler would put on the trace's host plane."""
+
+    def __init__(self) -> None:
+        self.opened = []     # (name, kwargs) in the order entered
+        self.depth = 0
+
+    def __call__(self, name, **kw):
+        rec = self
+
+        class _Span:
+            def __enter__(self):
+                rec.opened.append((name, kw))
+                rec.depth += 1
+                return self
+
+            def __exit__(self, *exc):
+                rec.depth -= 1
+                return False
+
+            def set_metadata(self, **more):
+                kw.update(more)
+
+        return _Span()
+
+
 def _profiler(**kw):
     kw.setdefault('clock', FakeClock())
     kw.setdefault('memory_cb', lambda: None)
     kw.setdefault('disabled', False)
+    kw.setdefault('annotate', RecordingAnnotation())
     return profiling.TickProfiler(**kw)
+
+
+def _run_phase(prof, name, record=True, **kw):
+    with prof.phase(name, **kw) as phase:
+        phase.record = record
+
+
+class FakeInflight:
+    def __init__(self, ready: bool) -> None:
+        self.ready = ready
+
+    def is_ready(self) -> bool:
+        return self.ready
 
 
 class TestTickProfiler:
 
     def test_laps_are_exclusive_and_one_read_each(self):
+        # Every clock read advances 1 s: a phase is two reads, so each
+        # lasts 1 s and 1 s passes between two of them.
         clock = FakeClock(step=1.0)
         prof = _profiler(clock=clock)
-        prof.begin_tick()                       # t=1
-        prof.lap('handoff', record=False)       # t=2, not attributed
-        prof.lap('admit')                       # t=3: admit gets 1s
-        prof.lap('decode-step')                 # t=4: decode gets 1s
-        prof.end_tick()
+        prof.begin_tick()                              # t=1
+        _run_phase(prof, 'handoff', record=False)      # 2..3, dropped
+        _run_phase(prof, 'admit')                      # 4..5
+        _run_phase(prof, 'decode-step')                # 6..7
+        prof.end_tick()                                # t=8
         snap = prof.snapshot()
         assert snap['ticks'] == 1
         assert set(snap['phases']) == {'admit', 'decode-step'}
         assert snap['phases']['admit']['total_s'] == pytest.approx(1.0)
         assert snap['phases']['decode-step']['total_s'] == \
             pytest.approx(1.0)
-        # The unrecorded handoff lap still advanced the lap clock, so
-        # its second was attributed to NO phase (phases sum < tick).
+        # The unrecorded handoff phase was attributed to NO phase
+        # (phases sum < iteration).
         [rec] = snap['ring']
-        assert rec['dur_s'] == pytest.approx(3.0)
-        assert sum(d for _, _, d in rec['phases']) == pytest.approx(2.0)
+        assert rec['dur_s'] == pytest.approx(7.0)
+        assert sum(p[2] for p in rec['phases']) == pytest.approx(2.0)
+        assert [p[1] for p in rec['phases']] == [3.0, 5.0]
+
+    def test_device_wait_is_split_from_sample(self):
+        """`sample` no longer holds the blocking read: phases of an
+        iteration stay exclusive and, with the gaps between them, sum
+        to it with `device-wait` its own term."""
+        clock = FakeClock(step=0.0)
+        prof = _profiler(clock=clock)
+        clock.queued = [0.0,            # begin
+                        0.0, 0.002,     # decode-step: 2 ms dispatch
+                        0.0, 0.090,     # device-wait: 90 ms
+                        0.0, 0.003,     # sample: 3 ms
+                        0.0]            # end
+        prof.begin_tick()
+        _run_phase(prof, 'decode-step', count=16)
+        _run_phase(prof, 'device-wait', count=16)
+        _run_phase(prof, 'sample')
+        prof.end_tick()
+        [rec] = prof.snapshot()['ring']
+        by_name = {p[0]: p[2] for p in rec['phases']}
+        assert by_name == {'decode-step': pytest.approx(0.002),
+                           'device-wait': pytest.approx(0.090),
+                           'sample': pytest.approx(0.003)}
+        assert sum(by_name.values()) == pytest.approx(rec['dur_s'])
+        loop = prof.tick_loop()
+        host = loop['loop_s'] - loop['phase_s']['device-wait']
+        assert host == pytest.approx(0.005)
+
+    def test_nested_phase_comes_out_of_the_enclosing_one(self):
+        clock = FakeClock(step=1.0)
+        prof = _profiler(clock=clock)
+        prof.begin_tick()
+        with prof.phase('decode-step'):          # 2 ..
+            _run_phase(prof, 'slice-sync')       # 3..4
+        prof.end_tick()                          # .. 5
+        [rec] = prof.snapshot()['ring']
+        by_name = {p[0]: p[2] for p in rec['phases']}
+        # decode-step spans 3 s of which the sync took 1.
+        assert by_name == {'slice-sync': pytest.approx(1.0),
+                           'decode-step': pytest.approx(2.0)}
+
+    def test_phases_reach_the_trace_with_number_id_and_count(self):
+        ann = RecordingAnnotation()
+        prof = _profiler(annotate=ann)
+        del ann.opened[:]       # the constructor's self-calibration
+        for _ in range(2):
+            prof.begin_tick()
+            _run_phase(prof, 'admit', request_id='req-7', count=512)
+            _run_phase(prof, 'handoff', record=False)
+            prof.end_tick()
+        assert ann.depth == 0                   # every span closed
+        assert [name for name, _ in ann.opened] == [
+            'skytpu/tick', 'skytpu/admit', 'skytpu/handoff'] * 2
+        assert ann.opened[0][1] == {'n': 1}
+        assert ann.opened[3][1] == {'n': 2}
+        assert ann.opened[4][1] == {'n': 2, 'request_id': 'req-7',
+                                    'count': 512}
+        # A count known only when the phase ends still reaches the
+        # trace (as metadata set on the open span).
+        prof.begin_tick()
+        with prof.phase('sample') as phase:
+            phase.count = 9
+        prof.end_tick()
+        assert ann.opened[-1] == ('skytpu/sample', {'n': 3, 'count': 9})
+        ring = prof.snapshot()['ring'][:2]
+        assert [rec['n'] for rec in ring] == [1, 2]
+        assert ring[1]['phases'][0][3:] == [512, 'req-7']
 
     def test_idle_ticks_never_enter_the_ring(self):
         prof = _profiler()
         for _ in range(5):
             prof.begin_tick()
-            prof.lap('admit', record=False)     # machinery ran, no work
+            _run_phase(prof, 'admit', record=False)  # ran, no work
             prof.end_tick()
         assert prof.ticks == 0
         assert prof.snapshot()['ring'] == []
+        assert prof.iteration == 5               # numbered all the same
 
     def test_ring_is_bounded_but_aggregates_are_cumulative(self):
         prof = _profiler(ring_ticks=4)
         for _ in range(10):
             prof.begin_tick()
-            prof.lap('decode-step')
+            _run_phase(prof, 'decode-step')
             prof.end_tick()
         snap = prof.snapshot()
         assert len(snap['ring']) == 4
         assert snap['ticks'] == 10
         assert snap['phases']['decode-step']['count'] == 10
 
+    def test_tick_loop_is_monotone_and_equals_the_ring(self):
+        """`stats()['tick_loop']` is what a reader differences: every
+        field only grows, and while the ring still holds every
+        iteration its phase seconds are the ring's."""
+        prof = _profiler(clock=FakeClock(step=0.5), ring_ticks=64)
+        seen = [prof.tick_loop()]
+        for i in range(6):
+            prof.begin_tick()
+            _run_phase(prof, 'admit', record=bool(i % 2))
+            _run_phase(prof, 'decode-step')
+            _run_phase(prof, 'device-wait')
+            prof.end_tick()
+            seen.append(prof.tick_loop())
+        for a, b in zip(seen, seen[1:]):
+            assert b['iterations'] == a['iterations'] + 1
+            assert b['loop_s'] > a['loop_s']
+            for name, total in a['phase_s'].items():
+                assert b['phase_s'][name] >= total
+        snap = prof.snapshot()
+        ring_s = {}
+        for rec in snap['ring']:
+            for name, _, dur, _, _ in rec['phases']:
+                ring_s[name] = ring_s.get(name, 0.0) + dur
+        assert seen[-1]['phase_s'] == pytest.approx(ring_s)
+        assert seen[-1]['loop_s'] == pytest.approx(
+            sum(rec['dur_s'] for rec in snap['ring']))
+        assert snap['tick_loop'] == seen[-1]
+
     def test_disable_gate_is_a_noop(self):
-        prof = _profiler(disabled=True)
+        prof = _profiler(disabled=True, annotate=None)
         prof.begin_tick()
-        prof.lap('decode-step')
+        with prof.phase('decode-step', count=3) as phase:
+            phase.record = False
+        assert prof.probe_starved(FakeInflight(True)) is False
         prof.end_tick()
         snap = prof.snapshot()
         assert snap['enabled'] is False
         assert snap['ticks'] == 0 and snap['ring'] == []
+        assert snap['tick_loop']['iterations'] == 0
 
     def test_env_knobs(self, monkeypatch):
         monkeypatch.setenv('SKYTPU_PROFILE_RING_TICKS', '7')
@@ -113,9 +255,9 @@ class TestTickProfiler:
         clock = FakeClock(step=0.0)
         prof = _profiler(clock=clock, ring_ticks=128)
         for dur in (1.0, 2.0, 3.0, 4.0):
-            clock.queued = [0.0, dur]          # begin, lap
+            clock.queued = [0.0, 0.0, dur]     # begin, phase in, out
             prof.begin_tick()
-            prof.lap('sample')
+            _run_phase(prof, 'sample')
             prof.end_tick()
         agg = prof.snapshot()['phases']['sample']
         assert agg['p50_s'] == pytest.approx(3.0)
@@ -123,20 +265,118 @@ class TestTickProfiler:
         assert agg['total_s'] == pytest.approx(10.0)
 
     def test_memory_watermark_and_dead_backend(self):
+        """The watermark is read once a snapshot, on the reader's
+        thread, and never from `end_tick` (it only rises, so a
+        per-iteration series carried no more)."""
         mems = [100, 300, 200]
-        prof = _profiler(memory_cb=lambda: mems.pop(0) if mems else None)
+        asked = []
+
+        def memory_cb():
+            asked.append(len(asked))
+            return mems.pop(0) if mems else None
+
+        prof = _profiler(memory_cb=memory_cb)
         for _ in range(3):
             prof.begin_tick()
-            prof.lap('decode-step')
+            _run_phase(prof, 'decode-step')
             prof.end_tick()
-        snap = prof.snapshot()
-        assert snap['device_memory']['watermark_bytes'] == 300
-        assert snap['device_memory']['last_bytes'] == 200
+        assert asked == []                      # the loop never asks
+        assert 'mem_bytes' not in prof.snapshot()['ring'][0]
+        snaps = [prof.snapshot()['device_memory'] for _ in range(2)]
+        assert asked == [0, 1, 2]
+        assert snaps[0] == {'watermark_bytes': 300, 'last_bytes': 300}
+        assert snaps[1] == {'watermark_bytes': 300, 'last_bytes': 200}
         # Backend went dark: the profiler stops asking (no raise).
-        prof.begin_tick()
-        prof.lap('decode-step')
-        prof.end_tick()
+        dark = prof.snapshot()['device_memory']
+        assert dark == {'watermark_bytes': 300, 'last_bytes': None}
         assert prof._mem_dead is True
+        prof.snapshot()
+        assert len(asked) == 4
+
+
+class TestStarvationProbe:
+    """The probe sees, right before an iteration's first dispatch,
+    whether the tick in flight has already finished."""
+
+    TICK = 0.100
+
+    def _iteration(self, prof, clock, *, host_s, ready, wait_s):
+        """One pipelined iteration: `host_s` of host work, the probe,
+        a dispatch, then the blocking read returning after `wait_s`."""
+        clock.queued = [0.0]
+        prof.begin_tick()
+        clock.queued = [host_s]                 # the probe's own read
+        starved = prof.probe_starved(FakeInflight(ready))
+        if not starved:
+            clock.queued = []
+            clock.now += host_s                 # (no read was made)
+        clock.queued = [0.0, 0.0]
+        _run_phase(prof, 'decode-step')
+        clock.queued = [0.0, wait_s]
+        _run_phase(prof, 'device-wait')
+        clock.queued = [0.0]
+        prof.end_tick()
+        return starved
+
+    def test_counts_exactly_and_estimates_from_the_running_tick(self):
+        clock = FakeClock(step=0.0)
+        prof = _profiler(clock=clock)
+        # Two unstarved iterations teach the tick length (100 ms
+        # between two device-wait returns).
+        for _ in range(2):
+            assert not self._iteration(prof, clock, host_s=0.005,
+                                       ready=False, wait_s=0.095)
+        assert prof.tick_loop()['starved_ticks'] == 0
+        # The host stands still 400 ms: the tick in flight (100 ms)
+        # finished 300 ms before the next dispatch.
+        assert self._iteration(prof, clock, host_s=0.400, ready=True,
+                               wait_s=0.0)
+        loop = prof.tick_loop()
+        assert loop['starved_ticks'] == 1
+        assert loop['starved_s'] == pytest.approx(0.300)
+        # A starved interval is not taken for a tick length: the next
+        # stall is still judged against 100 ms.
+        assert not self._iteration(prof, clock, host_s=0.005,
+                                   ready=False, wait_s=0.095)
+        assert self._iteration(prof, clock, host_s=0.150, ready=True,
+                               wait_s=0.0)
+        loop = prof.tick_loop()
+        assert loop['starved_ticks'] == 2
+        assert loop['starved_s'] == pytest.approx(0.350)
+
+    def test_estimate_is_floored_at_zero(self):
+        clock = FakeClock(step=0.0)
+        prof = _profiler(clock=clock)
+        for _ in range(2):
+            self._iteration(prof, clock, host_s=0.005, ready=False,
+                            wait_s=0.095)
+        # Ready after only 60 ms of a 100 ms running tick (a short
+        # tick): counted, but no negative seconds.
+        assert self._iteration(prof, clock, host_s=0.060, ready=True,
+                               wait_s=0.0)
+        loop = prof.tick_loop()
+        assert loop['starved_ticks'] == 1
+        assert loop['starved_s'] == 0.0
+
+    def test_no_estimate_across_an_idle_engine(self):
+        """An iteration without a device-wait (no tick was in flight)
+        breaks the chain: the wait that follows an idle spell is not a
+        tick length, and a starved tick right after it counts with no
+        seconds."""
+        clock = FakeClock(step=0.0)
+        prof = _profiler(clock=clock)
+        for _ in range(2):
+            self._iteration(prof, clock, host_s=0.005, ready=False,
+                            wait_s=0.095)
+        clock.queued = [0.0, 0.0, 0.0, 5.0]     # idle: 5 s, no wait
+        prof.begin_tick()
+        _run_phase(prof, 'decode-step')
+        prof.end_tick()
+        assert self._iteration(prof, clock, host_s=0.500, ready=True,
+                               wait_s=0.0)
+        loop = prof.tick_loop()
+        assert loop['starved_ticks'] == 1
+        assert loop['starved_s'] == 0.0
 
 
 def _counter_value(name, **labels):
@@ -226,7 +466,7 @@ class TestExports:
         prof = _profiler(clock=clock, memory_cb=lambda: 4096)
         prof.begin_tick()
         for phase in profiling.PHASES:
-            prof.lap(phase)
+            _run_phase(prof, phase, count=2)
         prof.end_tick()
         return prof.snapshot()
 
@@ -249,10 +489,11 @@ class TestExports:
         events = blob['traceEvents']
         bars = [e for e in events if e['ph'] == 'X']
         assert {e['name'] for e in bars} == set(profiling.PHASES)
+        assert 'device-wait' in {e['name'] for e in bars}
         for e in bars:
             assert e['dur'] > 0 and e['ts'] > 0 and e['pid'] == 3
-        [mem] = [e for e in events if e['ph'] == 'C']
-        assert mem['args']['bytes_in_use'] == 4096
+            assert e['args'] == {'n': 1, 'count': 2}
+        assert [e for e in events if e['ph'] != 'X'] == []
 
 
 @pytest.fixture(scope='module')
@@ -306,8 +547,8 @@ class TestServeProfileCli:
     def test_export_trace_carries_all_phases(self, tmp_path,
                                              monkeypatch):
         """`sky serve profile --export-trace` against a replica whose
-        ring saw every phase writes a valid Chrome trace with all
-        eight phase bars."""
+        ring saw every phase writes a valid Chrome trace with every
+        phase's bar."""
         import http.server
         import threading
 
@@ -321,7 +562,7 @@ class TestServeProfileCli:
                                       clock=clock)
         prof.begin_tick()
         for phase in profiling.PHASES:
-            prof.lap(phase)
+            _run_phase(prof, phase)
         prof.end_tick()
         sentinel = profiling.RecompileSentinel(
             disabled=False, journal_factory=RecordingJournal)
@@ -409,14 +650,20 @@ class TestOverheadBudget:
     @classmethod
     def _per_tick_cost(cls, prof):
         """Seconds per tick of the instrumentation calls alone, at the
-        real call pattern (4 laps + begin/end per tick)."""
+        real call pattern of a steady pipelined iteration (the probe,
+        three phases, begin/end; the real `TraceAnnotation`, with no
+        profiler session open)."""
+        inflight = FakeInflight(False)
         t0 = time.perf_counter()
         for _ in range(cls.TICKS):
             prof.begin_tick()
-            prof.lap('handoff', record=False)
-            prof.lap('admit')
-            prof.lap('decode-step')
-            prof.lap('sample')
+            prof.probe_starved(inflight)
+            with prof.phase('decode-step', count=16):
+                pass
+            with prof.phase('device-wait', count=16):
+                pass
+            with prof.phase('sample') as phase:
+                phase.count = 16
             prof.end_tick()
         return (time.perf_counter() - t0) / cls.TICKS
 
