@@ -179,10 +179,11 @@ def _layer_forward(x, lp, cfg, positions, k_cache, v_cache,
     q = _rope(q, positions, cfg)
 
     if isinstance(k_cache, _PagedView):
-        # Paged-kernel decode: the Pallas kernel reads K/V pages from
-        # the pool by block-table index in-grid (fused int8 dequant on
-        # the loaded operand); `positions` is implied by the view's
-        # lengths — query token j of slot b sits at lengths[b] + j.
+        # Paged-kernel decode: the Pallas kernel copies each slot's
+        # live K/V pages from the pool by block-table index (fused
+        # int8 dequant on the loaded operand); `positions` is implied
+        # by the view's lengths — query token j of slot b sits at
+        # lengths[b] + j.
         with jax.named_scope('paged_attention'):
             out = paged_attention_ops.paged_attention(
                 q, k_cache.leaf, v_cache.leaf, k_cache.tables,

@@ -1,17 +1,27 @@
-"""Pallas paged-attention decode kernel: block-table reads in-kernel.
+"""Pallas paged-attention decode kernel: block-table reads in-kernel,
+bounded by what each slot's cache holds.
 
 The paged engine's fallback decode path gathers every slot's pages
 into a dense `[b, h_kv, len, d]` view before attending
 (`paged_batched_step`'s view closure) — fine on CPU emulation, a
 bandwidth disaster on TPU: the gather materialises the whole cache
 window in HBM every tick.  This kernel reads K/V pages directly from
-the page pool by block-table index inside the kernel grid — the
-gathered view never exists.  Grid is (slot, kv_head, table_row); the
-block tables and per-slot lengths ride in scalar-prefetch memory so
-each program's K/V BlockSpec index map picks its pool page
-dynamically, and an online softmax accumulates across the table-row
-grid axis in VMEM scratch (TPU grids iterate the minor axis
-sequentially, so scratch carries between pages of the same slot).
+the page pool by block-table index — the gathered view never exists.
+
+The grid has one program a slot and nothing else: a table row is not
+a grid step.  The pools stay in HBM; inside a program a loop of
+DYNAMIC length `ceil((lengths[b] + S) / (pages_per_step * ps))` walks
+the slot's live pages, `pages_per_step` of them a step.  Each page is
+one contiguous `[h_kv, ps, d]` slab of the pool, so one async copy by
+table index (tables and lengths ride in scalar-prefetch memory) brings
+every kv head into a double-buffered VMEM scratch; the next step's
+copies — or the next slot's first — start before this step's are
+waited for.  Table rows past a slot's length are never read, their
+pages never fetched, and they cost no step, so the kernel's time
+follows the caches and not `max_len`; a freed slot (length 0) costs
+one step of one page.  The online softmax of all kv heads accumulates
+across a slot's steps in VMEM scratch.  `pages_per_step` comes from
+shapes alone (`_pages_per_step`).
 
 Queries generalise to S tokens per slot (query row r sits at absolute
 position `lengths[b] + r % S`), so one kernel serves single-token
@@ -20,7 +30,8 @@ are verified through the same paged kernel.
 
 int8 pools (PR 7's per-page absmax scales) run the same kernel body
 with the dequant fused into the score and probability tiles: the int8
-bytes are what moves from HBM, the scales multiply VMEM-resident tiles.
+bytes are what moves from HBM, their scales ride the same copies and
+multiply VMEM-resident tiles.
 
 Same interpret-mode pattern as ops/attention.py
 (`SKYTPU_PALLAS_INTERPRET=1`, CPU backend only); off-TPU without
@@ -68,81 +79,229 @@ def decode_kernel_choice() -> str:
     return 'pallas' if _use_pallas() else 'gather'
 
 
-def _paged_decode_kernel(tables_ref, lengths_ref, q_ref, *refs,
-                         page_size: int, s_q: int, num_rows: int,
-                         quantized: bool):
-    """One (slot, kv_head, table_row) program streams its pool page
-    through VMEM and folds it into the slot's online softmax.
+# VMEM the kernel's double-buffered K and V page buffers may take, and
+# the most tokens one step attends.  Both only cap `pages_per_step`:
+# the budget where pages are fat (many kv heads a page), the token cap
+# where they are thin (a tensor shard's two heads), so that a short
+# slot's last step is not mostly masked columns.
+_KV_VMEM_BUDGET = 4 * 1024 * 1024
+_STEP_TOKENS = 512
 
-    Refs: q [1, 1, R, d] pre-scaled f32 (R = rep * s_q); k/v
-    [1, 1, ps, d] in the pool dtype; int8 pools add ks/vs
-    [1, h_kv, ps] f32 per-token scales (the block carries every kv
-    head of the page — a `(1, 1, ps)` block has a second-to-last dim
-    Mosaic cannot tile — and the program reads its own head's row);
-    o [1, 1, R, d].  Scratch acc [R, d], m/l [R, _LANES] (per-row
-    scalars broadcast across lanes for Mosaic tiling, like the flash
-    kernels' LSE layout).
+
+def _pages_per_step(num_rows: int, h_kv: int, page_size: int, d: int,
+                    itemsize: int) -> int:
+    """Pages one loop step fetches and attends: as many as the VMEM
+    budget holds twice over (K and V, two buffers each), no more than
+    `_STEP_TOKENS` tokens' worth, no more than the table has rows."""
+    page_bytes = h_kv * page_size * d * itemsize
+    by_vmem = _KV_VMEM_BUDGET // (4 * page_bytes)
+    return max(1, min(by_vmem, _STEP_TOKENS // page_size, num_rows))
+
+
+def _paged_decode_kernel(tables_ref, lengths_ref, q_ref, *refs,
+                         page_size: int, s_q: int, pages_per_step: int,
+                         sm_scale: float, quantized: bool):
+    """One program per slot: walk the slot's live pages,
+    `pages_per_step` a step, through a double-buffered VMEM scratch and
+    fold each step into the online softmax of every kv head.
+
+    Refs: tables [B, P] and lengths [B] in SMEM; q [1, h_kv, R, d]
+    (R = rep * s_q padded to whole sublane tiles, unscaled, q's dtype);
+    the pools in HBM (k/v [n_pages, h_kv, ps, d]; int8 pools add ks/vs
+    [n_pages, W] f32, a page's [h_kv, ps] scales as one row);
+    o [1, h_kv, R, d].  Scratch: k/v buffers
+    [2, pages_per_step, h_kv, ps, d] (and scale buffers
+    [2, pages_per_step, W]), DMA semaphores [2, 2] (buffer; K or V),
+    base [1] in SMEM (see below), acc [h_kv * R, d] and m/l
+    [h_kv * R, _LANES] (rows head-major; per-row scalars broadcast
+    across lanes for Mosaic tiling, like the flash kernels' LSE
+    layout).
+
+    Step i covers table rows [i * pps, (i + 1) * pps) cut off at the
+    slot's live page count ceil((length + s_q) / ps): a row past it is
+    not read from the table, its page is not fetched, and a step made
+    only of such rows does not run.
 
     int8 dequant is fused without ever building the f32 page: with
     k[t] = kq[t] * ks[t], q.k[t] = (q.kq[t]) * ks[t] scales a COLUMN of
     the score tile, and sum_t p[t] v[t] = sum_t (p[t] vs[t]) vq[t]
-    scales a column of the probabilities — both are row-vector
-    broadcasts of the [1, ps] scale row as loaded.
+    scales a column of the probabilities.
     """
     from jax.experimental import pallas as pl  # pylint: disable=import-outside-toplevel
+    from jax.experimental.pallas import tpu as pltpu  # pylint: disable=import-outside-toplevel
 
     if quantized:
-        k_ref, ks_ref, v_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref = refs
+        (k_hbm, ks_hbm, v_hbm, vs_hbm, o_ref, k_buf, v_buf, ks_buf,
+         vs_buf, sems, base_ref, acc_ref, m_ref, l_ref) = refs
+        streams = ((k_hbm, k_buf, 0), (ks_hbm, ks_buf, 0),
+                   (v_hbm, v_buf, 1), (vs_hbm, vs_buf, 1))
     else:
-        k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref = refs
+        (k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, base_ref, acc_ref, m_ref,
+         l_ref) = refs
+        streams = ((k_hbm, k_buf, 0), (v_hbm, v_buf, 1))
+    pps = pages_per_step
+    _, h_kv, r, d = q_ref.shape
+    hr = h_kv * r
+    t = pps * page_size
+    n_slots = pl.num_programs(0)
     b = pl.program_id(0)
-    hh = pl.program_id(1)
-    i = pl.program_id(2)
     length = lengths_ref[b]
 
-    @pl.when(i == 0)
-    def _init():  # pylint: disable=unused-variable
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+    def live_pages(bb):
+        return jnp.minimum(
+            (lengths_ref[bb] + s_q + page_size - 1) // page_size,
+            tables_ref.shape[1])
 
-    # Pages past the written window contribute nothing; row 0 always
-    # computes (kpos 0 <= length), so m is finite from the first page.
-    @pl.when(i * page_size <= length + s_q - 1)
-    def _compute():  # pylint: disable=unused-variable
-        q = q_ref[0, 0]
-        r = q.shape[0]
-        s = jax.lax.dot_general(
-            q, k_ref[0, 0].astype(jnp.float32), (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        if quantized:
-            s = s * ks_ref[0, pl.ds(hh, 1), :]
-        kpos = i * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (r, page_size), 1)
-        # Query row r sits at absolute position length + (r % s_q): the
-        # GQA fold keeps the S query tokens of each q-head contiguous.
+    n_steps = (live_pages(b) + pps - 1) // pps
+
+    def copies(bb, i, slot, which, fn):
+        """Apply fn to the copy descriptor of every live page of slot
+        bb's step i (into buffer `slot`) that signals a semaphore in
+        `which` (0: K, 1: V)."""
+        first = i * pps
+
+        def one(j, _):
+            page = tables_ref[bb, first + j]
+            for hbm, buf, w in streams:
+                if w in which:
+                    # A pool page is hbm[page]; a scale page is one row
+                    # of a 2-D array, sliced (Mosaic slices a DMA's
+                    # last two dims only by whole tiles).
+                    src, dst = ((hbm.at[page], buf.at[slot, j])
+                                if len(hbm.shape) == 4 else
+                                (hbm.at[pl.ds(page, 1)],
+                                 buf.at[slot, pl.ds(j, 1)]))
+                    fn(pltpu.make_async_copy(src, dst, sems.at[slot, w]))
+            return _
+
+        jax.lax.fori_loop(
+            0, jnp.minimum(pps, live_pages(bb) - first), one, None)
+
+    def start(bb, i, slot):
+        copies(bb, i, slot, (0, 1), lambda c: c.start())
+
+    # The two buffers alternate over the steps of ALL programs (which
+    # run in order on one core): a program's last step starts the next
+    # program's first copies, so only the call's very first step waits
+    # for a copy nothing overlaps.  base_ref holds the buffer of this
+    # program's step 0.
+    @pl.when(b == 0)
+    def _first():  # pylint: disable=unused-variable
+        # A step's unfetched tail keeps what an earlier step left in
+        # the buffer; its columns are masked to p = 0, and 0 * v must
+        # be 0, so the buffers start finite.
+        for _, buf, _ in streams:
+            buf[...] = jnp.zeros_like(buf)
+        base_ref[0] = 0
+        start(0, 0, 0)
+
+    # What bf16 holds exactly (bf16 and int8 pages) goes to the MXU as
+    # bf16; an f32 pool (tests) stays f32.
+    exact = k_buf.dtype in (jnp.bfloat16, jnp.int8)
+    k_dtype = (jnp.bfloat16 if exact and q_ref.dtype == jnp.bfloat16
+               else jnp.float32)
+
+    def per_head(fn):
+        return jnp.concatenate([fn(hh) for hh in range(h_kv)], axis=0)
+
+    base = base_ref[0]
+    base_ref[0] = (base + n_steps) % 2
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+
+    def step(i, _):
+        slot = (base + i) % 2
+        more = i + 1 < n_steps
+        next_b = jnp.where(more, b, b + 1)
+
+        @pl.when(next_b < n_slots)
+        def _prefetch():  # pylint: disable=unused-variable
+            start(next_b, jnp.where(more, i + 1, 0), 1 - slot)
+
+        kpos = i * t + jax.lax.broadcasted_iota(jnp.int32, (hr, t), 1)
+        # Rows are head-major, r a head.  Row j of a head sits at
+        # absolute position length + (j % s_q): the GQA fold keeps the
+        # S query tokens of each q-head contiguous.
         qpos = length + jax.lax.broadcasted_iota(
-            jnp.int32, (r, page_size), 0) % s_q
+            jnp.int32, (hr, t), 0) % r % s_q
+
+        def flat(buf, hh, dtype):
+            """Head hh of the step's pages as one [t, d] MXU operand
+            (int8 pages by way of f32, whose [pps, ps, d] Mosaic can
+            reshape without moving data)."""
+            x = buf[slot, :, hh]
+            if x.dtype == jnp.int8 or dtype == jnp.float32:
+                x = x.astype(jnp.float32)
+            return x.reshape(t, d).astype(dtype)
+
+        def scale_rows(buf):
+            """The step's scales over the score or probability tile,
+            [hr, t]: head hh's rows all hold its [1, t] scale row.  A
+            page's scales sit in one buffer row, head-major; Mosaic has
+            no reshape from [pps, ps] to [1, t], so a row is a lane
+            concatenation."""
+            x = buf[slot]
+            return per_head(lambda hh: jnp.broadcast_to(
+                jnp.concatenate(
+                    [x[j:j + 1, hh * page_size:(hh + 1) * page_size]
+                     for j in range(pps)], axis=1), (r, t)))
+
+        # The dots are a head's; everything between them runs once on
+        # the [hr, t] tile of all heads.
+        copies(b, i, slot, (0,), lambda c: c.wait())
+        # bf16 q and K go to the MXU as they are (products exact, f32
+        # accumulation) and the SCORES are scaled; any other pair is
+        # widened to f32 first.
+        s = per_head(lambda hh: jax.lax.dot_general(
+            q_ref[0, hh].astype(k_dtype), flat(k_buf, hh, k_dtype),
+            (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)) * sm_scale
+        if quantized:
+            s = s * scale_rows(ks_buf)
         s = jnp.where(kpos <= qpos, s, NEG_INF)
+        # Column 0 is always live (kpos 0 <= length), so m is finite
+        # from the first step on.
         m_prev = jnp.max(m_ref[...], axis=-1, keepdims=True)
         l_prev = jnp.max(l_ref[...], axis=-1, keepdims=True)
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         corr = jnp.exp(m_prev - m_new)
         p = jnp.exp(s - m_new)
         l_new = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
+        copies(b, i, slot, (1,), lambda c: c.wait())
         if quantized:
-            p = p * vs_ref[0, pl.ds(hh, 1), :]
-        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
-            p, v_ref[0, 0].astype(jnp.float32), (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[...] = jnp.broadcast_to(m_new, (r, _LANES))
-        l_ref[...] = jnp.broadcast_to(l_new, (r, _LANES))
+            p = p * scale_rows(vs_buf)
+        if exact:
+            # p as three bf16 pieces whose sum is p exactly (3 x 8
+            # mantissa bits), a head's three stacked on the row axis so
+            # that its V is loaded into the MXU once: every product is
+            # exact in f32, as with p and V both widened, in one pass.
+            hi = p.astype(jnp.bfloat16).astype(jnp.float32)
+            mid = (p - hi).astype(jnp.bfloat16).astype(jnp.float32)
+            pieces = [x.astype(jnp.bfloat16) for x in (hi, mid, p - hi - mid)]
 
-    @pl.when(i == num_rows - 1)
-    def _finish():  # pylint: disable=unused-variable
-        l = jnp.max(l_ref[...], axis=-1, keepdims=True)
-        o_ref[0, 0] = (acc_ref[...] /
-                       jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+            def pv_head(hh):
+                o3 = jax.lax.dot_general(
+                    jnp.concatenate([x[hh * r:(hh + 1) * r] for x in pieces],
+                                    axis=0),
+                    flat(v_buf, hh, jnp.bfloat16), (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+                return o3[:r] + o3[r:2 * r] + o3[2 * r:]
+        else:
+            def pv_head(hh):
+                return jax.lax.dot_general(
+                    p[hh * r:(hh + 1) * r], flat(v_buf, hh, jnp.float32),
+                    (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+        acc_ref[...] = acc_ref[...] * corr + per_head(pv_head)
+        m_ref[...] = jnp.broadcast_to(m_new, (hr, _LANES))
+        l_ref[...] = jnp.broadcast_to(l_new, (hr, _LANES))
+        return _
+
+    jax.lax.fori_loop(0, n_steps, step, None)
+    l = jnp.max(l_ref[...], axis=-1, keepdims=True)
+    o_ref[0] = (acc_ref[...] / jnp.maximum(l, 1e-30)).reshape(
+        h_kv, r, d).astype(o_ref.dtype)
 
 
 def _paged_attention_pallas(q, k_leaf, v_leaf, tables, lengths, *,
@@ -156,57 +315,65 @@ def _paged_attention_pallas(q, k_leaf, v_leaf, tables, lengths, *,
     h_kv, ps = pool.shape[1], pool.shape[2]
     rep = h_q // h_kv
     r = rep * s_q
-    num_rows = tables.shape[1]
-    # Fold GQA + the S query tokens into one row axis: row
-    # qh_local * s_q + j is q-head (qh_local within the kv group) at
-    # query token j.  sm_scale is folded into q once, outside.
-    qr = (q.reshape(b, h_kv, rep, s_q, d).reshape(b, h_kv, r, d)
-          .astype(jnp.float32) * sm_scale)
     tables = jnp.asarray(tables, jnp.int32)
     lengths = jnp.asarray(lengths, jnp.int32)
+    pps = _pages_per_step(tables.shape[1], h_kv, ps, d,
+                          pool.dtype.itemsize)
+    # Fold GQA + the S query tokens into one row axis: row
+    # qh_local * s_q + j is q-head (qh_local within the kv group) at
+    # query token j.  Rows are padded to whole sublane tiles (the MXU
+    # and the vector unit work on 8 rows whatever r is); a padding row
+    # is a zero query, its output dropped.
+    r_pad = -(-r // 8) * 8
+    qr = jnp.pad(q.reshape(b, h_kv, rep, s_q, d).reshape(b, h_kv, r, d),
+                 ((0, 0), (0, 0), (0, r_pad - r), (0, 0)))
 
-    grid = (b, h_kv, num_rows)
-    q_spec = pl.BlockSpec(
-        (1, 1, r, d), lambda bb, hh, ii, tt, ll: (bb, hh, 0, 0),
+    row_spec = pl.BlockSpec(
+        (1, h_kv, r_pad, d), lambda bb, tt, ll: (bb, 0, 0, 0),
         memory_space=pltpu.VMEM)
-    # The block-table read happens HERE: each program's K/V page is
-    # pool row tables[b, i] — the gathered view never materialises.
-    kv_spec = pl.BlockSpec(
-        (1, 1, ps, d),
-        lambda bb, hh, ii, tt, ll: (tt[bb, ii], hh, 0, 0),
-        memory_space=pltpu.VMEM)
-    # Last two block dims equal the array's (h_kv, ps): see the kernel
-    # docstring.
-    scale_spec = pl.BlockSpec(
-        (1, h_kv, ps), lambda bb, hh, ii, tt, ll: (tt[bb, ii], 0, 0),
-        memory_space=pltpu.VMEM)
-    out_spec = pl.BlockSpec(
-        (1, 1, r, d), lambda bb, hh, ii, tt, ll: (bb, hh, 0, 0),
-        memory_space=pltpu.VMEM)
-    scratch = [pltpu.VMEM((r, d), jnp.float32),
-               pltpu.VMEM((r, _LANES), jnp.float32),
-               pltpu.VMEM((r, _LANES), jnp.float32)]
+    # The pools never enter VMEM whole: the kernel copies the pages a
+    # slot's table names, and only the live ones.
+    pool_spec = pl.BlockSpec(memory_space=pl.ANY)
+    kv_buf = pltpu.VMEM((2, pps, h_kv, ps, d), pool.dtype)
     if quantized:
-        in_specs = [q_spec, kv_spec, scale_spec, kv_spec, scale_spec]
-        operands = (qr, k_leaf['q'], k_leaf['scale'], v_leaf['q'],
-                    v_leaf['scale'])
+        # A page's [h_kv, ps] scales as one row, padded to whole lane
+        # tiles: what a DMA can slice by page index.
+        width = -(-h_kv * ps // _LANES) * _LANES
+
+        def rows(scale):
+            flat = scale.reshape(scale.shape[0], h_kv * ps)
+            return jnp.pad(flat, ((0, 0), (0, width - h_kv * ps)))
+
+        scale_buf = pltpu.VMEM((2, pps, width), jnp.float32)
+        operands = (qr, k_leaf['q'], rows(k_leaf['scale']), v_leaf['q'],
+                    rows(v_leaf['scale']))
+        buffers = [kv_buf, kv_buf, scale_buf, scale_buf]
     else:
-        in_specs = [q_spec, kv_spec, kv_spec]
         operands = (qr, k_leaf, v_leaf)
+        buffers = [kv_buf, kv_buf]
+    scratch = buffers + [pltpu.SemaphoreType.DMA((2, 2)),
+                         pltpu.SMEM((1,), jnp.int32),
+                         pltpu.VMEM((h_kv * r_pad, d), jnp.float32),
+                         pltpu.VMEM((h_kv * r_pad, _LANES), jnp.float32),
+                         pltpu.VMEM((h_kv * r_pad, _LANES), jnp.float32)]
     out = pl.pallas_call(
         functools.partial(_paged_decode_kernel, page_size=ps, s_q=s_q,
-                          num_rows=num_rows, quantized=quantized),
+                          pages_per_step=pps, sm_scale=sm_scale,
+                          quantized=quantized),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=grid,
-            in_specs=in_specs,
-            out_specs=out_spec,
+            grid=(b,),
+            in_specs=[row_spec] + [pool_spec] * (len(operands) - 1),
+            out_specs=row_spec,
             scratch_shapes=scratch),
-        out_shape=jax.ShapeDtypeStruct((b, h_kv, r, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, h_kv, r_pad, d), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=('arbitrary',)),
         interpret=interpret_mode(),
         name='paged_decode_attention',
     )(tables, lengths, *operands)
-    return out.reshape(b, h_kv, rep, s_q, d).reshape(b, h_q, s_q, d)
+    return out[:, :, :r].reshape(b, h_kv, rep, s_q, d).reshape(
+        b, h_q, s_q, d)
 
 
 def _paged_attention_reference(q, k_leaf, v_leaf, tables, lengths, *,

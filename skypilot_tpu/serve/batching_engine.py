@@ -204,6 +204,11 @@ _M_SPEC_ACCEPT_LEN = metrics_lib.histogram(
     'Tokens emitted per slot per speculative verify tick (1 = every '
     'draft rejected; k+1 = all accepted plus the bonus token).',
     buckets=(1.0, 2.0, 3.0, 4.0, 6.0, 8.0, 12.0, 16.0))
+_M_KERNEL_LIVE_SHARE = metrics_lib.gauge(
+    'skytpu_engine_paged_kernel_live_page_share',
+    'Share of the block tables\' rows (slots x rows) that the last '
+    'paged decode tick\'s live contexts reached: the pages the decode '
+    'kernel walks over the pages a table-sized grid would visit.')
 _M_KERNEL_PALLAS = metrics_lib.gauge(
     'skytpu_engine_decode_kernel_pallas',
     'Whether the paged decode attention runs the Pallas kernel '
@@ -434,6 +439,8 @@ class ContinuousBatchingEngine:
         self._metrics_lock = threading.Lock()
         self._tokens_generated = 0
         self._ticks = 0
+        self._kernel_live_pages = 0
+        self._kernel_table_pages = 0
         self._prefill_chunks = 0
         self._page_deferrals = 0
         self._spec_ticks = 0
@@ -926,7 +933,9 @@ class ContinuousBatchingEngine:
         merely popular (serve/autoscalers.py consumes busy/slots as
         replica load).  Paged engines add the page-pool view:
         kv_pages_{total,used,free,pinned}, prefix-cache entry/hit/miss
-        counts, and pages_exhausted_deferrals."""
+        counts, pages_exhausted_deferrals, and paged_kernel (the pages
+        the decode ticks' live contexts held beside the rows of every
+        block table: how much of `max_len` the traffic uses)."""
         busy = sum(1 for s in self._slots if s.active)
         with self._metrics_lock:
             stats = {
@@ -959,6 +968,11 @@ class ContinuousBatchingEngine:
             stats.update(self._kv.stats())
             with self._metrics_lock:
                 stats['pages_exhausted_deferrals'] = self._page_deferrals
+                # Cumulative over paged ticks: what the decode kernel
+                # walked of what the block tables have rows for.
+                stats['paged_kernel'] = {
+                    'live_pages': self._kernel_live_pages,
+                    'table_pages': self._kernel_table_pages}
         rate = round(self._decode_rate(), 3)
         stats['decode_tokens_per_s'] = rate
         # The worker loop's cumulative totals (iterations, seconds by
@@ -1321,7 +1335,9 @@ class ContinuousBatchingEngine:
                   token: int, length: int, *, remaining: int,
                   key) -> None:
         """Flip a slot live in the device state (one jitted dispatch)."""
-        del length  # cache lengths are set by insert/admission paths
+        # The device's cache lengths are set by the insert/admission
+        # paths; the host keeps its own count for stats()['paged_kernel'].
+        self._slots[slot_id].depth = length
         if self.spec_tokens:
             # Seed the slot's drafter with everything decoded so far:
             # the history must END with the token the next tick feeds
@@ -1350,6 +1366,20 @@ class ContinuousBatchingEngine:
             return
         self._cache = self._release_paged(self._cache, slot_id)
         self._kv.release(slot_id)
+
+    def _count_kernel_pages(self, live, s_q: int) -> None:
+        """Add one paged tick to stats()['paged_kernel']: the pages the
+        decode kernel walks (per live slot, those that hold its cache
+        and the tick's `s_q` new tokens) beside the rows of every
+        slot's block table.  From the host's own depth of each slot,
+        no device read."""
+        ps = self._kv.page_size
+        walked = sum(-(-(self._slots[i].depth + s_q) // ps) for i in live)
+        rows = len(self._slots) * (self.max_len // ps)
+        with self._metrics_lock:
+            self._kernel_live_pages += walked
+            self._kernel_table_pages += rows
+        _M_KERNEL_LIVE_SHARE.set(walked / rows)
 
     def _dispatch_step(self):
         """Dispatch one jitted engine tick.  The slice engine
@@ -1391,6 +1421,7 @@ class ContinuousBatchingEngine:
                 drafter = self._slots[slot_id].drafter
                 if drafter is not None:
                     drafts[slot_id] = drafter.propose(k)
+            self._count_kernel_pages(live, k + 1)
             drafts_dev = self._jnp.asarray(drafts)
             if self._mesh is not None:
                 from skypilot_tpu.parallel import sharding as sharding_lib  # pylint: disable=import-outside-toplevel
@@ -1412,6 +1443,7 @@ class ContinuousBatchingEngine:
                     continue
                 slot_ticks += 1
                 c = int(counts[slot_id])
+                self._slots[slot_id].depth += c
                 emitted = [int(t) for t in toks[slot_id, :c]]
                 drafter = self._slots[slot_id].drafter
                 if drafter is not None and emitted:
@@ -1588,6 +1620,10 @@ class ContinuousBatchingEngine:
                     with prof.phase('decode-step', count=len(live)):
                         self._state, self._cache, finished = (
                             self._dispatch_step())
+                    if self._kv is not None:
+                        self._count_kernel_pages(live, 1)
+                        for slot_id in live:
+                            self._slots[slot_id].depth += 1
                     dispatched = (self._state, finished,
                                   list(live.items()))
                 if inflight is not None:

@@ -285,6 +285,7 @@ class Slot:
         self.request: Optional[Request] = None
         self.next_token = 0          # legacy (unpipelined) loop only
         self.drafter = None          # NgramDrafter when spec decoding
+        self.depth = 0               # cache length the next tick starts at
 
     @property
     def active(self) -> bool:

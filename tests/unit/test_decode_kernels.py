@@ -12,6 +12,7 @@ module-scoped: every instance re-jits the paged step."""
 from __future__ import annotations
 
 import os
+import time
 
 import flax.linen as nn
 import jax
@@ -134,6 +135,122 @@ class TestKernelChoice:
         assert pallas_engine.stats()['decode_kernel'] == 'pallas'
 
 
+def _kernel_case(quantized, s_q, rep, poison, *, h_kv=2, d=32, ps=8,
+                 slots=4, rows=11):
+    """Pools, tables and ragged lengths for one kernel call: an empty
+    slot, one a token short of a page boundary, one on it, one that
+    fills its table.  Every slot's live rows name pages of its own;
+    its dead rows name the null page and pages no slot uses.  With
+    `poison` those pages hold NaN (an int8 pool: NaN scales), so one
+    dead row fetched and used shows in the output."""
+    from skypilot_tpu.models.decode import _quant_kv
+    max_len = rows * ps
+    lengths = np.asarray([0, 3 * ps - 1, 3 * ps, max_len - s_q], np.int32)
+    live = -(-(lengths + s_q) // ps)
+    n_pages = 1 + int(live.sum()) + 5
+    ks = jax.random.split(jax.random.PRNGKey(7), 3)
+    q = jax.random.normal(ks[0], (slots, h_kv * rep, s_q, d), jnp.float32)
+    k = jax.random.normal(ks[1], (n_pages, h_kv, ps, d), jnp.float32)
+    v = jax.random.normal(ks[2], (n_pages, h_kv, ps, d), jnp.float32)
+    unused = np.arange(1 + int(live.sum()), n_pages)
+    tables = np.zeros((slots, rows), np.int32)
+    nxt = 1
+    for b in range(slots):
+        tables[b, :live[b]] = np.arange(nxt, nxt + live[b])
+        nxt += live[b]
+        dead = rows - live[b]
+        tables[b, live[b]:] = np.resize(np.append(unused, 0), dead)
+    bad = np.zeros((n_pages,), bool)
+    bad[0] = True
+    bad[unused] = True
+
+    def pool(x):
+        if quantized:
+            xq, scale = _quant_kv(x)
+            clean = {'q': xq, 'scale': scale}
+            dirty = {'q': xq, 'scale': jnp.where(
+                bad[:, None, None], jnp.nan, scale)}
+        else:
+            clean = x
+            dirty = jnp.where(bad[:, None, None, None], jnp.nan, x)
+        return clean, dirty if poison else clean
+
+    (k_clean, k_run), (v_clean, v_run) = pool(k), pool(v)
+    return (q, k_clean, v_clean, k_run, v_run, jnp.asarray(tables),
+            jnp.asarray(lengths))
+
+
+class TestPagedKernel:
+    """The kernel itself, in the interpreter, against the gather
+    reference: no engine, so every shape of the walk is named here."""
+
+    @pytest.mark.parametrize('poison', [False, True],
+                             ids=['clean', 'nan-dead-pages'])
+    @pytest.mark.parametrize('rep', [2, 4], ids=['gqa2', 'gqa4'])
+    @pytest.mark.parametrize('s_q', [1, 4], ids=['decode', 'verify4'])
+    @pytest.mark.parametrize('quantized', [False, True],
+                             ids=['f32pool', 'int8pool'])
+    def test_walk_matches_reference(self, monkeypatch, quantized, s_q,
+                                    rep, poison):
+        """Three pages a step over tables of eleven rows: a trip count
+        that does not divide the table, slots of 1, 3, 4 and 11 live
+        pages in one batch.  With dead pages poisoned the output is
+        finite and equal to the reference on the clean pool, so a dead
+        row is neither fetched nor used."""
+        monkeypatch.setenv('SKYTPU_PALLAS_INTERPRET', '1')
+        monkeypatch.setattr(paged_attention, '_STEP_TOKENS', 3 * 8)
+        q, k_clean, v_clean, k_run, v_run, tables, lengths = (
+            _kernel_case(quantized, s_q, rep, poison))
+        assert paged_attention._pages_per_step(
+            tables.shape[1], 2, 8, 32, 1 if quantized else 4) == 3
+        sm_scale = 32 ** -0.5
+        out = paged_attention._paged_attention_pallas(
+            q, k_run, v_run, tables, lengths, sm_scale=sm_scale)
+        ref = paged_attention._paged_attention_reference(
+            q, k_clean, v_clean, tables, lengths, sm_scale=sm_scale)
+        assert np.isfinite(np.asarray(out)).all()
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   atol=2e-5, rtol=2e-5)
+
+    @pytest.mark.parametrize('pool_dtype', [jnp.bfloat16, jnp.int8],
+                             ids=['bf16pool', 'int8pool'])
+    def test_bf16_operands_take_the_mxu_path(self, monkeypatch,
+                                             pool_dtype):
+        """bf16 q on bf16 or int8 pages (what a chip serves): K goes to
+        the dot as bf16 and p as three bf16 pieces that sum to p, so
+        the result is the f32 one to f32 rounding, not bf16's."""
+        monkeypatch.setenv('SKYTPU_PALLAS_INTERPRET', '1')
+        monkeypatch.setattr(paged_attention, '_STEP_TOKENS', 3 * 8)
+        quantized = pool_dtype == jnp.int8
+        q, k, v, _, _, tables, lengths = _kernel_case(
+            quantized, 1, 4, False)
+        q = q.astype(jnp.bfloat16)
+        if not quantized:
+            k, v = k.astype(jnp.bfloat16), v.astype(jnp.bfloat16)
+        sm_scale = 32 ** -0.5
+        out = paged_attention._paged_attention_pallas(
+            q, k, v, tables, lengths, sm_scale=sm_scale)
+        ref = paged_attention._paged_attention_reference(
+            q.astype(jnp.float32), k, v, tables, lengths,
+            sm_scale=sm_scale)
+        assert out.dtype == jnp.bfloat16
+        # One bf16 rounding of an output of magnitude <= 4.
+        np.testing.assert_allclose(np.asarray(out, np.float32),
+                                   np.asarray(ref), atol=2 ** -6)
+
+    @pytest.mark.parametrize('shape,expected', [
+        # rows, kv heads, page size, head dim, itemsize -> pages a step
+        ((160, 8, 16, 128, 2), 32),     # Mistral-7B, bf16: 512 tokens
+        ((96, 8, 16, 128, 2), 32),      # InternLM2-1.8B
+        ((160, 2, 16, 128, 2), 32),     # a tensor-4 shard: the token cap
+        ((160, 32, 16, 128, 2), 8),     # fat pages: the VMEM budget
+        ((20, 8, 16, 128, 1), 20),      # a short table: its rows
+        ((8, 2, 8, 32, 4), 8),          # the tiny engine of these tests
+    ])
+    def test_pages_per_step_from_shapes(self, shape, expected):
+        assert paged_attention._pages_per_step(*shape) == expected
+
+
 class TestPallasKernelParity:
 
     def test_greedy_parity_vs_dense_reference(self, setup,
@@ -182,6 +299,44 @@ class TestPallasKernelParity:
         finally:
             eng_p.stop()
             eng_g.stop()
+
+    def test_paged_kernel_counter_follows_the_caches(self, setup):
+        """stats()['paged_kernel']: per tick and live slot the pages
+        that hold its cache and the new token, beside the rows of
+        every table.  A request of prompt n and m new tokens rides
+        m + 1 ticks (the loop dispatches one tick ahead of the read
+        that shows it the finish) at cache lengths n - 1, n, ..."""
+        from skypilot_tpu.observability import metrics as metrics_lib
+        cfg, params = setup
+        eng = _engine(cfg, params, kernel='pallas')
+        try:
+            ps, rows, slots = 8, 64 // 8, 2
+            assert eng.stats()['paged_kernel'] == {
+                'live_pages': 0, 'table_pages': 0}
+            script = (([3, 1, 4, 1, 5, 9, 2, 6, 5, 3, 5], 6),
+                      ([7, 2, 9], 9))
+            handles = [eng.submit(p, max_new_tokens=n)
+                       for p, n in script]
+            for h, (_, n) in zip(handles, script):
+                assert len(h.result(timeout=180)) == n
+            deadline = time.monotonic() + 30
+            while (eng.stats()['busy_slots'] and
+                   time.monotonic() < deadline):
+                time.sleep(0.01)
+            stats = eng.stats()
+            walked = sum(-(-(len(p) - 1 + j + 1) // ps)
+                         for p, n in script for j in range(n + 1))
+            assert stats['paged_kernel']['live_pages'] == walked
+            # Every dispatched tick adds the rows of every table; a
+            # tick is counted in `ticks` only once it has been read.
+            table_pages = stats['paged_kernel']['table_pages']
+            assert table_pages % (slots * rows) == 0
+            assert table_pages >= stats['ticks'] * slots * rows
+            assert 0 < walked <= table_pages
+            assert ('skytpu_engine_paged_kernel_live_page_share'
+                    in metrics_lib.expose())
+        finally:
+            eng.stop()
 
     def test_kernel_gauge_tracks_choice(self, setup):
         from skypilot_tpu.observability import metrics as metrics_lib

@@ -1,0 +1,63 @@
+"""Compile the main path's kernels for a TPU v5e that is described,
+not attached: Mosaic refuses here, in seconds and without a chip, what
+interpret mode lets through (a block or a DMA slice off the tiling, a
+reshape it has no layout for, more VMEM than a kernel may use).  It
+compiles only — whether the result is right is the chip suite's
+(tests/tpu/).
+
+The topology is described inside a fixture, never at import: only one
+process may load libtpu, and every xdist worker imports this file.
+Keep such tests in this one file.
+"""
+from __future__ import annotations
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from skypilot_tpu.ops import paged_attention
+
+
+@pytest.fixture(scope='module')
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    os.environ.setdefault('TPU_LOG_DIR', 'disabled')
+    try:
+        topo = topologies.get_topology_desc(platform='tpu',
+                                            topology_name='v5e:2x2')
+    except Exception as e:  # pylint: disable=broad-except
+        pytest.skip(f'no v5e:2x2 topology can be described here: {e}')
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize('slots,h_q,h_kv,s_q,rows,n_pages,quantized', [
+    (16, 32, 8, 1, 160, 2432, False),   # benchmark: Mistral-7B engine
+    (24, 16, 8, 1, 96, 2048, False),    # benchmark: InternLM2-1.8B
+    (16, 32, 8, 4, 160, 2432, False),   # speculative verify, k = 3
+    (16, 32, 8, 1, 160, 2432, True),    # int8 pages
+    (16, 8, 2, 1, 160, 2432, False),    # one shard of --tensor 4
+    (16, 8, 2, 4, 160, 2432, True),     # ... int8, verify
+], ids=['mistral', 'internlm2', 'verify4', 'int8', 'shard', 'shard-int8'])
+def test_paged_decode_kernel_compiles_for_v5e(
+        one_chip, monkeypatch, slots, h_q, h_kv, s_q, rows, n_pages,
+        quantized):
+    monkeypatch.delenv('SKYTPU_PALLAS_INTERPRET', raising=False)
+    d, ps = 128, 16
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool = (
+        {'q': arg((n_pages, h_kv, ps, d), jnp.int8),
+         'scale': arg((n_pages, h_kv, ps), jnp.float32)}
+        if quantized else arg((n_pages, h_kv, ps, d), jnp.bfloat16))
+    compiled = jax.jit(
+        lambda *a: paged_attention._paged_attention_pallas(
+            *a, sm_scale=d ** -0.5)).lower(
+                arg((slots, h_q, s_q, d), jnp.bfloat16), pool, pool,
+                arg((slots, rows), jnp.int32),
+                arg((slots,), jnp.int32)).compile()
+    assert 'tpu_custom_call' in compiled.as_text()
