@@ -1,7 +1,6 @@
 """What the traffic needed inside the traced span, from the benchmark's
 own record of prompt lengths, cached prefixes and token arrival times:
 shared by the readers that set needed work against device time."""
-from benchmarks import cost
 
 
 def decoded_contexts(run):
@@ -20,7 +19,7 @@ def needed_flops(run) -> float:
     none."""
     t_a, t_b = run.trace_span
     page = run.geometry['page_size']
-    total = sum(cost.decode_flops(run.model, c)
+    total = sum(run.family.decode_flops(run.model, c)
                 for c in decoded_contexts(run))
     for r in run.requests:
         span = getattr(r.handle, 'span', None)
@@ -34,6 +33,6 @@ def needed_flops(run) -> float:
         cached = span.prefix_hit_pages * page
         fresh = len(r.prompt) - 1 - cached
         if fresh > 0:
-            total += (cost.prefill_flops(run.model, cached, fresh) *
+            total += (run.family.prefill_flops(run.model, cached, fresh) *
                       overlap / (end - begin))
     return total

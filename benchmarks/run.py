@@ -3,9 +3,11 @@
     python3 benchmarks/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 looks the cell up in `BENCHMARK.json`, loads `configs/<config>.json`
-and `workloads/<traffic>.json`, imports `layouts/<layout>.py` and, for
-a traced run, `layers/<metric>.py` for each per-layer metric of the
-cell.  Nothing here switches on the name of a cell, a configuration, a
+and `workloads/<traffic>.json`, imports `families/<family>.py` (the
+architecture: program config, weight shapes, plain reference, work
+counts) and `layouts/<layout>.py` and, for a traced run,
+`layers/<metric>.py` for each per-layer metric of the cell.  Nothing
+here switches on the name of a cell, a configuration, a family, a
 layout or a per-layer metric: each is a file found by its name.
 
 One run, in order: find the chips or fail; persistent compile cache;
@@ -50,7 +52,8 @@ if _ROOT not in sys.path:
 # this many and they hold the cell's `sampled_tokens_min` served tokens.
 _SAMPLE_REQUESTS = 6
 # The reference's sequences are padded to a multiple of this, and the
-# rows it unembeds to `_ROWS`, so that few shapes compile.
+# rows it unembeds to `_ROWS`, so that few shapes compile; an answer
+# longer than that is read in blocks of `_ROWS`.
 _SEQ_BUCKET = 512
 _ROWS = 512
 # Set-up's requests in flight at once.
@@ -79,30 +82,6 @@ def _find(rows: List[Dict[str, Any]], name: str, what: str):
 
 def _reports(metric: Dict[str, Any], cell: str) -> bool:
     return 'workloads' not in metric or cell in metric['workloads']
-
-
-# HF config key -> the program's ModelConfig field.
-_TO_PROGRAM = {
-    'hidden_size': 'd_model', 'num_hidden_layers': 'n_layers',
-    'num_attention_heads': 'n_heads', 'num_key_value_heads': 'n_kv_heads',
-    'intermediate_size': 'd_ff', 'vocab_size': 'vocab_size',
-    'rope_theta': 'rope_theta', 'rms_norm_eps': 'norm_eps',
-    'hidden_act': 'mlp_act', 'tie_word_embeddings': 'tie_embeddings',
-    'torch_dtype': 'dtype',
-}
-
-
-def program_config(model: Dict[str, Any], max_len: int):
-    """The configuration file's published keys as the program's
-    `ModelConfig`, through `config_from_json_dict`."""
-    from skypilot_tpu.models import configs
-    d = {ours: model[theirs] for theirs, ours in _TO_PROGRAM.items()}
-    d.update(param_dtype=model['torch_dtype'], max_seq_len=max_len,
-             remat=False)
-    derived = model['hidden_size'] // model['num_attention_heads']
-    if model.get('head_dim') not in (None, derived):
-        d['head_dim_override'] = model['head_dim']
-    return configs.config_from_json_dict(d)
 
 
 def find_devices(chips: int, dry_run: bool):
@@ -172,6 +151,20 @@ def _pad(tokens: List[int]) -> List[int]:
     return tokens + [0] * (n - len(tokens))
 
 
+def _blocks(n: int, m: int, length: int):
+    """Row n - 1 + j of a sequence of `length` gives served token j of
+    m: yields (first, lo, j, k), blocks of `_ROWS` rows from `first` of
+    which rows lo.. hold tokens j..j + k.  One block where the answer
+    fits one, as many as it needs where it does not."""
+    j = 0
+    while j < m:
+        first = min(n - 1 + j, length - _ROWS)
+        lo = n - 1 + j - first
+        k = min(m - j, _ROWS - lo)
+        yield first, lo, j, k
+        j += k
+
+
 def compare(run, params, control: Optional[str],
             min_tokens: int) -> Dict[str, Any]:
     """How `correct` is decided: the served tokens of a seeded sample of
@@ -180,7 +173,6 @@ def compare(run, params, control: Optional[str],
     import jax
     import jax.numpy as jnp
     import numpy as np
-    from benchmarks import reference
 
     done = [r for r in run.requests
             if r.error is None and r.done_s is not None and
@@ -219,27 +211,26 @@ def compare(run, params, control: Optional[str],
         served = list(r.handle.tokens)
         n, m = len(r.prompt), len(served)
         seq = _pad(r.prompt + served[:-1])
-        first = min(n - 1, len(seq) - _ROWS)
-        ref = reference.logits(run.model, params, seq, first, _ROWS)
-        valid = np.zeros((_ROWS,), bool)
-        picked = np.zeros((_ROWS,), np.int32)
-        lo = n - 1 - first
-        valid[lo:lo + m] = True
-        picked[lo:lo + m] = served
-        gaps = np.asarray(gaps_of(ref, jnp.asarray(picked),
-                                  jnp.asarray(valid)))
-        worst = max(worst, float(gaps.max()))
-        flips += int((gaps > 0).sum())
+        for first, lo, j, k in _blocks(n, m, len(seq)):
+            ref = run.family.logits(run.model, params, seq, first, _ROWS)
+            valid = np.zeros((_ROWS,), bool)
+            picked = np.zeros((_ROWS,), np.int32)
+            valid[lo:lo + k] = True
+            picked[lo:lo + k] = served[j:j + k]
+            gaps = np.asarray(gaps_of(ref, jnp.asarray(picked),
+                                      jnp.asarray(valid)))
+            worst = max(worst, float(gaps.max()))
+            flips += int((gaps > 0).sum())
+            if control:
+                low = run.family.logits(run.model, params, seq, first,
+                                        _ROWS, precision=control)
+                gaps_c = np.asarray(gaps_of(
+                    ref, jnp.argmax(low, axis=-1).astype(jnp.int32),
+                    jnp.asarray(valid)))
+                worst_ctrl = max(worst_ctrl or 0.0, float(gaps_c.max()))
+                del low
+            del ref
         n_tokens += m
-        if control:
-            low = reference.logits(run.model, params, seq, first, _ROWS,
-                                   precision=control)
-            gaps_c = np.asarray(gaps_of(
-                ref, jnp.argmax(low, axis=-1).astype(jnp.int32),
-                jnp.asarray(valid)))
-            worst_ctrl = max(worst_ctrl or 0.0, float(gaps_c.max()))
-            del low
-        del ref
     numbers.update(sampled_tokens=n_tokens, sampled_flips=flips,
                    logit_gap_max=worst)
     if control:
@@ -304,7 +295,7 @@ def main(argv: Optional[List[str]] = None) -> int:
 
     import jax
     from skypilot_tpu import compile_cache
-    from benchmarks import traffic
+    from benchmarks import families, traffic
 
     devices, peak, attach_s = find_devices(chips, args.dry_run)
     cache_dir = None
@@ -317,13 +308,14 @@ def main(argv: Optional[List[str]] = None) -> int:
     _log(f'devices {[d.device_kind for d in devices]} (runtime up in '
          f'{attach_s:.2f} s, not counted in setup_s) cache {cache_dir}')
 
+    family = families.of(model)
     layout = importlib.import_module(f'benchmarks.layouts.{model["layout"]}')
     mesh, params = layout.build(model, devices, args.seed)
     jax.block_until_ready(params)
     _log('weights on device')
 
     from skypilot_tpu.serve import batching_engine
-    cfg = program_config(model, geometry['max_len'])
+    cfg = family.program_config(model, geometry['max_len'])
     engine = batching_engine.ContinuousBatchingEngine(
         cfg, params, mesh=mesh, **geometry)
     mix = traffic.Mix(spec, args.seed, model['vocab_size'])
@@ -404,7 +396,7 @@ def main(argv: Optional[List[str]] = None) -> int:
     requests = driver.sent
     del warm, driver    # they hold the engine's entry
     run = types.SimpleNamespace(
-        cell=cell, model=model, spec=spec, geometry=geometry,
+        cell=cell, model=model, family=family, spec=spec, geometry=geometry,
         seed=args.seed, seconds=args.seconds, chips=chips, peak=peak,
         requests=requests, setup_s=setup_s, closed_s=closed_s,
         stats0=stats0, stats1=stats1, trace=None, trace_span=trace_span,

@@ -26,6 +26,14 @@ Keys of a mix (all but `loop` and the two length blocks optional):
                   tokens (the question, where a prefix is shared)
   output_tokens   {median, sigma, min, max, levels}
   order_seed      fixes the order of lengths and gaps (see above)
+  dither_ms       open loop: each request falls due up to this much
+                  later, drawn from the run's seed.  A fixed trace
+                  replays one alignment of the arrivals with the
+                  engine's loop to a tenth of a percent, until the
+                  host stalls once and every later first token moves by
+                  a part of a tick (PR 28): with the dither a run
+                  samples the alignment, so the spread of a set of
+                  seeds is the metric's own resolution on any machine
   shared_prefix   {count, tokens}: each request starts with one of
                   `count` seeded documents, drawn evenly; set-up asks
                   each document once, so the window runs on a filled
@@ -146,12 +154,16 @@ class Mix:
         gaps *= seconds * (n - 1) / n / gaps.sum()
         gaps = gaps[_rng(self.order_seed, 4).permutation(n - 1)]
         due = np.concatenate([[0.0], np.cumsum(gaps)]) + seconds / (2 * n)
+        dither = float(self.spec.get('dither_ms', 0.0)) / 1e3
+        if dither:
+            due = due + _rng(self.seed, 8).uniform(0.0, dither, n)
         out = []
         for i in range(n):
             r = self.request(i)
             r.due_s = float(due[i])
             out.append(r)
-        return out
+        # A dither wider than the smallest gap can swap two neighbours.
+        return sorted(out, key=lambda r: r.due_s)
 
     def warmup(self) -> List[List[Request]]:
         """Set-up's requests, in phases that run one after another.
