@@ -2,9 +2,20 @@
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax.numpy as jnp
+
+# Layer kinds a `layer_pattern` may name, with what each kind sets: does
+# the layer rotate q and k (rotary embedding), and does a query see only
+# the last `sliding_window` keys.  A new kind is a row here, read by
+# `ModelConfig.layer_kinds`; the layer body takes the two settings as
+# per-layer data and never switches on a kind's name.
+LAYER_KINDS = {
+    'full': {'rope': True, 'window': False},
+    'window': {'rope': True, 'window': True},
+    'full_nope': {'rope': False, 'window': False},
+}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -49,11 +60,31 @@ class ModelConfig:
     # (two all-to-alls re-shard seq<->heads, one plain flash per
     # device; needs heads % sequence_axis == 0).  See ops/.
     sequence_parallel: str = 'ring'
-    # Mixture-of-Experts (0 experts = dense MLP).
+    # Mixture-of-Experts (0 experts = dense MLP); d_ff is one expert's
+    # width.  n_experts is the ROUTER's width (the published count);
+    # `experts_held` = (lo, n) says which of them this program holds
+    # (None = all): the layer routes over all n_experts and computes its
+    # own experts' part of the result (models/moe.py).
     n_experts: int = 0
     expert_top_k: int = 2
-    expert_capacity_factor: float = 1.25
+    experts_held: Optional[Tuple[int, int]] = None
+    expert_score_fn: str = 'softmax'  # 'softmax' | 'sigmoid'
+    # Shared experts every token passes through, added to the routed
+    # sum as their 'sum' or their 'average'.
+    n_shared_experts: int = 0
+    shared_expert_combine: str = 'sum'
     router_aux_loss_coef: float = 0.02
+    # One period of layer kinds (names of LAYER_KINDS), repeated over
+    # n_layers; () = every layer 'full'.  'window' layers attend the
+    # last `sliding_window` keys.
+    layer_pattern: Tuple[str, ...] = ()
+    sliding_window: int = 0
+    # 'rms' (RMSNorm) | 'layernorm' (mean-subtracting, scale, no bias).
+    norm_type: str = 'rms'
+    # Attention and the FFN read ONE normed input and are added to the
+    # residual together (no mlp_norm).
+    parallel_block: bool = False
+    logit_scale: float = 1.0          # logits x this, after the head
     # Family switches beyond Llama (Gemma/Qwen-style decoders):
     tie_embeddings: bool = False      # lm_head = embed^T (Gemma)
     qkv_bias: bool = False            # bias on q/k/v projections (Qwen2)
@@ -69,6 +100,36 @@ class ModelConfig:
         if self.head_dim_override is not None:
             return self.head_dim_override
         return self.d_model // self.n_heads
+
+    @property
+    def held_experts(self) -> Tuple[int, int]:
+        """(lo, n): the routed experts held, all where none is named."""
+        return self.experts_held or (0, self.n_experts)
+
+    def layer_kinds(self) -> Optional[Tuple[Tuple[bool, int], ...]]:
+        """Per layer (rope, window): whether q and k are rotated, and
+        how many keys a query sees (0 = all).  None where every layer
+        is 'full', so a model of one kind of layer takes the code it
+        took before kinds existed."""
+        if not self.layer_pattern:
+            return None
+        unknown = set(self.layer_pattern) - set(LAYER_KINDS)
+        if unknown:
+            raise ValueError(f'Unknown layer kinds {sorted(unknown)}; '
+                             f'have {sorted(LAYER_KINDS)}')
+        if self.n_layers % len(self.layer_pattern):
+            raise ValueError(
+                f'n_layers {self.n_layers} is not whole periods of '
+                f'layer_pattern {self.layer_pattern}')
+        period = []
+        for name in self.layer_pattern:
+            kind = LAYER_KINDS[name]
+            if kind['window'] and self.sliding_window <= 0:
+                raise ValueError(
+                    f'layer kind {name!r} needs sliding_window > 0')
+            period.append((kind['rope'],
+                           self.sliding_window if kind['window'] else 0))
+        return tuple(period) * (self.n_layers // len(period))
 
     def replace(self, **kw) -> 'ModelConfig':
         return dataclasses.replace(self, **kw)
@@ -92,6 +153,9 @@ def config_from_json_dict(d: dict) -> ModelConfig:
             # np.dtype resolves 'bfloat16' via ml_dtypes registration.
             d[key] = (jnp.bfloat16 if d[key] == 'bfloat16'
                       else np.dtype(d[key]).type)
+    for key in ('experts_held', 'layer_pattern'):
+        if isinstance(d.get(key), list):   # JSON has no tuple
+            d[key] = tuple(d[key])
     known = {f.name for f in dataclasses.fields(ModelConfig)}
     unknown = set(d) - known
     if unknown:

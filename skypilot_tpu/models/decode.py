@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 
 from skypilot_tpu.models import heads
+from skypilot_tpu.models import moe
 from skypilot_tpu.models.configs import ModelConfig
 from skypilot_tpu.models.quantize import maybe_dequant
 from skypilot_tpu.models.transformer import _rope
@@ -102,9 +103,19 @@ def _attn_proj(x, proj):
     return out
 
 
-def _mlp(x, lp, cfg):
+def _mlp(x, lp, cfg, row_mask=None):
+    """The layer's FFN on [b, s, d] -> (out, counts): the dense SwiGLU
+    (counts None), or the expert layer without drops (float32 out,
+    which the caller casts to the stream's dtype), with its int32 [3]
+    counts over the rows `row_mask` [b * s] marks (`moe.moe_apply`: the
+    one layer for a prefill chunk and a decode tick alike; a token's
+    result depends on no other token, so chunks may be padded and split
+    and slots batched)."""
     if cfg.n_experts > 0:
-        return _moe_mlp(x, lp['moe_mlp'], cfg)
+        b, s, d = x.shape
+        out, _, counts = moe.moe_apply(x.reshape(b * s, d), lp['moe_mlp'],
+                                       cfg, row_mask)
+        return out.reshape(b, s, d), counts
     act = {'silu': jax.nn.silu, 'gelu': jax.nn.gelu}[cfg.mlp_act]
     gate = jnp.einsum('bsd,df->bsf', x,
                       maybe_dequant(lp['mlp']['gate_proj']['kernel'],
@@ -114,69 +125,51 @@ def _mlp(x, lp, cfg):
                                   x.dtype))
     return jnp.einsum('bsf,fd->bsd', act(gate) * up,
                       maybe_dequant(lp['mlp']['down_proj']['kernel'],
-                                    x.dtype))
+                                    x.dtype)), None
 
 
-def _moe_mlp(x, mp, cfg):
-    """Inference MoE.  Prefill (s > 1) reuses the training path's
-    capacity dispatch (`moe.moe_apply`) — identical math AND identical
-    FLOPs profile, instead of paying n_experts/top_k x on long prompts.
-    Single-token decode uses dense-gather top-k without capacity
-    dropping (every selected token computes — the Mixtral inference
-    convention; with one token per sequence, balanced batched dispatch
-    buys nothing)."""
-    b, s, d = x.shape
-    tokens = x.reshape(b * s, d)
-    # Router stays full precision (routing decisions are
-    # quality-critical); expert stacks may be int8.
-    w_gate = maybe_dequant(mp['gate_proj'], jnp.float32)
-    w_up = maybe_dequant(mp['up_proj'], jnp.float32)
-    w_down = maybe_dequant(mp['down_proj'], jnp.float32)
-    logits = jnp.einsum('nd,de->ne', tokens.astype(jnp.float32),
-                        mp['router']['kernel'].astype(jnp.float32))
-    if s > 1:
-        from skypilot_tpu.models import moe  # pylint: disable=import-outside-toplevel
-        out, _ = moe.moe_apply(tokens, logits, w_gate, w_up, w_down, cfg)
-        return out.astype(x.dtype).reshape(b, s, d)
-    probs = jax.nn.softmax(logits, axis=-1)
-    gate_vals, gate_idx = jax.lax.top_k(probs, cfg.expert_top_k)
-    gate_vals = gate_vals / jnp.sum(gate_vals, axis=-1, keepdims=True)
-    # Dense [N, E] gates (zero off the top-k): tiny N makes computing
-    # every expert cheaper than gather/scatter of expert weights.
-    gates = jnp.sum(
-        jax.nn.one_hot(gate_idx, cfg.n_experts, dtype=jnp.float32) *
-        gate_vals[..., None], axis=1)                    # [N, E]
-    xt = tokens.astype(jnp.float32)
-    act = {'silu': jax.nn.silu, 'gelu': jax.nn.gelu}[cfg.mlp_act]
-    h = act(jnp.einsum('nd,edf->nef', xt, w_gate))
-    h = h * jnp.einsum('nd,edf->nef', xt, w_up)
-    out_e = jnp.einsum('nef,efd->ned', h, w_down)
-    out = jnp.einsum('ne,ned->nd', gates, out_e)
-    return out.astype(x.dtype).reshape(b, s, d)
-
-
-def _norm(x, scale, eps, plus_one: bool = False):
-    if plus_one:  # Gemma: weights parameterize (1 + w)
+def _norm(x, scale, cfg):
+    """The model's norm: RMSNorm (Gemma: weights parameterize 1 + w)
+    or, `norm_type` 'layernorm', the mean-subtracting norm with a
+    scale and no bias."""
+    if cfg.norm_scale_plus_one:
         scale = 1.0 + scale
     x32 = x.astype(jnp.float32)
+    if cfg.norm_type == 'layernorm':
+        x32 = x32 - jnp.mean(x32, axis=-1, keepdims=True)
+    elif cfg.norm_type != 'rms':
+        raise ValueError(f'Unknown norm_type {cfg.norm_type!r}; '
+                         "have 'rms', 'layernorm'.")
     normed = x32 * jax.lax.rsqrt(
-        jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
+        jnp.mean(x32 * x32, axis=-1, keepdims=True) + cfg.norm_eps)
     return (normed * scale).astype(x.dtype)
 
 
+# A window that cuts nothing: what a layer without one passes where the
+# window is per-layer data.
+_NO_WINDOW = 1 << 30
+
+
 def _layer_forward(x, lp, cfg, positions, k_cache, v_cache,
-                   *, use_flash: bool, mesh=None):
+                   *, use_flash: bool, mesh=None, rope_on=None,
+                   window=None, row_mask=None):
     """One decoder layer against an explicit KV cache slice.
 
     x [b, s, d]; k_cache/v_cache [b, h_kv, max_len, hd] already contain
-    this call's k/v written at [positions].  Returns the layer output.
-    `mesh` (the mesh the params and cache are sharded over, if any)
-    goes to the attention kernels, which run per shard under it.
+    this call's k/v written at [positions].  Returns (the layer output,
+    the expert layer's counts or None).  `mesh` (the mesh the params
+    and cache are sharded over, if any) goes to the attention kernels,
+    which run per shard under it.
+
+    `rope_on` (bool scalar) and `window` (int32 scalar, `_NO_WINDOW`
+    where the layer has none) are this layer's kind where the model has
+    layers of more than one (`cfg.layer_kinds`); both None for a model
+    of one kind of layer, which takes the code it always took.  A
+    query at position p of a window layer sees keys p - window + 1 .. p.
     """
-    h = _norm(x, lp['attn_norm']['scale'], cfg.norm_eps,
-              cfg.norm_scale_plus_one)
+    h = _norm(x, lp['attn_norm']['scale'], cfg)
     q = _attn_proj(h, lp['attn']['q_proj'])
-    q = _rope(q, positions, cfg)
+    q = _rope_if(rope_on, q, positions, cfg)
 
     if isinstance(k_cache, _PagedView):
         # Paged-kernel decode: the Pallas kernel copies each slot's
@@ -188,12 +181,14 @@ def _layer_forward(x, lp, cfg, positions, k_cache, v_cache,
             out = paged_attention_ops.paged_attention(
                 q, k_cache.leaf, v_cache.leaf, k_cache.tables,
                 k_cache.lengths, sm_scale=cfg.head_dim ** -0.5,
-                mesh=mesh)
+                mesh=mesh, window=window)
         out = out.astype(x.dtype)
     elif use_flash:
         # Prefill from index 0: the valid cache region is exactly the
         # prompt window [0, s) — a STATIC slice (q.shape[2]), as jit
-        # requires.  (Chunks at index>0 take the masked path instead.)
+        # requires.  (Chunks at index>0 take the masked path instead,
+        # and so does a chunk longer than a layer's window: the caller
+        # sees to it, `_flash_ok`.)
         s = q.shape[2]
         with jax.named_scope('flash_attention'):
             out = flash_attention(q, k_cache[:, :, :s],
@@ -203,7 +198,7 @@ def _layer_forward(x, lp, cfg, positions, k_cache, v_cache,
         # Masked decode: grouped einsums against the cache — GQA
         # q-heads fold into a `rep` axis per kv-head, so the repeated
         # K/V never materialises (8x cache-read savings on llama3-70b).
-        b, h, qs, d = q.shape
+        b, h_q, qs, d = q.shape
         rep = cfg.n_heads // cfg.n_kv_heads
         qg = q.reshape(b, cfg.n_kv_heads, rep, qs, d).astype(jnp.float32)
         k32 = k_cache.astype(jnp.float32)
@@ -219,22 +214,46 @@ def _layer_forward(x, lp, cfg, positions, k_cache, v_cache,
         pos = jnp.asarray(positions)
         if pos.ndim == 1:
             pos = pos[None]                               # [1, s]
-        mask = (kpos[None, None, None, None, :] <=
-                pos[:, None, None, :, None])
+        kpos = kpos[None, None, None, None, :]
+        pos = pos[:, None, None, :, None]
+        mask = kpos <= pos
+        if window is not None:
+            mask = mask & (kpos > pos - window)
         s = jnp.where(mask, s, NEG_INF)
         p = jax.nn.softmax(s, axis=-1)
         out = jnp.einsum('bgrqk,bgkd->bgrqd', p,
                          v_cache.astype(jnp.float32))
-        out = out.reshape(b, h, qs, d).astype(x.dtype)
+        out = out.reshape(b, h_q, qs, d).astype(x.dtype)
 
     out = jnp.einsum('bhsk,hkd->bsd', out,
                      maybe_dequant(lp['attn']['o_proj']['kernel'],
                                    x.dtype))
+    if cfg.parallel_block:
+        # Attention and the FFN read the one normed input and join the
+        # residual together.
+        with jax.named_scope('mlp'):
+            m, counts = _mlp(h, lp, cfg, row_mask)
+        return x + out + m.astype(x.dtype), counts
     x = x + out
-    h = _norm(x, lp['mlp_norm']['scale'], cfg.norm_eps,
-              cfg.norm_scale_plus_one)
+    h = _norm(x, lp['mlp_norm']['scale'], cfg)
     with jax.named_scope('mlp'):
-        return x + _mlp(h, lp, cfg)
+        m, counts = _mlp(h, lp, cfg, row_mask)
+    return x + m.astype(x.dtype), counts
+
+
+def _rope_if(rope_on, x, positions, cfg):
+    """Rotary embedding where the layer's kind has one (`rope_on` None:
+    every layer has)."""
+    rotated = _rope(x, positions, cfg)
+    return rotated if rope_on is None else jnp.where(rope_on, rotated, x)
+
+
+def _flash_ok(cfg, use_flash: bool, s: int) -> bool:
+    """The flash path attends the whole causal triangle of its [0, s)
+    chunk: right for every layer only where no window is shorter than
+    the chunk."""
+    return use_flash and not (cfg.layer_pattern and
+                              0 < cfg.sliding_window < s)
 
 
 def _embed(cfg, params, tokens):
@@ -248,11 +267,14 @@ def _embed(cfg, params, tokens):
 def _scan_layers_and_unembed(cfg, params, x, positions, cache_k, cache_v,
                              write_fn, *, use_flash: bool,
                              view_fn=None, all_positions: bool = False,
-                             mesh=None):
+                             mesh=None, row_mask=None):
     """The shared per-layer loop: project+rope k/v, write them into the
     cache via `write_fn(k_cache, k_new) -> k_cache`, run the layer, then
     final-norm + unembed the last position.  Single-sequence decode and
     slot-batched decode differ ONLY in write_fn / positions shapes.
+    Returns (logits, new_k, new_v, counts): counts is None for a model
+    without experts, else the expert layers' int32 [3] counts summed
+    over the layers (`moe.moe_apply`, over the rows `row_mask` marks).
 
     `view_fn(cache_leaf) -> [b, h_kv, len, d]` maps the stored cache to
     the array attention reads — identity for dense caches; the paged
@@ -260,47 +282,59 @@ def _scan_layers_and_unembed(cfg, params, x, positions, cache_k, cache_v,
     Pallas kernel a `_PagedView`), so one layer body serves every cache
     layout.
 
+    Layers of more than one kind (`cfg.layer_kinds`: rotary or not, a
+    window or none) run under the one scan: each layer's kind rides the
+    scan as data beside its weights and reaches the rotation, the mask
+    and the kernel's first page.
+
     `all_positions=True` unembeds EVERY position ([b, s, V] logits
     instead of last-position [b, V]) — the speculative verify step
-    needs the model's output after each drafted token.  RMSNorm and
+    needs the model's output after each drafted token.  The norm and
     unembed are per-position, so position j's logits are the same
     either way.
     """
     layers = _layer_params(params, cfg)
     if view_fn is None:
         view_fn = lambda c: c
+    kinds = cfg.layer_kinds()
+    use_flash = _flash_ok(cfg, use_flash, x.shape[1])
+    xs = (layers, cache_k, cache_v)
+    if kinds is not None:
+        xs += (jnp.asarray([rope for rope, _ in kinds]),
+               jnp.asarray([w or _NO_WINDOW for _, w in kinds],
+                           jnp.int32))
 
     def body(x, layer_state):
-        lp, k_cache, v_cache = layer_state
-        h = _norm(x, lp['attn_norm']['scale'], cfg.norm_eps,
-                  cfg.norm_scale_plus_one)
+        lp, k_cache, v_cache = layer_state[:3]
+        rope_on, window = layer_state[3:] or (None, None)
+        h = _norm(x, lp['attn_norm']['scale'], cfg)
         k = _attn_proj(h, lp['attn']['k_proj'])
         v = _attn_proj(h, lp['attn']['v_proj'])
-        k = _rope(k, positions, cfg)
+        k = _rope_if(rope_on, k, positions, cfg)
         with jax.named_scope('kv_write'):
             k_cache = write_fn(k_cache, k)
             v_cache = write_fn(v_cache, v)
-        x = _layer_forward(x, lp, cfg, positions, view_fn(k_cache),
-                           view_fn(v_cache), use_flash=use_flash,
-                           mesh=mesh)
-        return x, (k_cache, v_cache)
+        x, counts = _layer_forward(
+            x, lp, cfg, positions, view_fn(k_cache), view_fn(v_cache),
+            use_flash=use_flash, mesh=mesh, rope_on=rope_on,
+            window=window, row_mask=row_mask)
+        return x, (k_cache, v_cache, counts)
 
     # The scan slices each layer's share out of the stacked cache and
     # writes it back: in a device trace those copies are the ops under
     # `layer_scan` that are under none of the scopes inside the body.
     with jax.named_scope('layer_scan'):
-        x, (new_k, new_v) = jax.lax.scan(
-            lambda carry, ls: body(carry, ls),
-            x, (layers, cache_k, cache_v))
+        x, (new_k, new_v, counts) = jax.lax.scan(
+            lambda carry, ls: body(carry, ls), x, xs)
+    if counts is not None:
+        counts = jnp.sum(counts, axis=0)
     with jax.named_scope('lm_head'):
         if all_positions:
-            x = _norm(x, params['final_norm']['scale'], cfg.norm_eps,
-                      cfg.norm_scale_plus_one)
-            return heads.unembed(x, params, cfg), new_k, new_v
-        x = _norm(x[:, -1:], params['final_norm']['scale'],
-                  cfg.norm_eps, cfg.norm_scale_plus_one)
+            x = _norm(x, params['final_norm']['scale'], cfg)
+            return heads.unembed(x, params, cfg), new_k, new_v, counts
+        x = _norm(x[:, -1:], params['final_norm']['scale'], cfg)
         logits = heads.unembed(x, params, cfg)[:, 0]
-    return logits, new_k, new_v
+    return logits, new_k, new_v, counts
 
 
 def _forward_with_cache(cfg, params, tokens, cache, *, use_flash: bool,
@@ -316,7 +350,7 @@ def _forward_with_cache(cfg, params, tokens, cache, *, use_flash: bool,
         return jax.lax.dynamic_update_slice(
             c, new.astype(c.dtype), (0, 0, start, 0))
 
-    logits, new_k, new_v = _scan_layers_and_unembed(
+    logits, new_k, new_v, _ = _scan_layers_and_unembed(
         cfg, params, _embed(cfg, params, tokens), positions,
         cache['k'], cache['v'], write, use_flash=use_flash, mesh=mesh)
     return logits, {'k': new_k, 'v': new_v, 'index': cache_len}
@@ -367,15 +401,13 @@ def prefill_sp(cfg: ModelConfig, params, tokens, *, mesh, max_len: int,
     single-process chunked path (pinned by tests/unit/
     test_slice_replica.py).
 
-    MoE configs are rejected: the capacity dispatch couples every
-    prompt token globally, so a sequence-split prefill changes which
-    tokens drop (same reason MoE skips chunked prefill and prefix
-    reuse).
+    Configs with a layer pattern are rejected: ring attention has no
+    window, and the body below is the one block of a model whose layers
+    are all alike.
     """
-    if cfg.n_experts > 0:
-        raise ValueError('sequence-parallel prefill does not support '
-                         'MoE configs (the capacity dispatch couples '
-                         'every prompt token)')
+    if cfg.layer_pattern or cfg.parallel_block:
+        raise ValueError('sequence-parallel prefill serves one kind of '
+                         'layer (no layer_pattern, no parallel block)')
     from skypilot_tpu.ops.ring_attention import ring_attention  # pylint: disable=import-outside-toplevel
 
     b, s = tokens.shape
@@ -394,8 +426,7 @@ def prefill_sp(cfg: ModelConfig, params, tokens, *, mesh, max_len: int,
     layers = _layer_params(params, cfg)
 
     def body(x, lp):
-        h = _norm(x, lp['attn_norm']['scale'], cfg.norm_eps,
-                  cfg.norm_scale_plus_one)
+        h = _norm(x, lp['attn_norm']['scale'], cfg)
         q = _rope(_attn_proj(h, lp['attn']['q_proj']), positions, cfg)
         k = _rope(_attn_proj(h, lp['attn']['k_proj']), positions, cfg)
         v = _attn_proj(h, lp['attn']['v_proj'])
@@ -406,11 +437,10 @@ def prefill_sp(cfg: ModelConfig, params, tokens, *, mesh, max_len: int,
                          maybe_dequant(lp['attn']['o_proj']['kernel'],
                                        x.dtype))
         x = x + out
-        h = _norm(x, lp['mlp_norm']['scale'], cfg.norm_eps,
-                  cfg.norm_scale_plus_one)
+        h = _norm(x, lp['mlp_norm']['scale'], cfg)
         # k/v cached post-RoPE, exactly like the chunked write path.
-        return x + _mlp(h, lp, cfg), (k.astype(cfg.dtype),
-                                      v.astype(cfg.dtype))
+        return x + _mlp(h, lp, cfg)[0].astype(x.dtype), (
+            k.astype(cfg.dtype), v.astype(cfg.dtype))
 
     _, (ks, vs) = jax.lax.scan(body, x, layers)
 
@@ -558,7 +588,8 @@ def insert_prefill(slot_cache: Dict[str, Any], slot: int,
 def batched_step(cfg: ModelConfig, params, tokens, slot_cache,
                  active=None):
     """One decode step across ALL slots; each slot attends its own
-    depth.  tokens [B, 1]; returns (logits [B, V], new slot_cache).
+    depth.  tokens [B, 1]; returns (logits [B, V], new slot_cache,
+    the expert layers' counts over the active slots or None).
     Without `active`, every length advances by 1 (callers ignore/reset
     inactive slots).  With `active` [B] bool, only active slots advance
     — inactive slots' writes land at their frozen length (garbage that
@@ -576,13 +607,14 @@ def batched_step(cfg: ModelConfig, params, tokens, slot_cache,
                 cc, nn.astype(cc.dtype), (0, st, 0))
         )(c, new, lengths)
 
-    logits, new_k, new_v = _scan_layers_and_unembed(
+    logits, new_k, new_v, counts = _scan_layers_and_unembed(
         cfg, params, _embed(cfg, params, tokens), positions,
         slot_cache['k'], slot_cache['v'], write,
-        use_flash=False)
+        use_flash=False, row_mask=active)
     advance = (jnp.ones_like(lengths) if active is None
                else active.astype(lengths.dtype))
-    return logits, {'k': new_k, 'v': new_v, 'lengths': lengths + advance}
+    return logits, {'k': new_k, 'v': new_v,
+                    'lengths': lengths + advance}, counts
 
 
 def batched_sample(logits, keys, temperature, top_k, *,
@@ -642,7 +674,11 @@ def engine_step(cfg: ModelConfig, params, state, slot_cache, *,
     select its next token (greedy or temperature/top-k), and update the
     stop bookkeeping — no host round-trip anywhere in the loop.
 
-    Returns (new_state, new_cache, finished [B]).  new_state['tokens']
+    Returns (new_state, new_cache, finished [B], counts): counts is
+    None for a model without experts, else the expert layers' int32
+    [3] counts of the tick over the active slots (`moe.moe_apply`),
+    which the engine reads one tick behind with `finished`.
+    new_state['tokens']
     is the next tick's input, so the engine can dispatch tick t+1
     before fetching tick t's tokens and read results one tick behind;
     slots that stop at tick t are already inactive ON DEVICE when tick
@@ -655,7 +691,8 @@ def engine_step(cfg: ModelConfig, params, state, slot_cache, *,
         state['active']), max_top_k=max_top_k)
 
 
-def _select_and_bookkeep(state, logits, new_cache, *, max_top_k: int):
+def _select_and_bookkeep(state, logits, new_cache, counts, *,
+                         max_top_k: int):
     """Shared tick tail for dense and paged steps: on-device token
     selection + stop/countdown bookkeeping (see engine_step docs)."""
     active = state['active']
@@ -674,7 +711,7 @@ def _select_and_bookkeep(state, logits, new_cache, *, max_top_k: int):
         remaining=remaining,
         keys=split[:, 0],
     )
-    return new_state, new_cache, finished
+    return new_state, new_cache, finished, counts
 
 
 # ------------------------------------------------------ paged KV cache
@@ -745,12 +782,15 @@ def _dequant_kv(leaf_slice, dtype):
 
 
 def _paged_forward(cfg: ModelConfig, params, tokens, paged, *,
-                   kernel=None, all_positions: bool = False, mesh=None):
+                   kernel=None, all_positions: bool = False, mesh=None,
+                   active=None):
     """Shared write-then-attend body for paged decode: tokens [B, S]
     land at positions lengths..lengths+S-1, then every query attends
-    through the pool.  Returns (logits, new_k, new_v) WITHOUT
+    through the pool.  Returns (logits, new_k, new_v, counts) WITHOUT
     advancing lengths — callers own the bookkeeping (the speculative
-    step only advances by the accepted count).
+    step only advances by the accepted count).  counts: the expert
+    layers' over the rows of the `active` [B] slots (None without
+    experts).
 
     Writes scatter each (slot, token) at (block_tables[b, pos//ps],
     pos % ps).  Positions past the slot's table ([n_rows * ps, ...))
@@ -812,7 +852,8 @@ def _paged_forward(cfg: ModelConfig, params, tokens, paged, *,
     return _scan_layers_and_unembed(
         cfg, params, _embed(cfg, params, tokens), positions,
         paged['k'], paged['v'], write, use_flash=False, view_fn=view,
-        all_positions=all_positions, mesh=mesh)
+        all_positions=all_positions, mesh=mesh,
+        row_mask=None if active is None else jnp.repeat(active, s_q))
 
 
 def paged_batched_step(cfg: ModelConfig, params, tokens, paged,
@@ -822,21 +863,24 @@ def paged_batched_step(cfg: ModelConfig, params, tokens, paged,
     gathered pages in table order ARE the slot's cache with positions
     page_index * page_size + offset; the Pallas kernel path computes
     the same online-softmax sums without materialising the gather).
-    """
-    logits, new_k, new_v = _paged_forward(cfg, params, tokens, paged,
-                                          kernel=kernel, mesh=mesh)
+    Returns (logits, new paged cache, the expert layers' counts over
+    the active slots or None)."""
+    logits, new_k, new_v, counts = _paged_forward(
+        cfg, params, tokens, paged, kernel=kernel, mesh=mesh,
+        active=active)
     lengths = paged['lengths']
     advance = (jnp.ones_like(lengths) if active is None
                else active.astype(lengths.dtype))
     return logits, dict(paged, k=new_k, v=new_v,
-                        lengths=lengths + advance)
+                        lengths=lengths + advance), counts
 
 
 def paged_engine_step(cfg: ModelConfig, params, state, paged, *,
                       max_top_k: int = 64, kernel=None, mesh=None):
     """`engine_step` against the page pool: same on-device token
     selection and stop bookkeeping, cache reads/writes through the
-    block tables.  Returns (new_state, new_paged, finished [B])."""
+    block tables.  Returns (new_state, new_paged, finished [B],
+    counts)."""
     return _select_and_bookkeep(state, *paged_batched_step(
         cfg, params, state['tokens'][:, None], paged,
         state['active'], kernel=kernel, mesh=mesh),
@@ -864,8 +908,9 @@ def paged_spec_engine_step(cfg: ModelConfig, params, state, paged,
     (see `_paged_forward`).
 
     Returns (new_state, new_paged, finished [B], toks [B, k+1],
-    counts [B]); the host pushes toks[b, :counts[b]] per live slot.
-    Inactive slots emit nothing (counts 0).
+    counts [B], the expert layers' counts or None); the host pushes
+    toks[b, :counts[b]] per live slot.  Inactive slots emit nothing
+    (counts 0).
     """
     active = state['active']
     b, _ = drafts.shape
@@ -873,9 +918,9 @@ def paged_spec_engine_step(cfg: ModelConfig, params, state, paged,
     tokens = jnp.concatenate(
         [state['tokens'][:, None], jnp.asarray(drafts, jnp.int32)],
         axis=1)                                    # [B, S]
-    logits, new_k, new_v = _paged_forward(
+    logits, new_k, new_v, moe_counts = _paged_forward(
         cfg, params, tokens, paged, kernel=kernel, all_positions=True,
-        mesh=mesh)
+        mesh=mesh, active=active)
 
     # Per-slot key chain: position j samples with exactly the key a
     # plain tick would use at that step; carries[j] is the post-split
@@ -937,7 +982,7 @@ def paged_spec_engine_step(cfg: ModelConfig, params, state, paged,
     )
     new_paged = dict(paged, k=new_k, v=new_v,
                      lengths=paged['lengths'] + counts)
-    return new_state, new_paged, finished, toks, counts
+    return new_state, new_paged, finished, toks, counts, moe_counts
 
 
 def paged_admit_slot(paged, slot, pages_row, length):

@@ -228,21 +228,37 @@ def config_from_hf(hf: Dict[str, Any]) -> Tuple[configs.ModelConfig, str]:
                 f'Unsupported rope_scaling type {rtype!r} (have '
                 "'llama3', 'linear'); importing with plain RoPE would "
                 'silently diverge from the source model.')
-    # Sliding-window attention is not implemented; only reject it when
-    # it would actually truncate attention inside the usable context
-    # (configs often carry an inert window >= max_position_embeddings).
+    # A sliding window counts only where it would cut attention inside
+    # the usable context (configs often carry an inert window >=
+    # max_position_embeddings).  One on EVERY layer is a layer pattern
+    # this program serves (models/decode.py: the masked path and the
+    # paged kernel take a layer's window; the training module does not
+    # build it yet and says so).  One on some layers only, where those
+    # are not a repeating period, it does not.
     window = hf.get('sliding_window')
     window_active = (window is not None and
                      int(window) < int(common['max_seq_len']))
     if family == 'qwen2':
         window_active = window_active and bool(
             hf.get('use_sliding_window', False))
+        # Qwen2 windows the layers from `max_window_layers` on.
+        first_windowed = hf.get('max_window_layers')
+        if window_active and first_windowed != 0:
+            if (first_windowed is not None and
+                    int(first_windowed) >= common['n_layers']):
+                window_active = False     # no layer reaches it
+            else:
+                raise ValueError(
+                    f'{family} checkpoint uses sliding-window attention '
+                    f'(window={window} < context='
+                    f'{common["max_seq_len"]}) on the layers from '
+                    f'max_window_layers={first_windowed} on only: not '
+                    'a repeating layer pattern, which is what this '
+                    'program serves; importing would silently change '
+                    'attention semantics.')
     if window_active:
-        raise ValueError(
-            f'{family} checkpoint uses sliding-window attention '
-            f'(window={window} < context={common["max_seq_len"]}), '
-            'which this importer does not implement; importing would '
-            'silently change attention semantics.')
+        common.update(layer_pattern=('window',),
+                      sliding_window=int(window))
     if family == 'qwen2':
         common['qkv_bias'] = True
     elif family == 'gemma':

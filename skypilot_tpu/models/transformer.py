@@ -265,6 +265,13 @@ class Transformer(nn.Module):
         cfg.logits_in_f32 matmul dtype) for the fused linear+CE loss
         (models/losses.py) — the [b,s,V] tensor is never built."""
         cfg = self.config
+        if (cfg.layer_pattern or cfg.parallel_block or
+                cfg.norm_type != 'rms' or cfg.logit_scale != 1.0):
+            raise ValueError(
+                'the training module builds one kind of layer (RMSNorm, '
+                'sequential attention then FFN); layer_pattern, '
+                'parallel_block, norm_type and logit_scale are served '
+                'by models/decode.py only')
         _, s = tokens.shape
         positions = jnp.arange(s)
 

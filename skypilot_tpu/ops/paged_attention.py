@@ -23,6 +23,13 @@ one step of one page.  The online softmax of all kv heads accumulates
 across a slot's steps in VMEM scratch.  `pages_per_step` comes from
 shapes alone (`_pages_per_step`).
 
+A window (`window`, a traced scalar: the layer's, where a model has
+layers with and without one) moves the walk's START: a query at
+position p sees keys p - window + 1 .. p, so the loop begins at the
+page that holds the first query's first key and masks inside it; the
+pages behind the window are neither fetched nor stepped over.  Without
+a window (None) the kernel is the one it was.
+
 Queries generalise to S tokens per slot (query row r sits at absolute
 position `lengths[b] + r % S`), so one kernel serves single-token
 decode (S=1) AND the self-speculative verify step (S=k+1) — drafts
@@ -98,14 +105,16 @@ def _pages_per_step(num_rows: int, h_kv: int, page_size: int, d: int,
     return max(1, min(by_vmem, _STEP_TOKENS // page_size, num_rows))
 
 
-def _paged_decode_kernel(tables_ref, lengths_ref, q_ref, *refs,
+def _paged_decode_kernel(tables_ref, lengths_ref, *refs,
                          page_size: int, s_q: int, pages_per_step: int,
-                         sm_scale: float, quantized: bool):
+                         sm_scale: float, quantized: bool,
+                         windowed: bool = False):
     """One program per slot: walk the slot's live pages,
     `pages_per_step` a step, through a double-buffered VMEM scratch and
     fold each step into the online softmax of every kv head.
 
-    Refs: tables [B, P] and lengths [B] in SMEM; q [1, h_kv, R, d]
+    Refs: tables [B, P] and lengths [B] in SMEM (and, `windowed`, the
+    window [1] after them); q [1, h_kv, R, d]
     (R = rep * s_q padded to whole sublane tiles, unscaled, q's dtype);
     the pools in HBM (k/v [n_pages, h_kv, ps, d]; int8 pools add ks/vs
     [n_pages, W] f32, a page's [h_kv, ps] scales as one row);
@@ -120,7 +129,9 @@ def _paged_decode_kernel(tables_ref, lengths_ref, q_ref, *refs,
     Step i covers table rows [i * pps, (i + 1) * pps) cut off at the
     slot's live page count ceil((length + s_q) / ps): a row past it is
     not read from the table, its page is not fetched, and a step made
-    only of such rows does not run.
+    only of such rows does not run.  `windowed`, the rows count from
+    the slot's first page inside the window, (length - window + 1) //
+    ps, and keys at or before position - window are masked.
 
     int8 dequant is fused without ever building the f32 page: with
     k[t] = kq[t] * ks[t], q.k[t] = (q.kq[t]) * ks[t] scales a COLUMN of
@@ -130,6 +141,10 @@ def _paged_decode_kernel(tables_ref, lengths_ref, q_ref, *refs,
     from jax.experimental import pallas as pl  # pylint: disable=import-outside-toplevel
     from jax.experimental.pallas import tpu as pltpu  # pylint: disable=import-outside-toplevel
 
+    if windowed:
+        window_ref, q_ref, *refs = refs
+    else:
+        q_ref, *refs = refs
     if quantized:
         (k_hbm, ks_hbm, v_hbm, vs_hbm, o_ref, k_buf, v_buf, ks_buf,
          vs_buf, sems, base_ref, acc_ref, m_ref, l_ref) = refs
@@ -152,13 +167,24 @@ def _paged_decode_kernel(tables_ref, lengths_ref, q_ref, *refs,
             (lengths_ref[bb] + s_q + page_size - 1) // page_size,
             tables_ref.shape[1])
 
-    n_steps = (live_pages(b) + pps - 1) // pps
+    def first_page(bb):
+        """The page that holds the first key slot bb's first query sees
+        in the window (only asked where there is one)."""
+        return jnp.maximum(
+            lengths_ref[bb] - window_ref[0] + 1, 0) // page_size
+
+    def walk_pages(bb):
+        return live_pages(bb) - first_page(bb) if windowed \
+            else live_pages(bb)
+
+    n_steps = (walk_pages(b) + pps - 1) // pps
 
     def copies(bb, i, slot, which, fn):
         """Apply fn to the copy descriptor of every live page of slot
         bb's step i (into buffer `slot`) that signals a semaphore in
         `which` (0: K, 1: V)."""
-        first = i * pps
+        rel = i * pps
+        first = first_page(bb) + rel if windowed else rel
 
         def one(j, _):
             page = tables_ref[bb, first + j]
@@ -175,7 +201,7 @@ def _paged_decode_kernel(tables_ref, lengths_ref, q_ref, *refs,
             return _
 
         jax.lax.fori_loop(
-            0, jnp.minimum(pps, live_pages(bb) - first), one, None)
+            0, jnp.minimum(pps, walk_pages(bb) - rel), one, None)
 
     def start(bb, i, slot):
         copies(bb, i, slot, (0, 1), lambda c: c.start())
@@ -219,7 +245,9 @@ def _paged_decode_kernel(tables_ref, lengths_ref, q_ref, *refs,
         def _prefetch():  # pylint: disable=unused-variable
             start(next_b, jnp.where(more, i + 1, 0), 1 - slot)
 
-        kpos = i * t + jax.lax.broadcasted_iota(jnp.int32, (hr, t), 1)
+        kpos = (first_page(b) * page_size + i * t if windowed
+                else i * t) + jax.lax.broadcasted_iota(
+                    jnp.int32, (hr, t), 1)
         # Rows are head-major, r a head.  Row j of a head sits at
         # absolute position length + (j % s_q): the GQA fold keeps the
         # S query tokens of each q-head contiguous.
@@ -259,9 +287,13 @@ def _paged_decode_kernel(tables_ref, lengths_ref, q_ref, *refs,
             preferred_element_type=jnp.float32)) * sm_scale
         if quantized:
             s = s * scale_rows(ks_buf)
-        s = jnp.where(kpos <= qpos, s, NEG_INF)
-        # Column 0 is always live (kpos 0 <= length), so m is finite
-        # from the first step on.
+        live = kpos <= qpos
+        if windowed:
+            live = live & (kpos > qpos - window_ref[0])
+        s = jnp.where(live, s, NEG_INF)
+        # The walk's first column is always live (kpos 0 <= length; in a
+        # window, the first query's first key), so m is finite from the
+        # first step on.
         m_prev = jnp.max(m_ref[...], axis=-1, keepdims=True)
         l_prev = jnp.max(l_ref[...], axis=-1, keepdims=True)
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
@@ -305,7 +337,7 @@ def _paged_decode_kernel(tables_ref, lengths_ref, q_ref, *refs,
 
 
 def _paged_attention_pallas(q, k_leaf, v_leaf, tables, lengths, *,
-                            sm_scale: float):
+                            sm_scale: float, window=None):
     from jax.experimental import pallas as pl  # pylint: disable=import-outside-toplevel
     from jax.experimental.pallas import tpu as pltpu  # pylint: disable=import-outside-toplevel
 
@@ -329,7 +361,7 @@ def _paged_attention_pallas(q, k_leaf, v_leaf, tables, lengths, *,
                  ((0, 0), (0, 0), (0, r_pad - r), (0, 0)))
 
     row_spec = pl.BlockSpec(
-        (1, h_kv, r_pad, d), lambda bb, tt, ll: (bb, 0, 0, 0),
+        (1, h_kv, r_pad, d), lambda bb, *_: (bb, 0, 0, 0),
         memory_space=pltpu.VMEM)
     # The pools never enter VMEM whole: the kernel copies the pages a
     # slot's table names, and only the live ones.
@@ -356,12 +388,17 @@ def _paged_attention_pallas(q, k_leaf, v_leaf, tables, lengths, *,
                          pltpu.VMEM((h_kv * r_pad, d), jnp.float32),
                          pltpu.VMEM((h_kv * r_pad, _LANES), jnp.float32),
                          pltpu.VMEM((h_kv * r_pad, _LANES), jnp.float32)]
+    # Tables and lengths ride in scalar-prefetch memory, and a layer's
+    # window after them where it has one.
+    scalars = (tables, lengths) + (() if window is None else (
+        jnp.asarray(window, jnp.int32).reshape(1),))
     out = pl.pallas_call(
         functools.partial(_paged_decode_kernel, page_size=ps, s_q=s_q,
                           pages_per_step=pps, sm_scale=sm_scale,
-                          quantized=quantized),
+                          quantized=quantized,
+                          windowed=window is not None),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=len(scalars),
             grid=(b,),
             in_specs=[row_spec] + [pool_spec] * (len(operands) - 1),
             out_specs=row_spec,
@@ -371,13 +408,13 @@ def _paged_attention_pallas(q, k_leaf, v_leaf, tables, lengths, *,
             dimension_semantics=('arbitrary',)),
         interpret=interpret_mode(),
         name='paged_decode_attention',
-    )(tables, lengths, *operands)
+    )(*scalars, *operands)
     return out[:, :, :r].reshape(b, h_kv, rep, s_q, d).reshape(
         b, h_q, s_q, d)
 
 
 def _paged_attention_reference(q, k_leaf, v_leaf, tables, lengths, *,
-                               sm_scale: float):
+                               sm_scale: float, window=None):
     """Pure-jnp reference with the kernel's exact masking math: gather
     the pool rows each table names, dequant, attend.  Used off-TPU
     without interpret mode (and by parity tests as the pinned
@@ -403,8 +440,11 @@ def _paged_attention_reference(q, k_leaf, v_leaf, tables, lengths, *,
     s = jnp.einsum('bgrqd,bgkd->bgrqk', qg, k) * sm_scale
     kpos = jnp.arange(k.shape[2])
     qpos = lengths[:, None] + jnp.arange(s_q)[None, :]      # [B, S]
-    mask = (kpos[None, None, None, None, :] <=
-            qpos[:, None, None, :, None])
+    kpos = kpos[None, None, None, None, :]
+    qpos = qpos[:, None, None, :, None]
+    mask = kpos <= qpos
+    if window is not None:
+        mask = mask & (kpos > qpos - window)
     s = jnp.where(mask, s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum('bgrqk,bgkd->bgrqd', p, v)
@@ -412,13 +452,16 @@ def _paged_attention_reference(q, k_leaf, v_leaf, tables, lengths, *,
 
 
 def paged_attention(q, k_leaf: Any, v_leaf: Any, tables, lengths, *,
-                    sm_scale: Optional[float] = None, mesh=None):
+                    sm_scale: Optional[float] = None, mesh=None,
+                    window=None):
     """Paged decode attention over one layer's page pool.
 
     q [B, h_q, S, d] (query token j of slot b at absolute position
     lengths[b] + j, already written into the pool); pool leaves
     [n_pages, h_kv, ps, d] (or int8 {'q','scale'}); tables [B, P];
-    lengths [B].  Returns [B, h_q, S, d] in q's dtype.
+    lengths [B].  Returns [B, h_q, S, d] in q's dtype.  `window` (an
+    int32 scalar, traced or not; None = no window): a query at position
+    p sees keys p - window + 1 .. p only.
 
     Under a `mesh` of more than one device each device runs the kernel
     on its own heads (ops/sp_common.py says why): q heads and the
@@ -434,12 +477,18 @@ def paged_attention(q, k_leaf: Any, v_leaf: Any, tables, lengths, *,
         _, head_axes, _ = sp_common.batch_head_axes(mesh)
         heads = P(None, head_axes)
         leaf_spec = jax.tree.map(lambda _: heads, k_leaf)
-        fn = functools.partial(paged_attention, sm_scale=sm_scale)
-        return sp_common.sp_shard_map(
-            fn, mesh, (heads, leaf_spec, leaf_spec, P(), P()),
-            heads)(q, k_leaf, v_leaf, tables, lengths)
-    if _use_pallas():
-        return _paged_attention_pallas(q, k_leaf, v_leaf, tables,
-                                       lengths, sm_scale=sm_scale)
-    return _paged_attention_reference(q, k_leaf, v_leaf, tables,
-                                      lengths, sm_scale=sm_scale)
+        args = (q, k_leaf, v_leaf, tables, lengths)
+        specs = (heads, leaf_spec, leaf_spec, P(), P())
+        if window is not None:       # a traced scalar: an operand too
+            args += (jnp.asarray(window, jnp.int32),)
+            specs += (P(),)
+
+        def fn(q, k_leaf, v_leaf, tables, lengths, window=None):
+            return paged_attention(q, k_leaf, v_leaf, tables, lengths,
+                                   sm_scale=sm_scale, window=window)
+
+        return sp_common.sp_shard_map(fn, mesh, specs, heads)(*args)
+    impl = (_paged_attention_pallas if _use_pallas()
+            else _paged_attention_reference)
+    return impl(q, k_leaf, v_leaf, tables, lengths, sm_scale=sm_scale,
+                window=window)

@@ -78,7 +78,7 @@ paged-attention kernel where it can (`SKYTPU_DECODE_KERNEL=
 pallas|gather`, ops/paged_attention.py) with the jnp gather fallback
 elsewhere — both parity-pinned against the dense engine.
 
-Exact-prefill trick for static shapes (dense models): the prompt's
+Exact-prefill trick for static shapes: the prompt's
 first n-1 tokens are prefilled PADDED to a power-of-two bucket
 (bounding compile count), the slot is inserted at length n-1, and the
 LAST real prompt token is fed through the next batched step — it
@@ -88,12 +88,12 @@ decode.generate).  Chunk 0 keeps that flash-prefill path; chunks at
 index > 0 run `decode.prefill_chunk` (per-position causal mask), which
 preserves the same n-1/last-token trick per chunk.  A prefix-cache hit
 replaces chunk 0: the cached pages seed the private prefill cache and
-only the unmatched tail chunks run.  MoE models instead prefill the
-FULL prompt unpadded in one piece (the capacity dispatch couples every
-token, so padding, the n-1 split, and chunk boundaries would all
-perturb expert drops) and take their first token from the prefill
-logits; the capacity dispatch also couples KV to the whole prompt, so
-MoE skips prefix reuse (pages still pool).
+only the unmatched tail chunks run.  Expert models take the same path:
+their layer drops no token (models/moe.py), so a token's result does
+not depend on its neighbours and chunks may be padded, split and
+served from cached pages like any other model's.  Their ticks return
+the expert layers' counts beside `finished`, read one tick behind
+(stats()['moe']).
 
 Admission is BOUNDED: `max_queue` rejects new submits when the backlog
 is full (`QueueFull` -> HTTP 429) and `queue_ttl` expires requests
@@ -311,6 +311,17 @@ class ContinuousBatchingEngine:
                     'paged KV engine (kv_pages): rejected drafts roll '
                     'back through the pool\'s reserved null page')
             self._cache = decode.init_slot_cache(cfg, slots, max_len)
+        # Prompts that may be mid-prefill at once.  Each holds a private
+        # cache of max_len until it joins the engine's cache, so a burst
+        # of admissions may hold as many bytes beside that cache as it
+        # holds itself (the slots' caches: every slot may; a pool
+        # smaller than slots x max_len: fewer); past the bound a request
+        # waits in the queue for a prefill to finish.
+        private = (2 * cfg.n_layers * cfg.n_kv_heads * cfg.head_dim *
+                   max_len * jnp.dtype(cfg.dtype).itemsize)
+        held = sum(leaf.nbytes for leaf in jax.tree.leaves(
+            (self._cache['k'], self._cache['v'])))
+        self._max_prefills = max(1, min(slots, held // private))
         # Which attention path the paged tick runs — resolved ONCE here
         # (env SKYTPU_DECODE_KERNEL, defaulting to the Pallas kernel
         # wherever it can run) and baked into the jitted partials below
@@ -441,6 +452,17 @@ class ContinuousBatchingEngine:
         self._ticks = 0
         self._kernel_live_pages = 0
         self._kernel_table_pages = 0
+        self._kernel_walked_pages = 0
+        # window (0 = none) -> how many layers have it: what
+        # `_count_kernel_pages` needs of the layer pattern.
+        self._layers_by_window = collections.Counter(
+            w for _, w in (cfg.layer_kinds() or
+                           ((True, 0),) * cfg.n_layers))
+        # Expert layers' counts, summed over ticks and layers: rows
+        # routed, (row, held expert) pairs, the fullest expert's rows.
+        # None until a tick returns some (a model without experts
+        # never does).
+        self._moe_counts: Optional[List[int]] = None
         self._prefill_chunks = 0
         self._page_deferrals = 0
         self._spec_ticks = 0
@@ -530,7 +552,8 @@ class ContinuousBatchingEngine:
                     f'{self._kv.pool.capacity} (pool of '
                     f'{self._kv.pool.capacity} pages x '
                     f'{self._kv.page_size} tokens)')
-            if len(self._queue) > 0 and not self._kv.can_admit(need):
+            if len(self._queue) > 0 and not self._pool_has_room(
+                    prompt_ids, need):
                 raise self._queue.reject(
                     'pages_exhausted',
                     f'KV page pool exhausted ({need} page(s) needed, '
@@ -543,6 +566,21 @@ class ContinuousBatchingEngine:
                 request._finish(  # pylint: disable=protected-access
                     RuntimeError('batching engine stopped'))
         return request
+
+    def _pool_has_room(self, prompt_ids: List[int], need: int) -> bool:
+        """Could the pool cover this request right now: `need` pages,
+        less those of its prompt that the prefix cache already holds
+        (a request on a long cached document needs its tail only; the
+        whole row would read as exhaustion whenever the other slots'
+        tails are long).  The match is only made where the whole row
+        does not fit."""
+        if self._kv.can_admit(need):
+            return True
+        if not self._kv.prefix_caching:
+            return False
+        cached = self._kv.import_prefix_depth(cache_manager.chunk_hashes(
+            prompt_ids[:-1], self._kv.page_size))
+        return cached > 0 and self._kv.can_admit(need - cached)
 
     def generate(self, prompt_ids: List[int], max_new_tokens: int,
                  stop_token=None, sampling=None,
@@ -575,10 +613,6 @@ class ContinuousBatchingEngine:
         import numpy as np  # pylint: disable=import-outside-toplevel
 
         from skypilot_tpu.models import decode  # pylint: disable=import-outside-toplevel
-        if self.cfg.n_experts > 0:
-            raise HandoffError(
-                'MoE prefill couples every prompt token through the '
-                'capacity dispatch; its KV cannot transfer page-wise')
         if self._stop.is_set() or self._failed is not None:
             raise RuntimeError('batching engine is stopped'
                                if self._failed is None else
@@ -669,8 +703,6 @@ class ContinuousBatchingEngine:
         if not self._kv.prefix_caching:
             raise HandoffError('KV import needs the prefix cache '
                                '(imports publish pages through it)')
-        if self.cfg.n_experts > 0:
-            raise HandoffError('MoE engines do not reuse prefix pages')
         if int(page_size) != self._kv.page_size:
             raise HandoffError(
                 f'page_size mismatch: payload {page_size}, '
@@ -935,7 +967,11 @@ class ContinuousBatchingEngine:
         kv_pages_{total,used,free,pinned}, prefix-cache entry/hit/miss
         counts, pages_exhausted_deferrals, and paged_kernel (the pages
         the decode ticks' live contexts held beside the rows of every
-        block table: how much of `max_len` the traffic uses)."""
+        block table: how much of `max_len` the traffic uses; and
+        `walked_pages`, the pages the decode kernel is given to walk
+        summed over the layers, a window layer's being those that hold
+        its last `sliding_window` keys).  Expert models add `moe`: the
+        expert layers' counts summed over ticks and layers."""
         busy = sum(1 for s in self._slots if s.active)
         with self._metrics_lock:
             stats = {
@@ -963,6 +999,10 @@ class ContinuousBatchingEngine:
                            self._spec_slot_ticks) /
                           self._spec_slot_ticks, 3)
                     if self._spec_slot_ticks else None)
+            if self._moe_counts is not None:
+                stats['moe'] = dict(zip(
+                    ('tokens', 'held_pairs', 'max_expert_tokens'),
+                    self._moe_counts))
         stats.update(self._queue.stats())
         if self._kv is not None:
             stats.update(self._kv.stats())
@@ -972,7 +1012,8 @@ class ContinuousBatchingEngine:
                 # walked of what the block tables have rows for.
                 stats['paged_kernel'] = {
                     'live_pages': self._kernel_live_pages,
-                    'table_pages': self._kernel_table_pages}
+                    'table_pages': self._kernel_table_pages,
+                    'walked_pages': self._kernel_walked_pages}
         rate = round(self._decode_rate(), 3)
         stats['decode_tokens_per_s'] = rate
         # The worker loop's cumulative totals (iterations, seconds by
@@ -1076,12 +1117,8 @@ class ContinuousBatchingEngine:
         request's pages (raises PagesExhausted -> caller defers)."""
         if self._kv is None:
             return None
-        # MoE prefill couples every prompt token through the capacity
-        # dispatch, so a shared prefix does NOT have shared KV — pages
-        # pool, but never cross-request reuse.
         plan = self._kv.plan_admission(
-            request.prompt_ids, request.max_new_tokens,
-            prefix_ok=(self.cfg.n_experts == 0))
+            request.prompt_ids, request.max_new_tokens)
         request.span.prefix_hit_pages = plan.prefix_hit_pages
         return plan
 
@@ -1108,49 +1145,6 @@ class ContinuousBatchingEngine:
         if plan is not None:
             self._kv.commit(slot_id, plan)
         self._queue.record_admission(request, self._profiler.iteration)
-        if self.cfg.n_experts > 0 and n > 0:
-            # MoE: the capacity dispatch couples EVERY prompt token, so
-            # pad tokens, an n-1/last-token split, and chunk boundaries
-            # would all change which tokens drop — only a full-prompt
-            # unpadded prefill matches the single-sequence reference.
-            # The first generated token therefore comes from the
-            # prefill logits (one compile per distinct MoE prompt
-            # length), selected with the same key chain a tick uses.
-            t_prefill = time.monotonic()
-            logits, pre = self._prefill(
-                self.params, jnp.asarray([prompt], jnp.int32))
-            request.span.mark_prefill_chunk(
-                time.monotonic() - t_prefill)
-            if plan is not None:
-                import numpy as np  # pylint: disable=import-outside-toplevel
-                n_pages = -(-n // self._kv.page_size)
-                self._cache = self._insert_pages(
-                    self._cache, pre,
-                    np.asarray(plan.row[:n_pages], np.int32),
-                    first_page=0)
-            else:
-                self._cache = self._insert(self._cache, slot_id, pre, n)
-            key = self._jax.random.PRNGKey(request.seed)
-            carry, sub = self._jax.random.split(key)
-            first = self._sampler.sample_one(logits, sub,
-                                             request.temperature,
-                                             request.top_k)
-            request._push(first)  # pylint: disable=protected-access
-            self._record_tokens(1)
-            if (request.max_new_tokens <= 1 or
-                    first in request.stop_ids):
-                request._finish()  # pylint: disable=protected-access
-                if plan is not None:
-                    self._kv.release(slot_id)
-                return None
-            if plan is not None:
-                self._cache = self._admit_paged(
-                    self._cache, slot_id, self._pad_row(plan.row), n)
-            slot.request = request
-            self._activate(slot_id, request, first, n,
-                           remaining=request.max_new_tokens - 1,
-                           key=carry)
-            return None
         if n <= 1:
             # Single-token prompt: empty slot; stale keys are masked
             # (per-position causal mask) and position 0 is overwritten
@@ -1178,7 +1172,7 @@ class ContinuousBatchingEngine:
                            remaining=request.max_new_tokens,
                            key=self._jax.random.PRNGKey(request.seed))
             return None
-        # Dense: prefill tokens [0, n-1) in chunks; the last REAL
+        # Prefill tokens [0, n-1) in chunks; the last REAL
         # prompt token is fed through the first batched step (it
         # overwrites the first pad position and attends only real
         # keys, so logits match unpadded decode exactly).
@@ -1341,8 +1335,8 @@ class ContinuousBatchingEngine:
         if self.spec_tokens:
             # Seed the slot's drafter with everything decoded so far:
             # the history must END with the token the next tick feeds
-            # (prompt[-1], or the MoE first-from-prefill token) so the
-            # n-gram tail predicts continuations of it.
+            # (prompt[-1]) so the n-gram tail predicts continuations of
+            # it.
             self._slots[slot_id].drafter = sampler_lib.NgramDrafter(
                 list(request.prompt_ids) + list(request.tokens))
         self._state = self._sampler.admit(
@@ -1368,18 +1362,37 @@ class ContinuousBatchingEngine:
         self._kv.release(slot_id)
 
     def _count_kernel_pages(self, live, s_q: int) -> None:
-        """Add one paged tick to stats()['paged_kernel']: the pages the
-        decode kernel walks (per live slot, those that hold its cache
-        and the tick's `s_q` new tokens) beside the rows of every
-        slot's block table.  From the host's own depth of each slot,
-        no device read."""
+        """Add one paged tick to stats()['paged_kernel']: the pages
+        that hold each live slot's cache and the tick's `s_q` new
+        tokens (`live_pages`) beside the rows of every slot's block
+        table, and the pages the decode kernel is given to walk summed
+        over the layers (`walked_pages`): a window layer's walk starts
+        at the page of the first query's first key.  From the host's
+        own depth of each slot, no device read."""
         ps = self._kv.page_size
-        walked = sum(-(-(self._slots[i].depth + s_q) // ps) for i in live)
+        held = walked = 0
+        for i in live:
+            depth = self._slots[i].depth
+            pages = -(-(depth + s_q) // ps)
+            held += pages
+            walked += sum(
+                n * (pages - (max(depth - w + 1, 0) // ps if w else 0))
+                for w, n in self._layers_by_window.items())
         rows = len(self._slots) * (self.max_len // ps)
         with self._metrics_lock:
-            self._kernel_live_pages += walked
+            self._kernel_live_pages += held
             self._kernel_table_pages += rows
-        _M_KERNEL_LIVE_SHARE.set(walked / rows)
+            self._kernel_walked_pages += walked
+        _M_KERNEL_LIVE_SHARE.set(held / rows)
+
+    def _count_moe(self, counts) -> None:
+        """Add one tick's expert-layer counts (the tick's fourth
+        output, already on the host) to stats()['moe']."""
+        with self._metrics_lock:
+            if self._moe_counts is None:
+                self._moe_counts = [0, 0, 0]
+            for j, c in enumerate(counts):
+                self._moe_counts[j] += int(c)
 
     def _dispatch_step(self):
         """Dispatch one jitted engine tick.  The slice engine
@@ -1428,12 +1441,14 @@ class ContinuousBatchingEngine:
                 drafts_dev = self._jax.device_put(
                     drafts_dev,
                     sharding_lib.spec_drafts_sharding(self._mesh))
-            self._state, self._cache, finished, toks_d, counts_d = (
-                self._dispatch_spec_step(drafts_dev))
+            (self._state, self._cache, finished, toks_d, counts_d,
+             moe_d) = self._dispatch_spec_step(drafts_dev)
         with prof.phase('device-wait', count=n_live):
             toks = np.asarray(toks_d)
             counts = np.asarray(counts_d)
             fins = np.asarray(finished)
+            if moe_d is not None:
+                self._count_moe(np.asarray(moe_d))
         with prof.phase('sample') as phase:
             pushed = 0
             accepted = 0
@@ -1507,8 +1522,10 @@ class ContinuousBatchingEngine:
     def _run_pipelined(self, prof: profiling.TickProfiler) -> None:
         import numpy as np  # pylint: disable=import-outside-toplevel
         # One in-flight tick: (state_handles, finished_handle,
-        # [(slot_id, request), ...]) — read one tick behind.
-        inflight: Optional[Tuple[Any, Any, List[Tuple[int, Any]]]] = None
+        # [(slot_id, request), ...], the expert layers' counts or
+        # None) — read one tick behind.
+        inflight: Optional[Tuple[Any, Any, List[Tuple[int, Any]],
+                                 Any]] = None
         pending_prefills: Deque[scheduler.PendingPrefill] = (
             collections.deque())
         live: Dict[int, scheduler.Request] = {}  # slot -> decoding req
@@ -1562,6 +1579,8 @@ class ContinuousBatchingEngine:
                     # (queued requests keep their WRR order; running
                     # decodes always finish).
                     if not self._queue.admission_allowed(occupied):
+                        break
+                    if len(pending_prefills) >= self._max_prefills:
                         break
                     request = self._queue.pop()
                     if request is None:
@@ -1618,21 +1637,23 @@ class ContinuousBatchingEngine:
                     self._spec_tick(live)
                 elif live:
                     with prof.phase('decode-step', count=len(live)):
-                        self._state, self._cache, finished = (
+                        self._state, self._cache, finished, moe = (
                             self._dispatch_step())
                     if self._kv is not None:
                         self._count_kernel_pages(live, 1)
                         for slot_id in live:
                             self._slots[slot_id].depth += 1
                     dispatched = (self._state, finished,
-                                  list(live.items()))
+                                  list(live.items()), moe)
                 if inflight is not None:
-                    state_t, finished_t, snapshot = inflight
+                    state_t, finished_t, snapshot, moe_t = inflight
                     # The one place the host waits for the device: the
                     # blocking read of the tick in flight, nothing else.
                     with prof.phase('device-wait', count=len(snapshot)):
                         toks = np.asarray(state_t['tokens'])
                         fins = np.asarray(finished_t)
+                        if moe_t is not None:
+                            self._count_moe(np.asarray(moe_t))
                     with prof.phase('sample') as phase:
                         pushed = 0
                         for slot_id, request in snapshot:
@@ -1698,20 +1719,6 @@ class ContinuousBatchingEngine:
         slot = self._slots[slot_id]
         prompt = request.prompt_ids
         n = len(prompt)
-        if self.cfg.n_experts > 0 and n > 0:
-            logits, pre = self._prefill(
-                self.params, jnp.asarray([prompt], jnp.int32))
-            self._cache = self._insert(self._cache, slot_id, pre, n)
-            first = int(jnp.argmax(logits[0]))
-            request._push(first)  # pylint: disable=protected-access
-            self._record_tokens(1)
-            if (request.max_new_tokens <= 1 or
-                    first in request.stop_ids):
-                request._finish()  # pylint: disable=protected-access
-                return
-            slot.request = request
-            slot.next_token = first
-            return
         if n > 1:
             bucket = min(self._bucket(n - 1), self.max_len)
             padded = jnp.zeros((1, bucket), jnp.int32)
@@ -1749,8 +1756,8 @@ class ContinuousBatchingEngine:
         tokens = self._tokens
         for i in active:
             tokens = tokens.at[i, 0].set(self._slots[i].next_token)
-        logits, self._cache = self._legacy_step(self.params, tokens,
-                                                self._cache)
+        logits, self._cache, _ = self._legacy_step(
+            self.params, tokens, self._cache)
         import numpy as np  # pylint: disable=import-outside-toplevel
         nxt = np.asarray(jnp.argmax(logits, axis=-1))  # one host sync
         pushed = 0

@@ -146,6 +146,14 @@ class PagePool:
         with self._lock:
             return sum(1 for p in self._pin if p > 0)
 
+    @property
+    def idle_pinned_count(self) -> int:
+        """Pages that only the prefix cache holds (pinned, no slot
+        ref): what evicting its entries would free."""
+        with self._lock:
+            return sum(1 for ref, pin in zip(self._ref, self._pin)
+                       if pin > 0 and ref == 0)
+
     def refcount(self, page: int) -> int:
         with self._lock:
             return self._ref[page]
@@ -326,9 +334,12 @@ class PrefixCache:
         return released
 
     def evictable(self) -> int:
-        """Pages the cache could release right now (no slot refs)."""
-        return sum(1 for page in self._entries.values()
-                   if self._pool.refcount(page) == 0)
+        """Pages the cache could release right now (no slot refs).
+        Each entry pins its own page once, so the pool can say, under
+        its lock: submit() threads probe this (`can_admit`) while the
+        worker registers, matches (an LRU touch) and evicts entries,
+        and the entries themselves are the worker's alone to walk."""
+        return self._pool.idle_pinned_count
 
     def hot_entries(self, n: int) -> List[Tuple[int, int]]:
         """The n most-recently-used (hash, page) entries.  Entries are

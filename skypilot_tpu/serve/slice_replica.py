@@ -267,9 +267,10 @@ class SliceReplicaEngine(batching_engine_lib.ContinuousBatchingEngine):
                         n_target: int) -> Optional[Dict[str, Any]]:
         """One-shot sequence-parallel prefill of [0, n_target), or None
         when the prompt should take the chunked path (below threshold,
-        MoE, or padding does not fit)."""
+        a layer pattern or parallel block, or padding does not fit)."""
         import numpy as np  # pylint: disable=import-outside-toplevel
-        if (n_target < self.sp_threshold or self.cfg.n_experts > 0):
+        if (n_target < self.sp_threshold or self.cfg.layer_pattern or
+                self.cfg.parallel_block):
             return None
         width = self._sp_padded_width(n_target)
         if width is None:

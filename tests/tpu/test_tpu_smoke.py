@@ -162,7 +162,7 @@ def test_slot_batched_decode_on_tpu():
                                        prompt.shape[1])
     tokens = jnp.zeros((2, 1), jnp.int32).at[0, 0].set(
         jnp.argmax(logits[0]))
-    got, _ = decode.batched_step(cfg, params, tokens, slot_cache)
+    got, _, _ = decode.batched_step(cfg, params, tokens, slot_cache)
     np.testing.assert_allclose(np.asarray(got[0], np.float32),
                                np.asarray(ref[0], np.float32),
                                rtol=2e-2, atol=2e-2)

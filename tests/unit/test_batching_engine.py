@@ -105,8 +105,9 @@ class TestEngine:
 class TestEngineRobustness:
 
     def test_moe_config_exact(self, setup):
-        """MoE prefill must stay exact (pad tokens would perturb the
-        capacity dispatch, so MoE prompts prefill unpadded)."""
+        """MoE prompts take the chunked, padded path of every other
+        model and stay exact: the expert layer drops no token, so pad
+        tokens and chunk boundaries move no real token's result."""
         cfg = configs.get_config('tiny-moe')
         model = Transformer(cfg)
         prompt = [3, 1, 4, 1, 5, 9, 2]
@@ -114,10 +115,13 @@ class TestEngineRobustness:
             jax.random.PRNGKey(0),
             jnp.asarray([prompt], jnp.int32))['params'])
         eng = batching_engine.ContinuousBatchingEngine(
-            cfg, params, max_len=64, slots=2)
+            cfg, params, max_len=64, slots=2, prefill_chunk=4)
         try:
+            # 6 prefilled tokens: a chunk of 4, then 2 padded to 4.
             got = eng.generate(prompt, max_new_tokens=5, timeout=180)
             assert got == _reference(cfg, params, prompt, 5)
+            assert eng.stats()['prefill_chunks'] == 2
+            assert eng.stats()['moe']['tokens'] == 5 * cfg.n_layers
         finally:
             eng.stop()
 

@@ -87,10 +87,11 @@ def test_max_len_validation(setup):
 
 
 def test_moe_greedy_generation_parity():
-    """MoE decode (dense-gather routing) matches the training-path
-    forward when capacity never drops tokens (factor large enough)."""
-    cfg = configs.get_config('tiny-moe',
-                             expert_capacity_factor=16.0)
+    """MoE decode through the cache matches the training module's full
+    re-forward: both run the one expert layer without drops
+    (`moe.moe_apply`), so whatever the routing, a token's result is the
+    same alone in a tick and among the prompt's other tokens."""
+    cfg = configs.get_config('tiny-moe')
     model = Transformer(cfg)
     prompt = jax.random.randint(jax.random.PRNGKey(3), (2, 8), 0,
                                 cfg.vocab_size, dtype=jnp.int32)
@@ -163,7 +164,7 @@ class TestSlotBatchedDecode:
                                            p2.shape[1])
         tokens = jnp.concatenate(
             [t1, t2, jnp.zeros((1, 1), jnp.int32)], axis=0)
-        logits, new_cache = decode.batched_step(cfg, params, tokens,
+        logits, new_cache, _ = decode.batched_step(cfg, params, tokens,
                                                 slot_cache)
         np.testing.assert_allclose(np.asarray(logits[0]),
                                    np.asarray(ref1[0]),
@@ -193,7 +194,7 @@ class TestSlotBatchedDecode:
         got = [int(tok)]
         tokens = jnp.zeros((2, 1), jnp.int32).at[0, 0].set(tok)
         for _ in range(4):
-            logits, slot_cache = decode.batched_step(
+            logits, slot_cache, _ = decode.batched_step(
                 cfg, params, tokens, slot_cache)
             tok = jnp.argmax(logits[0], axis=-1)
             got.append(int(tok))
@@ -307,7 +308,7 @@ class TestEngineStep:
         state, cache = self._setup_state(cfg, params)
         before_tok = int(state['tokens'][1])
         before_len = int(cache['lengths'][1])
-        state, cache, finished = decode.engine_step(cfg, params, state,
+        state, cache, finished, _ = decode.engine_step(cfg, params, state,
                                                     cache)
         assert bool(state['active'][0])
         assert not bool(state['active'][1])
@@ -321,7 +322,7 @@ class TestEngineStep:
         state, cache = self._setup_state(cfg, params)
         fins = []
         for _ in range(4):
-            state, cache, finished = decode.engine_step(
+            state, cache, finished, _ = decode.engine_step(
                 cfg, params, state, cache)
             fins.append(bool(finished[0]))
         # remaining=3 -> exactly the third tick finishes the slot, and
@@ -334,12 +335,12 @@ class TestEngineStep:
         state, cache = self._setup_state(cfg, params)
         # Run one step to learn the next token, then rerun with that
         # token as a stop id: the step itself must flag fin.
-        probe_state, _, _ = decode.engine_step(
+        probe_state, _, _, _ = decode.engine_step(
             cfg, params, dict(state),
             jax.tree.map(jnp.copy, cache))
         stop = int(probe_state['tokens'][0])
         state = dict(state, stop_ids=state['stop_ids'].at[0, 0].set(stop))
-        state, cache, finished = decode.engine_step(cfg, params, state,
+        state, cache, finished, _ = decode.engine_step(cfg, params, state,
                                                     cache)
         assert bool(finished[0])
         assert not bool(state['active'][0])

@@ -312,7 +312,7 @@ class TestPallasKernelParity:
         try:
             ps, rows, slots = 8, 64 // 8, 2
             assert eng.stats()['paged_kernel'] == {
-                'live_pages': 0, 'table_pages': 0}
+                'live_pages': 0, 'table_pages': 0, 'walked_pages': 0}
             script = (([3, 1, 4, 1, 5, 9, 2, 6, 5, 3, 5], 6),
                       ([7, 2, 9], 9))
             handles = [eng.submit(p, max_new_tokens=n)
@@ -327,6 +327,9 @@ class TestPallasKernelParity:
             walked = sum(-(-(len(p) - 1 + j + 1) // ps)
                          for p, n in script for j in range(n + 1))
             assert stats['paged_kernel']['live_pages'] == walked
+            # No layer has a window: every layer walks every live page.
+            assert stats['paged_kernel']['walked_pages'] == \
+                walked * cfg.n_layers
             # Every dispatched tick adds the rows of every table; a
             # tick is counted in `ticks` only once it has been read.
             table_pages = stats['paged_kernel']['table_pages']

@@ -123,25 +123,44 @@ def test_unsupported_rope_scaling_rejected():
 
 
 def test_active_sliding_window_rejected():
+    """A window on some layers only, which is no repeating pattern, is
+    refused; an inert one passes."""
     from skypilot_tpu.models import import_weights as iw
     base = {'model_type': 'qwen2', 'num_attention_heads': 4,
             'hidden_size': 32, 'vocab_size': 64, 'num_hidden_layers': 2,
             'intermediate_size': 48, 'max_position_embeddings': 8192,
             'sliding_window': 1024}
     # Inert window (flag off): imports fine — Qwen2 ships these.
-    iw.config_from_hf(dict(base, use_sliding_window=False))
-    with pytest.raises(ValueError, match='sliding-window'):
-        iw.config_from_hf(dict(base, use_sliding_window=True))
+    assert not iw.config_from_hf(
+        dict(base, use_sliding_window=False))[0].layer_pattern
+    # Flag on: the layers from max_window_layers on are windowed.
+    for first in (None, 1):
+        with pytest.raises(ValueError, match='sliding-window'):
+            iw.config_from_hf(dict(base, use_sliding_window=True,
+                                   max_window_layers=first))
+    # ... which no layer reaches here: inert again.
+    assert not iw.config_from_hf(dict(
+        base, use_sliding_window=True,
+        max_window_layers=2))[0].layer_pattern
+
+
+def test_uniform_sliding_window_imported():
+    """A window on every layer is a layer pattern the program serves."""
+    from skypilot_tpu.models import import_weights as iw
     # Mixtral has no flag: any window smaller than the context is live.
     mix = {'model_type': 'mixtral', 'num_attention_heads': 4,
            'hidden_size': 32, 'vocab_size': 64, 'num_hidden_layers': 2,
            'intermediate_size': 48, 'max_position_embeddings': 8192,
            'num_local_experts': 4, 'num_experts_per_tok': 2,
            'sliding_window': 1024}
-    with pytest.raises(ValueError, match='sliding-window'):
-        iw.config_from_hf(mix)
+    cfg, _ = iw.config_from_hf(mix)
+    assert (cfg.layer_pattern, cfg.sliding_window) == (('window',), 1024)
+    assert cfg.layer_kinds() == ((True, 1024),) * 2
     mix['sliding_window'] = None
-    iw.config_from_hf(mix)
+    assert iw.config_from_hf(mix)[0].layer_kinds() is None
+    qwen = dict(mix, model_type='qwen2', sliding_window=1024,
+                use_sliding_window=True, max_window_layers=0)
+    assert iw.config_from_hf(qwen)[0].sliding_window == 1024
 
 
 def test_qwen2_logits_match_hf(tmp_path):

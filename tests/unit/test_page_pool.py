@@ -147,6 +147,40 @@ class TestPrefixCache:
         pool.decref(a)
         assert cache.evictable() == 1
 
+    def test_headroom_probe_beside_the_worker(self):
+        """submit() threads ask `can_admit` while the engine's worker
+        matches prefixes (an LRU touch of every matched entry): with
+        some thousands of entries the probe used to walk the entries
+        and die of 'OrderedDict mutated during iteration'."""
+        import threading
+        mgr = cache_manager.PagedKVManager(4097, 16, slots=4)
+        pages = mgr.pool.alloc(4000)
+        mgr.prefix.register(list(range(4000)), pages)
+        mgr.pool.decref(pages)
+        errors, stop = [], threading.Event()
+
+        def probe():
+            while not stop.is_set():
+                try:
+                    # 96 free + 4000 cached, 512 of them matched.
+                    assert mgr.can_admit(3000)
+                except Exception as e:  # pylint: disable=broad-except
+                    errors.append(repr(e))
+                    return
+
+        prober = threading.Thread(target=probe)
+        prober.start()
+        try:
+            for _ in range(300):
+                mgr.pool.decref(mgr.prefix.match(list(range(512))))
+                if errors:
+                    break
+        finally:
+            stop.set()
+            prober.join()
+        assert not errors
+
+
 
 class TestPagedKVManager:
 

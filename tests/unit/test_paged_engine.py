@@ -136,19 +136,25 @@ class TestPagedParity:
             eng.stop()
 
     def test_moe_paged_exact(self):
-        """MoE + pages: full-prompt prefill scatters into pages (no
-        prefix reuse — the capacity dispatch couples KV to the whole
-        prompt) and decode stays exact."""
+        """MoE + pages: chunked prefill scatters into pages, a second
+        prompt with the same first two pages is served from them (the
+        expert layer drops no token, so equal prefixes have equal KV),
+        and decode stays exact on the miss and on the hit."""
         cfg = configs.get_config('tiny-moe')
-        prompt = [3, 1, 4, 1, 5, 9, 2]
+        shared = [3, 1, 4, 1, 5, 9, 2, 6, 5, 3, 5, 8, 9, 7, 9, 3]
+        prompts = [shared + [2, 3, 8], shared + [4, 6]]
         params = nn.meta.unbox(Transformer(cfg).init(
             jax.random.PRNGKey(0),
-            jnp.asarray([prompt], jnp.int32))['params'])
+            jnp.asarray([prompts[0]], jnp.int32))['params'])
         eng = _paged_engine(cfg, params)
         try:
-            got = eng.generate(prompt, 5, timeout=180)
-            assert got == _reference(cfg, params, prompt, 5)
-            assert eng.stats()['prefix_cache_entries'] == 0
+            for prompt, hit_pages in zip(prompts, (0, 2)):
+                request = eng.submit(prompt, 5)
+                assert request.result(timeout=180) == _reference(
+                    cfg, params, prompt, 5)
+                assert request.span.prefix_hit_pages == hit_pages
+            # The prefix cache counts hits in pages.
+            assert eng.stats()['prefix_cache_hits'] == 2
         finally:
             eng.stop()
 
@@ -172,7 +178,7 @@ class TestInt8KVBound:
                                             first_page=0)
         row = jnp.zeros((4,), jnp.int32).at[:4].set(pages)
         paged = decode.paged_admit_slot(paged, 0, row, 15)
-        logits, _ = decode.paged_batched_step(
+        logits, _, _ = decode.paged_batched_step(
             cfg, params, prompt[:, -1:], paged)
         ref = np.asarray(ref_logits)[0]
         got = np.asarray(logits)[0]
@@ -359,6 +365,56 @@ class TestPoolAccounting:
                 cfg, params, list(range(1, 20)), 8)
         finally:
             eng.stop()
+
+    def test_backpressure_counts_the_cached_prefix(self, setup):
+        """Submit-time backpressure asks for a request's pages less
+        those its prompt already has in the prefix cache: a question
+        on a long cached document is not refused because the
+        document's pages would not fit a second time."""
+        cfg, params = setup
+        eng = _paged_engine(cfg, params, kv_pages=10, page_size=8,
+                            slots=2)
+        try:
+            kv = eng._kv
+            doc = list(range(1, 34))            # 32 prefilled: 4 pages
+            plan = kv.plan_admission(doc, 30)   # 62 positions: 8 pages
+            kv.commit(0, plan)
+            kv.register_prefix(plan)
+            assert kv.pool.free_count == 1
+            same_doc = doc[:32] + [77, 78]
+            need = kv.pages_needed(len(same_doc), 4)
+            assert need == 5 and not kv.can_admit(need)
+            assert eng._pool_has_room(same_doc, need)
+            other = list(range(100, 134))
+            assert not eng._pool_has_room(other, need)
+            kv.release(0)
+        finally:
+            eng.stop()
+
+    def test_prefills_in_flight_bounded_by_the_cache(self, setup):
+        """Each prompt mid-prefill holds a private cache of max_len;
+        together they may hold the bytes of the engine's own cache, a
+        bound the engine works out from its shapes.  Requests past it
+        wait in the queue and are served all the same."""
+        cfg, params = setup
+        # 16 pages of 8 are two max_len of 64, under 4 slots.
+        eng = _paged_engine(cfg, params, kv_pages=16, slots=4)
+        try:
+            assert eng._max_prefills == 2
+            prompts = [list(range(k, k + 20)) for k in (1, 31, 61, 91)]
+            handles = [eng.submit(p, 4) for p in prompts]
+            for p, h in zip(prompts, handles):
+                assert h.result(timeout=180) == _reference(cfg, params,
+                                                           p, 4)
+        finally:
+            eng.stop()
+        # The slots' own caches (no pool): every slot may prefill.
+        dense = batching_engine.ContinuousBatchingEngine(
+            cfg, params, max_len=64, slots=3)
+        try:
+            assert dense._max_prefills == 3
+        finally:
+            dense.stop()
 
     def test_validation(self, setup):
         cfg, params = setup

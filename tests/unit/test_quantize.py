@@ -37,12 +37,25 @@ class TestQuantizeParams:
             layer['attn_norm']['scale'])
 
     def test_moe_experts_quantized_router_not(self):
-        _, params = _params('tiny-moe')
+        """Expert stacks go int8, the router stays as it is, and the
+        expert layer without drops reads both (`moe.moe_apply` through
+        `maybe_dequant`): the same experts chosen, a result close to
+        the full-precision one."""
+        from skypilot_tpu.models import moe as moe_lib
+        cfg, params = _params('tiny-moe')
         q = quantize.quantize_params(params)
         moe = q['layers']['layer']['moe_mlp']
         assert quantize.is_quantized_leaf(moe['gate_proj'])
         assert quantize.is_quantized_leaf(moe['down_proj'])
         assert not quantize.is_quantized_leaf(moe['router']['kernel'])
+        x = jax.random.normal(jax.random.PRNGKey(7), (6, cfg.d_model))
+        first = lambda tree: jax.tree.map(lambda a: a[0], tree)
+        want, _, counts = moe_lib.moe_apply(
+            x, first(params['layers']['layer']['moe_mlp']), cfg)
+        got, _, counts_q = moe_lib.moe_apply(x, first(moe), cfg)
+        assert [int(c) for c in counts] == [int(c) for c in counts_q]
+        err = float(jnp.max(jnp.abs(got - want)))
+        assert 0 < err < 0.05 * float(jnp.max(jnp.abs(want))), err
 
     def test_per_channel_exactness_on_channel_scaled_matrix(self):
         """A matrix whose rows are +-multiples of one channel scale is

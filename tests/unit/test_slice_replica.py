@@ -287,12 +287,24 @@ class TestCoordinator:
 
 class TestPrefillSp:
 
-    def test_matches_flash_prefill(self, tiny):
+    @pytest.mark.parametrize('name', ['tiny', 'tiny-moe'])
+    def test_matches_flash_prefill(self, tiny, name):
+        """The dense block, and an expert model: its layer drops no
+        token, so a prompt split over the sequence axis routes as it
+        does whole, and both prefill paths carry the one stream dtype."""
+        import flax.linen as nn
         import jax
         import jax.numpy as jnp
 
+        from skypilot_tpu.models import configs
         from skypilot_tpu.models import decode
+        from skypilot_tpu.models.transformer import Transformer
         cfg, params = tiny
+        if name != 'tiny':
+            cfg = configs.get_config(name)
+            params = nn.meta.unbox(Transformer(cfg).init(
+                jax.random.PRNGKey(0),
+                jnp.zeros((1, 8), jnp.int32))['params'])
         prompt = jnp.asarray([list(range(1, 49))], jnp.int32)
         _, ref = decode.prefill(cfg, params, prompt, max_len=64)
         mesh = slice_replica.build_slice_mesh(2, cfg, sequence=2)
@@ -304,17 +316,21 @@ class TestPrefillSp:
             want = jnp.asarray(ref[leaf], jnp.float32)[..., :48, :]
             assert float(jnp.max(jnp.abs(got - want))) < 1e-4
 
-    def test_moe_rejected(self, tiny):
+    def test_layer_pattern_rejected(self, tiny):
+        """Ring attention has no window: a model with a layer pattern
+        takes the chunked path (expert models no longer do: their
+        layer drops no token, so a split prompt routes as a whole)."""
         import dataclasses
 
         import jax.numpy as jnp
 
         from skypilot_tpu.models import decode
         cfg, params = tiny
-        moe_cfg = dataclasses.replace(cfg, n_experts=4)
+        win_cfg = dataclasses.replace(cfg, layer_pattern=('window',),
+                                      sliding_window=4)
         mesh = slice_replica.build_slice_mesh(2, cfg, sequence=2)
-        with pytest.raises(ValueError, match='MoE'):
-            decode.prefill_sp(moe_cfg, params,
+        with pytest.raises(ValueError, match='layer_pattern'):
+            decode.prefill_sp(win_cfg, params,
                               jnp.zeros((1, 8), jnp.int32),
                               mesh=mesh, max_len=64)
 
@@ -355,6 +371,38 @@ class TestSliceEngineExactness:
         # The span records the coordinated-tick overhead.
         spans = stats['recent_spans']
         assert all('slice_sync_ms' in s for s in spans)
+
+    def test_two_host_expert_model_token_exact(self):
+        """An expert model on the slice replica: the long prompt takes
+        the one-shot SP prefill (sequence=2, a real ring split), the
+        short one the chunked path, and both give the single-process
+        engine's tokens."""
+        import flax.linen as nn
+        import jax
+        import jax.numpy as jnp
+
+        from skypilot_tpu.models import configs
+        from skypilot_tpu.models.transformer import Transformer
+        cfg = configs.get_config('tiny-moe')
+        params = nn.meta.unbox(Transformer(cfg).init(
+            jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))['params'])
+        kw = dict(max_len=128, slots=2, prefill_chunk=16, kv_pages=48,
+                  page_size=8)
+        prompts = (_PROMPTS[0], _PROMPTS[2])
+        ref = batching_engine.ContinuousBatchingEngine(cfg, params, **kw)
+        try:
+            want = [ref.generate(p, 8, timeout=120) for p in prompts]
+        finally:
+            ref.stop()
+        eng = slice_replica.SliceReplicaEngine(
+            cfg, params, num_hosts=2, sequence=2, sp_threshold=32, **kw)
+        try:
+            got = [eng.generate(p, 8, timeout=120) for p in prompts]
+            stats = eng.stats()
+        finally:
+            eng.stop()
+        assert got == want
+        assert stats['slice']['sp_prefills'] == 1
 
     def test_two_host_sequence_axis_int8_kv_token_exact(self, tiny):
         """sequence=2 layout (real ring split) + int8 KV pages: still

@@ -61,3 +61,25 @@ def test_paged_decode_kernel_compiles_for_v5e(
                 arg((slots, rows), jnp.int32),
                 arg((slots,), jnp.int32)).compile()
     assert 'tpu_custom_call' in compiled.as_text()
+
+
+@pytest.mark.parametrize('s_q', [1, 4], ids=['tick', 'verify4'])
+def test_windowed_paged_decode_kernel_compiles_for_v5e(
+        one_chip, monkeypatch, s_q):
+    """The kernel with a layer's window as a third prefetched scalar,
+    at the benchmark's expert cell: 64 slots, 128 query heads on 8 KV
+    heads (16 a KV head: 128 rows a step), tables of 544 rows."""
+    monkeypatch.delenv('SKYTPU_PALLAS_INTERPRET', raising=False)
+    d, ps, slots, n_pages = 128, 16, 64, 6144
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool = arg((n_pages, 8, ps, d), jnp.bfloat16)
+    compiled = jax.jit(
+        lambda *a: paged_attention._paged_attention_pallas(
+            *a[:-1], sm_scale=d ** -0.5, window=a[-1])).lower(
+                arg((slots, 128, s_q, d), jnp.bfloat16), pool, pool,
+                arg((slots, 544), jnp.int32), arg((slots,), jnp.int32),
+                arg((), jnp.int32)).compile()
+    assert 'tpu_custom_call' in compiled.as_text()
