@@ -9,9 +9,12 @@ Serving replicas (serve/) wrap this in their model servers.
 
 Design notes:
 - The cache is a plain pytree {k: [L, b, h_kv, max_len, d], v: ...,
-  'index': []} — scan_layers stacks the per-layer cache on a leading
-  axis exactly like the params, so cache shardings follow the same
-  logical rules (kv_heads on 'tensor').
+  'index': []} — the per-layer caches are stacked on a leading axis
+  exactly like the scan-layout params, so cache shardings follow the
+  same logical rules (kv_heads on 'tensor').  The layer loop CARRIES
+  the stacked caches and writes each layer's new rows into them in
+  place; it never slices a layer's share out and stacks it back
+  (`_scan_layers_and_unembed`).
 - Decode attends with an explicit length mask (positions > index are
   masked), so one compiled step serves every sequence length.
 - Sampling: greedy or temperature/top-k, RNG threaded explicitly.
@@ -37,11 +40,14 @@ from skypilot_tpu.ops.attention import flash_attention
 class _PagedView(NamedTuple):
     """The paged-KERNEL path's cache 'view': instead of gathering the
     pool into a dense [b, h_kv, len, d] array, attention receives the
-    raw pool leaf + block tables + lengths and the Pallas kernel does
-    the table-indexed page reads inside its grid (the gathered view
-    never materialises in HBM).  Produced by `_paged_forward`'s view_fn
-    when kernel='pallas'; `_layer_forward` dispatches on it."""
+    WHOLE pool leaf ([L, n_pages, h_kv, ps, d], never sliced by layer)
+    + the layer's index + block tables + lengths, and the Pallas kernel
+    does the (layer, table-indexed page) reads inside its grid (neither
+    the layer's share nor the gathered view materialises in HBM).
+    Produced by `_paged_forward`'s view_fn when kernel='pallas';
+    `_layer_forward` dispatches on it."""
     leaf: Any
+    layer: jax.Array
     tables: jax.Array
     lengths: jax.Array
 
@@ -153,10 +159,11 @@ _NO_WINDOW = 1 << 30
 def _layer_forward(x, lp, cfg, positions, k_cache, v_cache,
                    *, use_flash: bool, mesh=None, rope_on=None,
                    window=None, row_mask=None):
-    """One decoder layer against an explicit KV cache slice.
+    """One decoder layer against its view of the KV cache.
 
-    x [b, s, d]; k_cache/v_cache [b, h_kv, max_len, hd] already contain
-    this call's k/v written at [positions].  Returns (the layer output,
+    x [b, s, d]; k_cache/v_cache [b, h_kv, max_len, hd] (or the paged
+    kernel's `_PagedView` of the whole pool and the layer's index)
+    already contain this call's k/v written at [positions].  Returns (the layer output,
     the expert layer's counts or None).  `mesh` (the mesh the params
     and cache are sharded over, if any) goes to the attention kernels,
     which run per shard under it.
@@ -173,15 +180,15 @@ def _layer_forward(x, lp, cfg, positions, k_cache, v_cache,
 
     if isinstance(k_cache, _PagedView):
         # Paged-kernel decode: the Pallas kernel copies each slot's
-        # live K/V pages from the pool by block-table index (fused
-        # int8 dequant on the loaded operand); `positions` is implied
-        # by the view's lengths — query token j of slot b sits at
-        # lengths[b] + j.
+        # live K/V pages of this layer from the whole pool by (layer,
+        # block-table) index (fused int8 dequant on the loaded
+        # operand); `positions` is implied by the view's lengths —
+        # query token j of slot b sits at lengths[b] + j.
         with jax.named_scope('paged_attention'):
             out = paged_attention_ops.paged_attention(
                 q, k_cache.leaf, v_cache.leaf, k_cache.tables,
                 k_cache.lengths, sm_scale=cfg.head_dim ** -0.5,
-                mesh=mesh, window=window)
+                mesh=mesh, window=window, layer=k_cache.layer)
         out = out.astype(x.dtype)
     elif use_flash:
         # Prefill from index 0: the valid cache region is exactly the
@@ -268,19 +275,30 @@ def _scan_layers_and_unembed(cfg, params, x, positions, cache_k, cache_v,
                              write_fn, *, use_flash: bool,
                              view_fn=None, all_positions: bool = False,
                              mesh=None, row_mask=None):
-    """The shared per-layer loop: project+rope k/v, write them into the
-    cache via `write_fn(k_cache, k_new) -> k_cache`, run the layer, then
-    final-norm + unembed the last position.  Single-sequence decode and
-    slot-batched decode differ ONLY in write_fn / positions shapes.
+    """The shared per-layer loop: project+rope k/v, write them into
+    layer l of the cache via `write_fn(cache, l, new) -> cache`, run
+    the layer against `view_fn(cache, l)`, then final-norm + unembed
+    the last position.  Single-sequence decode, slot-batched decode and
+    the page pool differ ONLY in write_fn / view_fn / positions shapes.
     Returns (logits, new_k, new_v, counts): counts is None for a model
     without experts, else the expert layers' int32 [3] counts summed
     over the layers (`moe.moe_apply`, over the rows `row_mask` marks).
 
-    `view_fn(cache_leaf) -> [b, h_kv, len, d]` maps the stored cache to
-    the array attention reads — identity for dense caches; the paged
-    cache gathers (and dequantizes) its pages through it (or hands the
-    Pallas kernel a `_PagedView`), so one layer body serves every cache
-    layout.
+    The stacked caches (`[L, ...]` leaves, or int8 {'q','scale'} dicts
+    of them) ride the loop as its CARRY beside `x`, whole: the scanned
+    inputs are the layers' weights, their kinds and the layer's index
+    `l` (a traced int32 scalar).  So a layer's share of a cache is
+    never sliced out of the stack and written back: `write_fn` puts the
+    new rows where they belong in the carried buffer (in place, when
+    the caller donated it) and the returned caches are the carried
+    ones.
+
+    `view_fn(cache, l)` maps the stored cache to what layer l's
+    attention reads — by default the layer's slice `cache[l]`
+    ([b, h_kv, len, d], the dense caches); the paged cache gathers (and
+    dequantizes) the layer's pages through it, or hands the Pallas
+    kernel a `_PagedView` of the whole pool and `l`, so one layer body
+    serves every cache layout.
 
     Layers of more than one kind (`cfg.layer_kinds`: rotary or not, a
     window or none) run under the one scan: each layer's kind rides the
@@ -295,37 +313,40 @@ def _scan_layers_and_unembed(cfg, params, x, positions, cache_k, cache_v,
     """
     layers = _layer_params(params, cfg)
     if view_fn is None:
-        view_fn = lambda c: c
+        view_fn = lambda c, l: jax.lax.dynamic_index_in_dim(
+            c, l, axis=0, keepdims=False)
     kinds = cfg.layer_kinds()
     use_flash = _flash_ok(cfg, use_flash, x.shape[1])
-    xs = (layers, cache_k, cache_v)
+    xs = (layers, jnp.arange(cfg.n_layers, dtype=jnp.int32))
     if kinds is not None:
         xs += (jnp.asarray([rope for rope, _ in kinds]),
                jnp.asarray([w or _NO_WINDOW for _, w in kinds],
                            jnp.int32))
 
-    def body(x, layer_state):
-        lp, k_cache, v_cache = layer_state[:3]
-        rope_on, window = layer_state[3:] or (None, None)
+    def body(carry, layer_state):
+        x, k_cache, v_cache = carry
+        lp, l = layer_state[:2]
+        rope_on, window = layer_state[2:] or (None, None)
         h = _norm(x, lp['attn_norm']['scale'], cfg)
         k = _attn_proj(h, lp['attn']['k_proj'])
         v = _attn_proj(h, lp['attn']['v_proj'])
         k = _rope_if(rope_on, k, positions, cfg)
         with jax.named_scope('kv_write'):
-            k_cache = write_fn(k_cache, k)
-            v_cache = write_fn(v_cache, v)
+            k_cache = write_fn(k_cache, l, k)
+            v_cache = write_fn(v_cache, l, v)
         x, counts = _layer_forward(
-            x, lp, cfg, positions, view_fn(k_cache), view_fn(v_cache),
-            use_flash=use_flash, mesh=mesh, rope_on=rope_on,
-            window=window, row_mask=row_mask)
-        return x, (k_cache, v_cache, counts)
+            x, lp, cfg, positions, view_fn(k_cache, l),
+            view_fn(v_cache, l), use_flash=use_flash, mesh=mesh,
+            rope_on=rope_on, window=window, row_mask=row_mask)
+        return (x, k_cache, v_cache), counts
 
-    # The scan slices each layer's share out of the stacked cache and
-    # writes it back: in a device trace those copies are the ops under
-    # `layer_scan` that are under none of the scopes inside the body.
+    # The caches are in the carry, so nothing cache-sized is sliced or
+    # stacked around the body: in a device trace, an op under
+    # `layer_scan` that is under none of the scopes inside the body and
+    # moves a layer's share of a cache is a copy the compiler put back.
     with jax.named_scope('layer_scan'):
-        x, (new_k, new_v, counts) = jax.lax.scan(
-            lambda carry, ls: body(carry, ls), x, xs)
+        (x, new_k, new_v), counts = jax.lax.scan(
+            body, (x, cache_k, cache_v), xs)
     if counts is not None:
         counts = jnp.sum(counts, axis=0)
     with jax.named_scope('lm_head'):
@@ -346,9 +367,9 @@ def _forward_with_cache(cfg, params, tokens, cache, *, use_flash: bool,
     positions = start + jnp.arange(s)
     cache_len = start + s
 
-    def write(c, new):
+    def write(c, l, new):
         return jax.lax.dynamic_update_slice(
-            c, new.astype(c.dtype), (0, 0, start, 0))
+            c, new.astype(c.dtype)[None], (l, 0, 0, start, 0))
 
     logits, new_k, new_v, _ = _scan_layers_and_unembed(
         cfg, params, _embed(cfg, params, tokens), positions,
@@ -599,13 +620,13 @@ def batched_step(cfg: ModelConfig, params, tokens, slot_cache,
     lengths = slot_cache['lengths']                    # [B]
     positions = lengths[:, None]                       # [B, 1]
 
-    def write(c, new):
-        # Per-slot scatter at that slot's depth: vmap the single-
-        # sequence dynamic_update_slice over the slot axis.
+    def write(c, l, new):
+        # Per-slot scatter at that slot's depth in layer l: vmap the
+        # single-sequence dynamic_update_slice over the slot axis.
         return jax.vmap(
             lambda cc, nn, st: jax.lax.dynamic_update_slice(
-                cc, nn.astype(cc.dtype), (0, st, 0))
-        )(c, new, lengths)
+                cc, nn.astype(cc.dtype)[None], (l, 0, st, 0)),
+            in_axes=(1, 0, 0), out_axes=1)(c, new, lengths)
 
     logits, new_k, new_v, counts = _scan_layers_and_unembed(
         cfg, params, _embed(cfg, params, tokens), positions,
@@ -739,7 +760,14 @@ def init_paged_cache(cfg: ModelConfig, n_pages: int, page_size: int,
     """Zeroed page-pool cache.  k/v are [L, n_pages, h_kv, ps, d]
     (int8 {'q','scale'} leaves when quantize_kv); block_tables [B, P]
     name each slot's pages in order (0 = the reserved null page) and
-    lengths [B] are the per-slot decode depths."""
+    lengths [B] are the per-slot decode depths.
+
+    The layout is row-major as written, a page of a layer one
+    contiguous [h_kv, ps, d] slab: it is what the paged kernel's page
+    copies read (`hbm.at[layer, page]`), and the decode tick keeps the
+    donated leaves in it from argument to result: writes are scatters
+    of [d] rows at (layer, page, head, offset) and nothing slices the
+    leaves by layer (`_paged_forward`)."""
     kv_shape = (cfg.n_layers, n_pages, cfg.n_kv_heads, page_size,
                 cfg.head_dim)
 
@@ -792,8 +820,17 @@ def _paged_forward(cfg: ModelConfig, params, tokens, paged, *,
     layers' over the rows of the `active` [B] slots (None without
     experts).
 
-    Writes scatter each (slot, token) at (block_tables[b, pos//ps],
-    pos % ps).  Positions past the slot's table ([n_rows * ps, ...))
+    The pool leaves are handed to the layer loop whole and come back
+    whole (its carry): layer l's writes scatter each (slot, token, kv
+    head) as one [d] row at (l, block_tables[b, pos//ps], head,
+    pos % ps) of the stacked leaf, which XLA does in place on a donated
+    pool.  The head is an index and not a slice of the scatter on
+    purpose: with `[h_kv, d]` windows the TPU compiler gives the
+    carried pool a layout with the heads beside the lanes, and then
+    converts the WHOLE pool to the kernel's row-major layout and back
+    around every layer (compiled for a v5e, PR 31); with [d] rows the
+    pool keeps the one layout the kernel reads.  Positions past the
+    slot's table ([n_rows * ps, ...))
     route to the reserved null page instead of clipping — clipping
     would corrupt the LAST VALID page of a near-full slot when a
     speculative tick writes drafts beyond the allocation.  Inactive
@@ -801,9 +838,10 @@ def _paged_forward(cfg: ModelConfig, params, tokens, paged, *,
     freed slots' tables on the null page so a stale write can never
     corrupt recycled pages.
 
-    kernel='pallas' hands attention a `_PagedView` (the Pallas kernel
-    reads pages by table index in-grid); None/'gather' keeps the dense
-    page-gather view.
+    kernel='pallas' hands attention a `_PagedView` of the whole pool
+    and the layer's index (the Pallas kernel reads layer l's pages by
+    table index in-grid); None/'gather' gathers layer l's pages that
+    the tables name into the dense view.
     """
     lengths = paged['lengths']                     # [B]
     tables = paged['block_tables']                 # [B, P]
@@ -820,32 +858,34 @@ def _paged_forward(cfg: ModelConfig, params, tokens, paged, *,
     flat_pages = pages.reshape(-1)                 # [B*S]
     flat_off = offsets.reshape(-1)
 
-    def write(c, new):
-        # new [B, h_kv, S, d] -> one (page, offset) scatter per
-        # (slot, token).
+    def write(c, l, new):
+        # new [B, h_kv, S, d] -> one [d] row per (slot, token, head)
+        # at (layer, page, head, offset) of the whole pool (why the
+        # head is indexed too: the docstring).
         tok = new.transpose(0, 2, 1, 3).reshape(
             b * s_q, new.shape[1], new.shape[3])   # [B*S, h_kv, d]
+        at = (l, flat_pages[:, None], jnp.arange(new.shape[1])[None, :],
+              flat_off[:, None])
         if isinstance(c, dict):
             q, scale = _quant_kv(tok)
-            return {'q': c['q'].at[flat_pages, :, flat_off].set(q),
-                    'scale':
-                        c['scale'].at[flat_pages, :, flat_off].set(scale)}
-        return c.at[flat_pages, :, flat_off].set(tok.astype(c.dtype))
+            return {'q': c['q'].at[at].set(q),
+                    'scale': c['scale'].at[at].set(scale)}
+        return c.at[at].set(tok.astype(c.dtype))
 
     if kernel == 'pallas':
-        def view(c):
-            return _PagedView(c, tables, lengths)
+        def view(c, l):
+            return _PagedView(c, l, tables, lengths)
     else:
-        def view(c):
-            # Gather the pool rows each slot's table names ->
-            # [B, P, h_kv, ps, d], dequantized, then fold pages into
+        def view(c, l):
+            # Gather layer l's pool rows that each slot's table names
+            # -> [B, P, h_kv, ps, d], dequantized, then fold pages into
             # the position axis (table order IS position order).
             if isinstance(c, dict):
-                arr = _dequant_kv({'q': c['q'][tables],
-                                   'scale': c['scale'][tables]},
+                arr = _dequant_kv({'q': c['q'][l, tables],
+                                   'scale': c['scale'][l, tables]},
                                   cfg.dtype)
             else:
-                arr = c[tables]
+                arr = c[l, tables]
             bb, p, h, s, d = arr.shape
             return arr.transpose(0, 2, 1, 3, 4).reshape(bb, h, p * s, d)
 

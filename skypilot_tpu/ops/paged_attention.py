@@ -8,13 +8,21 @@ bandwidth disaster on TPU: the gather materialises the whole cache
 window in HBM every tick.  This kernel reads K/V pages directly from
 the page pool by block-table index — the gathered view never exists.
 
+The kernel takes the WHOLE pool, every layer's pages stacked
+(`[L, n_pages, h_kv, ps, d]`, as `models/decode.init_paged_cache` lays
+it out and the decode tick's layer loop carries it), and the layer to
+read as a traced scalar: a layer's share is never sliced out of the
+pool for the kernel's operand, which is what lets the tick keep the
+pool in place.
+
 The grid has one program a slot and nothing else: a table row is not
 a grid step.  The pools stay in HBM; inside a program a loop of
 DYNAMIC length `ceil((lengths[b] + S) / (pages_per_step * ps))` walks
-the slot's live pages, `pages_per_step` of them a step.  Each page is
-one contiguous `[h_kv, ps, d]` slab of the pool, so one async copy by
-table index (tables and lengths ride in scalar-prefetch memory) brings
-every kv head into a double-buffered VMEM scratch; the next step's
+the slot's live pages, `pages_per_step` of them a step.  Each page of
+a layer is one contiguous `[h_kv, ps, d]` slab of the pool, so one
+async copy by (layer, table) index (tables, lengths and the layer ride
+in scalar-prefetch memory) brings every kv head into a double-buffered
+VMEM scratch; the next step's
 copies — or the next slot's first — start before this step's are
 waited for.  Table rows past a slot's length are never read, their
 pages never fetched, and they cost no step, so the kernel's time
@@ -38,7 +46,8 @@ are verified through the same paged kernel.
 int8 pools (PR 7's per-page absmax scales) run the same kernel body
 with the dequant fused into the score and probability tiles: the int8
 bytes are what moves from HBM, their scales ride the same copies and
-multiply VMEM-resident tiles.
+multiply VMEM-resident tiles.  The scales (3% of an int8 pool) reach
+the kernel as the layer's slice, a page a row.
 
 Same interpret-mode pattern as ops/attention.py
 (`SKYTPU_PALLAS_INTERPRET=1`, CPU backend only); off-TPU without
@@ -47,11 +56,12 @@ math is used, and `SKYTPU_DECODE_KERNEL=pallas|gather` pins the
 engine's path choice (default: pallas wherever Pallas can run, else
 gather).
 
-Shapes: q [B, h_q, S, d]; pool leaves [n_pages, h_kv, ps, d] (int8
-pools: {'q': int8, 'scale': f32 [n_pages, h_kv, ps]}); tables [B, P];
-lengths [B] (pre-write depths — the S new tokens are assumed already
-written at positions lengths..lengths+S-1, exactly how
-`paged_batched_step` orders write-then-attend).
+Shapes: q [B, h_q, S, d]; pool leaves [L, n_pages, h_kv, ps, d] (int8
+pools: {'q': int8, 'scale': f32 [L, n_pages, h_kv, ps]}) with `layer`
+an int32 scalar, or one layer's [n_pages, h_kv, ps, d] without it;
+tables [B, P]; lengths [B] (pre-write depths — the S new tokens are
+assumed already written at positions lengths..lengths+S-1, exactly
+how `paged_batched_step` orders write-then-attend).
 """
 from __future__ import annotations
 
@@ -105,7 +115,7 @@ def _pages_per_step(num_rows: int, h_kv: int, page_size: int, d: int,
     return max(1, min(by_vmem, _STEP_TOKENS // page_size, num_rows))
 
 
-def _paged_decode_kernel(tables_ref, lengths_ref, *refs,
+def _paged_decode_kernel(tables_ref, lengths_ref, layer_ref, *refs,
                          page_size: int, s_q: int, pages_per_step: int,
                          sm_scale: float, quantized: bool,
                          windowed: bool = False):
@@ -113,11 +123,12 @@ def _paged_decode_kernel(tables_ref, lengths_ref, *refs,
     `pages_per_step` a step, through a double-buffered VMEM scratch and
     fold each step into the online softmax of every kv head.
 
-    Refs: tables [B, P] and lengths [B] in SMEM (and, `windowed`, the
-    window [1] after them); q [1, h_kv, R, d]
+    Refs: tables [B, P], lengths [B] and the layer [1] in SMEM (and,
+    `windowed`, the window [1] after them); q [1, h_kv, R, d]
     (R = rep * s_q padded to whole sublane tiles, unscaled, q's dtype);
-    the pools in HBM (k/v [n_pages, h_kv, ps, d]; int8 pools add ks/vs
-    [n_pages, W] f32, a page's [h_kv, ps] scales as one row);
+    the pools in HBM (k/v [L, n_pages, h_kv, ps, d], of which only
+    layer `layer`'s pages are read; int8 pools add ks/vs [n_pages, W]
+    f32, the layer's, a page's [h_kv, ps] scales as one row);
     o [1, h_kv, R, d].  Scratch: k/v buffers
     [2, pages_per_step, h_kv, ps, d] (and scale buffers
     [2, pages_per_step, W]), DMA semaphores [2, 2] (buffer; K or V),
@@ -161,6 +172,7 @@ def _paged_decode_kernel(tables_ref, lengths_ref, *refs,
     n_slots = pl.num_programs(0)
     b = pl.program_id(0)
     length = lengths_ref[b]
+    layer = layer_ref[0]
 
     def live_pages(bb):
         return jnp.minimum(
@@ -190,11 +202,11 @@ def _paged_decode_kernel(tables_ref, lengths_ref, *refs,
             page = tables_ref[bb, first + j]
             for hbm, buf, w in streams:
                 if w in which:
-                    # A pool page is hbm[page]; a scale page is one row
-                    # of a 2-D array, sliced (Mosaic slices a DMA's
-                    # last two dims only by whole tiles).
-                    src, dst = ((hbm.at[page], buf.at[slot, j])
-                                if len(hbm.shape) == 4 else
+                    # A pool page is hbm[layer, page]; a scale page is
+                    # one row of a 2-D array, sliced (Mosaic slices a
+                    # DMA's last two dims only by whole tiles).
+                    src, dst = ((hbm.at[layer, page], buf.at[slot, j])
+                                if len(hbm.shape) == 5 else
                                 (hbm.at[pl.ds(page, 1)],
                                  buf.at[slot, pl.ds(j, 1)]))
                     fn(pltpu.make_async_copy(src, dst, sems.at[slot, w]))
@@ -336,15 +348,26 @@ def _paged_decode_kernel(tables_ref, lengths_ref, *refs,
         h_kv, r, d).astype(o_ref.dtype)
 
 
+def _stacked(k_leaf, v_leaf, layer):
+    """-> (k_leaf, v_leaf, layer) with the leaves stacked by layer: one
+    layer's leaves (`layer` None) are layer 0 of a stack of one, a
+    reshape that moves nothing."""
+    if layer is not None:
+        return k_leaf, v_leaf, jnp.asarray(layer, jnp.int32)
+    lift = lambda leaf: jax.tree.map(lambda a: a[None], leaf)
+    return lift(k_leaf), lift(v_leaf), jnp.zeros((), jnp.int32)
+
+
 def _paged_attention_pallas(q, k_leaf, v_leaf, tables, lengths, *,
-                            sm_scale: float, window=None):
+                            sm_scale: float, window=None, layer=None):
     from jax.experimental import pallas as pl  # pylint: disable=import-outside-toplevel
     from jax.experimental.pallas import tpu as pltpu  # pylint: disable=import-outside-toplevel
 
     b, h_q, s_q, d = q.shape
+    k_leaf, v_leaf, layer = _stacked(k_leaf, v_leaf, layer)
     quantized = isinstance(k_leaf, dict)
     pool = k_leaf['q'] if quantized else k_leaf
-    h_kv, ps = pool.shape[1], pool.shape[2]
+    h_kv, ps = pool.shape[2], pool.shape[3]
     rep = h_q // h_kv
     r = rep * s_q
     tables = jnp.asarray(tables, jnp.int32)
@@ -363,17 +386,20 @@ def _paged_attention_pallas(q, k_leaf, v_leaf, tables, lengths, *,
     row_spec = pl.BlockSpec(
         (1, h_kv, r_pad, d), lambda bb, *_: (bb, 0, 0, 0),
         memory_space=pltpu.VMEM)
-    # The pools never enter VMEM whole: the kernel copies the pages a
-    # slot's table names, and only the live ones.
+    # The pools never enter VMEM whole, nor is a layer's share sliced
+    # out of them: the kernel copies the pages of `layer` that a slot's
+    # table names, and only the live ones.
     pool_spec = pl.BlockSpec(memory_space=pl.ANY)
     kv_buf = pltpu.VMEM((2, pps, h_kv, ps, d), pool.dtype)
     if quantized:
-        # A page's [h_kv, ps] scales as one row, padded to whole lane
-        # tiles: what a DMA can slice by page index.
+        # The layer's scales, a page's [h_kv, ps] as one row, padded to
+        # whole lane tiles: what a DMA can slice by page index.
         width = -(-h_kv * ps // _LANES) * _LANES
 
         def rows(scale):
-            flat = scale.reshape(scale.shape[0], h_kv * ps)
+            flat = jax.lax.dynamic_index_in_dim(
+                scale, layer, axis=0, keepdims=False).reshape(
+                    scale.shape[1], h_kv * ps)
             return jnp.pad(flat, ((0, 0), (0, width - h_kv * ps)))
 
         scale_buf = pltpu.VMEM((2, pps, width), jnp.float32)
@@ -388,10 +414,11 @@ def _paged_attention_pallas(q, k_leaf, v_leaf, tables, lengths, *,
                          pltpu.VMEM((h_kv * r_pad, d), jnp.float32),
                          pltpu.VMEM((h_kv * r_pad, _LANES), jnp.float32),
                          pltpu.VMEM((h_kv * r_pad, _LANES), jnp.float32)]
-    # Tables and lengths ride in scalar-prefetch memory, and a layer's
-    # window after them where it has one.
-    scalars = (tables, lengths) + (() if window is None else (
-        jnp.asarray(window, jnp.int32).reshape(1),))
+    # Tables, lengths and the layer's index ride in scalar-prefetch
+    # memory, and the layer's window after them where it has one.
+    scalars = (tables, lengths, layer.reshape(1)) + (
+        () if window is None else (
+            jnp.asarray(window, jnp.int32).reshape(1),))
     out = pl.pallas_call(
         functools.partial(_paged_decode_kernel, page_size=ps, s_q=s_q,
                           pages_per_step=pps, sm_scale=sm_scale,
@@ -414,21 +441,22 @@ def _paged_attention_pallas(q, k_leaf, v_leaf, tables, lengths, *,
 
 
 def _paged_attention_reference(q, k_leaf, v_leaf, tables, lengths, *,
-                               sm_scale: float, window=None):
+                               sm_scale: float, window=None, layer=None):
     """Pure-jnp reference with the kernel's exact masking math: gather
-    the pool rows each table names, dequant, attend.  Used off-TPU
-    without interpret mode (and by parity tests as the pinned
-    semantics of the kernel)."""
+    the rows of the pool's layer that each table names, dequant,
+    attend.  Used off-TPU without interpret mode (and by parity tests
+    as the pinned semantics of the kernel)."""
     b, h_q, s_q, d = q.shape
+    k_leaf, v_leaf, layer = _stacked(k_leaf, v_leaf, layer)
     quantized = isinstance(k_leaf, dict)
 
     def gather(leaf):
         if quantized:
-            vals = leaf['q'][tables].astype(jnp.float32)
-            scale = leaf['scale'][tables].astype(jnp.float32)
+            vals = leaf['q'][layer, tables].astype(jnp.float32)
+            scale = leaf['scale'][layer, tables].astype(jnp.float32)
             arr = vals * scale[..., None]
         else:
-            arr = leaf[tables].astype(jnp.float32)
+            arr = leaf[layer, tables].astype(jnp.float32)
         bb, p, h, s, dd = arr.shape
         return arr.transpose(0, 2, 1, 3, 4).reshape(bb, h, p * s, dd)
 
@@ -453,42 +481,48 @@ def _paged_attention_reference(q, k_leaf, v_leaf, tables, lengths, *,
 
 def paged_attention(q, k_leaf: Any, v_leaf: Any, tables, lengths, *,
                     sm_scale: Optional[float] = None, mesh=None,
-                    window=None):
-    """Paged decode attention over one layer's page pool.
+                    window=None, layer=None):
+    """Paged decode attention over one layer of the page pool.
 
     q [B, h_q, S, d] (query token j of slot b at absolute position
     lengths[b] + j, already written into the pool); pool leaves
-    [n_pages, h_kv, ps, d] (or int8 {'q','scale'}); tables [B, P];
-    lengths [B].  Returns [B, h_q, S, d] in q's dtype.  `window` (an
-    int32 scalar, traced or not; None = no window): a query at position
-    p sees keys p - window + 1 .. p only.
+    [L, n_pages, h_kv, ps, d] (or int8 {'q','scale'}), every layer's
+    pages, of which `layer` (an int32 scalar, traced or not) is the one
+    attended; tables [B, P]; lengths [B].  Without `layer` the leaves
+    are one layer's, [n_pages, h_kv, ps, d].  Returns [B, h_q, S, d] in
+    q's dtype.  `window` (an int32 scalar, traced or not; None = no
+    window): a query at position p sees keys p - window + 1 .. p only.
 
     Under a `mesh` of more than one device each device runs the kernel
     on its own heads (ops/sp_common.py says why): q heads and the
     pool's kv heads are sharded over 'tensor' as
-    parallel/sharding.page_pool_sharding places them; slots, tables
-    and lengths are replicated.
+    parallel/sharding.page_pool_sharding places them; layers, slots,
+    tables and lengths are replicated.
     """
     if sm_scale is None:
         sm_scale = float(q.shape[-1]) ** -0.5
     if mesh is not None and mesh.size > 1:
         from skypilot_tpu.ops import sp_common  # pylint: disable=import-outside-toplevel
+        k_leaf, v_leaf, layer = _stacked(k_leaf, v_leaf, layer)
         P = jax.sharding.PartitionSpec
         _, head_axes, _ = sp_common.batch_head_axes(mesh)
         heads = P(None, head_axes)
-        leaf_spec = jax.tree.map(lambda _: heads, k_leaf)
-        args = (q, k_leaf, v_leaf, tables, lengths)
-        specs = (heads, leaf_spec, leaf_spec, P(), P())
-        if window is not None:       # a traced scalar: an operand too
+        pool_heads = P(None, None, head_axes)
+        leaf_spec = jax.tree.map(lambda _: pool_heads, k_leaf)
+        # Traced scalars (the layer, a window) are operands too.
+        args = (q, k_leaf, v_leaf, tables, lengths, layer)
+        specs = (heads, leaf_spec, leaf_spec, P(), P(), P())
+        if window is not None:
             args += (jnp.asarray(window, jnp.int32),)
             specs += (P(),)
 
-        def fn(q, k_leaf, v_leaf, tables, lengths, window=None):
+        def fn(q, k_leaf, v_leaf, tables, lengths, layer, window=None):
             return paged_attention(q, k_leaf, v_leaf, tables, lengths,
-                                   sm_scale=sm_scale, window=window)
+                                   sm_scale=sm_scale, window=window,
+                                   layer=layer)
 
         return sp_common.sp_shard_map(fn, mesh, specs, heads)(*args)
     impl = (_paged_attention_pallas if _use_pallas()
             else _paged_attention_reference)
     return impl(q, k_leaf, v_leaf, tables, lengths, sm_scale=sm_scale,
-                window=window)
+                window=window, layer=layer)

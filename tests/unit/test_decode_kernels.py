@@ -180,6 +180,9 @@ def _kernel_case(quantized, s_q, rep, poison, *, h_kv=2, d=32, ps=8,
             jnp.asarray(lengths))
 
 
+_ALONE = {}
+
+
 class TestPagedKernel:
     """The kernel itself, in the interpreter, against the gather
     reference: no engine, so every shape of the walk is named here."""
@@ -237,6 +240,88 @@ class TestPagedKernel:
         # One bf16 rounding of an output of magnitude <= 4.
         np.testing.assert_allclose(np.asarray(out, np.float32),
                                    np.asarray(ref), atol=2 ** -6)
+
+    @pytest.mark.parametrize('layer', [0, 2, 4],
+                             ids=['first', 'middle', 'last'])
+    @pytest.mark.parametrize('window', [None, 12],
+                             ids=['no-window', 'window12'])
+    @pytest.mark.parametrize('s_q', [1, 4], ids=['decode', 'verify4'])
+    @pytest.mark.parametrize('pool_dtype', [jnp.bfloat16, jnp.int8],
+                             ids=['bf16pool', 'int8pool'])
+    def test_stacked_pool_at_a_layer_is_that_layers_pool(
+            self, monkeypatch, pool_dtype, s_q, window, layer):
+        """The kernel is given every layer's pages and a layer's index
+        (as the tick's layer loop carries them): it equals, bit for
+        bit, the kernel on that layer's pool alone, and the reference
+        on either.  The other layers hold NaN (an int8 pool: NaN
+        scales), so a page of another layer fetched and used shows."""
+        monkeypatch.setenv('SKYTPU_PALLAS_INTERPRET', '1')
+        monkeypatch.setattr(paged_attention, '_STEP_TOKENS', 3 * 8)
+        quantized = pool_dtype == jnp.int8
+        q, k, v, _, _, tables, lengths = _kernel_case(
+            quantized, s_q, 2, False)
+        q = q.astype(jnp.bfloat16)
+        if not quantized:
+            k, v = k.astype(jnp.bfloat16), v.astype(jnp.bfloat16)
+
+        def stack(leaf):
+            def one(a):
+                # int8 has no NaN: its other layers hold 127 under NaN
+                # scales.
+                other = jnp.full_like(
+                    a, jnp.nan if a.dtype != jnp.int8 else 127)
+                return jnp.stack([a if i == layer else other
+                                  for i in range(5)])
+            return jax.tree.map(one, leaf)
+
+        kw = dict(sm_scale=32 ** -0.5, window=None if window is None
+                  else jnp.asarray(window, jnp.int32))
+        at = jnp.asarray(layer, jnp.int32)
+        out = jax.jit(lambda *a: paged_attention._paged_attention_pallas(
+            *a[:-1], layer=a[-1], **kw))(
+                q, stack(k), stack(v), tables, lengths, at)
+        # One call on the layer's pool alone serves the three layers.
+        key = (quantized, s_q, window)
+        if key not in _ALONE:
+            _ALONE[key] = paged_attention._paged_attention_pallas(
+                q, k, v, tables, lengths, **kw)
+        alone = _ALONE[key]
+        np.testing.assert_array_equal(np.asarray(out, np.float32),
+                                      np.asarray(alone, np.float32))
+        ref = paged_attention._paged_attention_reference(
+            q.astype(jnp.float32), stack(k), stack(v), tables, lengths,
+            layer=at, **kw)
+        ref_alone = paged_attention._paged_attention_reference(
+            q.astype(jnp.float32), k, v, tables, lengths, **kw)
+        np.testing.assert_array_equal(np.asarray(ref),
+                                      np.asarray(ref_alone))
+        np.testing.assert_allclose(np.asarray(out, np.float32),
+                                   np.asarray(ref), atol=2 ** -6)
+
+    @pytest.mark.parametrize('quantized', [False, True],
+                             ids=['f32pool', 'int8pool'])
+    def test_mesh_shards_the_stacked_pool_by_its_heads(self, monkeypatch,
+                                                       quantized):
+        """Under a tensor mesh each device attends its own kv heads of
+        the stacked pool (layers and pages whole on every device, the
+        layer and the window replicated scalars): the result is the
+        unsharded one."""
+        from skypilot_tpu.parallel import mesh as mesh_lib
+        monkeypatch.setenv('SKYTPU_PALLAS_INTERPRET', '1')
+        q, k, v, _, _, tables, lengths = _kernel_case(quantized, 1, 2,
+                                                      False)
+        stack = lambda leaf: jax.tree.map(
+            lambda a: jnp.stack([jnp.zeros_like(a), a]), leaf)
+        mesh = mesh_lib.build_mesh(mesh_lib.MeshConfig(tensor=2),
+                                   devices=jax.devices()[:2])
+        kw = dict(window=jnp.asarray(12, jnp.int32),
+                  layer=jnp.asarray(1, jnp.int32))
+        out = jax.jit(lambda *a: paged_attention.paged_attention(
+            *a, mesh=mesh, **kw))(q, stack(k), stack(v), tables, lengths)
+        ref = paged_attention.paged_attention(q, k, v, tables, lengths,
+                                              window=kw['window'])
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   atol=2e-5, rtol=2e-5)
 
     @pytest.mark.parametrize('shape,expected', [
         # rows, kv heads, page size, head dim, itemsize -> pages a step

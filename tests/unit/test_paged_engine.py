@@ -159,6 +159,185 @@ class TestPagedParity:
             eng.stop()
 
 
+def _seeded_tick_state(cfg, *, quantize_kv, s_q):
+    """A pool full of seeded noise (every page of every layer, the null
+    page too), tables of disjoint pages, ragged depths (mid-page, on a
+    page boundary, one whose last drafted token falls off its table to
+    the null page) and the tokens of one tick."""
+    slots, ps, rows = 3, 4, 6
+    n_pages = 1 + slots * rows + 3
+    rng = np.random.default_rng(11)
+    paged = decode.init_paged_cache(cfg, n_pages, ps, slots, rows,
+                                    quantize_kv=quantize_kv)
+
+    def noise(a):
+        if a.dtype == jnp.int8:
+            return jnp.asarray(rng.integers(-127, 128, a.shape), jnp.int8)
+        return jnp.asarray(rng.uniform(0.5, 1.5, a.shape), a.dtype)
+
+    paged = dict(paged, k=jax.tree.map(noise, paged['k']),
+                 v=jax.tree.map(noise, paged['v']))
+    paged['block_tables'] = jnp.asarray(
+        1 + rng.permutation(slots * rows).reshape(slots, rows), jnp.int32)
+    paged['lengths'] = jnp.asarray(
+        [ps + 1, 2 * ps, rows * ps - s_q + (s_q > 1)], jnp.int32)
+    tokens = jnp.asarray(rng.integers(1, cfg.vocab_size, (slots, s_q)),
+                         jnp.int32)
+    return paged, tokens
+
+
+def _tick_layer_by_layer(cfg, params, tokens, paged):
+    """The write-then-attend forward as it ran before the pool rode
+    the layer loop: a Python loop over the layers, each layer's share
+    sliced out of the pool, the new rows scattered into the slice, the
+    slice's pages gathered for attention, and the slices stacked back.
+    -> (logits [B, S, V], new k, new v)."""
+    from skypilot_tpu.models import heads
+    lengths, tables = paged['lengths'], paged['block_tables']
+    ps = decode._page_size_of(paged)
+    b, s_q = tokens.shape
+    positions = lengths[:, None] + jnp.arange(s_q)[None, :]
+    rows = positions // ps
+    pages = jnp.where(
+        rows < tables.shape[1],
+        jnp.take_along_axis(tables, jnp.minimum(rows, tables.shape[1] - 1),
+                            axis=1), 0).reshape(-1)
+    offs = (positions % ps).reshape(-1)
+
+    def write(c, new):
+        tok = new.transpose(0, 2, 1, 3).reshape(b * s_q, new.shape[1], -1)
+        if isinstance(c, dict):
+            q, scale = decode._quant_kv(tok)
+            return {'q': c['q'].at[pages, :, offs].set(q),
+                    'scale': c['scale'].at[pages, :, offs].set(scale)}
+        return c.at[pages, :, offs].set(tok.astype(c.dtype))
+
+    def view(c):
+        arr = decode._dequant_kv(jax.tree.map(lambda a: a[tables], c),
+                                 cfg.dtype)
+        bb, p, h, s, d = arr.shape
+        return arr.transpose(0, 2, 1, 3, 4).reshape(bb, h, p * s, d)
+
+    x = decode._embed(cfg, params, tokens)
+    new_k, new_v = [], []
+    for l in range(cfg.n_layers):
+        lp = jax.tree.map(lambda a: a[l], decode._layer_params(params, cfg))
+        h = decode._norm(x, lp['attn_norm']['scale'], cfg)
+        k = decode._rope_if(None, decode._attn_proj(h, lp['attn']['k_proj']),
+                            positions, cfg)
+        v = decode._attn_proj(h, lp['attn']['v_proj'])
+        k_l = write(jax.tree.map(lambda a: a[l], paged['k']), k)
+        v_l = write(jax.tree.map(lambda a: a[l], paged['v']), v)
+        new_k.append(k_l)
+        new_v.append(v_l)
+        x, _ = decode._layer_forward(x, lp, cfg, positions, view(k_l),
+                                     view(v_l), use_flash=False)
+    x = decode._norm(x, params['final_norm']['scale'], cfg)
+    stack = lambda leaves: jax.tree.map(lambda *a: jnp.stack(a), *leaves)
+    return heads.unembed(x, params, cfg), stack(new_k), stack(new_v)
+
+
+class TestPoolInPlace:
+    """The pool rides the tick's layer loop whole: a layer's rows go
+    into that layer of it and nothing else of it moves."""
+
+    @pytest.mark.parametrize('s_q', [1, 4], ids=['tick', 'verify4'])
+    @pytest.mark.parametrize('quantize_kv', [False, True],
+                             ids=['plain', 'int8'])
+    def test_tick_equals_the_tick_layer_by_layer(self, setup, quantize_kv,
+                                                 s_q):
+        """Logits and the whole new pool equal what slicing each
+        layer's share out, writing it and stacking it back gives; and
+        against the old pool, bit for bit: layer l's new rows are in
+        layer l at (page, offset) of each (slot, token), every other
+        element of every layer is untouched."""
+        cfg, params = setup
+        paged, tokens = _seeded_tick_state(cfg, quantize_kv=quantize_kv,
+                                           s_q=s_q)
+        want_logits, want_k, want_v = _tick_layer_by_layer(
+            cfg, params, tokens, paged)
+        logits, new_k, new_v, _ = jax.jit(
+            lambda t, p: decode._paged_forward(
+                cfg, params, t, p, all_positions=True))(tokens, paged)
+        np.testing.assert_allclose(np.asarray(logits),
+                                   np.asarray(want_logits), atol=1e-5)
+        ps = decode._page_size_of(paged)
+        pos = np.asarray(paged['lengths'])[:, None] + np.arange(s_q)
+        tables = np.asarray(paged['block_tables'])
+        written = {(int(tables[b, p // ps]) if p // ps < tables.shape[1]
+                    else 0, int(p % ps))
+                   for b in range(pos.shape[0]) for p in pos[b]}
+        assert (0, 0) in written or s_q == 1   # a draft fell off a table
+        for name, new, want in (('k', new_k, want_k), ('v', new_v, want_v)):
+            for got, exp, old in zip(jax.tree.leaves(new),
+                                     jax.tree.leaves(want),
+                                     jax.tree.leaves(paged[name])):
+                got, old = np.asarray(got), np.asarray(old)
+                # The new rows to float32 rounding (eager against
+                # jitted arithmetic; an int8 value may round the other
+                # way), everything else exactly: see `moved`.
+                np.testing.assert_allclose(
+                    got.astype(np.float32), np.asarray(exp, np.float32),
+                    atol=1 if got.dtype == np.int8 else 1e-5)
+                moved = np.argwhere(
+                    (got != old).reshape(got.shape[:4] + (-1,)).any(-1))
+                # [layer, page, head, offset] of every row that moved:
+                # all layers, all heads, only the written (page, offset).
+                assert {(int(p), int(o)) for _, p, _, o in moved} == written
+                assert len(moved) == (cfg.n_layers * cfg.n_kv_heads *
+                                      len(written))
+
+    @pytest.mark.parametrize('step', ['paged-gather', 'paged-pallas',
+                                      'paged-int8', 'verify', 'dense'])
+    def test_caches_ride_the_layer_loop_as_its_carry(self, setup, step):
+        """Structural, on the traced program: no scanned input or
+        output of a loop in the tick has a cache's rank-5 shape or a
+        layer's share of it (rank 4 or 5 with the leading axis 1); the
+        stacked caches are in the layer loop's carry.  What the scan
+        slices and stacks, the compiled tick copies."""
+        cfg, params = setup
+        slots = 3
+        state = decode.init_engine_state(slots)
+        if step == 'dense':
+            cache = decode.init_slot_cache(cfg, slots, 32)
+            fn = lambda s, c: decode.engine_step(cfg, params, s, c)
+        else:
+            cache = decode.init_paged_cache(
+                cfg, 16, 4, slots, 6, quantize_kv=step == 'paged-int8')
+            kernel = 'pallas' if step == 'paged-pallas' else 'gather'
+            if step == 'verify':
+                fn = lambda s, c: decode.paged_spec_engine_step(
+                    cfg, params, s, c, jnp.zeros((slots, 3), jnp.int32))
+            else:
+                fn = lambda s, c: decode.paged_engine_step(
+                    cfg, params, s, c, kernel=kernel)
+        cache_shapes = {a.shape for a in jax.tree.leaves(
+            {'k': cache['k'], 'v': cache['v']})}
+        shares = {s[1:] for s in cache_shapes} | {
+            (1,) + s[1:] for s in cache_shapes}
+
+        def scans(jaxpr):
+            for eqn in jaxpr.eqns:
+                if eqn.primitive.name == 'scan':
+                    yield eqn
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    yield from scans(sub)
+
+        carried = 0
+        for eqn in scans(jax.make_jaxpr(fn)(state, cache).jaxpr):
+            n_fixed = eqn.params['num_consts'] + eqn.params['num_carry']
+            xs = [v.aval.shape for v in eqn.invars[n_fixed:]]
+            ys = [v.aval.shape
+                  for v in eqn.outvars[eqn.params['num_carry']:]]
+            for shape in xs + ys:
+                assert shape not in cache_shapes and shape not in shares, (
+                    f'a cache is sliced or stacked by a scan: {shape}')
+            carry = [v.aval.shape
+                     for v in eqn.outvars[:eqn.params['num_carry']]]
+            carried += sum(shape in cache_shapes for shape in carry)
+        assert carried == 2 * len(jax.tree.leaves(cache['k']))
+
+
 class TestInt8KVBound:
 
     def test_int8_logits_divergence_bounded(self, setup):

@@ -33,17 +33,23 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.mark.parametrize('slots,h_q,h_kv,s_q,rows,n_pages,quantized', [
-    (16, 32, 8, 1, 160, 2432, False),   # benchmark: Mistral-7B engine
-    (24, 16, 8, 1, 96, 2048, False),    # benchmark: InternLM2-1.8B
-    (16, 32, 8, 4, 160, 2432, False),   # speculative verify, k = 3
-    (16, 32, 8, 1, 160, 2432, True),    # int8 pages
-    (16, 8, 2, 1, 160, 2432, False),    # one shard of --tensor 4
-    (16, 8, 2, 4, 160, 2432, True),     # ... int8, verify
-], ids=['mistral', 'internlm2', 'verify4', 'int8', 'shard', 'shard-int8'])
+@pytest.mark.parametrize(
+    'layers,slots,h_q,h_kv,s_q,rows,n_pages,quantized', [
+        (16, 16, 32, 8, 1, 160, 2432, False),  # benchmark: Mistral-7B
+        (24, 24, 16, 8, 1, 96, 2048, False),   # benchmark: InternLM2-1.8B
+        (16, 16, 32, 8, 4, 160, 2432, False),  # speculative verify, k = 3
+        (16, 16, 32, 8, 1, 160, 2432, True),   # int8 pages
+        (16, 16, 8, 2, 1, 160, 2432, False),   # one shard of --tensor 4
+        (16, 16, 8, 2, 4, 160, 2432, True),    # ... int8, verify
+    ], ids=['mistral', 'internlm2', 'verify4', 'int8', 'shard',
+            'shard-int8'])
 def test_paged_decode_kernel_compiles_for_v5e(
-        one_chip, monkeypatch, slots, h_q, h_kv, s_q, rows, n_pages,
-        quantized):
+        one_chip, monkeypatch, layers, slots, h_q, h_kv, s_q, rows,
+        n_pages, quantized):
+    """The kernel as the tick calls it: every layer's pages in one
+    operand and the layer a traced scalar, so what Mosaic compiles is
+    the copy from `hbm.at[layer, page]`; nothing pool-sized may be
+    made on the way to the kernel."""
     monkeypatch.delenv('SKYTPU_PALLAS_INTERPRET', raising=False)
     d, ps = 128, 16
 
@@ -51,23 +57,28 @@ def test_paged_decode_kernel_compiles_for_v5e(
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     pool = (
-        {'q': arg((n_pages, h_kv, ps, d), jnp.int8),
-         'scale': arg((n_pages, h_kv, ps), jnp.float32)}
-        if quantized else arg((n_pages, h_kv, ps, d), jnp.bfloat16))
+        {'q': arg((layers, n_pages, h_kv, ps, d), jnp.int8),
+         'scale': arg((layers, n_pages, h_kv, ps), jnp.float32)}
+        if quantized else arg((layers, n_pages, h_kv, ps, d), jnp.bfloat16))
     compiled = jax.jit(
         lambda *a: paged_attention._paged_attention_pallas(
-            *a, sm_scale=d ** -0.5)).lower(
+            *a[:-1], sm_scale=d ** -0.5, layer=a[-1])).lower(
                 arg((slots, h_q, s_q, d), jnp.bfloat16), pool, pool,
                 arg((slots, rows), jnp.int32),
-                arg((slots,), jnp.int32)).compile()
+                arg((slots,), jnp.int32), arg((), jnp.int32)).compile()
     assert 'tpu_custom_call' in compiled.as_text()
+    # The pools go to the kernel as they came: at most the layer's
+    # scales of an int8 pool (3% of it) are sliced out.
+    assert compiled.memory_analysis().temp_size_in_bytes < (
+        n_pages * h_kv * ps * d * (1 if quantized else 2))
 
 
 @pytest.mark.parametrize('s_q', [1, 4], ids=['tick', 'verify4'])
 def test_windowed_paged_decode_kernel_compiles_for_v5e(
         one_chip, monkeypatch, s_q):
-    """The kernel with a layer's window as a third prefetched scalar,
-    at the benchmark's expert cell: 64 slots, 128 query heads on 8 KV
+    """The kernel with a layer's window as a fourth prefetched scalar
+    (after the layer's index), at the benchmark's expert cell: 4 layers'
+    pages, 64 slots, 128 query heads on 8 KV
     heads (16 a KV head: 128 rows a step), tables of 544 rows."""
     monkeypatch.delenv('SKYTPU_PALLAS_INTERPRET', raising=False)
     d, ps, slots, n_pages = 128, 16, 64, 6144
@@ -75,11 +86,12 @@ def test_windowed_paged_decode_kernel_compiles_for_v5e(
     def arg(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    pool = arg((n_pages, 8, ps, d), jnp.bfloat16)
+    pool = arg((4, n_pages, 8, ps, d), jnp.bfloat16)
     compiled = jax.jit(
         lambda *a: paged_attention._paged_attention_pallas(
-            *a[:-1], sm_scale=d ** -0.5, window=a[-1])).lower(
+            *a[:-2], sm_scale=d ** -0.5, window=a[-2],
+            layer=a[-1])).lower(
                 arg((slots, 128, s_q, d), jnp.bfloat16), pool, pool,
                 arg((slots, 544), jnp.int32), arg((slots,), jnp.int32),
-                arg((), jnp.int32)).compile()
+                arg((), jnp.int32), arg((), jnp.int32)).compile()
     assert 'tpu_custom_call' in compiled.as_text()

@@ -232,6 +232,52 @@ def test_paged_kernel_window_matches_reference(monkeypatch, lengths,
         np.testing.assert_array_equal(np.asarray(got), np.asarray(plain))
 
 
+@pytest.mark.parametrize('s_q', [1, 3], ids=['tick', 'verify3'])
+def test_tick_on_the_whole_pool_with_two_kinds_of_layer(monkeypatch, setup,
+                                                        s_q):
+    """One write-then-attend forward over a pool of seeded noise,
+    depths under, at and over the window: the kernel given the whole
+    pool, the layer's index and the layer's window (all three ride the
+    layer scan) against the gather view.  Logits agree; both leave the
+    same pool, in which each of the 8 layers (window, window, window,
+    full, twice) got its own rows and nothing else moved."""
+    monkeypatch.setenv('SKYTPU_PALLAS_INTERPRET', '1')
+    _, cfg, params, _, _ = setup
+    rng = np.random.default_rng(9)
+    slots, ps, rows = 3, 4, 10
+    paged = decode.init_paged_cache(cfg, 1 + slots * rows, ps, slots, rows)
+    noise = lambda a: jnp.asarray(rng.normal(size=a.shape), a.dtype)
+    paged = dict(
+        paged, k=noise(paged['k']), v=noise(paged['v']),
+        block_tables=jnp.asarray(
+            1 + rng.permutation(slots * rows).reshape(slots, rows),
+            jnp.int32),
+        lengths=jnp.asarray([5, _WINDOW, 33], jnp.int32))
+    tokens = jnp.asarray(rng.integers(1, 256, (slots, s_q)), jnp.int32)
+    run = lambda kernel: jax.jit(lambda t, p: decode._paged_forward(
+        cfg, params, t, p, kernel=kernel, all_positions=True))(
+            tokens, paged)
+    logits_g, k_g, v_g, counts_g = run('gather')
+    logits_p, k_p, v_p, counts_p = run('pallas')
+    np.testing.assert_allclose(np.asarray(logits_p), np.asarray(logits_g),
+                               atol=_TOL, rtol=0)
+    np.testing.assert_array_equal(np.asarray(counts_p),
+                                  np.asarray(counts_g))
+    for got, same, old in ((k_p, k_g, paged['k']), (v_p, v_g, paged['v'])):
+        # (The rows of later layers carry the two paths' rounding.)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(same),
+                                   atol=_TOL, rtol=0)
+        moved = np.argwhere((np.asarray(got) != np.asarray(old)).any(-1))
+        # [layer, page, head, offset]: s_q rows a slot in every layer
+        # and head, at the slots' own (page, offset).
+        assert len(moved) == cfg.n_layers * cfg.n_kv_heads * slots * s_q
+        tables = np.asarray(paged['block_tables'])
+        pos = np.asarray(paged['lengths'])[:, None] + np.arange(s_q)
+        assert {(int(p), int(o)) for _, p, _, o in moved} == {
+            (int(tables[b, q // ps]), int(q % ps))
+            for b in range(slots) for q in pos[b]}
+
+
 # -------------------------------------------------------- the experts
 
 
