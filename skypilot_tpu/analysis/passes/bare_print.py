@@ -27,9 +27,6 @@ ALLOWED = {
     'serve/core.py': 'tail_logs dumps the service log to stdout',
     'chaos/elastic_task.py':
         'gang-exec\'d task: stdout is the rank log `sky logs` tails',
-    'serve/slice_replica.py':
-        '--bench-prefill prints its JSON result on stdout (bench_serve '
-        'subprocess protocol)',
     'batch/runner.py':
         'managed-job driver: the summary JSON on stdout is the run '
         'output `sky jobs logs` tails',

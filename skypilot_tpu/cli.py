@@ -1763,8 +1763,7 @@ def bench_diff(last_n, history_file, min_rel):
     """Diff the newest bench run of each (metric, config) group
     against its history with noise-aware thresholds.
 
-    `bench.py` / `bench_serve.py` append one record per run to
-    BENCH_history.jsonl; this compares throughput, latency quantiles,
+    `bench.py` appends one record per run to BENCH_history.jsonl; this compares throughput, latency quantiles,
     and MFU against the baseline runs and **exits non-zero when any
     key moved past ``max(min_rel, 3 x cv)`` in the bad direction** —
     wire it after a bench run for a perf-regression gate."""
@@ -1774,7 +1773,7 @@ def bench_diff(last_n, history_file, min_rel):
         raise click.ClickException(
             f'No bench history at '
             f'{bench_history.history_path(history_file)} — run '
-            f'bench_serve.py / bench.py first.')
+            f'bench.py first.')
     kwargs = {}
     if min_rel is not None:
         kwargs['min_rel'] = min_rel

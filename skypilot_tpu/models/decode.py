@@ -405,8 +405,7 @@ def prefill_sp(cfg: ModelConfig, params, tokens, *, mesh, max_len: int,
     tokens [1, S] (S divisible by the mesh's sequence-axis size) ->
     a private prefill cache {'k', 'v', 'index'} with k/v
     [L, 1, h_kv, max_len, d] — the SAME layout the chunked admission
-    path produces, so `insert_prefill`/`insert_prefill_pages` adopt it
-    unchanged.  Attention runs through ops/ring_attention over the
+    path produces, so `insert_prefill_pages` adopts it unchanged.  Attention runs through ops/ring_attention over the
     'sequence' axis: each host holds S/P positions and k/v chunks
     rotate the ring, so a 100k-token context splits its quadratic
     attention (and its activation memory) across the slice instead of
@@ -574,68 +573,9 @@ def generate(cfg: ModelConfig, params, prompt, *, max_new_tokens: int,
 
 # -------------------------------------------------- slot-batched decoding
 # Building blocks for continuous batching (serve/batching_engine.py):
-# a fixed pool of B cache slots, each at its OWN depth, decoded
-# together in one jit'd step.  Static shapes throughout — slots, not
-# requests, are the batch dimension.
-
-
-def init_slot_cache(cfg: ModelConfig, slots: int, max_len: int
-                    ) -> Dict[str, Any]:
-    """Zeroed slot cache: like init_cache but with per-slot lengths."""
-    shape = (cfg.n_layers, slots, cfg.n_kv_heads, max_len, cfg.head_dim)
-    return {
-        'k': jnp.zeros(shape, cfg.dtype),
-        'v': jnp.zeros(shape, cfg.dtype),
-        'lengths': jnp.zeros((slots,), jnp.int32),
-    }
-
-
-def insert_prefill(slot_cache: Dict[str, Any], slot: int,
-                   prefill_cache: Dict[str, Any],
-                   length) -> Dict[str, Any]:
-    """Adopt a single-sequence prefill cache ([L, 1, h_kv, max_len, d])
-    into slot `slot`.  Jit-safe (slot may be traced)."""
-    k = jax.lax.dynamic_update_slice_in_dim(
-        slot_cache['k'], prefill_cache['k'].astype(slot_cache['k'].dtype),
-        slot, axis=1)
-    v = jax.lax.dynamic_update_slice_in_dim(
-        slot_cache['v'], prefill_cache['v'].astype(slot_cache['v'].dtype),
-        slot, axis=1)
-    lengths = slot_cache['lengths'].at[slot].set(
-        jnp.asarray(length, jnp.int32))
-    return {'k': k, 'v': v, 'lengths': lengths}
-
-
-def batched_step(cfg: ModelConfig, params, tokens, slot_cache,
-                 active=None):
-    """One decode step across ALL slots; each slot attends its own
-    depth.  tokens [B, 1]; returns (logits [B, V], new slot_cache,
-    the expert layers' counts over the active slots or None).
-    Without `active`, every length advances by 1 (callers ignore/reset
-    inactive slots).  With `active` [B] bool, only active slots advance
-    — inactive slots' writes land at their frozen length (garbage that
-    is overwritten by the next admission) and their logits are garbage
-    the caller masks out.
-    """
-    lengths = slot_cache['lengths']                    # [B]
-    positions = lengths[:, None]                       # [B, 1]
-
-    def write(c, l, new):
-        # Per-slot scatter at that slot's depth in layer l: vmap the
-        # single-sequence dynamic_update_slice over the slot axis.
-        return jax.vmap(
-            lambda cc, nn, st: jax.lax.dynamic_update_slice(
-                cc, nn.astype(cc.dtype)[None], (l, 0, st, 0)),
-            in_axes=(1, 0, 0), out_axes=1)(c, new, lengths)
-
-    logits, new_k, new_v, counts = _scan_layers_and_unembed(
-        cfg, params, _embed(cfg, params, tokens), positions,
-        slot_cache['k'], slot_cache['v'], write,
-        use_flash=False, row_mask=active)
-    advance = (jnp.ones_like(lengths) if active is None
-               else active.astype(lengths.dtype))
-    return logits, {'k': new_k, 'v': new_v,
-                    'lengths': lengths + advance}, counts
+# a fixed number of B slots, each at its OWN depth in the page pool
+# (below), decoded together in one jit'd step.  Static shapes
+# throughout — slots, not requests, are the batch dimension.
 
 
 def batched_sample(logits, keys, temperature, top_k, *,
@@ -689,33 +629,10 @@ def init_engine_state(slots: int, max_stop_ids: int = 16
     }
 
 
-def engine_step(cfg: ModelConfig, params, state, slot_cache, *,
-                max_top_k: int = 64):
-    """One fully-on-device serving tick: decode every active slot,
-    select its next token (greedy or temperature/top-k), and update the
-    stop bookkeeping — no host round-trip anywhere in the loop.
-
-    Returns (new_state, new_cache, finished [B], counts): counts is
-    None for a model without experts, else the expert layers' int32
-    [3] counts of the tick over the active slots (`moe.moe_apply`),
-    which the engine reads one tick behind with `finished`.
-    new_state['tokens']
-    is the next tick's input, so the engine can dispatch tick t+1
-    before fetching tick t's tokens and read results one tick behind;
-    slots that stop at tick t are already inactive ON DEVICE when tick
-    t+1 runs, so the pipelined tick never decodes past a stop.
-    Inactive slots freeze: their token/remaining are unchanged and
-    their cache length does not advance.
-    """
-    return _select_and_bookkeep(state, *batched_step(
-        cfg, params, state['tokens'][:, None], slot_cache,
-        state['active']), max_top_k=max_top_k)
-
-
 def _select_and_bookkeep(state, logits, new_cache, counts, *,
                          max_top_k: int):
-    """Shared tick tail for dense and paged steps: on-device token
-    selection + stop/countdown bookkeeping (see engine_step docs)."""
+    """The tick's tail: on-device token selection + stop/countdown
+    bookkeeping (see `paged_engine_step`)."""
     active = state['active']
     with jax.named_scope('sampling'):
         split = jax.vmap(lambda k: jax.random.split(k, 2))(state['keys'])
@@ -898,13 +815,17 @@ def _paged_forward(cfg: ModelConfig, params, tokens, paged, *,
 
 def paged_batched_step(cfg: ModelConfig, params, tokens, paged,
                        active=None, *, kernel=None, mesh=None):
-    """One decode step across all slots against the page pool; exact
-    parity with `batched_step` (same masked attention math — the
-    gathered pages in table order ARE the slot's cache with positions
-    page_index * page_size + offset; the Pallas kernel path computes
-    the same online-softmax sums without materialising the gather).
-    Returns (logits, new paged cache, the expert layers' counts over
-    the active slots or None)."""
+    """One decode step across ALL slots against the page pool; each
+    slot attends its own depth (the gathered pages in table order ARE
+    the slot's cache with positions page_index * page_size + offset;
+    the Pallas kernel path computes the same online-softmax sums
+    without materialising the gather).  tokens [B, 1]; returns (logits
+    [B, V], new paged cache, the expert layers' counts over the active
+    slots or None).  Without `active`, every length advances by 1
+    (callers ignore/reset inactive slots).  With `active` [B] bool,
+    only active slots advance — inactive slots' writes land at their
+    frozen length (garbage that is overwritten by the next admission)
+    and their logits are garbage the caller masks out."""
     logits, new_k, new_v, counts = _paged_forward(
         cfg, params, tokens, paged, kernel=kernel, mesh=mesh,
         active=active)
@@ -917,10 +838,21 @@ def paged_batched_step(cfg: ModelConfig, params, tokens, paged,
 
 def paged_engine_step(cfg: ModelConfig, params, state, paged, *,
                       max_top_k: int = 64, kernel=None, mesh=None):
-    """`engine_step` against the page pool: same on-device token
-    selection and stop bookkeeping, cache reads/writes through the
-    block tables.  Returns (new_state, new_paged, finished [B],
-    counts)."""
+    """One fully-on-device serving tick: decode every active slot
+    against the page pool, select its next token (greedy or
+    temperature/top-k), and update the stop bookkeeping — no host
+    round-trip anywhere in the loop.
+
+    Returns (new_state, new_paged, finished [B], counts): counts is
+    None for a model without experts, else the expert layers' int32
+    [3] counts of the tick over the active slots (`moe.moe_apply`),
+    which the engine reads one tick behind with `finished`.
+    new_state['tokens'] is the next tick's input, so the engine can
+    dispatch tick t+1 before fetching tick t's tokens and read results
+    one tick behind; slots that stop at tick t are already inactive ON
+    DEVICE when tick t+1 runs, so the pipelined tick never decodes past
+    a stop.  Inactive slots freeze: their token/remaining are unchanged
+    and their cache length does not advance."""
     return _select_and_bookkeep(state, *paged_batched_step(
         cfg, params, state['tokens'][:, None], paged,
         state['active'], kernel=kernel, mesh=mesh),

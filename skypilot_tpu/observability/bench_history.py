@@ -1,12 +1,11 @@
 """Perf-regression observatory: an append-only history of bench runs
 and noise-aware run-over-run diffing.
 
-`bench.py` and `bench_serve.py` append one JSON line per run — config,
-git rev, throughput, latency quantiles, MFU estimate, and the
-profiler's tick-phase breakdown — to a committed `BENCH_history.jsonl`
-at the repo root (`SKYTPU_BENCH_HISTORY_PATH` overrides; the pinned
-smoke runs write to a throwaway path so CI never churns the committed
-file).  `sky bench diff` compares the newest run of each
+`bench.py` appends one JSON line per run — config, git rev,
+throughput, latency quantiles, MFU estimate, and (for a serving run)
+the profiler's tick-phase breakdown — to a committed
+`BENCH_history.jsonl` at the repo root (`SKYTPU_BENCH_HISTORY_PATH`
+overrides).  `sky bench diff` compares the newest run of each
 (metric, config) group against its predecessors and exits non-zero on
 regression.
 

@@ -472,8 +472,8 @@ def expose() -> str:
 def parse_exposition(text: str) -> Dict[str, Dict[Tuple[Tuple[str, str],
                                                         ...], float]]:
     """Parse the text format back into {name: {labels: value}} — used
-    by the round-trip tests, the CLI pretty-printer, and the
-    bench_serve smoke scrape.  Labels are a sorted tuple of (k, v)."""
+    by the round-trip tests and the CLI pretty-printer.  Labels are a
+    sorted tuple of (k, v)."""
     out: Dict[str, Dict[Tuple[Tuple[str, str], ...], float]] = {}
     for line in text.splitlines():
         line = line.strip()
@@ -575,8 +575,7 @@ def start_exposition_server(port: int = 0,
                             registry: Optional[Registry] = None):
     """Standalone `GET /metrics` endpoint over `registry` (default: the
     process-global one); returns (port, shutdown_fn).  Used where no
-    serving front exists to piggyback on (bench_serve's smoke scrape,
-    training jobs)."""
+    serving front exists to piggyback on (training jobs)."""
     import http.server  # pylint: disable=import-outside-toplevel
     reg = registry or REGISTRY
 

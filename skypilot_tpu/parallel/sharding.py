@@ -82,21 +82,10 @@ def head_kernel_sharding(mesh):
     return logical_sharding(mesh, 'embed', 'vocab')
 
 
-def slot_cache_sharding(mesh):
-    """Sharding for the serving engine's slot KV cache
-    [layers, slots, kv_heads, max_len, head_dim]: kv_heads ride the
-    'tensor' axis exactly like the attention params, so the batched
-    decode step's cache reads/writes stay local to each tensor shard;
-    slots and positions are replicated axes (the slot pool is the batch
-    dimension and every chip holds every slot's depth)."""
-    return logical_sharding(mesh, 'layers', None, 'kv_heads', None,
-                            'head_dim')
-
-
 def page_pool_sharding(mesh):
     """Sharding for one paged-KV pool leaf
     [layers, n_pages, kv_heads, page_size, head_dim]: kv_heads ride
-    'tensor' exactly like `slot_cache_sharding` (the paged gather /
+    'tensor' exactly like the attention params (the paged gather /
     scatter in the tick stays local per tensor shard); pages and
     in-page positions are replicated axes — the page POOL is the
     memory unit, every chip holds every page's slice of its own

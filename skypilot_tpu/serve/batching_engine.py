@@ -4,8 +4,8 @@ vLLM-style scheduling, rebuilt TPU-first (no reference equivalent —
 SkyPilot ships no serving internals): a FIXED pool of KV-cache slots is
 the batch dimension, so every jit'd shape is static.  Requests join a
 running batch the moment a slot frees (no wait for the batch to drain),
-and one `models.decode.engine_step` call advances every active slot a
-token per engine tick — new arrivals ride along with half-finished
+and one `models.decode.paged_engine_step` call advances every active
+slot a token per engine tick — new arrivals ride along with half-finished
 generations.
 
 This module is the compatibility FACADE over the engine's three parts
@@ -23,32 +23,29 @@ Existing imports keep working: `batching_engine.QueueFull`,
 `batching_engine._Request`, `ContinuousBatchingEngine`, ... are all
 re-exported here.
 
-KV cache modes:
-
-- DENSE (default, `kv_pages=None`): one `[L, slots, h_kv, max_len, d]`
-  cache — every slot reserves max_len positions, so concurrency is
-  bounded by the worst-case sequence length.
-- PAGED (`kv_pages=N`): a pool of N pages `[L, N, h_kv, page_size, d]`
-  with per-slot block tables (`models/decode.paged_engine_step`
-  gathers pages by table index inside the jitted tick).  Memory is
-  bounded by the tokens a request can actually touch, decoupling slot
-  count from max_len; admission allocates `ceil((prompt + max_new - 1)
-  / page_size)` pages and BACKPRESSURES (QueueFull/429 + Retry-After)
-  on pool exhaustion instead of failing the engine.  Pages free on
-  completion, cancel, and TTL expiry.  `quantize_kv=True` stores pages
-  as int8 with per-page-per-head scales (~2x more tokens per byte;
-  dequant fuses into the attention einsum).  `prefix_caching=True`
-  registers every FULL prefilled prompt page under a chain hash, so
-  requests sharing a system prompt adopt the cached pages instead of
-  re-prefilling — TTFT on a prefix hit collapses to the tail chunks.
-  Sessions diverging mid-page stop matching at the divergence page and
-  each writes its own copy (full pages are immutable once written, so
-  shared pages are never mutated).
+The KV cache is a pool of `kv_pages` pages `[L, N, h_kv, page_size, d]`
+with per-slot block tables (`models/decode.paged_engine_step` reads
+pages by table index inside the jitted tick).  Memory is bounded by
+the tokens a request can actually touch, decoupling slot count from
+max_len; admission allocates `ceil((prompt + max_new - 1) /
+page_size)` pages and BACKPRESSURES (QueueFull/429 + Retry-After) on
+pool exhaustion instead of failing the engine.  Pages free on
+completion, cancel, and TTL expiry.  `kv_pages=None` sizes the pool so
+that every slot can hold a request of max_len at once
+(`PagedKVManager.pool_pages`).  `quantize_kv=True` stores pages as int8
+with per-page-per-head scales (~2x more tokens per byte; dequant fuses
+into the attention einsum).  `prefix_caching=True` registers every FULL
+prefilled prompt page under a chain hash, so requests sharing a system
+prompt adopt the cached pages instead of re-prefilling — TTFT on a
+prefix hit collapses to the tail chunks.  Sessions diverging mid-page
+stop matching at the divergence page and each writes its own copy
+(full pages are immutable once written, so shared pages are never
+mutated).
 
 Decode hot loop (the device never waits on Python):
 - Token selection happens ON DEVICE inside the jitted step — greedy
   argmax plus per-slot temperature/top-k sampling, stop-set matching,
-  and max_new_tokens countdown all live in `decode.engine_step`, so
+  and max_new_tokens countdown all live in the jitted tick, so
   tick t+1's input IS tick t's output with zero host transfer.
 - Ticks are PIPELINED one deep: the worker dispatches tick t+1 before
   fetching tick t's tokens and reads results one tick behind for
@@ -60,11 +57,10 @@ Decode hot loop (the device never waits on Python):
   between ticks), so the worst ITL stall any admission can impose on
   running requests is one chunk's compute, not one prompt's.
 
-Self-speculative decoding (`spec_tokens=k > 0`, paged engines only): a
-per-slot host-side n-gram/prompt-lookup drafter
-(`serve/sampler.NgramDrafter`) proposes k tokens, ONE batched verify
-tick (`decode.paged_spec_engine_step`) scores all of them against the
-paged cache, and each slot emits its longest exactly-matching draft
+Self-speculative decoding (`spec_tokens=k > 0`): a per-slot host-side
+n-gram/prompt-lookup drafter (`serve/sampler.NgramDrafter`) proposes
+k tokens, ONE batched verify tick (`decode.paged_spec_engine_step`)
+scores all of them against the paged cache, and each slot emits its longest exactly-matching draft
 prefix plus the verified bonus token.  Token streams are byte-identical
 to spec-off — greedy AND seeded sampling — because every emitted token
 is the engine's own verified choice; drafts only decide how many land
@@ -76,7 +72,7 @@ tick just emitted), trading the one-deep pipeline for up to k+1 tokens
 per dispatch.  The paged attention inside every tick runs the Pallas
 paged-attention kernel where it can (`SKYTPU_DECODE_KERNEL=
 pallas|gather`, ops/paged_attention.py) with the jnp gather fallback
-elsewhere — both parity-pinned against the dense engine.
+elsewhere — both parity-pinned against `decode.generate`.
 
 Exact-prefill trick for static shapes: the prompt's
 first n-1 tokens are prefilled PADDED to a power-of-two bucket
@@ -99,11 +95,6 @@ Admission is BOUNDED: `max_queue` rejects new submits when the backlog
 is full (`QueueFull` -> HTTP 429) and `queue_ttl` expires requests
 that waited too long queued (`QueueExpired` -> HTTP 503), so a load
 spike degrades with fast, honest rejections instead of unbounded TTFT.
-
-`pipelined=False` keeps the pre-pipeline loop (inline full-prompt
-prefill, one host sync per generated token, greedy only, dense cache
-only) for A/B benchmarking — `bench_serve.py` reports the speedup
-against it.
 """
 from __future__ import annotations
 
@@ -212,7 +203,7 @@ _M_KERNEL_LIVE_SHARE = metrics_lib.gauge(
 _M_KERNEL_PALLAS = metrics_lib.gauge(
     'skytpu_engine_decode_kernel_pallas',
     'Whether the paged decode attention runs the Pallas kernel '
-    '(1) or the jnp gather fallback (0); absent-dense engines set 0.')
+    '(1) or the jnp gather fallback (0).')
 
 
 def _maybe_page_journal():
@@ -237,7 +228,7 @@ class ContinuousBatchingEngine:
                  max_queue: int = 0,
                  queue_ttl: Optional[float] = None,
                  max_top_k: int = 64, max_stop_ids: int = 16,
-                 pipelined: bool = True, mesh=None,
+                 mesh=None,
                  kv_pages: Optional[int] = None, page_size: int = 16,
                  quantize_kv: bool = False,
                  prefix_caching: bool = True,
@@ -261,7 +252,6 @@ class ContinuousBatchingEngine:
         self.queue_ttl = queue_ttl               # None = no expiry
         self.max_top_k = int(max_top_k)
         self.max_stop_ids = int(max_stop_ids)
-        self.pipelined = pipelined
         self._jnp = jnp
         self._jax = jax
         self._slots = [scheduler.Slot() for _ in range(slots)]
@@ -287,36 +277,21 @@ class ContinuousBatchingEngine:
         if self.spec_tokens < 0:
             raise ValueError(
                 f'spec_tokens must be >= 0, got {spec_tokens}')
-        self._kv: Optional[cache_manager.PagedKVManager] = None
-        if kv_pages is not None:
-            if not pipelined:
-                raise ValueError('kv_pages (paged KV cache) requires '
-                                 'the pipelined engine')
-            if max_len % page_size:
-                raise ValueError(
-                    f'max_len {max_len} must be a multiple of '
-                    f'page_size {page_size} (private prefill caches '
-                    f'scatter whole pages into the pool)')
-            self._kv = cache_manager.PagedKVManager(
-                int(kv_pages), int(page_size), slots,
-                prefix_caching=prefix_caching,
-                journal=_maybe_page_journal())
-            self._cache = decode.init_paged_cache(
-                cfg, int(kv_pages), int(page_size), slots,
-                max_len // int(page_size), quantize_kv=quantize_kv)
-        else:
-            if self.spec_tokens:
-                raise ValueError(
-                    'spec_tokens (speculative decoding) requires the '
-                    'paged KV engine (kv_pages): rejected drafts roll '
-                    'back through the pool\'s reserved null page')
-            self._cache = decode.init_slot_cache(cfg, slots, max_len)
+        page_size = int(page_size)
+        n_pages = cache_manager.PagedKVManager.pool_pages(
+            kv_pages, slots, max_len, page_size)
+        self._kv = cache_manager.PagedKVManager(
+            n_pages, page_size, slots, prefix_caching=prefix_caching,
+            journal=_maybe_page_journal())
+        self._cache = decode.init_paged_cache(
+            cfg, n_pages, page_size, slots, max_len // page_size,
+            quantize_kv=quantize_kv)
         # Prompts that may be mid-prefill at once.  Each holds a private
         # cache of max_len until it joins the engine's cache, so a burst
         # of admissions may hold as many bytes beside that cache as it
-        # holds itself (the slots' caches: every slot may; a pool
-        # smaller than slots x max_len: fewer); past the bound a request
-        # waits in the queue for a prefill to finish.
+        # holds itself (a pool of slots x max_len: every slot may; a
+        # smaller one: fewer); past the bound a request waits in the
+        # queue for a prefill to finish.
         private = (2 * cfg.n_layers * cfg.n_kv_heads * cfg.head_dim *
                    max_len * jnp.dtype(cfg.dtype).itemsize)
         held = sum(leaf.nbytes for leaf in jax.tree.leaves(
@@ -327,9 +302,7 @@ class ContinuousBatchingEngine:
         # wherever it can run) and baked into the jitted partials below
         # as a closure constant, so the hot loop never re-reads the
         # environment.
-        self.decode_kernel = (
-            paged_attention_lib.decode_kernel_choice()
-            if self._kv is not None else 'dense')
+        self.decode_kernel = paged_attention_lib.decode_kernel_choice()
         _M_KERNEL_PALLAS.set(
             1 if self.decode_kernel == 'pallas' else 0)
         self._state = decode.init_engine_state(slots, max_stop_ids)
@@ -340,75 +313,57 @@ class ContinuousBatchingEngine:
             # replicated) instead of leaving GSPMD to guess from the
             # first donated step.
             from skypilot_tpu.parallel import sharding as sharding_lib
-            if self._kv is not None:
-                self._cache = jax.device_put(
-                    self._cache, sharding_lib.paged_cache_sharding(
-                        mesh, quantized=quantize_kv))
-            else:
-                # Per-leaf shardings: the rank-5 kv spec must not be
-                # broadcast onto the rank-1 lengths leaf.
-                kv_sharding = sharding_lib.slot_cache_sharding(mesh)
-                self._cache = jax.device_put(
-                    self._cache,
-                    {'k': kv_sharding, 'v': kv_sharding,
-                     'lengths': sharding_lib.replicated(mesh)})
+            self._cache = jax.device_put(
+                self._cache, sharding_lib.paged_cache_sharding(
+                    mesh, quantized=quantize_kv))
             self._state = jax.device_put(
                 self._state, sharding_lib.engine_state_sharding(mesh))
-        self._tokens = jnp.zeros((slots, 1), jnp.int32)  # legacy loop
 
         # Every jitted entry is a function under its own name
         # (`decode.bind`, never a partial or a lambda): the name is the
         # program's in a device trace (`jit_paged_engine_step`), and the
         # benchmark's readers find the tick and the prefill programs by
         # it.
-        if self._kv is not None:
-            self._step = jax.jit(
-                decode.bind(decode.paged_engine_step, cfg,
-                            max_top_k=self.max_top_k,
-                            kernel=self.decode_kernel, mesh=mesh),
-                donate_argnums=(2,))
-            # Speculative verify tick: same donated-pool discipline as
-            # the plain tick, plus the [slots, k] draft batch; the
-            # kernel choice is a closure constant, so both ticks hit
-            # the same attention path.
-            self._spec_step = jax.jit(
-                decode.bind(decode.paged_spec_engine_step, cfg,
-                            max_top_k=self.max_top_k,
-                            kernel=self.decode_kernel, mesh=mesh),
-                donate_argnums=(2,))
-            # Block-table surgery: donated so XLA patches the pool's
-            # tiny int32 tables in place.
-            self._admit_paged = jax.jit(decode.paged_admit_slot,
-                                        donate_argnums=(0,))
-            self._release_paged = jax.jit(decode.paged_release_slot,
-                                          donate_argnums=(0,))
-            # Private-prefill -> pool page scatter (quantizing when the
-            # pool is int8); the pool is donated (in-place patch), the
-            # private cache is not (its [L,1,h,T,d] layout cannot alias
-            # the page-major pool output — donating it just warns).
-            self._insert_pages = jax.jit(
-                decode.insert_prefill_pages,
-                static_argnames=('first_page',), donate_argnums=(0,))
-            # Prefix-hit seeding: cached pages -> the leading positions
-            # of a fresh private cache (pool read-only, NOT donated).
-            self._seed_private = jax.jit(
-                decode.bind(decode.paged_seed_private, cfg),
-                static_argnames=('priv_len',))
-            # KV handoff adoption: imported page contents -> pool pages
-            # (quantizing when the pool is int8); pool donated.  The
-            # quantized variant lands int8 wire bytes verbatim — the
-            # import path's hot case never dequantizes.
-            self._write_pages = jax.jit(decode.write_pages,
-                                        donate_argnums=(0,))
-            self._write_pages_q = jax.jit(decode.write_pages_quantized,
-                                          donate_argnums=(0,))
-        else:
-            self._step = jax.jit(
-                decode.bind(decode.engine_step, cfg,
-                            max_top_k=self.max_top_k),
-                donate_argnums=(2,))
-        self._legacy_step = jax.jit(decode.bind(decode.batched_step, cfg),
-                                    donate_argnums=(2,))
+        self._step = jax.jit(
+            decode.bind(decode.paged_engine_step, cfg,
+                        max_top_k=self.max_top_k,
+                        kernel=self.decode_kernel, mesh=mesh),
+            donate_argnums=(2,))
+        # Speculative verify tick: same donated-pool discipline as
+        # the plain tick, plus the [slots, k] draft batch; the
+        # kernel choice is a closure constant, so both ticks hit
+        # the same attention path.
+        self._spec_step = jax.jit(
+            decode.bind(decode.paged_spec_engine_step, cfg,
+                        max_top_k=self.max_top_k,
+                        kernel=self.decode_kernel, mesh=mesh),
+            donate_argnums=(2,))
+        # Block-table surgery: donated so XLA patches the pool's
+        # tiny int32 tables in place.
+        self._admit_paged = jax.jit(decode.paged_admit_slot,
+                                    donate_argnums=(0,))
+        self._release_paged = jax.jit(decode.paged_release_slot,
+                                      donate_argnums=(0,))
+        # Private-prefill -> pool page scatter (quantizing when the
+        # pool is int8); the pool is donated (in-place patch), the
+        # private cache is not (its [L,1,h,T,d] layout cannot alias
+        # the page-major pool output — donating it just warns).
+        self._insert_pages = jax.jit(
+            decode.insert_prefill_pages,
+            static_argnames=('first_page',), donate_argnums=(0,))
+        # Prefix-hit seeding: cached pages -> the leading positions
+        # of a fresh private cache (pool read-only, NOT donated).
+        self._seed_private = jax.jit(
+            decode.bind(decode.paged_seed_private, cfg),
+            static_argnames=('priv_len',))
+        # KV handoff adoption: imported page contents -> pool pages
+        # (quantizing when the pool is int8); pool donated.  The
+        # quantized variant lands int8 wire bytes verbatim — the
+        # import path's hot case never dequantizes.
+        self._write_pages = jax.jit(decode.write_pages,
+                                    donate_argnums=(0,))
+        self._write_pages_q = jax.jit(decode.write_pages_quantized,
+                                      donate_argnums=(0,))
         # Jitted prefill: one compile per prompt-length bucket (the
         # whole point of the bucket padding), not eager per-op dispatch
         # per admission.
@@ -419,11 +374,6 @@ class ContinuousBatchingEngine:
         # is donated so XLA extends it in place.
         self._prefill_chunk = jax.jit(
             decode.bind(decode.prefill_chunk, cfg), donate_argnums=(2,))
-        # Jitted in-place slot adoption (dense): eager
-        # dynamic_update_slice would materialize two full copies of the
-        # pool cache per admission; donation lets XLA update in place.
-        self._insert = jax.jit(decode.insert_prefill,
-                               donate_argnums=(0,))
         # ---- continuous profiling plane (observability/profiling.py).
         # Tick-phase spans + recompile sentinel; both collapse to no-ops
         # under SKYTPU_PROFILE_DISABLE.  Every resolved jit entry above
@@ -435,12 +385,10 @@ class ContinuousBatchingEngine:
         self._sentinel = profiling.RecompileSentinel()
         for attr in ('_step', '_spec_step', '_admit_paged',
                      '_release_paged', '_insert_pages', '_seed_private',
-                     '_write_pages', '_write_pages_q', '_legacy_step',
-                     '_prefill', '_prefill_chunk', '_insert'):
-            entry = getattr(self, attr, None)
-            if entry is not None:
-                setattr(self, attr,
-                        self._sentinel.wrap(attr.lstrip('_'), entry))
+                     '_write_pages', '_write_pages_q', '_prefill',
+                     '_prefill_chunk'):
+            setattr(self, attr, self._sentinel.wrap(
+                attr.lstrip('_'), getattr(self, attr)))
         self._failed: Optional[Exception] = None
 
         # ---- metrics (updated under _metrics_lock; read by stats()).
@@ -520,8 +468,7 @@ class ContinuousBatchingEngine:
                 f'prompt {len(prompt_ids)} + new {max_new_tokens} '
                 f'exceeds max_len {self.max_len}')
         temperature, top_k, seed = sampler_lib.validate_sampling(
-            sampling, max_top_k=self.max_top_k,
-            pipelined=self.pipelined)
+            sampling, max_top_k=self.max_top_k)
         request = scheduler.Request(prompt_ids, max_new_tokens,
                                     stop_token, temperature=temperature,
                                     top_k=top_k, seed=seed,
@@ -539,25 +486,23 @@ class ContinuousBatchingEngine:
             raise RuntimeError('batching engine is stopped'
                                if self._failed is None else
                                f'batching engine failed: {self._failed}')
-        if self._kv is not None:
-            # Admission is page-aware: a request that could NEVER fit
-            # is a caller error; a pool too busy RIGHT NOW while a
-            # backlog already waits is backpressure (429 + Retry-After)
-            # — the honest degraded mode for an exhausted pool.
-            need = self._kv.pages_needed(len(prompt_ids),
-                                         max_new_tokens)
-            if need > self._kv.pool.capacity:
-                raise ValueError(
-                    f'request needs {need} KV pages > pool capacity '
-                    f'{self._kv.pool.capacity} (pool of '
-                    f'{self._kv.pool.capacity} pages x '
-                    f'{self._kv.page_size} tokens)')
-            if len(self._queue) > 0 and not self._pool_has_room(
-                    prompt_ids, need):
-                raise self._queue.reject(
-                    'pages_exhausted',
-                    f'KV page pool exhausted ({need} page(s) needed, '
-                    f'{self._kv.pool.free_count} free); retry later')
+        # Admission is page-aware: a request that could NEVER fit is a
+        # caller error; a pool too busy RIGHT NOW while a backlog
+        # already waits is backpressure (429 + Retry-After) — the
+        # honest degraded mode for an exhausted pool.
+        need = self._kv.pages_needed(len(prompt_ids), max_new_tokens)
+        if need > self._kv.pool.capacity:
+            raise ValueError(
+                f'request needs {need} KV pages > pool capacity '
+                f'{self._kv.pool.capacity} (pool of '
+                f'{self._kv.pool.capacity} pages x '
+                f'{self._kv.page_size} tokens)')
+        if len(self._queue) > 0 and not self._pool_has_room(
+                prompt_ids, need):
+            raise self._queue.reject(
+                'pages_exhausted',
+                f'KV page pool exhausted ({need} page(s) needed, '
+                f'{self._kv.pool.free_count} free); retry later')
         self._queue.submit(request)
         if self._stop.is_set():
             # Lost the race with stop(): its drain may have already run,
@@ -597,10 +542,10 @@ class ContinuousBatchingEngine:
         replica to adopt (the prefill side of a disaggregated handoff).
 
         Runs the same chunked-prefill path an admission would, but into
-        a private cache that never touches this engine's slot pool or
-        page pool — a prefill replica can export for many decode
-        replicas without competing with its own admissions.  Returns
-        the serve/handoff.py wire payload: the prompt's full pages in
+        a private cache that never touches this engine's page pool —
+        a prefill replica can export for many decode replicas without
+        competing with its own admissions.  Returns the
+        serve/handoff.py wire payload: the prompt's full pages in
         page-major layout (int8 + scales when this engine quantizes
         KV), plus the chain hashes the importer registers them under.
         The sub-page tail of the prompt is the importer's to prefill
@@ -617,8 +562,7 @@ class ContinuousBatchingEngine:
             raise RuntimeError('batching engine is stopped'
                                if self._failed is None else
                                f'batching engine failed: {self._failed}')
-        ps = int(page_size) if page_size else (
-            self._kv.page_size if self._kv is not None else 16)
+        ps = int(page_size) if page_size else self._kv.page_size
         n = len(prompt_ids)
         if n < 2:
             raise HandoffError('prompt too short to export')
@@ -652,7 +596,7 @@ class ContinuousBatchingEngine:
     def _prefill_private(self, prompt_ids: List[int],
                          n_target: int) -> Dict[str, Any]:
         """Prefill tokens [0, n_target) into a FRESH private cache
-        ([L, 1, h_kv, max_len, d]) without touching the slot pool:
+        ([L, 1, h_kv, max_len, d]) without touching the page pool:
         chunk 0 through the bucketed flash path, then masked chunk
         continuations — the same compile cache the admission path
         uses.  The slice engine overrides this with a one-shot
@@ -697,9 +641,6 @@ class ContinuousBatchingEngine:
         """
         import numpy as np  # pylint: disable=import-outside-toplevel
         from skypilot_tpu.chaos import injector  # pylint: disable=import-outside-toplevel
-        if self._kv is None:
-            raise HandoffError('KV import needs a paged engine '
-                               '(--kv-pages)')
         if not self._kv.prefix_caching:
             raise HandoffError('KV import needs the prefix cache '
                                '(imports publish pages through it)')
@@ -814,12 +755,8 @@ class ContinuousBatchingEngine:
 
         Returns the binary octet-stream frame (binary=True) or the
         JSON/base64 dict; raises HandoffError when this engine has no
-        exportable prefixes (dense cache, prefix caching off, empty
-        cache)."""
+        exportable prefixes (prefix caching off, empty cache)."""
         import numpy as np  # pylint: disable=import-outside-toplevel
-        if self._kv is None:
-            raise HandoffError('prefix export needs a paged engine '
-                               '(--kv-pages)')
         if not self._kv.prefix_caching:
             raise HandoffError('prefix export needs the prefix cache')
         if self._stop.is_set() or self._failed is not None:
@@ -963,7 +900,7 @@ class ContinuousBatchingEngine:
         scale-out signals, decode_tokens_per_s and the queue-wait
         histogram say whether the replica is decode-bound rather than
         merely popular (serve/autoscalers.py consumes busy/slots as
-        replica load).  Paged engines add the page-pool view:
+        replica load).  The page-pool view:
         kv_pages_{total,used,free,pinned}, prefix-cache entry/hit/miss
         counts, pages_exhausted_deferrals, and paged_kernel (the pages
         the decode ticks' live contexts held beside the rows of every
@@ -982,8 +919,6 @@ class ContinuousBatchingEngine:
                 'ticks': self._ticks,
                 'prefill_chunks': self._prefill_chunks,
                 'prefill_chunk': self.prefill_chunk,
-                'pipelined': self.pipelined,
-                'paged': self._kv is not None,
                 'decode_kernel': self.decode_kernel,
                 'spec_tokens': self.spec_tokens,
                 'weight_epoch': self._weight_epoch,
@@ -1004,16 +939,15 @@ class ContinuousBatchingEngine:
                     ('tokens', 'held_pairs', 'max_expert_tokens'),
                     self._moe_counts))
         stats.update(self._queue.stats())
-        if self._kv is not None:
-            stats.update(self._kv.stats())
-            with self._metrics_lock:
-                stats['pages_exhausted_deferrals'] = self._page_deferrals
-                # Cumulative over paged ticks: what the decode kernel
-                # walked of what the block tables have rows for.
-                stats['paged_kernel'] = {
-                    'live_pages': self._kernel_live_pages,
-                    'table_pages': self._kernel_table_pages,
-                    'walked_pages': self._kernel_walked_pages}
+        stats.update(self._kv.stats())
+        with self._metrics_lock:
+            stats['pages_exhausted_deferrals'] = self._page_deferrals
+            # Cumulative over ticks: what the decode kernel walked of
+            # what the block tables have rows for.
+            stats['paged_kernel'] = {
+                'live_pages': self._kernel_live_pages,
+                'table_pages': self._kernel_table_pages,
+                'walked_pages': self._kernel_walked_pages}
         rate = round(self._decode_rate(), 3)
         stats['decode_tokens_per_s'] = rate
         # The worker loop's cumulative totals (iterations, seconds by
@@ -1044,7 +978,6 @@ class ContinuousBatchingEngine:
         recompile sentinel's per-jit-entry compile counts."""
         snap = self._profiler.snapshot()
         snap['recompiles'] = self._sentinel.snapshot()
-        snap['pipelined'] = self.pipelined
         return snap
 
     def set_role_budget(
@@ -1075,11 +1008,10 @@ class ContinuousBatchingEngine:
                     RuntimeError('batching engine stopped'))
                 slot.request = None
             slot.drafter = None
-        if self._kv is not None:
-            # Host-side accounting only (the device is going away):
-            # every slot- and prefix-held page returns to the pool, so
-            # the alloc/free journal balances.
-            self._kv.release_all()
+        # Host-side accounting only (the device is going away): every
+        # slot- and prefix-held page returns to the pool, so the
+        # alloc/free journal balances.
+        self._kv.release_all()
         # Handoff imports still queued never ran; unblock their waiters.
         self._drain_host_ops()
 
@@ -1109,18 +1041,7 @@ class ContinuousBatchingEngine:
                 return b
         return n
 
-    # ----------------------------------------------- pipelined admission
-
-    def _plan_pages(self, request: scheduler.Request
-                    ) -> Optional[cache_manager.AdmissionPlan]:
-        """Paged mode: match the prefix cache and allocate this
-        request's pages (raises PagesExhausted -> caller defers)."""
-        if self._kv is None:
-            return None
-        plan = self._kv.plan_admission(
-            request.prompt_ids, request.max_new_tokens)
-        request.span.prefix_hit_pages = plan.prefix_hit_pages
-        return plan
+    # --------------------------------------------------------- admission
 
     def _pad_row(self, row: List[int]):
         import numpy as np  # pylint: disable=import-outside-toplevel
@@ -1137,31 +1058,27 @@ class ContinuousBatchingEngine:
         (or the request finished at admission).  Raises PagesExhausted
         (pool backpressure) BEFORE touching any state — the caller
         requeues the request at the head."""
-        jnp = self._jnp
         slot = self._slots[slot_id]
         prompt = request.prompt_ids
         n = len(prompt)
-        plan = self._plan_pages(request)   # may raise PagesExhausted
-        if plan is not None:
-            self._kv.commit(slot_id, plan)
+        # Match the prefix cache and allocate the request's pages.
+        plan = self._kv.plan_admission(    # may raise PagesExhausted
+            prompt, request.max_new_tokens)
+        request.span.prefix_hit_pages = plan.prefix_hit_pages
+        self._kv.commit(slot_id, plan)
         self._queue.record_admission(request, self._profiler.iteration)
         if n <= 1:
             # Single-token prompt: empty slot; stale keys are masked
             # (per-position causal mask) and position 0 is overwritten
             # by the first step's write.
-            if plan is not None:
-                self._cache = self._admit_paged(
-                    self._cache, slot_id, self._pad_row(plan.row), 0)
-            else:
-                self._cache = dict(
-                    self._cache,
-                    lengths=self._cache['lengths'].at[slot_id].set(0))
+            self._cache = self._admit_paged(
+                self._cache, slot_id, self._pad_row(plan.row), 0)
             slot.request = request
             self._activate(slot_id, request, int(prompt[-1]), 0,
                            remaining=request.max_new_tokens,
                            key=self._jax.random.PRNGKey(request.seed))
             return None
-        if plan is not None and plan.n_reuse_tokens >= n - 1:
+        if plan.n_reuse_tokens >= n - 1:
             # Full prefix hit (the prefilled region [0, n-1) is page-
             # aligned and entirely cached): no prefill at all — the
             # slot joins the next tick and TTFT collapses to one step.
@@ -1177,9 +1094,7 @@ class ContinuousBatchingEngine:
         # overwrites the first pad position and attends only real
         # keys, so logits match unpadded decode exactly).
         slot.request = request
-        pending = scheduler.PendingPrefill(slot_id, request, n - 1)
-        pending.plan = plan
-        return pending
+        return scheduler.PendingPrefill(slot_id, request, n - 1, plan)
 
     def _advance_prefill(self, pending: scheduler.PendingPrefill
                          ) -> bool:
@@ -1198,8 +1113,7 @@ class ContinuousBatchingEngine:
                     scheduler.DeadlineExceeded(
                         'request deadline passed mid-prefill'))
             self._slots[pending.slot_id].request = None
-            if pending.plan is not None:
-                self._release_slot_pages(pending.slot_id)
+            self._release_slot_pages(pending.slot_id)
             return True  # pending is finished (slot freed)
         import numpy as np  # pylint: disable=import-outside-toplevel
         n_target = pending.n_target
@@ -1207,7 +1121,7 @@ class ContinuousBatchingEngine:
         # per-tick piece (floor 1 — prefill slows, never stalls).
         chunk = self._queue.prefill_tokens_per_tick(self.prefill_chunk)
         plan = pending.plan
-        reuse_tokens = plan.n_reuse_tokens if plan is not None else 0
+        reuse_tokens = plan.n_reuse_tokens
         seeding = pending.cache is None and reuse_tokens > 0
         # The phase and `span.prefill_s` time the host's DISPATCH of
         # the program (asynchronous), not the program.
@@ -1283,7 +1197,7 @@ class ContinuousBatchingEngine:
         return self._finish_prefill(pending)
 
     def _finish_prefill(self, pending: scheduler.PendingPrefill) -> bool:
-        """All chunks in: adopt the private cache into the slot pool
+        """All chunks in: adopt the private cache into the page pool
         and join the next decode tick at length n-1 with the last REAL
         prompt token as input.  Split out of `_advance_prefill` so the
         slice engine's sequence-parallel prefill (one shot instead of
@@ -1292,33 +1206,28 @@ class ContinuousBatchingEngine:
         request = pending.request
         n_target = pending.n_target
         plan = pending.plan
-        # Cache adoption (page scatter / dense insert) + activation:
-        # its own phase so prefill compute and pool surgery separate.
+        # Cache adoption (page scatter) + activation: its own phase so
+        # prefill compute and pool surgery separate.
         with self._profiler.phase('page-scatter',
                                   request_id=request.request_id) as phase:
-            if plan is not None:
-                # Scatter only the FRESH pages (the reused prefix
-                # already lives in the pool — rewriting pages another
-                # slot shares, even with identical values, is what this
-                # skips), then point the block table at the full row
-                # and publish the fresh full pages for the next prefix
-                # hit.
-                ps = self._kv.page_size
-                r = len(plan.reuse_pages)
-                n_prompt_pages = -(-n_target // ps)
-                phase.count = n_prompt_pages - r
-                self._cache = self._insert_pages(
-                    self._cache, pending.cache,
-                    np.asarray(plan.row[r:n_prompt_pages], np.int32),
-                    first_page=r)
-                pending.cache = None   # donated to the scatter
-                self._cache = self._admit_paged(
-                    self._cache, pending.slot_id,
-                    self._pad_row(plan.row), n_target)
-                self._kv.register_prefix(plan)
-            else:
-                self._cache = self._insert(self._cache, pending.slot_id,
-                                           pending.cache, n_target)
+            # Scatter only the FRESH pages (the reused prefix already
+            # lives in the pool — rewriting pages another slot shares,
+            # even with identical values, is what this skips), then
+            # point the block table at the full row and publish the
+            # fresh full pages for the next prefix hit.
+            ps = self._kv.page_size
+            r = len(plan.reuse_pages)
+            n_prompt_pages = -(-n_target // ps)
+            phase.count = n_prompt_pages - r
+            self._cache = self._insert_pages(
+                self._cache, pending.cache,
+                np.asarray(plan.row[r:n_prompt_pages], np.int32),
+                first_page=r)
+            pending.cache = None   # donated to the scatter
+            self._cache = self._admit_paged(
+                self._cache, pending.slot_id,
+                self._pad_row(plan.row), n_target)
+            self._kv.register_prefix(plan)
             self._activate(pending.slot_id, request,
                            int(request.prompt_ids[-1]), n_target,
                            remaining=request.max_new_tokens,
@@ -1329,8 +1238,8 @@ class ContinuousBatchingEngine:
                   token: int, length: int, *, remaining: int,
                   key) -> None:
         """Flip a slot live in the device state (one jitted dispatch)."""
-        # The device's cache lengths are set by the insert/admission
-        # paths; the host keeps its own count for stats()['paged_kernel'].
+        # The device's cache lengths are set by the admission paths;
+        # the host keeps its own count for stats()['paged_kernel'].
         self._slots[slot_id].depth = length
         if self.spec_tokens:
             # Seed the slot's drafter with everything decoded so far:
@@ -1353,16 +1262,14 @@ class ContinuousBatchingEngine:
         self._state = dict(self._state, active=active)
 
     def _release_slot_pages(self, slot_id: int) -> None:
-        """Paged mode: park the slot's block table on the null page
-        (stale in-flight writes land in garbage, never in recycled
-        pages), THEN return its pages to the pool."""
-        if self._kv is None:
-            return
+        """Park the slot's block table on the null page (stale
+        in-flight writes land in garbage, never in recycled pages),
+        THEN return its pages to the pool."""
         self._cache = self._release_paged(self._cache, slot_id)
         self._kv.release(slot_id)
 
     def _count_kernel_pages(self, live, s_q: int) -> None:
-        """Add one paged tick to stats()['paged_kernel']: the pages
+        """Add one tick to stats()['paged_kernel']: the pages
         that hold each live slot's cache and the tick's `s_q` new
         tokens (`live_pages`) beside the rows of every slot's block
         table, and the pages the decode kernel is given to walk summed
@@ -1492,12 +1399,9 @@ class ContinuousBatchingEngine:
             _M_BUSY_SLOTS.set(sum(1 for s in self._slots if s.active))
             phase.count = pushed
 
-    # ------------------------------------------------- pipelined worker
+    # ------------------------------------------------------------ worker
 
     def _run(self) -> None:
-        if not self.pipelined:
-            self._run_legacy()
-            return
         # Profiling lifecycle: one start/end pair brackets the worker's
         # whole run so journal replay can attribute the ring's ticks to
         # an engine incarnation (and see whether it died or drained).
@@ -1639,10 +1543,9 @@ class ContinuousBatchingEngine:
                     with prof.phase('decode-step', count=len(live)):
                         self._state, self._cache, finished, moe = (
                             self._dispatch_step())
-                    if self._kv is not None:
-                        self._count_kernel_pages(live, 1)
-                        for slot_id in live:
-                            self._slots[slot_id].depth += 1
+                    self._count_kernel_pages(live, 1)
+                    for slot_id in live:
+                        self._slots[slot_id].depth += 1
                     dispatched = (self._state, finished,
                                   list(live.items()), moe)
                 if inflight is not None:
@@ -1697,124 +1600,13 @@ class ContinuousBatchingEngine:
                                 self._cond.wait(timeout=0.05)
             except Exception as e:  # pylint: disable=broad-except
                 logger.exception('batching engine tick failed')
-                # The jit'd step donates the slot cache — after a
+                # The jit'd step donates the page pool — after a
                 # failure mid-step the cache buffers may be invalid, so
                 # the engine CANNOT safely continue: fail everything in
                 # flight, mark failed (submit() rejects from now on),
                 # and exit the worker.
                 self._fail_everything(e)
                 return
-
-    # --------------------------------------------------- legacy worker
-
-    def _admit_legacy(self, slot_id: int,
-                      request: scheduler.Request) -> None:
-        """Pre-pipeline admission: the WHOLE prompt prefills inline
-        (one long stall for every running request — what chunked
-        prefill bounds).  Dense cache only."""
-        if request.cancelled:
-            request._finish()  # pylint: disable=protected-access
-            return
-        jnp = self._jnp
-        slot = self._slots[slot_id]
-        prompt = request.prompt_ids
-        n = len(prompt)
-        if n > 1:
-            bucket = min(self._bucket(n - 1), self.max_len)
-            padded = jnp.zeros((1, bucket), jnp.int32)
-            padded = padded.at[0, :n - 1].set(
-                jnp.asarray(prompt[:-1], jnp.int32))
-            _, pre = self._prefill(self.params, padded)
-            self._cache = self._insert(self._cache, slot_id, pre, n - 1)
-        else:
-            self._cache = dict(
-                self._cache,
-                lengths=self._cache['lengths'].at[slot_id].set(0))
-        slot.request = request
-        slot.next_token = int(prompt[-1])
-
-    def _tick_legacy(self) -> None:
-        """Pre-pipeline tick: eager per-slot token staging, one host
-        sync per generated token, greedy only.  Kept as the A/B
-        baseline `bench_serve.py` measures the pipelined loop against
-        (and as a debugging fallback)."""
-        jnp = self._jnp
-        active = [i for i, s in enumerate(self._slots) if s.active]
-        for i in active:
-            req = self._slots[i].request
-            if req.cancelled:
-                self._slots[i].request = None
-                req._finish()  # pylint: disable=protected-access
-            elif req.deadline_exceeded():
-                self._slots[i].request = None
-                _M_DEADLINE_REAPED.inc()
-                req._finish(scheduler.DeadlineExceeded(  # pylint: disable=protected-access
-                    'request deadline passed mid-generation'))
-        active = [i for i, s in enumerate(self._slots) if s.active]
-        if not active:
-            return
-        tokens = self._tokens
-        for i in active:
-            tokens = tokens.at[i, 0].set(self._slots[i].next_token)
-        logits, self._cache, _ = self._legacy_step(
-            self.params, tokens, self._cache)
-        import numpy as np  # pylint: disable=import-outside-toplevel
-        nxt = np.asarray(jnp.argmax(logits, axis=-1))  # one host sync
-        pushed = 0
-        for i in active:
-            slot = self._slots[i]
-            request = slot.request
-            token = int(nxt[i])
-            request._push(token)  # pylint: disable=protected-access
-            pushed += 1
-            finished = (len(request.tokens) >= request.max_new_tokens or
-                        token in request.stop_ids)
-            if finished:
-                slot.request = None
-                request._finish()  # pylint: disable=protected-access
-            else:
-                slot.next_token = token
-        self._tokens = tokens
-        self._record_tokens(pushed)
-        with self._metrics_lock:
-            self._ticks += 1
-        _M_TICKS.inc()
-        _M_BUSY_SLOTS.set(sum(1 for s in self._slots if s.active))
-
-    def _run_legacy(self) -> None:
-        while not self._stop.is_set():
-            try:
-                self._queue.expire_stale()
-                idle = not any(s.active for s in self._slots)
-                free = [i for i, s in enumerate(self._slots)
-                        if not s.active]
-                for slot_id in free:
-                    request = self._pop_admitted()
-                    if request is None:
-                        if idle:
-                            with self._cond:
-                                if (not len(self._queue) and
-                                        not self._stop.is_set()):
-                                    self._cond.wait(timeout=0.05)
-                            request = self._pop_admitted()
-                        if request is None:
-                            break
-                    try:
-                        self._admit_legacy(slot_id, request)
-                        idle = False
-                    except Exception as e:  # pylint: disable=broad-except
-                        request._finish(e)  # pylint: disable=protected-access
-                self._tick_legacy()
-            except Exception as e:  # pylint: disable=broad-except
-                logger.exception('batching engine tick failed')
-                self._fail_everything(e)
-                return
-
-    def _pop_admitted(self) -> Optional[scheduler.Request]:
-        request = self._queue.pop()
-        if request is not None:
-            self._queue.record_admission(request)
-        return request
 
     # ------------------------------------------------------------ failure
 
@@ -1829,6 +1621,5 @@ class ContinuousBatchingEngine:
             slot.drafter = None
         self._queue.drain(
             lambda: RuntimeError(f'batching engine failed: {e}'))
-        if self._kv is not None:
-            self._kv.release_all()
+        self._kv.release_all()
         self._drain_host_ops()  # stop is set: pending imports error out

@@ -391,6 +391,22 @@ class PagedKVManager:
 
     # ------------------------------------------------------------ sizing
 
+    @staticmethod
+    def pool_pages(kv_pages: Optional[int], slots: int, max_len: int,
+                   page_size: int) -> int:
+        """The pool's `n_pages` for an engine's geometry: `kv_pages`
+        as given, else what lets every slot hold a request of `max_len`
+        at once (`slots * max_len / page_size` pages to hand out, and
+        the reserved null page)."""
+        if max_len % page_size:
+            raise ValueError(
+                f'max_len {max_len} must be a multiple of '
+                f'page_size {page_size} (private prefill caches '
+                f'scatter whole pages into the pool)')
+        if kv_pages is None:
+            return slots * (max_len // page_size) + 1
+        return int(kv_pages)
+
     def pages_needed(self, prompt_len: int, max_new_tokens: int) -> int:
         """Pages covering every position this request can touch: the
         prompt occupies [0, n) and decode writes through position

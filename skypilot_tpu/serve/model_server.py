@@ -495,7 +495,7 @@ class ModelServer:
                     prefix_caching=prefix_caching,
                     spec_tokens=spec_tokens)
         # 'dense': the lock-step generate path (flash prefill + masked
-        # decode), the same word a dense-cache engine reports.
+        # decode) of a server without an engine.
         self.runtime['decode_kernel'] = (
             self._engine.decode_kernel if self._engine is not None
             else 'dense')
@@ -1508,17 +1508,19 @@ def main() -> None:
                                  if _os.environ.get(
                                      'SKYTPU_SERVE_KV_PAGES')
                                  else None),
-                        help='Paged KV cache: pool of N pages with '
+                        help='Size of the KV page pool: N pages with '
                              'per-slot block tables — slot count '
                              'decouples from --max-len, pool '
                              'exhaustion backpressures (429). '
-                             'Default: dense per-slot cache '
+                             'Default: what every slot needs to hold '
+                             '--max-len at once, max_batch * max_len '
+                             '/ page_size + 1 '
                              '(env SKYTPU_SERVE_KV_PAGES).')
     parser.add_argument('--page-size', type=int,
                         default=int(_os.environ.get(
                             'SKYTPU_SERVE_PAGE_SIZE', '16')),
-                        help='Tokens per KV page (--kv-pages mode; '
-                             '--max-len must be a multiple; env '
+                        help='Tokens per KV page (--max-len must be '
+                             'a multiple; env '
                              'SKYTPU_SERVE_PAGE_SIZE).')
     parser.add_argument('--quantize-kv', action='store_true',
                         default=_os.environ.get(
@@ -1535,13 +1537,13 @@ def main() -> None:
                              'all in one batched tick — token streams '
                              'stay byte-identical, ITL drops by the '
                              'acceptance length on repetitive text '
-                             '(--kv-pages mode; 0 = off; env '
+                             '(0 = off; env '
                              'SKYTPU_SERVE_SPEC_TOKENS).')
     parser.add_argument('--no-prefix-cache', action='store_true',
                         default=_os.environ.get(
                             'SKYTPU_SERVE_PREFIX_CACHE', '1') == '0',
                         help='Disable prompt prefix reuse across '
-                             'requests (--kv-pages mode; env '
+                             'requests (env '
                              'SKYTPU_SERVE_PREFIX_CACHE=0).')
     parser.add_argument('--temperature', type=float, default=0.0,
                         help='Default sampling temperature for '

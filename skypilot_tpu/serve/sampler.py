@@ -69,8 +69,8 @@ class NgramDrafter:
         return out
 
 
-def validate_sampling(sampling: Optional[Any], *, max_top_k: int,
-                      pipelined: bool) -> Tuple[float, int, int]:
+def validate_sampling(sampling: Optional[Any], *, max_top_k: int
+                      ) -> Tuple[float, int, int]:
     """-> (temperature, top_k, seed), raising ValueError on parameters
     the engine's compiled graphs cannot honor."""
     temperature, top_k, seed = 0.0, 0, 0
@@ -81,10 +81,6 @@ def validate_sampling(sampling: Optional[Any], *, max_top_k: int,
     if top_k > max_top_k:
         raise ValueError(
             f'top_k {top_k} > engine max_top_k {max_top_k}')
-    if temperature > 0.0 and not pipelined:
-        raise ValueError(
-            'the legacy (pipelined=False) loop serves greedy '
-            'decoding only')
     return temperature, top_k, seed
 
 

@@ -283,7 +283,6 @@ class Slot:
 
     def __init__(self) -> None:
         self.request: Optional[Request] = None
-        self.next_token = 0          # legacy (unpipelined) loop only
         self.drafter = None          # NgramDrafter when spec decoding
         self.depth = 0               # cache length the next tick starts at
 
@@ -293,19 +292,19 @@ class Slot:
 
 
 class PendingPrefill:
-    """A dense prompt mid-chunked-prefill: the slot is reserved but
+    """A prompt mid-chunked-prefill: the slot is reserved but
     does not join decode ticks until every chunk has run."""
 
     def __init__(self, slot_id: int, request: Request,
-                 n_target: int) -> None:
+                 n_target: int, plan: Any) -> None:
         self.slot_id = slot_id
         self.request = request
-        self.n_target = n_target     # tokens to prefill (n-1, dense)
+        self.n_target = n_target     # tokens to prefill (n-1)
         self.consumed = 0
         self.cache: Optional[Dict[str, Any]] = None  # private [*,1,..]
-        # Paged mode: the cache_manager.AdmissionPlan holding this
-        # request's pages (reuse + fresh) until activation/abandon.
-        self.plan: Optional[Any] = None
+        # The cache_manager.AdmissionPlan holding this request's pages
+        # (reuse + fresh) until activation/abandon.
+        self.plan = plan
 
 
 @dataclasses.dataclass

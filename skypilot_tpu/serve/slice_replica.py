@@ -29,12 +29,12 @@ processes.  This module makes "replica" mean "slice":
   `models/decode.prefill_sp` shot: ring attention
   (`ops/ring_attention.py`) splits the quadratic attention and its
   activations across the slice's sequence axis, so a 100k-token
-  context that would OOM (or stall) one host prefills in ~1/hosts the
-  time (bench_serve.py `sp_prefill` pins the scaling).
+  context that would OOM (or stall) one host prefills in ~1/hosts
+  the time.
 
 Emulated vs real:
 
-- *Emulated* (tests, CPU bench): all `num_hosts` virtual devices live
+- *Emulated* (tests): all `num_hosts` virtual devices live
   in this process (`xla_force_host_platform_device_count`); follower
   ranks are `LocalRank` threads that execute the command log (and its
   `serve.rank_exec` chaos site) while rank 0's dispatch covers every
@@ -49,7 +49,6 @@ Emulated vs real:
 from __future__ import annotations
 
 import argparse
-import json
 import os
 import time
 from typing import Any, Dict, List, Optional
@@ -219,8 +218,6 @@ class SliceReplicaEngine(batching_engine_lib.ContinuousBatchingEngine):
         planner allocated, and the per-slot decode state (token,
         budget, stop set, key chain seed, sampling params)."""
         import numpy as np  # pylint: disable=import-outside-toplevel
-        row = (self._kv.slot_row(slot_id)
-               if self._kv is not None else None)
         self._coordinator.broadcast(
             coordinator_lib.CMD_ADMIT, slot=slot_id,
             tokens=len(request.prompt_ids),
@@ -230,7 +227,7 @@ class SliceReplicaEngine(batching_engine_lib.ContinuousBatchingEngine):
             stop_ids=sorted(int(s) for s in request.stop_ids),
             key=np.asarray(key).tolist(),
             temperature=float(request.temperature),
-            top_k=int(request.top_k), row=row,
+            top_k=int(request.top_k), row=self._kv.slot_row(slot_id),
             request_id=request.request_id)
         request.span.slice_sync_ms = round(
             self._coordinator.sync_ms_mean(), 4)
@@ -242,9 +239,8 @@ class SliceReplicaEngine(batching_engine_lib.ContinuousBatchingEngine):
         the slot's block table on the null page exactly when rank 0
         does, so stale in-flight writes land in garbage on EVERY
         host."""
-        if self._kv is not None:
-            self._coordinator.broadcast(
-                coordinator_lib.CMD_RELEASE, slot=slot_id)
+        self._coordinator.broadcast(
+            coordinator_lib.CMD_RELEASE, slot=slot_id)
         super()._release_slot_pages(slot_id)
 
     # ------------------------------------------------------ SP prefill
@@ -285,9 +281,8 @@ class SliceReplicaEngine(batching_engine_lib.ContinuousBatchingEngine):
 
     def _advance_prefill(self, pending) -> bool:
         request = pending.request
-        reuse = (pending.plan.n_reuse_tokens
-                 if pending.plan is not None else 0)
-        if (pending.cache is None and reuse == 0 and
+        if (pending.cache is None and
+                pending.plan.n_reuse_tokens == 0 and
                 not request.cancelled):
             with self._profiler.phase(
                     'prefill-chunk', request_id=request.request_id,
@@ -359,7 +354,7 @@ class FollowerExecutor:
       verify tick instead — same attention kernel either way.
     - ``ADMIT``: replay the chunked prefill of prompt positions
       ``[0, length)`` into a private cache, scatter it into the page
-      row rank 0's planner allocated (or the dense slot), point the
+      row rank 0's planner allocated, point the
       slot's block table at the row, and arm the sampler state
       (token/budget/stop set/key chain/sampling params).  Prefix
       reuse needs no special case: rewriting a reused page lands the
@@ -379,7 +374,7 @@ class FollowerExecutor:
     def __init__(self, cfg, params, *, max_len: int = 512,
                  slots: int = 4, prefill_chunk: int = 512,
                  kv_pages: Optional[int] = None, page_size: int = 16,
-                 quantize_kv: bool = False, spec_tokens: int = 0,
+                 quantize_kv: bool = False,
                  max_top_k: int = 64, max_stop_ids: int = 16) -> None:
         import jax  # pylint: disable=import-outside-toplevel
         import jax.numpy as jnp  # pylint: disable=import-outside-toplevel
@@ -394,42 +389,31 @@ class FollowerExecutor:
         self._jnp = jnp
         self._sampler = sampler_lib.SlotSampler(int(max_top_k),
                                                 int(max_stop_ids))
-        self._paged = kv_pages is not None
         self._page_size = int(page_size)
         self._commands = 0
-        if self._paged:
-            kernel = paged_attention_lib.decode_kernel_choice()
-            self._step = jax.jit(
-                decode.bind(decode.paged_engine_step, cfg,
-                            max_top_k=int(max_top_k), kernel=kernel),
-                donate_argnums=(2,))
-            self._spec_step = jax.jit(
-                decode.bind(decode.paged_spec_engine_step, cfg,
-                            max_top_k=int(max_top_k), kernel=kernel),
-                donate_argnums=(2,))
-            self._admit_paged = jax.jit(decode.paged_admit_slot,
-                                        donate_argnums=(0,))
-            self._release_paged = jax.jit(decode.paged_release_slot,
-                                          donate_argnums=(0,))
-            self._insert_pages = jax.jit(
-                decode.insert_prefill_pages,
-                static_argnames=('first_page',), donate_argnums=(0,))
-            self._cache = decode.init_paged_cache(
-                cfg, int(kv_pages), self._page_size, int(slots),
-                self.max_len // self._page_size,
-                quantize_kv=bool(quantize_kv))
-        else:
-            if spec_tokens:
-                raise ValueError('spec_tokens requires the paged KV '
-                                 'engine (kv_pages)')
-            self._step = jax.jit(
-                decode.bind(decode.engine_step, cfg,
-                            max_top_k=int(max_top_k)),
-                donate_argnums=(2,))
-            self._insert = jax.jit(decode.insert_prefill,
-                                   donate_argnums=(0,))
-            self._cache = decode.init_slot_cache(cfg, int(slots),
-                                                 self.max_len)
+        kernel = paged_attention_lib.decode_kernel_choice()
+        self._step = jax.jit(
+            decode.bind(decode.paged_engine_step, cfg,
+                        max_top_k=int(max_top_k), kernel=kernel),
+            donate_argnums=(2,))
+        self._spec_step = jax.jit(
+            decode.bind(decode.paged_spec_engine_step, cfg,
+                        max_top_k=int(max_top_k), kernel=kernel),
+            donate_argnums=(2,))
+        self._admit_paged = jax.jit(decode.paged_admit_slot,
+                                    donate_argnums=(0,))
+        self._release_paged = jax.jit(decode.paged_release_slot,
+                                      donate_argnums=(0,))
+        self._insert_pages = jax.jit(
+            decode.insert_prefill_pages,
+            static_argnames=('first_page',), donate_argnums=(0,))
+        # The pool rank 0's engine builds from the same geometry.
+        self._cache = decode.init_paged_cache(
+            cfg, batching_engine_lib.PagedKVManager.pool_pages(
+                kv_pages, int(slots), self.max_len, self._page_size),
+            self._page_size, int(slots),
+            self.max_len // self._page_size,
+            quantize_kv=bool(quantize_kv))
         self._state = decode.init_engine_state(int(slots),
                                                int(max_stop_ids))
         self._prefill = jax.jit(
@@ -486,21 +470,12 @@ class FollowerExecutor:
         row = payload.get('row')
         if length > 0:
             pre = self._replay_prefill(prompt, length)
-            if self._paged:
-                n_pages = -(-length // self._page_size)
-                self._cache = self._insert_pages(
-                    self._cache, pre,
-                    np.asarray(row[:n_pages], np.int32), first_page=0)
-            else:
-                self._cache = self._insert(self._cache, slot, pre,
-                                           length)
-        if self._paged:
-            self._cache = self._admit_paged(
-                self._cache, slot, self._pad_row(row), length)
-        elif length == 0:
-            self._cache = dict(
-                self._cache,
-                lengths=self._cache['lengths'].at[slot].set(0))
+            n_pages = -(-length // self._page_size)
+            self._cache = self._insert_pages(
+                self._cache, pre,
+                np.asarray(row[:n_pages], np.int32), first_page=0)
+        self._cache = self._admit_paged(
+            self._cache, slot, self._pad_row(row), length)
         self._state = self._sampler.admit(
             self._state, slot, int(payload['token']),
             int(payload['remaining']),
@@ -528,9 +503,8 @@ class FollowerExecutor:
             if payload and 'prompt' in payload:
                 self._admit(payload)
         elif cmd.kind == coordinator_lib.CMD_RELEASE:
-            if self._paged:
-                self._cache = self._release_paged(self._cache,
-                                                  int(payload['slot']))
+            self._cache = self._release_paged(self._cache,
+                                              int(payload['slot']))
         # CMD_PREFILL: SP one-shot notification — the ADMIT replay
         # writes the same KV, nothing to mirror here.
 
@@ -549,52 +523,6 @@ def follower_main(rank: int, coordinator_address: str,
     coordinator_lib.follower_serve(sock, rank, executor)
 
 
-def _bench_prefill(args) -> None:
-    """--bench-prefill: time ONE sequence-parallel prefill at a given
-    host count (used by bench_serve.py's long-context scaling probe;
-    each invocation is its own process so CPU affinity can model
-    per-host compute)."""
-    import flax.linen as nn  # pylint: disable=import-outside-toplevel
-    import jax  # pylint: disable=import-outside-toplevel
-    import jax.numpy as jnp  # pylint: disable=import-outside-toplevel
-    import numpy as np  # pylint: disable=import-outside-toplevel
-
-    from skypilot_tpu.models import configs  # pylint: disable=import-outside-toplevel
-    from skypilot_tpu.models import decode  # pylint: disable=import-outside-toplevel
-    from skypilot_tpu.models.transformer import Transformer  # pylint: disable=import-outside-toplevel
-
-    cfg = configs.get_config(args.model)
-    params = nn.meta.unbox(Transformer(cfg).init(
-        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))['params'])
-    n = int(args.prompt_len)
-    sp = int(args.sequence or args.num_hosts)
-    width = -(-n // sp) * sp
-    max_len = width + 16
-    mesh = build_slice_mesh(args.num_hosts, cfg, sequence=sp)
-    rng = np.random.default_rng(0)
-    tokens = np.zeros((1, width), np.int32)
-    tokens[0, :n] = rng.integers(1, cfg.vocab_size - 1, size=n)
-    tokens = jnp.asarray(tokens)
-    fn = jax.jit(decode.bind(decode.prefill_sp, cfg, mesh=mesh,
-                             max_len=max_len))
-    cache = fn(params, tokens)             # compile
-    jax.block_until_ready(cache)
-    times = []
-    for _ in range(int(args.iters)):
-        t0 = time.perf_counter()
-        cache = fn(params, tokens)
-        jax.block_until_ready(cache)
-        times.append(time.perf_counter() - t0)
-    print(json.dumps({
-        'num_hosts': int(args.num_hosts),
-        'sequence': sp,
-        'tensor': int(mesh.shape.get('tensor', 1)),
-        'prompt_len': n,
-        'prefill_s': sorted(times)[len(times) // 2],
-        'prefill_s_all': [round(t, 6) for t in times],
-    }))
-
-
 def main() -> None:
     parser = argparse.ArgumentParser(description=__doc__)
     parser.add_argument('--num-hosts', type=int,
@@ -610,16 +538,9 @@ def main() -> None:
     parser.add_argument('--max-len', type=int, default=512)
     parser.add_argument('--max-batch', type=int, default=8)
     parser.add_argument('--prefill-chunk', type=int, default=512)
-    parser.add_argument('--bench-prefill', action='store_true')
-    parser.add_argument('--prompt-len', type=int, default=2048)
-    parser.add_argument('--sequence', type=int, default=None)
-    parser.add_argument('--iters', type=int, default=3)
     args, extra = parser.parse_known_args()
     from skypilot_tpu import compile_cache  # pylint: disable=import-outside-toplevel
     compile_cache.enable()
-    if args.bench_prefill:
-        _bench_prefill(args)
-        return
     if args.rank > 0:
         # Follower rank of a real slice: the rank-protocol port is the
         # JAX coordinator's + a fixed offset.  The executor mirrors
@@ -647,9 +568,7 @@ def main() -> None:
             page_size=int(os.environ.get('SKYTPU_SERVE_PAGE_SIZE',
                                          '16')),
             quantize_kv=os.environ.get('SKYTPU_SERVE_KV_INT8',
-                                       '') == '1',
-            spec_tokens=int(os.environ.get('SKYTPU_SERVE_SPEC_TOKENS',
-                                           '0')))
+                                       '') == '1')
         host, _, port = args.coordinator.rpartition(':')
         follower_main(args.rank,
                       f'{host}:{int(port) + SLICE_COORD_PORT_OFFSET}',

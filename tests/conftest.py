@@ -279,9 +279,7 @@ _SLOW_TESTS = {
     'test_decode.py': ('test_greedy_generation_parity',
                        'test_moe_greedy_generation_parity',
                        'test_family_variants_generation_parity',
-                       'test_prefill_logits_match_full_forward',
-                       'test_batched_step_matches_per_sequence_decode',
-                       'test_multi_step_generation_parity'),
+                       'test_prefill_logits_match_full_forward'),
     'test_chaos.py': ('test_elastic_expand_round_trip',
                       'test_replica_rank_death_full_rebuild'),
     'test_distributed_bootstrap.py': (

@@ -141,33 +141,6 @@ def test_train_step_runs_on_tpu():
     assert last < first
 
 
-def test_slot_batched_decode_on_tpu():
-    """Continuous batching's batched_step (per-slot depths, vmapped
-    cache writes) runs on hardware and matches single-sequence decode."""
-    import flax.linen as nn
-
-    from skypilot_tpu.models import configs, decode
-    from skypilot_tpu.models.transformer import Transformer
-    cfg = configs.get_config('tiny')
-    model = Transformer(cfg)
-    prompt = jax.random.randint(jax.random.PRNGKey(1), (1, 6), 0,
-                                cfg.vocab_size, dtype=jnp.int32)
-    params = nn.meta.unbox(model.init(jax.random.PRNGKey(0),
-                                      prompt)['params'])
-    logits, pre = decode.prefill(cfg, params, prompt, max_len=16)
-    ref, _ = decode.decode_step(
-        cfg, params, jnp.argmax(logits, axis=-1)[:, None], pre)
-    slot_cache = decode.init_slot_cache(cfg, slots=2, max_len=16)
-    slot_cache = decode.insert_prefill(slot_cache, 0, pre,
-                                       prompt.shape[1])
-    tokens = jnp.zeros((2, 1), jnp.int32).at[0, 0].set(
-        jnp.argmax(logits[0]))
-    got, _, _ = decode.batched_step(cfg, params, tokens, slot_cache)
-    np.testing.assert_allclose(np.asarray(got[0], np.float32),
-                               np.asarray(ref[0], np.float32),
-                               rtol=2e-2, atol=2e-2)
-
-
 def test_int8_decode_on_tpu():
     """Weight-only int8 decode (dequant fused into the matmul operand
     read) runs on hardware with close logits."""

@@ -133,75 +133,6 @@ def test_family_variants_generation_parity(preset):
     np.testing.assert_array_equal(np.asarray(tokens), np.asarray(ref))
 
 
-class TestSlotBatchedDecode:
-
-    def test_batched_step_matches_per_sequence_decode(self):
-        """Slots at different depths decoded in ONE step must match the
-        single-sequence decode path exactly."""
-        cfg = configs.get_config('tiny')
-        model = Transformer(cfg)
-        p1 = jax.random.randint(jax.random.PRNGKey(1), (1, 5), 0,
-                                cfg.vocab_size, dtype=jnp.int32)
-        p2 = jax.random.randint(jax.random.PRNGKey(2), (1, 9), 0,
-                                cfg.vocab_size, dtype=jnp.int32)
-        params = nn.meta.unbox(model.init(jax.random.PRNGKey(0),
-                                          p1)['params'])
-
-        logits1, cache1 = decode.prefill(cfg, params, p1, max_len=16)
-        logits2, cache2 = decode.prefill(cfg, params, p2, max_len=16)
-        t1 = jnp.argmax(logits1, axis=-1)[:, None]
-        t2 = jnp.argmax(logits2, axis=-1)[:, None]
-
-        # Reference: per-sequence decode_step.
-        ref1, _ = decode.decode_step(cfg, params, t1, cache1)
-        ref2, _ = decode.decode_step(cfg, params, t2, cache2)
-
-        # Slot pool: 3 slots, slot 2 left inactive.
-        slot_cache = decode.init_slot_cache(cfg, slots=3, max_len=16)
-        slot_cache = decode.insert_prefill(slot_cache, 0, cache1,
-                                           p1.shape[1])
-        slot_cache = decode.insert_prefill(slot_cache, 1, cache2,
-                                           p2.shape[1])
-        tokens = jnp.concatenate(
-            [t1, t2, jnp.zeros((1, 1), jnp.int32)], axis=0)
-        logits, new_cache, _ = decode.batched_step(cfg, params, tokens,
-                                                slot_cache)
-        np.testing.assert_allclose(np.asarray(logits[0]),
-                                   np.asarray(ref1[0]),
-                                   rtol=2e-4, atol=2e-4)
-        np.testing.assert_allclose(np.asarray(logits[1]),
-                                   np.asarray(ref2[0]),
-                                   rtol=2e-4, atol=2e-4)
-        assert list(np.asarray(new_cache['lengths'])[:2]) == [6, 10]
-
-    def test_multi_step_generation_parity(self):
-        """Greedy multi-token generation through the slot pool matches
-        decode.generate."""
-        cfg = configs.get_config('tiny')
-        model = Transformer(cfg)
-        prompt = jax.random.randint(jax.random.PRNGKey(3), (1, 6), 0,
-                                    cfg.vocab_size, dtype=jnp.int32)
-        params = nn.meta.unbox(model.init(jax.random.PRNGKey(0),
-                                          prompt)['params'])
-        _, ref_new = decode.generate(cfg, params, prompt,
-                                     max_new_tokens=5, max_len=32)
-
-        logits, pre = decode.prefill(cfg, params, prompt, max_len=32)
-        slot_cache = decode.init_slot_cache(cfg, slots=2, max_len=32)
-        slot_cache = decode.insert_prefill(slot_cache, 0, pre,
-                                           prompt.shape[1])
-        tok = jnp.argmax(logits, axis=-1)[0]
-        got = [int(tok)]
-        tokens = jnp.zeros((2, 1), jnp.int32).at[0, 0].set(tok)
-        for _ in range(4):
-            logits, slot_cache, _ = decode.batched_step(
-                cfg, params, tokens, slot_cache)
-            tok = jnp.argmax(logits[0], axis=-1)
-            got.append(int(tok))
-            tokens = tokens.at[0, 0].set(tok)
-        assert got == [int(t) for t in np.asarray(ref_new)[0]]
-
-
 class TestChunkedPrefill:
 
     def test_chunk_boundary_logits_match_full_prefill(self, setup):
@@ -289,13 +220,21 @@ class TestBatchedSampling:
 
 
 class TestEngineStep:
+    """The tick's tail (`_select_and_bookkeep`): freeze, countdown and
+    stop, on a pool of 4-token pages with slot 0 on pages 1-4."""
 
     def _setup_state(self, cfg, params, slots=2, max_len=16):
         prompt = jax.random.randint(jax.random.PRNGKey(9), (1, 4), 0,
                                     cfg.vocab_size, dtype=jnp.int32)
         logits, pre = decode.prefill(cfg, params, prompt, max_len=max_len)
-        cache = decode.init_slot_cache(cfg, slots, max_len)
-        cache = decode.insert_prefill(cache, 0, pre, prompt.shape[1])
+        ps = 4
+        rows = max_len // ps
+        cache = decode.init_paged_cache(cfg, slots * rows + 1, ps, slots,
+                                        rows)
+        row = np.arange(1, rows + 1, dtype=np.int32)
+        cache = decode.insert_prefill_pages(cache, pre, row[:1],
+                                            first_page=0)
+        cache = decode.paged_admit_slot(cache, 0, row, prompt.shape[1])
         state = decode.init_engine_state(slots)
         state = decode.admit_slot_state(
             state, 0, int(jnp.argmax(logits[0])), 3,
@@ -308,8 +247,8 @@ class TestEngineStep:
         state, cache = self._setup_state(cfg, params)
         before_tok = int(state['tokens'][1])
         before_len = int(cache['lengths'][1])
-        state, cache, finished, _ = decode.engine_step(cfg, params, state,
-                                                    cache)
+        state, cache, finished, _ = decode.paged_engine_step(
+            cfg, params, state, cache)
         assert bool(state['active'][0])
         assert not bool(state['active'][1])
         assert int(state['tokens'][1]) == before_tok
@@ -322,7 +261,7 @@ class TestEngineStep:
         state, cache = self._setup_state(cfg, params)
         fins = []
         for _ in range(4):
-            state, cache, finished, _ = decode.engine_step(
+            state, cache, finished, _ = decode.paged_engine_step(
                 cfg, params, state, cache)
             fins.append(bool(finished[0]))
         # remaining=3 -> exactly the third tick finishes the slot, and
@@ -335,12 +274,12 @@ class TestEngineStep:
         state, cache = self._setup_state(cfg, params)
         # Run one step to learn the next token, then rerun with that
         # token as a stop id: the step itself must flag fin.
-        probe_state, _, _, _ = decode.engine_step(
+        probe_state, _, _, _ = decode.paged_engine_step(
             cfg, params, dict(state),
             jax.tree.map(jnp.copy, cache))
         stop = int(probe_state['tokens'][0])
         state = dict(state, stop_ids=state['stop_ids'].at[0, 0].set(stop))
-        state, cache, finished, _ = decode.engine_step(cfg, params, state,
-                                                    cache)
+        state, cache, finished, _ = decode.paged_engine_step(
+            cfg, params, state, cache)
         assert bool(finished[0])
         assert not bool(state['active'][0])

@@ -522,11 +522,20 @@ class TestSpeculativeDecoding:
         finally:
             eng.stop()
 
-    def test_dense_engine_rejects_spec(self, setup):
+    def test_spec_on_the_derived_pool(self, setup):
+        """Speculation needs no `kv_pages`: on the pool the engine
+        derives, rejected drafts roll back through the null page and
+        the stream is the reference's."""
         cfg, params = setup
-        with pytest.raises(ValueError, match='paged KV'):
-            batching_engine.ContinuousBatchingEngine(cfg, params,
-                                                     spec_tokens=2)
+        eng = _engine(cfg, params, kernel='gather', kv_pages=None,
+                      spec_tokens=2)
+        try:
+            prompt = [3, 1, 4, 1, 5, 9, 2, 6] * 3
+            assert eng.generate(prompt, 12, timeout=300) == _reference(
+                cfg, params, prompt, 12)
+            assert eng.stats()['spec_ticks'] > 0
+        finally:
+            eng.stop()
 
     def test_negative_spec_tokens_rejected(self, setup):
         cfg, params = setup

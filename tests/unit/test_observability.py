@@ -319,33 +319,23 @@ def test_every_jitted_entry_is_a_named_function(tiny_paged_engine):
     name `benchmarks/layers/paged_attn_roofline.py` finds it by."""
     import functools
 
-    from skypilot_tpu.serve import batching_engine
-    eng = tiny_paged_engine
-    dense = batching_engine.ContinuousBatchingEngine(
-        eng.cfg, eng.params, max_len=64, slots=2)
-    try:
-        names = {}
-        for engine in (eng, dense):
-            for attr, entry in vars(engine).items():
-                jitted = getattr(entry, '__wrapped__', None)
-                if not hasattr(jitted, 'lower'):
-                    continue                  # not a sentinel-wrapped jit
-                fn = jitted.__wrapped__
-                assert not isinstance(fn, functools.partial), attr
-                assert fn.__name__ not in ('<lambda>', 'call'), attr
-                names[(engine is eng, attr)] = jitted.__name__
-    finally:
-        dense.stop()
-    assert names[(True, '_step')] == 'paged_engine_step'
-    assert names[(True, '_spec_step')] == 'paged_spec_engine_step'
-    assert names[(True, '_prefill')] == 'prefill'
-    assert names[(True, '_prefill_chunk')] == 'prefill_chunk'
-    assert names[(True, '_seed_private')] == 'paged_seed_private'
-    assert names[(True, '_insert_pages')] == 'insert_prefill_pages'
-    assert names[(True, '_admit_paged')] == 'paged_admit_slot'
-    assert names[(False, '_step')] == 'engine_step'
-    assert names[(False, '_legacy_step')] == 'batched_step'
-    assert len(names) >= 16
+    names = {}
+    for attr, entry in vars(tiny_paged_engine).items():
+        jitted = getattr(entry, '__wrapped__', None)
+        if not hasattr(jitted, 'lower'):
+            continue                  # not a sentinel-wrapped jit
+        fn = jitted.__wrapped__
+        assert not isinstance(fn, functools.partial), attr
+        assert fn.__name__ not in ('<lambda>', 'call'), attr
+        names[attr] = jitted.__name__
+    assert names['_step'] == 'paged_engine_step'
+    assert names['_spec_step'] == 'paged_spec_engine_step'
+    assert names['_prefill'] == 'prefill'
+    assert names['_prefill_chunk'] == 'prefill_chunk'
+    assert names['_seed_private'] == 'paged_seed_private'
+    assert names['_insert_pages'] == 'insert_prefill_pages'
+    assert names['_admit_paged'] == 'paged_admit_slot'
+    assert len(names) == 10
 
 
 def test_stats_tick_loop_counts_the_loop(tiny_paged_engine):

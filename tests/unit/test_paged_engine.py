@@ -1,5 +1,5 @@
-"""Paged-KV engine tests: paged/dense logits parity (greedy + sampled,
-native + int8 pages), chunked prefill across page boundaries, prefix
+"""Paged-KV engine tests: parity with `decode.generate` (greedy +
+sampled, native + int8 pages), the pool `kv_pages=None` derives, chunked prefill across page boundaries, prefix
 reuse with mid-page divergence, pool exhaustion -> 429 backpressure,
 and no page leaks across completion/cancel/TTL.
 
@@ -67,9 +67,8 @@ def int8_engine(setup):
 
 class TestPagedParity:
 
-    def test_greedy_parity_vs_dense_generate(self, setup,
-                                             plain_engine):
-        """Greedy decode through the page pool must match the dense
+    def test_greedy_parity_vs_generate(self, setup, plain_engine):
+        """Greedy decode through the page pool must match the
         single-sequence reference token-for-token (same masked
         attention over the same values, gathered by page index)."""
         cfg, params = setup
@@ -81,7 +80,7 @@ class TestPagedParity:
             assert got == _reference(cfg, params, prompt, n), prompt
 
     def test_greedy_parity_int8_kv(self, setup, int8_engine):
-        """int8 pages must still agree with the dense reference on the
+        """int8 pages must still agree with the reference on the
         tiny config's logit margins (the acceptance pin)."""
         cfg, params = setup
         for prompt, n in (([3, 1, 4, 1, 5, 9, 2, 6], 6),
@@ -99,12 +98,12 @@ class TestPagedParity:
         for (p, n), got in zip(prompts, results):
             assert got == _reference(cfg, params, p, n), (p, n)
 
-    def test_sampled_parity_vs_dense_engine(self, setup, plain_engine):
-        """Temperature sampling depends only on (logits, key chain);
-        paged at a given seed must match the dense single-sequence
-        path — sampled-path parity for the page gather.  (The dense
-        engine's row-parity vs decode.generate's sampling is pinned in
-        test_batching_engine; generate() is the shared reference.)"""
+    def test_sampled_parity_vs_generate(self, setup, plain_engine):
+        """Temperature sampling depends only on (logits, key chain):
+        the same seed gives the same stream through the pages, and
+        greedy through the sampling path matches generate().  (Row
+        parity with decode.generate's sampling is pinned in
+        test_batching_engine.)"""
         cfg, params = setup
         sampling = decode.SamplingConfig(temperature=0.8, top_k=10,
                                          seed=123)
@@ -288,7 +287,7 @@ class TestPoolInPlace:
                                       len(written))
 
     @pytest.mark.parametrize('step', ['paged-gather', 'paged-pallas',
-                                      'paged-int8', 'verify', 'dense'])
+                                      'paged-int8', 'verify'])
     def test_caches_ride_the_layer_loop_as_its_carry(self, setup, step):
         """Structural, on the traced program: no scanned input or
         output of a loop in the tick has a cache's rank-5 shape or a
@@ -298,19 +297,15 @@ class TestPoolInPlace:
         cfg, params = setup
         slots = 3
         state = decode.init_engine_state(slots)
-        if step == 'dense':
-            cache = decode.init_slot_cache(cfg, slots, 32)
-            fn = lambda s, c: decode.engine_step(cfg, params, s, c)
+        cache = decode.init_paged_cache(
+            cfg, 16, 4, slots, 6, quantize_kv=step == 'paged-int8')
+        kernel = 'pallas' if step == 'paged-pallas' else 'gather'
+        if step == 'verify':
+            fn = lambda s, c: decode.paged_spec_engine_step(
+                cfg, params, s, c, jnp.zeros((slots, 3), jnp.int32))
         else:
-            cache = decode.init_paged_cache(
-                cfg, 16, 4, slots, 6, quantize_kv=step == 'paged-int8')
-            kernel = 'pallas' if step == 'paged-pallas' else 'gather'
-            if step == 'verify':
-                fn = lambda s, c: decode.paged_spec_engine_step(
-                    cfg, params, s, c, jnp.zeros((slots, 3), jnp.int32))
-            else:
-                fn = lambda s, c: decode.paged_engine_step(
-                    cfg, params, s, c, kernel=kernel)
+            fn = lambda s, c: decode.paged_engine_step(
+                cfg, params, s, c, kernel=kernel)
         cache_shapes = {a.shape for a in jax.tree.leaves(
             {'k': cache['k'], 'v': cache['v']})}
         shares = {s[1:] for s in cache_shapes} | {
@@ -587,23 +582,79 @@ class TestPoolAccounting:
                                                            p, 4)
         finally:
             eng.stop()
-        # The slots' own caches (no pool): every slot may prefill.
-        dense = batching_engine.ContinuousBatchingEngine(
+        # The derived pool (slots x max_len): every slot may prefill.
+        derived = batching_engine.ContinuousBatchingEngine(
             cfg, params, max_len=64, slots=3)
         try:
-            assert dense._max_prefills == 3
+            assert derived._max_prefills == 3
         finally:
-            dense.stop()
+            derived.stop()
 
-    def test_validation(self, setup):
+    @pytest.mark.parametrize('kv_pages', [16, None])
+    def test_validation(self, setup, kv_pages):
+        """Private prefill caches scatter whole pages: `max_len` is a
+        multiple of `page_size`, whether the pool is given or derived."""
         cfg, params = setup
         with pytest.raises(ValueError, match='multiple'):
             batching_engine.ContinuousBatchingEngine(
-                cfg, params, max_len=60, kv_pages=16, page_size=8)
-        with pytest.raises(ValueError, match='pipelined'):
-            batching_engine.ContinuousBatchingEngine(
-                cfg, params, max_len=64, kv_pages=16, page_size=8,
-                pipelined=False)
+                cfg, params, max_len=60, kv_pages=kv_pages, page_size=8)
+
+
+class TestDerivedPool:
+    """`kv_pages=None` is a pool size worked out from the geometry,
+    not another cache: what every slot needs to hold `max_len` at
+    once, and the reserved null page."""
+
+    @pytest.mark.parametrize('slots,max_len,page_size,pages', [
+        (1, 32, 16, 2), (3, 64, 8, 24), (4, 64, 64, 4)])
+    def test_pool_size_from_the_geometry(self, setup, slots, max_len,
+                                         page_size, pages):
+        cfg, params = setup
+        assert cache_manager.PagedKVManager.pool_pages(
+            None, slots, max_len, page_size) == pages + 1
+        assert cache_manager.PagedKVManager.pool_pages(
+            7, slots, max_len, page_size) == 7
+        eng = batching_engine.ContinuousBatchingEngine(
+            cfg, params, max_len=max_len, slots=slots,
+            page_size=page_size)
+        try:
+            stats = eng.stats()
+            assert stats['kv_pages_total'] == pages
+            assert stats['page_size'] == page_size
+            assert eng._cache['k'].shape[1] == pages + 1
+            assert eng._cache['block_tables'].shape == (
+                slots, max_len // page_size)
+            assert eng._max_prefills == slots
+        finally:
+            eng.stop()
+
+    def test_every_slot_admits_max_len_at_once(self, setup):
+        """What the slot cache guaranteed: requests of `max_len` on
+        every slot at the same time, none refused for pages, and the
+        pool drained when they are done."""
+        cfg, params = setup
+        slots, max_len = 3, 64
+        eng = batching_engine.ContinuousBatchingEngine(
+            cfg, params, max_len=max_len, slots=slots, page_size=8,
+            prefill_chunk=8, prefix_caching=False)
+        try:
+            prompts = [list(range(k, k + 12)) for k in (1, 41, 81)]
+            handles = [eng.submit(p, max_len - len(p)) for p in prompts]
+            deadline = time.time() + 120
+            stats = eng.stats()
+            while stats['busy_slots'] < slots and time.time() < deadline:
+                time.sleep(0.01)
+                stats = eng.stats()
+            assert stats['busy_slots'] == slots
+            assert stats['kv_pages_used'] == stats['kv_pages_total']
+            for p, h in zip(prompts, handles):
+                assert h.result(timeout=180) == _reference(
+                    cfg, params, p, max_len - len(p), max_len=max_len)
+            stats = eng.stats()
+            assert stats['pages_exhausted_deferrals'] == 0
+            assert stats['kv_pages_used'] == 0
+        finally:
+            eng.stop()
 
 
 class TestStatsAndMetrics:
@@ -611,7 +662,6 @@ class TestStatsAndMetrics:
     def test_paged_stats_and_gauges(self, setup, plain_engine):
         from skypilot_tpu.observability import metrics as metrics_lib
         stats = plain_engine.stats()
-        assert stats['paged'] is True
         assert stats['kv_pages_total'] == 47
         assert stats['page_size'] == 8
         assert stats['prefix_cache_misses'] >= 0
@@ -625,20 +675,6 @@ class TestStatsAndMetrics:
         parsed = metrics_lib.parse_exposition(text)
         assert sum(parsed['skytpu_engine_kv_pages_total']
                    .values()) == 47
-
-    def test_dense_engine_unaffected(self, setup):
-        """A dense engine reports paged=False and no page keys —
-        the facade split must not change the dense contract."""
-        cfg, params = setup
-        eng = batching_engine.ContinuousBatchingEngine(
-            cfg, params, max_len=32, slots=1)
-        try:
-            stats = eng.stats()
-            assert stats['paged'] is False
-            assert 'kv_pages_total' not in stats
-        finally:
-            eng.stop()
-
 
 class TestFacadeCompat:
 
@@ -654,5 +690,5 @@ class TestFacadeCompat:
         assert batching_engine._PendingPrefill is scheduler.PendingPrefill  # pylint: disable=protected-access
         assert batching_engine.PagesExhausted is (
             cache_manager.PagesExhausted)
-        assert sampler.validate_sampling(None, max_top_k=4,
-                                         pipelined=True) == (0.0, 0, 0)
+        assert sampler.validate_sampling(None,
+                                         max_top_k=4) == (0.0, 0, 0)

@@ -514,7 +514,6 @@ class TestProfileEndpoint:
         # Steady-state must be clean on a well-behaved run.
         assert prof['recompiles']['steady_recompiles_total'] == 0
         assert 'step' in prof['recompiles']['fns']
-        assert prof['pipelined'] is True
 
     def test_threaded_front(self, profiled_server):
         port, shutdown = model_server.start_background(profiled_server)

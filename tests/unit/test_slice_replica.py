@@ -621,8 +621,8 @@ class TestFollowerExecutor:
     def _run(self, tiny, spec_tokens):
         import numpy as np
         cfg, params = tiny
-        follower = slice_replica.FollowerExecutor(
-            cfg, params, spec_tokens=spec_tokens, **self.GEOM)
+        follower = slice_replica.FollowerExecutor(cfg, params,
+                                                  **self.GEOM)
         chan = coordinator_lib.LocalRank(1, follower)
         eng = slice_replica.SliceReplicaEngine(
             cfg, params, num_hosts=2, rank_channels=[chan],
@@ -663,6 +663,40 @@ class TestFollowerExecutor:
         non-spec slice."""
         assert self._run(tiny, spec_tokens=0) == \
             self._run(tiny, spec_tokens=3)
+
+    def test_without_kv_pages_both_ranks_derive_the_pool(self, tiny):
+        """No `kv_pages`: rank 0 and a follower work the same pool out
+        of the geometry, the slice runs the paged tick, and the
+        follower's replay lands in rank 0's pages."""
+        import jax.numpy as jnp
+        import numpy as np
+
+        from skypilot_tpu.models import decode
+        cfg, params = tiny
+        geom = {k: v for k, v in self.GEOM.items() if k != 'kv_pages'}
+        follower = slice_replica.FollowerExecutor(cfg, params, **geom)
+        chan = coordinator_lib.LocalRank(1, follower)
+        eng = slice_replica.SliceReplicaEngine(
+            cfg, params, num_hosts=2, rank_channels=[chan], **geom)
+        prompt = [3, 1, 4, 1, 5, 9, 2, 6]
+        try:
+            got = eng.generate(prompt, 6, timeout=300)
+            stats = eng.stats()
+            assert stats['kv_pages_total'] == (
+                geom['slots'] * geom['max_len'] // geom['page_size'])
+            assert stats['kv_pages_used'] == stats['prefix_cache_entries']
+            assert eng._cache['k'].shape == follower._cache['k'].shape
+            assert eng._step.__wrapped__.__name__ == 'paged_engine_step'
+            diff = np.abs(
+                np.asarray(eng._cache['k'], np.float32) -
+                np.asarray(follower._cache['k'], np.float32)).max()
+            assert diff < 1e-3, diff
+        finally:
+            eng.stop()
+        _, want = decode.generate(
+            cfg, params, jnp.asarray([prompt], jnp.int32),
+            max_new_tokens=6, max_len=geom['max_len'])
+        assert got == [int(t) for t in np.asarray(want)[0]]
 
     def test_follower_release_parks_tables(self, tiny):
         import numpy as np
