@@ -30,6 +30,7 @@ import jax.numpy as jnp
 from skypilot_tpu.models import heads
 from skypilot_tpu.models import moe
 from skypilot_tpu.models.configs import ModelConfig
+from skypilot_tpu.models.quantize import is_quantized_leaf
 from skypilot_tpu.models.quantize import maybe_dequant
 from skypilot_tpu.models.transformer import _rope
 from skypilot_tpu.ops import paged_attention as paged_attention_ops
@@ -98,15 +99,116 @@ def _layer_params(params: Dict[str, Any], cfg: ModelConfig):
     return stacked
 
 
-def _attn_proj(x, proj):
-    """[b, s, d_model] x [d_model, heads, hd] -> [b, heads, s, hd].
-    `proj` is the q/k/v param dict; bias present iff cfg.qkv_bias."""
-    out = jnp.einsum('bsd,dhk->bhsk', x,
-                     maybe_dequant(proj['kernel'], x.dtype))
+def _attn_proj(x, proj, heads: int, head_dim: int):
+    """[b, s, d_model] x the q/k/v kernel -> [b, heads, s, hd].
+    `proj` is the q/k/v param dict; bias present iff cfg.qkv_bias.
+
+    The kernel comes in one of two forms, told by its rank: the serving
+    form [d_model, heads * hd] (`serving_params`), which is what the
+    product reads, or the training form [d_model, heads, hd].  Under
+    the layer scan the TPU compiler fuses the slice of layer l of a
+    stacked [L, d_model, heads * hd] into the product; out of a stacked
+    [L, d_model, heads, hd], tiled over (heads, hd), it copies the
+    layer's kernel first and the product reads the copy
+    (tests/unit/test_tpu_compile.py holds both)."""
+    kernel = maybe_dequant(proj['kernel'], x.dtype)
     bias = proj.get('bias')
-    if bias is not None:  # [heads, hd] -> broadcast over [b, ., s, .]
-        out = out + bias.astype(x.dtype)[None, :, None, :]
+    if kernel.ndim == 3:
+        out = jnp.einsum('bsd,dhk->bhsk', x, kernel)
+        if bias is not None:  # [heads, hd] over [b, ., s, .]
+            out = out + bias.astype(x.dtype)[None, :, None, :]
+        return out
+    out = jnp.einsum('bsd,df->bsf', x, kernel)
+    if bias is not None:      # [heads * hd]
+        out = out + bias.astype(x.dtype)
+    # The product ends here.  Left to itself the compiler moves the
+    # split of the flat axis into the product's kernel operand, which
+    # is the training form again: the layer's kernel sliced out of the
+    # stack and transposed, two copies a projection where there was one.
+    out = jax.lax.optimization_barrier(out)
+    b, s, _ = out.shape
+    return out.reshape(b, s, heads, head_dim).transpose(0, 2, 1, 3)
+
+
+def _qkv_projs(cfg: ModelConfig, params) -> Dict[Tuple[str, ...], Any]:
+    """{path: the q/k/v param dict at it} over every group of layers:
+    the one stacked group of the scanned layout, or each `layer_{i}`."""
+    groups = ((('layers', 'layer'),) if cfg.scan_layers else
+              tuple((f'layer_{i}',) for i in range(cfg.n_layers)))
+    out = {}
+    for group in groups:
+        attn = params
+        for key in group + ('attn',):
+            attn = attn[key]
+        for name in ('q_proj', 'k_proj', 'v_proj'):
+            out[group + ('attn', name)] = attn[name]
     return out
+
+
+def _in_serving_form(cfg: ModelConfig, proj) -> bool:
+    """Whether a q/k/v param dict holds its kernel as [.., d_model,
+    heads * hd]: one axis short of the training layout's."""
+    kernel = proj['kernel']
+    if is_quantized_leaf(kernel):
+        kernel = kernel['qvalue']
+    return kernel.ndim == 2 + bool(cfg.scan_layers)
+
+
+def _merged_sharding(leaf):
+    """The placement of `leaf` once its two last axes are one: heads
+    over a mesh axis become the flat axis over it, the same bytes on
+    the same device.  None (the compiler's choice) for a leaf that is
+    not placed over a mesh, or whose last axis is split."""
+    sharding = getattr(leaf, 'sharding', None)
+    if not isinstance(sharding, jax.sharding.NamedSharding):
+        return None
+    spec = tuple(sharding.spec) + (None,) * (leaf.ndim -
+                                             len(sharding.spec))
+    if spec[-1] is not None:
+        return None
+    return jax.sharding.NamedSharding(
+        sharding.mesh, jax.sharding.PartitionSpec(*spec[:-1]))
+
+
+def serving_params(cfg: ModelConfig, params):
+    """`params` with every layer's q/k/v projection in the form the
+    serving programs' product reads: kernel [.., d_model, heads, hd] ->
+    [.., d_model, heads * hd], its bias [.., heads, hd] ->
+    [.., heads * hd], an int8 kernel's `qvalue` and `scale` each by the
+    same merge of the two last axes; the scanned layout and the
+    unscanned one alike.  Every other leaf is `params`' own, and
+    `params` stays as it was: nothing is donated, a caller goes on
+    running `generate` or a reference on its tree, and while it keeps
+    the tree both forms of these kernels are held.  A sharded leaf
+    keeps its placement.  A tree already in this form comes back as it
+    is."""
+    picked = {path: proj for path, proj in _qkv_projs(cfg, params).items()
+              if not _in_serving_form(cfg, proj)}
+    if not picked:
+        return params
+    merged = jax.jit(
+        lambda tree: jax.tree.map(
+            lambda leaf: leaf.reshape(leaf.shape[:-2] + (-1,)), tree),
+        out_shardings=jax.tree.map(_merged_sharding, picked))(picked)
+    for path, proj in merged.items():
+        params = _with_node(params, path, proj)
+    return params
+
+
+def _with_node(tree, path: Tuple[str, ...], node):
+    """`tree` with `node` at `path`: the dicts along the path are new,
+    everything beside it is `tree`'s own."""
+    if not path:
+        return node
+    return {**tree, path[0]: _with_node(tree[path[0]], path[1:], node)}
+
+
+def serving_form_bytes(cfg: ModelConfig, params) -> int:
+    """Bytes of `params`' q/k/v projections held in the serving form
+    (`serving_params`); 0 for a training-layout tree."""
+    return sum(
+        leaf.nbytes for proj in _qkv_projs(cfg, params).values()
+        if _in_serving_form(cfg, proj) for leaf in jax.tree.leaves(proj))
 
 
 def _mlp(x, lp, cfg, row_mask=None):
@@ -175,7 +277,7 @@ def _layer_forward(x, lp, cfg, positions, k_cache, v_cache,
     query at position p of a window layer sees keys p - window + 1 .. p.
     """
     h = _norm(x, lp['attn_norm']['scale'], cfg)
-    q = _attn_proj(h, lp['attn']['q_proj'])
+    q = _attn_proj(h, lp['attn']['q_proj'], cfg.n_heads, cfg.head_dim)
     q = _rope_if(rope_on, q, positions, cfg)
 
     if isinstance(k_cache, _PagedView):
@@ -328,8 +430,10 @@ def _scan_layers_and_unembed(cfg, params, x, positions, cache_k, cache_v,
         lp, l = layer_state[:2]
         rope_on, window = layer_state[2:] or (None, None)
         h = _norm(x, lp['attn_norm']['scale'], cfg)
-        k = _attn_proj(h, lp['attn']['k_proj'])
-        v = _attn_proj(h, lp['attn']['v_proj'])
+        k = _attn_proj(h, lp['attn']['k_proj'], cfg.n_kv_heads,
+                       cfg.head_dim)
+        v = _attn_proj(h, lp['attn']['v_proj'], cfg.n_kv_heads,
+                       cfg.head_dim)
         k = _rope_if(rope_on, k, positions, cfg)
         with jax.named_scope('kv_write'):
             k_cache = write_fn(k_cache, l, k)
@@ -447,9 +551,12 @@ def prefill_sp(cfg: ModelConfig, params, tokens, *, mesh, max_len: int,
 
     def body(x, lp):
         h = _norm(x, lp['attn_norm']['scale'], cfg)
-        q = _rope(_attn_proj(h, lp['attn']['q_proj']), positions, cfg)
-        k = _rope(_attn_proj(h, lp['attn']['k_proj']), positions, cfg)
-        v = _attn_proj(h, lp['attn']['v_proj'])
+        q = _rope(_attn_proj(h, lp['attn']['q_proj'], cfg.n_heads,
+                             cfg.head_dim), positions, cfg)
+        k = _rope(_attn_proj(h, lp['attn']['k_proj'], cfg.n_kv_heads,
+                             cfg.head_dim), positions, cfg)
+        v = _attn_proj(h, lp['attn']['v_proj'], cfg.n_kv_heads,
+                       cfg.head_dim)
         out = ring_attention(q, k, v, mesh=mesh, axis_name=axis_name,
                              causal=True,
                              sm_scale=cfg.head_dim ** -0.5)

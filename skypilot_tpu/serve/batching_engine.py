@@ -240,7 +240,11 @@ class ContinuousBatchingEngine:
         from skypilot_tpu.ops import paged_attention as paged_attention_lib
 
         self.cfg = cfg
-        self.params = params
+        # The engine's own view of the weights: the caller's tree with
+        # the q/k/v projection kernels in the form the layer scan's
+        # product reads (`decode.serving_params`; every other leaf is
+        # shared, the caller's tree is left as it was).
+        self.params = decode.serving_params(cfg, params)
         # Live weight swap (POST /weights_swap): bumped by swap_params()
         # ON THE WORKER THREAD between ticks; read anywhere (int loads
         # are atomic under the GIL).  Epoch 0 = the params the engine
@@ -828,12 +832,16 @@ class ContinuousBatchingEngine:
 
         Callers are responsible for device placement (the server
         restores the checkpoint with the engine's shardings before
-        calling); this method only performs the epoch-ordered
+        calling) and pass the training layout, as to the constructor;
+        the q/k/v kernels are re-formed here, on the caller's thread
+        before the op is queued, so the pause stays the epoch-ordered
         assignment."""
         if self._stop.is_set() or self._failed is not None:
             raise RuntimeError('batching engine is stopped'
                                if self._failed is None else
                                f'batching engine failed: {self._failed}')
+        from skypilot_tpu.models import decode  # pylint: disable=import-outside-toplevel
+        new_params = decode.serving_params(self.cfg, new_params)
         holder: Dict[str, Any] = {}
         done = threading.Event()
 
@@ -909,6 +917,7 @@ class ContinuousBatchingEngine:
         summed over the layers, a window layer's being those that hold
         its last `sliding_window` keys).  Expert models add `moe`: the
         expert layers' counts summed over ticks and layers."""
+        from skypilot_tpu.models import decode  # pylint: disable=import-outside-toplevel
         busy = sum(1 for s in self._slots if s.active)
         with self._metrics_lock:
             stats = {
@@ -922,6 +931,11 @@ class ContinuousBatchingEngine:
                 'decode_kernel': self.decode_kernel,
                 'spec_tokens': self.spec_tokens,
                 'weight_epoch': self._weight_epoch,
+                # Bytes of q/k/v projection kernels (and biases) held
+                # in the serving form; 0: the layer scan copies each
+                # layer's kernels out of the stack before the product.
+                'weights': {'reformed_bytes': decode.serving_form_bytes(
+                    self.cfg, self.params)},
             }
             if self.spec_tokens:
                 stats['spec_ticks'] = self._spec_ticks

@@ -383,7 +383,9 @@ class FollowerExecutor:
         from skypilot_tpu.ops import paged_attention as paged_attention_lib  # pylint: disable=import-outside-toplevel
         from skypilot_tpu.serve import sampler as sampler_lib  # pylint: disable=import-outside-toplevel
         self.cfg = cfg
-        self.params = params
+        # The leader's engine re-forms the q/k/v kernels it is given;
+        # so does its follower, and both lower one program.
+        self.params = decode.serving_params(cfg, params)
         self.max_len = int(max_len)
         self.prefill_chunk = int(prefill_chunk)
         self._jnp = jnp

@@ -621,3 +621,88 @@ def test_request_finish_is_idempotent():
     req._push(99)
     assert list(req.stream(timeout=1)) == [42]
     assert req.tokens == [42]
+
+
+# --------------------------------------------- the engine's own weights
+
+
+def _family(case):
+    """-> (cfg, the caller's tree, engine keywords) of one case."""
+    preset = {'qkv-bias': 'tiny-qwen', 'tied': 'tiny-gemma',
+              'experts': 'tiny-moe'}.get(case, 'tiny')
+    cfg = configs.get_config(preset)
+    if case == 'unscanned':
+        cfg = cfg.replace(scan_layers=False)
+    params = nn.meta.unbox(Transformer(cfg).init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))['params'])
+    if case == 'int8':
+        from skypilot_tpu.models import quantize
+        params = jax.device_put(quantize.quantize_params(params))
+    return cfg, params, ({'spec_tokens': 3} if case == 'speculative'
+                         else {})
+
+
+@pytest.mark.parametrize('case', ['gqa', 'qkv-bias', 'tied', 'unscanned',
+                                  'experts', 'int8', 'speculative'])
+def test_engine_serves_the_callers_tree_on_its_own_kernels(case):
+    """The engine re-forms the q/k/v kernels it is given
+    (`decode.serving_params`) and serves what `decode.generate` gives
+    on the caller's tree, which stays whole and readable after the
+    engine is built and after it is stopped: nothing is donated, and
+    the benchmark runs its reference on that tree once the engine is
+    gone.  `stats()['weights']['reformed_bytes']` is what the engine
+    holds in re-formed leaves."""
+    cfg, params, kw = _family(case)
+    shapes = jax.tree.map(lambda leaf: leaf.shape, params)
+    prompts = [[3, 1, 4, 1, 5, 9, 2, 6], [2, 7, 1, 8, 2, 8, 1, 8, 2, 8]]
+    eng = batching_engine.ContinuousBatchingEngine(
+        cfg, params, max_len=64, slots=2, prefill_chunk=4, **kw)
+    try:
+        requests = [eng.submit(p, 6) for p in prompts]
+        got = [r.result(timeout=240) for r in requests]
+        qkv = decode._qkv_projs(cfg, params)  # pylint: disable=protected-access
+        held = decode._qkv_projs(cfg, eng.params)  # pylint: disable=protected-access
+        for path, proj in qkv.items():
+            for mine, theirs in zip(jax.tree.leaves(proj),
+                                    jax.tree.leaves(held[path])):
+                assert theirs.shape == mine.shape[:-2] + (
+                    mine.shape[-2] * mine.shape[-1],)
+        assert eng.stats()['weights']['reformed_bytes'] == sum(
+            leaf.nbytes for leaf in jax.tree.leaves(qkv)) > 0
+        if case == 'speculative':
+            assert eng.stats()['spec_ticks'] > 0
+    finally:
+        eng.stop()
+    assert jax.tree.map(lambda leaf: leaf.shape, params) == shapes
+    assert not any(leaf.is_deleted() for leaf in jax.tree.leaves(params))
+    for prompt, tokens in zip(prompts, got):
+        assert tokens == _reference(cfg, params, prompt, 6), case
+
+
+@pytest.mark.parametrize('case', ['gqa', 'qkv-bias', 'int8'])
+def test_swap_params_takes_the_training_layout(case):
+    """`swap_params` is given what the constructor is given, the
+    training layout (`/weights_swap` restores a checkpoint), and the
+    engine serves the new weights on kernels it re-formed itself."""
+    cfg, first, _ = _family(case)
+    second = jax.tree.map(
+        lambda leaf: (leaf * 1.5).astype(leaf.dtype)
+        if jnp.issubdtype(leaf.dtype, jnp.floating) else leaf, first)
+    prompt = [3, 1, 4, 1, 5, 9, 2, 6]
+    want = [_reference(cfg, tree, prompt, 6) for tree in (first, second)]
+    assert want[0] != want[1]
+    eng = batching_engine.ContinuousBatchingEngine(
+        cfg, first, max_len=64, slots=2)
+    try:
+        assert eng.generate(prompt, 6, timeout=240) == want[0]
+        held = eng.stats()['weights']['reformed_bytes']
+        assert eng.swap_params(second) == 1
+        assert eng.generate(prompt, 6, timeout=240) == want[1]
+        assert eng.stats()['weights']['reformed_bytes'] == held > 0
+        # A tree that is in the serving form already passes as it is.
+        assert eng.swap_params(eng.params) == 2
+        assert eng.generate(prompt, 6, timeout=240) == want[1]
+    finally:
+        eng.stop()
+    assert not any(leaf.is_deleted()
+                   for leaf in jax.tree.leaves((first, second)))

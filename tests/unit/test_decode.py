@@ -283,3 +283,134 @@ class TestEngineStep:
             cfg, params, state, cache)
         assert bool(finished[0])
         assert not bool(state['active'][0])
+
+
+class TestServingForm:
+    """The serving programs read q/k/v kernels held as
+    [.., d_model, heads * hd] (`decode.serving_params`); `generate`,
+    tests and the benchmark's callers keep the training layout.  One
+    helper, `_attn_proj`, takes both, told by the kernel's rank."""
+
+    @pytest.mark.parametrize('bias', [False, True],
+                             ids=['no-bias', 'bias'])
+    @pytest.mark.parametrize('heads', [4, 2], ids=['q', 'kv'])
+    @pytest.mark.parametrize('dtype', ['bfloat16', 'float32'])
+    def test_attn_proj_flat_equals_three_dimensional(self, dtype, heads,
+                                                     bias):
+        """The same products on the same operands.  In bfloat16, the
+        dtype every deployment serves in, the two forms agree to the
+        bit; in float32 the CPU backend orders the partial sums of the
+        two contractions differently, so they agree to a few units in
+        the last place."""
+        d, hd = 64, 16
+        keys = jax.random.split(jax.random.PRNGKey(3), 3)
+        x = jax.random.normal(keys[0], (2, 5, d), dtype)
+        proj = {'kernel': jax.random.normal(keys[1], (d, heads, hd),
+                                            dtype)}
+        if bias:
+            proj['bias'] = jax.random.normal(keys[2], (heads, hd), dtype)
+        flat = jax.tree.map(
+            lambda leaf: leaf.reshape(leaf.shape[:-2] + (-1,)), proj)
+        want = np.asarray(decode._attn_proj(x, proj, heads, hd),
+                          np.float32)
+        got = np.asarray(decode._attn_proj(x, flat, heads, hd),
+                         np.float32)
+        assert got.shape == (2, heads, 5, hd)
+        if dtype == 'bfloat16':
+            np.testing.assert_array_equal(got, want)
+        else:
+            np.testing.assert_allclose(got, want, rtol=2e-6, atol=2e-5)
+
+    @staticmethod
+    def _tree(preset, scan_layers, quantized):
+        cfg = configs.get_config(preset).replace(scan_layers=scan_layers)
+        prompt = jnp.asarray([[3, 1, 4, 1, 5, 9, 2, 6]], jnp.int32)
+        params = nn.meta.unbox(Transformer(cfg).init(
+            jax.random.PRNGKey(0), prompt)['params'])
+        if quantized:
+            from skypilot_tpu.models import quantize
+            params = jax.device_put(quantize.quantize_params(params))
+        return cfg, params, prompt
+
+    @pytest.mark.parametrize('preset,scan_layers,quantized', [
+        ('tiny', True, False), ('tiny', False, False),
+        ('tiny-qwen', True, False), ('tiny-qwen', False, False),
+        ('tiny', True, True), ('tiny', False, True),
+        ('tiny-gemma', True, False),
+    ], ids=['scanned', 'unscanned', 'bias', 'bias-unscanned', 'int8',
+            'int8-unscanned', 'tied'])
+    def test_serving_params_reforms_qkv_and_nothing_else(
+            self, preset, scan_layers, quantized):
+        cfg, params, prompt = self._tree(preset, scan_layers, quantized)
+        before = jax.tree.map(lambda leaf: leaf.shape, params)
+        serving = decode.serving_params(cfg, params)
+        # The caller's tree is as it was, and every array of it is
+        # still there to read: nothing was donated.
+        assert jax.tree.map(lambda leaf: leaf.shape, params) == before
+        assert not any(leaf.is_deleted()
+                       for leaf in jax.tree.leaves(params))
+        lead = 1 if scan_layers else 0
+        groups = ([(params['layers']['layer'],
+                    serving['layers']['layer'])] if scan_layers else
+                  [(params[f'layer_{i}'], serving[f'layer_{i}'])
+                   for i in range(cfg.n_layers)])
+        held = 0
+        for mine, theirs in groups:
+            for name, heads in (('q_proj', cfg.n_heads),
+                                ('k_proj', cfg.n_kv_heads),
+                                ('v_proj', cfg.n_kv_heads)):
+                flat = heads * cfg.head_dim
+                got = jax.tree.leaves(theirs['attn'][name])
+                want = jax.tree.leaves(mine['attn'][name])
+                assert len(got) == len(want) == (
+                    1 + quantized + cfg.qkv_bias)
+                for g, w in zip(got, want):
+                    assert g.shape == w.shape[:-2] + (flat,)
+                    assert g.dtype == w.dtype
+                    np.testing.assert_array_equal(
+                        np.asarray(g), np.asarray(w).reshape(g.shape))
+                    held += g.nbytes
+                kernel = theirs['attn'][name]['kernel']
+                if quantized:
+                    assert kernel['qvalue'].shape[lead:] == (cfg.d_model,
+                                                             flat)
+                    assert kernel['scale'].shape[lead:] == (1, flat)
+                else:
+                    assert kernel.shape[lead:] == (cfg.d_model, flat)
+            # o_proj and every other leaf are the caller's own arrays.
+            assert jax.tree.leaves(theirs['attn']['o_proj'])[0] is (
+                jax.tree.leaves(mine['attn']['o_proj'])[0])
+        shared = {id(leaf) for leaf in jax.tree.leaves(params)}
+        fresh = [leaf for leaf in jax.tree.leaves(serving)
+                 if id(leaf) not in shared]
+        assert sum(leaf.nbytes for leaf in fresh) == held
+        assert decode.serving_form_bytes(cfg, serving) == held > 0
+        assert decode.serving_form_bytes(cfg, params) == 0
+        # A tree already in the serving form comes back as it is.
+        assert decode.serving_params(cfg, serving) is serving
+        # And the programs give on it what they give on the caller's.
+        want, _ = decode.generate(cfg, params, prompt, max_new_tokens=5,
+                                  max_len=32)
+        got, _ = decode.generate(cfg, serving, prompt, max_new_tokens=5,
+                                 max_len=32)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+    @pytest.mark.parametrize('program', ['prefill', 'decode_step',
+                                         'prefill_chunk'])
+    def test_programs_agree_on_both_forms(self, setup, program):
+        cfg, _, params, prompt = setup
+        serving = decode.serving_params(cfg, params)
+
+        def run(tree):
+            logits, cache = decode.prefill(cfg, tree, prompt, max_len=32)
+            if program == 'decode_step':
+                logits, cache = decode.decode_step(
+                    cfg, tree, jnp.argmax(logits, -1)[:, None], cache)
+            elif program == 'prefill_chunk':
+                logits, cache = decode.prefill_chunk(
+                    cfg, tree, prompt[:, :4], cache)
+            return logits, cache['k']
+
+        for got, want in zip(run(serving), run(params)):
+            np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                       rtol=1e-5, atol=1e-5)

@@ -222,9 +222,12 @@ def _tick_layer_by_layer(cfg, params, tokens, paged):
     for l in range(cfg.n_layers):
         lp = jax.tree.map(lambda a: a[l], decode._layer_params(params, cfg))
         h = decode._norm(x, lp['attn_norm']['scale'], cfg)
-        k = decode._rope_if(None, decode._attn_proj(h, lp['attn']['k_proj']),
-                            positions, cfg)
-        v = decode._attn_proj(h, lp['attn']['v_proj'])
+        k = decode._rope_if(
+            None, decode._attn_proj(h, lp['attn']['k_proj'],
+                                    cfg.n_kv_heads, cfg.head_dim),
+            positions, cfg)
+        v = decode._attn_proj(h, lp['attn']['v_proj'], cfg.n_kv_heads,
+                              cfg.head_dim)
         k_l = write(jax.tree.map(lambda a: a[l], paged['k']), k)
         v_l = write(jax.tree.map(lambda a: a[l], paged['v']), v)
         new_k.append(k_l)
@@ -675,6 +678,52 @@ class TestStatsAndMetrics:
         parsed = metrics_lib.parse_exposition(text)
         assert sum(parsed['skytpu_engine_kv_pages_total']
                    .values()) == 47
+
+    def test_reformed_bytes_in_stats(self, setup, plain_engine):
+        """`stats()['weights']['reformed_bytes']`: the q/k/v kernels
+        the engine holds in the form the product reads; 0 would say the
+        layer scan copies each layer's kernels out of the stack."""
+        cfg, _ = setup
+        per_layer = cfg.d_model * cfg.head_dim * (
+            cfg.n_heads + 2 * cfg.n_kv_heads) * 4          # float32
+        assert plain_engine.stats()['weights'] == {
+            'reformed_bytes': cfg.n_layers * per_layer}
+
+
+class TestTensorShardedWeights:
+
+    def test_reformed_kernels_keep_their_placement(self):
+        """A `--tensor 2` server on the CPU devices: heads over
+        'tensor' become the flat axis over 'tensor', each device
+        holding the bytes it held, and the engine serves what the
+        unsharded server does."""
+        from skypilot_tpu.serve import model_server
+        single = model_server.ModelServer('tiny', max_len=32, max_batch=1)
+        sharded = model_server.ModelServer(
+            'tiny', max_len=32, max_batch=2, tensor=2,
+            continuous_batching=True)
+        try:
+            mine = sharded.params['layers']['layer']['attn']
+            held = sharded._engine.params['layers']['layer']['attn']  # pylint: disable=protected-access
+            for name in ('q_proj', 'k_proj', 'v_proj'):
+                before, after = mine[name]['kernel'], held[name]['kernel']
+                assert before.ndim == 4 and after.ndim == 3
+                assert tuple(after.sharding.spec) == tuple(
+                    before.sharding.spec)[:3]
+                assert after.sharding.spec[2] == 'tensor'
+                for a, b in zip(before.addressable_shards,
+                                after.addressable_shards):
+                    assert a.device == b.device
+                    np.testing.assert_array_equal(
+                        np.asarray(a.data).reshape(b.data.shape),
+                        np.asarray(b.data))
+            assert held['o_proj']['kernel'] is mine['o_proj']['kernel']
+            prompt = [[7, 2, 9]]
+            assert sharded.generate(prompt, 4) == single.generate(prompt,
+                                                                  4)
+        finally:
+            sharded.close()
+
 
 class TestFacadeCompat:
 

@@ -11,12 +11,15 @@ Keep such tests in this one file.
 """
 from __future__ import annotations
 
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 
+from skypilot_tpu.models import decode
 from skypilot_tpu.ops import paged_attention
 
 
@@ -95,3 +98,59 @@ def test_windowed_paged_decode_kernel_compiles_for_v5e(
                 arg((slots, 544), jnp.int32), arg((slots,), jnp.int32),
                 arg((), jnp.int32), arg((), jnp.int32)).compile()
     assert 'tpu_custom_call' in compiled.as_text()
+
+
+def _own_operations(hlo: str):
+    """(name, result dims, opcode) of every instruction that runs as an
+    operation of its own: those of the computations no fusion calls."""
+    fused = set(re.findall(r'kind=k\w+, calls=(%[\w.\-]+)', hlo))
+    out, inside = [], None
+    for line in hlo.splitlines():
+        head = re.match(r'^(?:ENTRY )?(%[\w.\-]+) \(.*\{$', line)
+        if head:
+            inside = head.group(1)
+        m = re.match(r'^\s+(?:ROOT )?(%[\w.\-]+) = \w+\[([\d,]*)\]\S* '
+                     r'([\w\-]+)\(', line)
+        if m and inside not in fused:
+            out.append((m.group(1),
+                        [int(n) for n in m.group(2).split(',') if n],
+                        m.group(3)))
+    return out
+
+
+@pytest.mark.parametrize('form', ['serving', 'training'])
+@pytest.mark.parametrize('heads', [32, 8, 128],
+                         ids=['q', 'kv', 'q-docs-window'])
+def test_layer_scan_reads_a_stacked_projection_kernel_in_place(
+        one_chip, form, heads):
+    """A scan over stacked q/k/v kernels at Mistral's widths (and the
+    128 query heads of the expert cell), 16 rows as a tick has.  In the
+    serving form `[L, d_model, heads * hd]` the slice of layer l is
+    part of the product's fusion: no operation of the loop's body
+    results in a whole layer's kernel.  The training form
+    `[L, d_model, heads, hd]` is the control: the compiler copies the
+    layer's kernel out of the stack first
+    (`constant_dynamic-slice_fusion`), which the serving engine's
+    re-forming exists to avoid.  Should a later compiler stop copying
+    it, this says so, and `decode.serving_params` can go."""
+    layers, d, hd, rows = 3, 4096, 128, 16
+
+    def arg(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+
+    def scanned(x, kernels):
+        return jax.lax.scan(
+            lambda x, kernel: (x, decode._attn_proj(  # pylint: disable=protected-access
+                x, {'kernel': kernel}, heads, hd)), x, kernels)[1]
+
+    kernel = (layers, d, heads * hd) if form == 'serving' else (
+        layers, d, heads, hd)
+    hlo = jax.jit(scanned).lower(arg((1, rows, d)),
+                                 arg(kernel)).compile().as_text()
+    whole = [(name, dims, op) for name, dims, op in _own_operations(hlo)
+             if math.prod(dims) == d * heads * hd]
+    if form == 'serving':
+        assert not whole, whole
+    else:
+        assert any('constant_dynamic-slice_fusion' in name
+                   for name, _, _ in whole), whole

@@ -147,9 +147,11 @@ def _attend(cfg, lp, kind, x, positions):
     rope_on, window = kind
     h = decode._norm(x, lp['attn_norm']['scale'], cfg)
     k = decode._rope_if(jnp.asarray(rope_on),
-                        decode._attn_proj(h, lp['attn']['k_proj']),
+                        decode._attn_proj(h, lp['attn']['k_proj'],
+                                          cfg.n_kv_heads, cfg.head_dim),
                         positions, cfg)
-    v = decode._attn_proj(h, lp['attn']['v_proj'])
+    v = decode._attn_proj(h, lp['attn']['v_proj'], cfg.n_kv_heads,
+                          cfg.head_dim)
     # The cache is indexed by key position: place the keys there.
     size = int(positions[-1]) + 1
     cache = lambda a: jnp.zeros(
@@ -444,3 +446,33 @@ def test_engine_counters_by_hand(setup):
     assert stats['paged_kernel']['live_pages'] == live
     assert stats['paged_kernel']['walked_pages'] == walked
     assert walked < 8 * live
+
+
+@pytest.mark.parametrize('kernel', ['gather', 'pallas'])
+def test_engine_on_reformed_kernels_equals_generate(setup, kernel):
+    """The parallel block with two kinds of layer, 16 query heads on 2
+    KV heads: the engine serves on q/k/v kernels it re-formed
+    (`decode.serving_params`) the tokens `decode.generate` gives on
+    the caller's training-layout tree, which it leaves whole."""
+    _, cfg, params, tokens, _ = setup
+    prompts = [tokens[:19], tokens[5:16]]
+    want = [np.asarray(decode.generate(
+        cfg, params, jnp.asarray([p], jnp.int32), max_new_tokens=10,
+        max_len=64)[1])[0].tolist() for p in prompts]
+    eng = _engine(cfg, params, kernel, slots=2)
+    try:
+        requests = [eng.submit(p, 10) for p in prompts]
+        assert [r.result(timeout=300) for r in requests] == want
+        attn = eng.params['layers']['layer']['attn']
+        assert attn['q_proj']['kernel'].shape == (
+            cfg.n_layers, cfg.d_model, cfg.n_heads * cfg.head_dim)
+        assert attn['k_proj']['kernel'].shape == (
+            cfg.n_layers, cfg.d_model, cfg.n_kv_heads * cfg.head_dim)
+        assert eng.stats()['weights']['reformed_bytes'] == sum(
+            params['layers']['layer']['attn'][name]['kernel'].nbytes
+            for name in ('q_proj', 'k_proj', 'v_proj'))
+    finally:
+        eng.stop()
+    assert params['layers']['layer']['attn']['q_proj']['kernel'].shape == (
+        cfg.n_layers, cfg.d_model, cfg.n_heads, cfg.head_dim)
+    assert not any(leaf.is_deleted() for leaf in jax.tree.leaves(params))
