@@ -94,12 +94,36 @@ class ModelConfig:
     # Per-head width when decoupled from d_model // n_heads (Gemma-7B:
     # d_model 3072, 16 heads x head_dim 256).  None = derived.
     head_dim_override: Optional[int] = None
+    # A looped stack: the n_layers layers run `loop_passes` times a
+    # token over the same weights, the final norm applied at the end of
+    # every pass (its output is the next pass's input).  Pass t, layer
+    # l keeps its keys and values in cache layer t * n_layers + l
+    # (`cache_layers`).  A gate (params['exit_gate']) reads each pass's
+    # normed output; the head reads the first pass at which the gate's
+    # cumulative exit mass reaches `exit_threshold`, else the last
+    # (models/decode.py).  1 pass: no gate, no selection.
+    loop_passes: int = 1
+    exit_threshold: float = 1.0
+    # Sandwich norms: each sub-layer's OUTPUT is normed before it joins
+    # the residual (`attn_post_norm`, `mlp_post_norm` beside the two
+    # input norms).
+    post_norms: bool = False
 
     @property
     def head_dim(self) -> int:
         if self.head_dim_override is not None:
             return self.head_dim_override
         return self.d_model // self.n_heads
+
+    def __post_init__(self):
+        if self.loop_passes < 1:
+            raise ValueError(
+                f'loop_passes must be >= 1, got {self.loop_passes}')
+
+    @property
+    def cache_layers(self) -> int:
+        """Layers of the KV caches: one for every (pass, layer)."""
+        return self.n_layers * self.loop_passes
 
     @property
     def held_experts(self) -> Tuple[int, int]:

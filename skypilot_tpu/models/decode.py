@@ -14,7 +14,8 @@ Design notes:
   same logical rules (kv_heads on 'tensor').  The layer loop CARRIES
   the stacked caches and writes each layer's new rows into them in
   place; it never slices a layer's share out and stacks it back
-  (`_scan_layers_and_unembed`).
+  (`_scan_layers_and_unembed`).  L is the cache's layers: the model's
+  layers, times the passes of a looped stack (`cfg.cache_layers`).
 - Decode attends with an explicit length mask (positions > index are
   masked), so one compiled step serves every sequence length.
 - Sampling: greedy or temperature/top-k, RNG threaded explicitly.
@@ -80,8 +81,10 @@ def bind(fn, *args, **kwargs):
 
 def init_cache(cfg: ModelConfig, batch: int, max_len: int
                ) -> Dict[str, Any]:
-    """Zeroed KV cache pytree (per-layer stacked, scan-layout)."""
-    shape = (cfg.n_layers, batch, cfg.n_kv_heads, max_len, cfg.head_dim)
+    """Zeroed KV cache pytree (stacked over the cache's layers, one a
+    (pass, layer) of a looped stack: `cfg.cache_layers`)."""
+    shape = (cfg.cache_layers, batch, cfg.n_kv_heads, max_len,
+             cfg.head_dim)
     return {
         'k': jnp.zeros(shape, cfg.dtype),
         'v': jnp.zeros(shape, cfg.dtype),
@@ -275,6 +278,8 @@ def _layer_forward(x, lp, cfg, positions, k_cache, v_cache,
     layers of more than one (`cfg.layer_kinds`); both None for a model
     of one kind of layer, which takes the code it always took.  A
     query at position p of a window layer sees keys p - window + 1 .. p.
+    `cfg.parallel_block` and `cfg.post_norms` are settings of this one
+    body.
     """
     h = _norm(x, lp['attn_norm']['scale'], cfg)
     q = _attn_proj(h, lp['attn']['q_proj'], cfg.n_heads, cfg.head_dim)
@@ -337,17 +342,26 @@ def _layer_forward(x, lp, cfg, positions, k_cache, v_cache,
     out = jnp.einsum('bhsk,hkd->bsd', out,
                      maybe_dequant(lp['attn']['o_proj']['kernel'],
                                    x.dtype))
+    out = _post_norm(out, lp, 'attn_post_norm', cfg)
     if cfg.parallel_block:
         # Attention and the FFN read the one normed input and join the
         # residual together.
         with jax.named_scope('mlp'):
             m, counts = _mlp(h, lp, cfg, row_mask)
-        return x + out + m.astype(x.dtype), counts
+        return x + out + _post_norm(m.astype(x.dtype), lp,
+                                    'mlp_post_norm', cfg), counts
     x = x + out
     h = _norm(x, lp['mlp_norm']['scale'], cfg)
     with jax.named_scope('mlp'):
         m, counts = _mlp(h, lp, cfg, row_mask)
-    return x + m.astype(x.dtype), counts
+    return x + _post_norm(m.astype(x.dtype), lp, 'mlp_post_norm',
+                          cfg), counts
+
+
+def _post_norm(out, lp, name: str, cfg):
+    """Sandwich norms (`cfg.post_norms`): a sub-layer's output is
+    normed, by a scale of its own, before it joins the residual."""
+    return _norm(out, lp[name]['scale'], cfg) if cfg.post_norms else out
 
 
 def _rope_if(rope_on, x, positions, cfg):
@@ -382,9 +396,11 @@ def _scan_layers_and_unembed(cfg, params, x, positions, cache_k, cache_v,
     the layer against `view_fn(cache, l)`, then final-norm + unembed
     the last position.  Single-sequence decode, slot-batched decode and
     the page pool differ ONLY in write_fn / view_fn / positions shapes.
-    Returns (logits, new_k, new_v, counts): counts is None for a model
-    without experts, else the expert layers' int32 [3] counts summed
-    over the layers (`moe.moe_apply`, over the rows `row_mask` marks).
+    Returns (logits, new_k, new_v, counts, exit_p): counts is None for
+    a model without experts, else the expert layers' int32 [3] counts
+    summed over the layers (`moe.moe_apply`, over the rows `row_mask`
+    marks); exit_p is None for a model of one pass, else the float32
+    [passes, b, s] exit mass of each unembedded position (below).
 
     The stacked caches (`[L, ...]` leaves, or int8 {'q','scale'} dicts
     of them) ride the loop as its CARRY beside `x`, whole: the scanned
@@ -407,6 +423,16 @@ def _scan_layers_and_unembed(cfg, params, x, positions, cache_k, cache_v,
     scan as data beside its weights and reaches the rotation, the mask
     and the kernel's first page.
 
+    A looped stack (`cfg.loop_passes` > 1) runs the one layer scan
+    once a pass, under a scan over the passes: pass t, layer l writes
+    and reads cache layer t * n_layers + l (a position's keys differ
+    from pass to pass, and later positions attend them in every pass),
+    the caches still the carry.  The final norm ends every pass and
+    its output is the next pass's input; the exit gate reads it, and
+    the head reads the pass the gate selects (`_exit_select`).  A
+    model of one pass takes none of this: its program is the one it
+    was before passes existed.
+
     `all_positions=True` unembeds EVERY position ([b, s, V] logits
     instead of last-position [b, V]) — the speculative verify step
     needs the model's output after each drafted token.  The norm and
@@ -425,41 +451,107 @@ def _scan_layers_and_unembed(cfg, params, x, positions, cache_k, cache_v,
                jnp.asarray([w or _NO_WINDOW for _, w in kinds],
                            jnp.int32))
 
-    def body(carry, layer_state):
-        x, k_cache, v_cache = carry
-        lp, l = layer_state[:2]
-        rope_on, window = layer_state[2:] or (None, None)
-        h = _norm(x, lp['attn_norm']['scale'], cfg)
-        k = _attn_proj(h, lp['attn']['k_proj'], cfg.n_kv_heads,
-                       cfg.head_dim)
-        v = _attn_proj(h, lp['attn']['v_proj'], cfg.n_kv_heads,
-                       cfg.head_dim)
-        k = _rope_if(rope_on, k, positions, cfg)
-        with jax.named_scope('kv_write'):
-            k_cache = write_fn(k_cache, l, k)
-            v_cache = write_fn(v_cache, l, v)
-        x, counts = _layer_forward(
-            x, lp, cfg, positions, view_fn(k_cache, l),
-            view_fn(v_cache, l), use_flash=use_flash, mesh=mesh,
-            rope_on=rope_on, window=window, row_mask=row_mask)
-        return (x, k_cache, v_cache), counts
+    def stack(carry, first):
+        """One pass over the layers; `first` is the pass's first cache
+        layer (None where there is one pass: the layer's own index)."""
 
-    # The caches are in the carry, so nothing cache-sized is sliced or
-    # stacked around the body: in a device trace, an op under
-    # `layer_scan` that is under none of the scopes inside the body and
-    # moves a layer's share of a cache is a copy the compiler put back.
-    with jax.named_scope('layer_scan'):
-        (x, new_k, new_v), counts = jax.lax.scan(
-            body, (x, cache_k, cache_v), xs)
+        def body(carry, layer_state):
+            x, k_cache, v_cache = carry
+            lp, l = layer_state[:2]
+            if first is not None:
+                l = first + l
+            rope_on, window = layer_state[2:] or (None, None)
+            h = _norm(x, lp['attn_norm']['scale'], cfg)
+            k = _attn_proj(h, lp['attn']['k_proj'], cfg.n_kv_heads,
+                           cfg.head_dim)
+            v = _attn_proj(h, lp['attn']['v_proj'], cfg.n_kv_heads,
+                           cfg.head_dim)
+            k = _rope_if(rope_on, k, positions, cfg)
+            with jax.named_scope('kv_write'):
+                k_cache = write_fn(k_cache, l, k)
+                v_cache = write_fn(v_cache, l, v)
+            x, counts = _layer_forward(
+                x, lp, cfg, positions, view_fn(k_cache, l),
+                view_fn(v_cache, l), use_flash=use_flash, mesh=mesh,
+                rope_on=rope_on, window=window, row_mask=row_mask)
+            return (x, k_cache, v_cache), counts
+
+        # The caches are in the carry, so nothing cache-sized is sliced
+        # or stacked around the body: in a device trace, an op under
+        # `layer_scan` that is under none of the scopes inside the body
+        # and moves a layer's share of a cache is a copy the compiler
+        # put back.
+        with jax.named_scope('layer_scan'):
+            return jax.lax.scan(body, carry, xs)
+
+    final = params['final_norm']['scale']
+    if cfg.loop_passes == 1:
+        (x, new_k, new_v), counts = stack((x, cache_k, cache_v), None)
+        if counts is not None:
+            counts = jnp.sum(counts, axis=0)
+        with jax.named_scope('lm_head'):
+            x = _norm(x if all_positions else x[:, -1:], final, cfg)
+            logits = heads.unembed(x, params, cfg)
+        return (logits if all_positions else logits[:, 0], new_k, new_v,
+                counts, None)
+
+    def one_pass(carry, t):
+        with jax.named_scope('loop_pass'):
+            (x, k_cache, v_cache), counts = stack(carry,
+                                                  t * cfg.n_layers)
+        with jax.named_scope('pass_norm'):
+            x = _norm(x, final, cfg)
+        return (x, k_cache, v_cache), (
+            x if all_positions else x[:, -1:], counts)
+
+    (_, new_k, new_v), (hs, counts) = jax.lax.scan(
+        one_pass, (x, cache_k, cache_v),
+        jnp.arange(cfg.loop_passes, dtype=jnp.int32))
     if counts is not None:
-        counts = jnp.sum(counts, axis=0)
+        counts = jnp.sum(counts, axis=(0, 1))
+    with jax.named_scope('exit_gate'):
+        x, exit_p = _exit_select(cfg, params['exit_gate'], hs)
     with jax.named_scope('lm_head'):
-        if all_positions:
-            x = _norm(x, params['final_norm']['scale'], cfg)
-            return heads.unembed(x, params, cfg), new_k, new_v, counts
-        x = _norm(x[:, -1:], params['final_norm']['scale'], cfg)
-        logits = heads.unembed(x, params, cfg)[:, 0]
-    return logits, new_k, new_v, counts
+        logits = heads.unembed(x, params, cfg)
+    return (logits if all_positions else logits[:, 0], new_k, new_v,
+            counts, exit_p)
+
+
+def _exit_select(cfg, gate, hs):
+    """Which pass's hidden state the head reads, per position.
+
+    hs [T, b, s, d]: every pass's normed output H_t.  The gate gives
+    lam_t = sigmoid(H_t w + bias); a position leaves after pass t with
+    probability p_t = lam_t * prod_{j<t} (1 - lam_j) (the last pass
+    takes what is left), and the head reads the first pass at which
+    sum_{j<=t} p_j reaches `cfg.exit_threshold`, else the last.  Every
+    pass has run for every position whatever is selected: later
+    positions attend this one's keys in every cache layer.  Returns
+    (the selected H [b, s, d], p [T, b, s]).
+
+    The gate's product, the sigmoid and the running products are
+    float32 whatever the stream's dtype: a bfloat16 sigmoid reads
+    exactly 1 from a logit near 6, and would end a position's passes
+    where the model's do not.  T is small and static, so the running
+    products are written out pass by pass, in one fixed order."""
+    last = hs.shape[0] - 1          # whose own gate nothing reads
+    w = gate['kernel'].astype(jnp.float32)[:, 0]
+    lam = jax.nn.sigmoid(
+        jnp.sum(hs[:last].astype(jnp.float32) * w, axis=-1) +
+        gate['bias'].astype(jnp.float32)[0])
+    stay = jnp.ones_like(lam[0])
+    mass, reached = [], []
+    total = jnp.zeros_like(stay)
+    for t in range(last):
+        mass.append(lam[t] * stay)
+        stay = stay * (1.0 - lam[t])
+        total = total + mass[-1]
+        reached.append(total >= cfg.exit_threshold)
+    mass.append(stay)
+    x = hs[last]
+    for t in reversed(range(last)):
+        x = jnp.where(reached[t][..., None], hs[t], x)
+    return x, jnp.stack(mass)
 
 
 def _forward_with_cache(cfg, params, tokens, cache, *, use_flash: bool,
@@ -475,7 +567,7 @@ def _forward_with_cache(cfg, params, tokens, cache, *, use_flash: bool,
         return jax.lax.dynamic_update_slice(
             c, new.astype(c.dtype)[None], (l, 0, 0, start, 0))
 
-    logits, new_k, new_v, _ = _scan_layers_and_unembed(
+    logits, new_k, new_v, _, _ = _scan_layers_and_unembed(
         cfg, params, _embed(cfg, params, tokens), positions,
         cache['k'], cache['v'], write, use_flash=use_flash, mesh=mesh)
     return logits, {'k': new_k, 'v': new_v, 'index': cache_len}
@@ -527,11 +619,13 @@ def prefill_sp(cfg: ModelConfig, params, tokens, *, mesh, max_len: int,
 
     Configs with a layer pattern are rejected: ring attention has no
     window, and the body below is the one block of a model whose layers
-    are all alike.
+    are all alike, run once.
     """
-    if cfg.layer_pattern or cfg.parallel_block:
+    if (cfg.layer_pattern or cfg.parallel_block or cfg.post_norms or
+            cfg.loop_passes != 1):
         raise ValueError('sequence-parallel prefill serves one kind of '
-                         'layer (no layer_pattern, no parallel block)')
+                         'layer, once (no layer_pattern, no parallel '
+                         'block, no post_norms, one pass)')
     from skypilot_tpu.ops.ring_attention import ring_attention  # pylint: disable=import-outside-toplevel
 
     b, s = tokens.shape
@@ -736,8 +830,8 @@ def init_engine_state(slots: int, max_stop_ids: int = 16
     }
 
 
-def _select_and_bookkeep(state, logits, new_cache, counts, *,
-                         max_top_k: int):
+def _select_and_bookkeep(state, logits, new_cache, counts, exit_mass,
+                         *, max_top_k: int):
     """The tick's tail: on-device token selection + stop/countdown
     bookkeeping (see `paged_engine_step`)."""
     active = state['active']
@@ -756,7 +850,7 @@ def _select_and_bookkeep(state, logits, new_cache, counts, *,
         remaining=remaining,
         keys=split[:, 0],
     )
-    return new_state, new_cache, finished, counts
+    return new_state, new_cache, finished, counts, exit_mass
 
 
 # ------------------------------------------------------ paged KV cache
@@ -781,8 +875,10 @@ def _page_size_of(paged: Dict[str, Any]) -> int:
 def init_paged_cache(cfg: ModelConfig, n_pages: int, page_size: int,
                      slots: int, max_pages_per_slot: int,
                      quantize_kv: bool = False) -> Dict[str, Any]:
-    """Zeroed page-pool cache.  k/v are [L, n_pages, h_kv, ps, d]
-    (int8 {'q','scale'} leaves when quantize_kv); block_tables [B, P]
+    """Zeroed page-pool cache.  k/v are [L, n_pages, h_kv, ps, d], L
+    the cache's layers (`cfg.cache_layers`: a page holds its tokens'
+    keys of every layer and pass; int8 {'q','scale'} leaves when
+    quantize_kv); block_tables [B, P]
     name each slot's pages in order (0 = the reserved null page) and
     lengths [B] are the per-slot decode depths.
 
@@ -792,7 +888,7 @@ def init_paged_cache(cfg: ModelConfig, n_pages: int, page_size: int,
     donated leaves in it from argument to result: writes are scatters
     of [d] rows at (layer, page, head, offset) and nothing slices the
     leaves by layer (`_paged_forward`)."""
-    kv_shape = (cfg.n_layers, n_pages, cfg.n_kv_heads, page_size,
+    kv_shape = (cfg.cache_layers, n_pages, cfg.n_kv_heads, page_size,
                 cfg.head_dim)
 
     def kv_leaf():
@@ -838,11 +934,12 @@ def _paged_forward(cfg: ModelConfig, params, tokens, paged, *,
                    active=None):
     """Shared write-then-attend body for paged decode: tokens [B, S]
     land at positions lengths..lengths+S-1, then every query attends
-    through the pool.  Returns (logits, new_k, new_v, counts) WITHOUT
-    advancing lengths — callers own the bookkeeping (the speculative
-    step only advances by the accepted count).  counts: the expert
-    layers' over the rows of the `active` [B] slots (None without
-    experts).
+    through the pool.  Returns (logits, new_k, new_v, counts, exit_p)
+    WITHOUT advancing lengths — callers own the bookkeeping (the
+    speculative step only advances by the accepted count).  counts:
+    the expert layers' over the rows of the `active` [B] slots (None
+    without experts); exit_p: a looped stack's exit mass [passes, B, S
+    or 1] of each unembedded position (None for one pass).
 
     The pool leaves are handed to the layer loop whole and come back
     whole (its carry): layer l's writes scatter each (slot, token, kv
@@ -928,19 +1025,34 @@ def paged_batched_step(cfg: ModelConfig, params, tokens, paged,
     the Pallas kernel path computes the same online-softmax sums
     without materialising the gather).  tokens [B, 1]; returns (logits
     [B, V], new paged cache, the expert layers' counts over the active
-    slots or None).  Without `active`, every length advances by 1
+    slots or None, a looped stack's exit mass [passes] summed over the
+    active slots or None).  Without `active`, every length advances by 1
     (callers ignore/reset inactive slots).  With `active` [B] bool,
     only active slots advance — inactive slots' writes land at their
     frozen length (garbage that is overwritten by the next admission)
     and their logits are garbage the caller masks out."""
-    logits, new_k, new_v, counts = _paged_forward(
+    logits, new_k, new_v, counts, exit_p = _paged_forward(
         cfg, params, tokens, paged, kernel=kernel, mesh=mesh,
         active=active)
     lengths = paged['lengths']
     advance = (jnp.ones_like(lengths) if active is None
                else active.astype(lengths.dtype))
-    return logits, dict(paged, k=new_k, v=new_v,
-                        lengths=lengths + advance), counts
+    return (logits, dict(paged, k=new_k, v=new_v,
+                         lengths=lengths + advance), counts,
+            _exit_mass(exit_p, None if active is None
+                       else active[:, None]))
+
+
+def _exit_mass(exit_p, decoded):
+    """exit_p [passes, B, S] summed over the (slot, position) pairs
+    `decoded` [B, S] marks (None: all) -> float32 [passes]: how much of
+    the tokens decoded left after each pass.  None where the model has
+    one pass."""
+    if exit_p is None:
+        return None
+    if decoded is not None:
+        exit_p = jnp.where(decoded[None], exit_p, 0.0)
+    return jnp.sum(exit_p, axis=(1, 2))
 
 
 def paged_engine_step(cfg: ModelConfig, params, state, paged, *,
@@ -950,10 +1062,13 @@ def paged_engine_step(cfg: ModelConfig, params, state, paged, *,
     temperature/top-k), and update the stop bookkeeping — no host
     round-trip anywhere in the loop.
 
-    Returns (new_state, new_paged, finished [B], counts): counts is
-    None for a model without experts, else the expert layers' int32
-    [3] counts of the tick over the active slots (`moe.moe_apply`),
-    which the engine reads one tick behind with `finished`.
+    Returns (new_state, new_paged, finished [B], counts, exit_mass):
+    counts is None for a model without experts, else the expert layers'
+    int32 [3] counts of the tick over the active slots
+    (`moe.moe_apply`); exit_mass is None for a model of one pass, else
+    float32 [passes], the share of the tick's decoded tokens that left
+    after each pass (`_exit_select`).  The engine reads both one tick
+    behind with `finished`.
     new_state['tokens'] is the next tick's input, so the engine can
     dispatch tick t+1 before fetching tick t's tokens and read results
     one tick behind; slots that stop at tick t are already inactive ON
@@ -987,9 +1102,10 @@ def paged_spec_engine_step(cfg: ModelConfig, params, state, paged,
     (see `_paged_forward`).
 
     Returns (new_state, new_paged, finished [B], toks [B, k+1],
-    counts [B], the expert layers' counts or None); the host pushes
-    toks[b, :counts[b]] per live slot.  Inactive slots emit nothing
-    (counts 0).
+    counts [B], the expert layers' counts or None, a looped stack's
+    exit mass [passes] over the emitted tokens or None); the host
+    pushes toks[b, :counts[b]] per live slot.  Inactive slots emit
+    nothing (counts 0).
     """
     active = state['active']
     b, _ = drafts.shape
@@ -997,7 +1113,7 @@ def paged_spec_engine_step(cfg: ModelConfig, params, state, paged,
     tokens = jnp.concatenate(
         [state['tokens'][:, None], jnp.asarray(drafts, jnp.int32)],
         axis=1)                                    # [B, S]
-    logits, new_k, new_v, moe_counts = _paged_forward(
+    logits, new_k, new_v, moe_counts, exit_p = _paged_forward(
         cfg, params, tokens, paged, kernel=kernel, all_positions=True,
         mesh=mesh, active=active)
 
@@ -1061,7 +1177,8 @@ def paged_spec_engine_step(cfg: ModelConfig, params, state, paged,
     )
     new_paged = dict(paged, k=new_k, v=new_v,
                      lengths=paged['lengths'] + counts)
-    return new_state, new_paged, finished, toks, counts, moe_counts
+    return (new_state, new_paged, finished, toks, counts, moe_counts,
+            _exit_mass(exit_p, emit))
 
 
 def paged_admit_slot(paged, slot, pages_row, length):
@@ -1123,6 +1240,11 @@ def insert_prefill_pages(paged, private_cache, pages_row, *,
                     v=leaf(paged['v'], private_cache['v']))
 
 
+# A pool leaf of this many bytes or more is copied out page by page
+# where a smaller one is gathered (`paged_seed_private`).
+_GATHER_LIMIT_BYTES = 1 << 31
+
+
 def paged_seed_private(cfg: ModelConfig, paged, pages_row, *,
                        priv_len: int):
     """Build a private prefill cache whose leading positions are the
@@ -1130,12 +1252,23 @@ def paged_seed_private(cfg: ModelConfig, paged, pages_row, *,
     admission path: the remaining prompt tokens then chunk-prefill
     against this cache from index len(pages_row) * page_size, exactly
     as if the prefix had been prefilled here.  Jit with priv_len
-    static; paged is read-only (NOT donated)."""
+    static; paged is read-only (NOT donated).
+
+    The pages are one gather out of the pool leaf, unless the leaf
+    holds 2 GiB or more (a looped stack's 192 cache layers): the TPU
+    compiler splits a gather over such an operand and copies two
+    thirds of the leaf first (2.2 GB of temporaries beside a 3.3 GB
+    leaf, compiled for a v5e, PR 35), so there the pages are sliced
+    out one at a time under a scan, which copies nothing else."""
     ps = _page_size_of(paged)
     r = pages_row.shape[0]
     ids = jnp.asarray(pages_row, jnp.int32)
 
     def leaf(pool_leaf):
+        whole = (pool_leaf['q'] if isinstance(pool_leaf, dict)
+                 else pool_leaf)
+        if whole.nbytes >= _GATHER_LIMIT_BYTES:
+            return _seed_by_pages(pool_leaf)
         if isinstance(pool_leaf, dict):
             arr = _dequant_kv({'q': pool_leaf['q'][:, ids],
                                'scale': pool_leaf['scale'][:, ids]},
@@ -1147,6 +1280,20 @@ def paged_seed_private(cfg: ModelConfig, paged, pages_row, *,
             l, 1, h, r * ps, d)               # [L, 1, h_kv, r*ps, d]
         out = jnp.zeros((l, 1, h, priv_len, d), cfg.dtype)
         return out.at[:, :, :, :r * ps, :].set(dense.astype(cfg.dtype))
+
+    def _seed_by_pages(pool_leaf):
+        def put(out, i):
+            page = _dequant_kv(jax.tree.map(
+                lambda a: jax.lax.dynamic_index_in_dim(
+                    a, ids[i], axis=1, keepdims=False), pool_leaf),
+                cfg.dtype)                     # [L, h_kv, ps, d]
+            return jax.lax.dynamic_update_slice(
+                out, page[:, None], (0, 0, 0, i * ps, 0)), None
+
+        l, _, h, _, d = jax.tree.leaves(pool_leaf)[0].shape
+        return jax.lax.scan(
+            put, jnp.zeros((l, 1, h, priv_len, d), cfg.dtype),
+            jnp.arange(r))[0]
 
     return {'k': leaf(paged['k']), 'v': leaf(paged['v']),
             'index': jnp.asarray(r * ps, jnp.int32)}

@@ -173,7 +173,7 @@ class CheckpointReader:
 # HF config.json -> ModelConfig
 # --------------------------------------------------------------------------
 
-_FAMILIES = ('llama', 'qwen2', 'gemma', 'mixtral')
+_FAMILIES = ('llama', 'qwen2', 'gemma', 'mixtral', 'ouro')
 
 
 def config_from_hf(hf: Dict[str, Any]) -> Tuple[configs.ModelConfig, str]:
@@ -274,6 +274,15 @@ def config_from_hf(hf: Dict[str, Any]) -> Tuple[configs.ModelConfig, str]:
             router_aux_loss_coef=float(
                 hf.get('router_aux_loss_coef', 0.02)),
         )
+    elif family == 'ouro':
+        # A looped stack: the layers run `total_ut_steps` times a token
+        # over the same weights, with sandwich norms and an exit gate
+        # (models/decode.py serves it; the training module does not
+        # build it and says so).
+        common.update(
+            loop_passes=int(hf['total_ut_steps']),
+            exit_threshold=float(hf.get('early_exit_threshold', 1.0)),
+            post_norms=True)
     return configs.ModelConfig(**common), family
 
 
@@ -347,6 +356,18 @@ def _plan_for(cfg: configs.ModelConfig, family: str):
     }
     if not cfg.tie_embeddings:
         plan[('lm_head', 'kernel')] = ('lm_head.weight', _t)
+    if cfg.post_norms:
+        # The sandwich norms on each sub-layer's output.
+        plan[('attn_post_norm', 'scale')] = (
+            'model.layers.{i}.input_layernorm_2.weight', lambda w: w)
+        plan[('mlp_post_norm', 'scale')] = (
+            'model.layers.{i}.post_attention_layernorm_2.weight',
+            lambda w: w)
+    if cfg.loop_passes > 1:
+        # torch Linear(d_model, 1): weight [1, d] -> kernel [d, 1].
+        plan[('exit_gate', 'kernel')] = ('model.early_exit_gate.weight', _t)
+        plan[('exit_gate', 'bias')] = ('model.early_exit_gate.bias',
+                                       lambda b: b)
     if cfg.qkv_bias:
         plan[('attn', 'q_proj', 'bias')] = (
             'model.layers.{i}.self_attn.q_proj.bias', qk_bias(nh))

@@ -266,12 +266,14 @@ class Transformer(nn.Module):
         (models/losses.py) — the [b,s,V] tensor is never built."""
         cfg = self.config
         if (cfg.layer_pattern or cfg.parallel_block or
-                cfg.norm_type != 'rms' or cfg.logit_scale != 1.0):
+                cfg.norm_type != 'rms' or cfg.logit_scale != 1.0 or
+                cfg.post_norms or cfg.loop_passes != 1):
             raise ValueError(
                 'the training module builds one kind of layer (RMSNorm, '
-                'sequential attention then FFN); layer_pattern, '
-                'parallel_block, norm_type and logit_scale are served '
-                'by models/decode.py only')
+                'sequential attention then FFN), run once; '
+                'layer_pattern, parallel_block, norm_type, logit_scale, '
+                'post_norms and loop_passes are served by '
+                'models/decode.py only')
         _, s = tokens.shape
         positions = jnp.arange(s)
 

@@ -89,7 +89,11 @@ their layer drops no token (models/moe.py), so a token's result does
 not depend on its neighbours and chunks may be padded, split and
 served from cached pages like any other model's.  Their ticks return
 the expert layers' counts beside `finished`, read one tick behind
-(stats()['moe']).
+(stats()['moe']).  A looped stack (`cfg.loop_passes` > 1: the layers
+run several times a token) takes the same path too: a page holds its
+tokens' keys of every cache layer (`cfg.cache_layers`, one a pass and
+layer), a tick still yields one token a slot, and returns the exit
+gate's mass by pass the same way (stats()['loop']).
 
 Admission is BOUNDED: `max_queue` rejects new submits when the backlog
 is full (`QueueFull` -> HTTP 429) and `queue_ttl` expires requests
@@ -206,6 +210,43 @@ _M_KERNEL_PALLAS = metrics_lib.gauge(
     '(1) or the jnp gather fallback (0).')
 
 
+def _device_memory_left(device) -> Optional[int]:
+    """Bytes `device` has left for new arrays (its limit less what is
+    in use now); None where the backend reports no memory statistics
+    (the CPU)."""
+    stats = device.memory_stats() or {}
+    if 'bytes_limit' not in stats or 'bytes_in_use' not in stats:
+        return None
+    return int(stats['bytes_limit']) - int(stats['bytes_in_use'])
+
+
+def _prefill_bound(slots: int, private: int, pool) -> int:
+    """How many prompts may be mid-prefill at once, each holding a
+    private cache of `private` bytes beside the page pool (`pool`: its
+    k/v leaves, already on the device like the weights).
+
+    Two bounds, the smaller holds, never under 1.  By the pool: a burst
+    of admissions may hold as many bytes beside the pool as the pool
+    holds itself (a pool of slots x max_len: every slot may; a smaller
+    one: fewer).  By the device: what it has left with weights and
+    pool resident, less one private cache more as room for the
+    programs' temporaries (the program that makes or moves a private
+    cache may hold a second one while it runs), divided among private
+    caches; they are placed as the pool is, so on a mesh a device holds
+    the pool's share of each.  A backend that reports no memory
+    statistics leaves the first bound alone."""
+    import jax  # pylint: disable=import-outside-toplevel
+    leaves = jax.tree.leaves(pool)
+    held = sum(leaf.nbytes for leaf in leaves)
+    bound = min(slots, held // private)
+    shards = [leaf.addressable_shards[0] for leaf in leaves]
+    left = _device_memory_left(shards[0].device)
+    if left is not None:
+        share = sum(s.data.nbytes for s in shards) / held
+        bound = min(bound, int(left // (private * share)) - 1)
+    return max(1, bound)
+
+
 def _maybe_page_journal():
     """Journal page alloc/free events only when someone is watching:
     the `serve.page_pool` chaos site is armed (scenarios replay the
@@ -291,16 +332,13 @@ class ContinuousBatchingEngine:
             cfg, n_pages, page_size, slots, max_len // page_size,
             quantize_kv=quantize_kv)
         # Prompts that may be mid-prefill at once.  Each holds a private
-        # cache of max_len until it joins the engine's cache, so a burst
-        # of admissions may hold as many bytes beside that cache as it
-        # holds itself (a pool of slots x max_len: every slot may; a
-        # smaller one: fewer); past the bound a request waits in the
-        # queue for a prefill to finish.
-        private = (2 * cfg.n_layers * cfg.n_kv_heads * cfg.head_dim *
+        # cache of max_len (over every cache layer) until it joins the
+        # engine's cache; past the bound a request waits in the queue
+        # for a prefill to finish.
+        private = (2 * cfg.cache_layers * cfg.n_kv_heads * cfg.head_dim *
                    max_len * jnp.dtype(cfg.dtype).itemsize)
-        held = sum(leaf.nbytes for leaf in jax.tree.leaves(
-            (self._cache['k'], self._cache['v'])))
-        self._max_prefills = max(1, min(slots, held // private))
+        self._max_prefills = _prefill_bound(
+            slots, private, (self._cache['k'], self._cache['v']))
         # Which attention path the paged tick runs — resolved ONCE here
         # (env SKYTPU_DECODE_KERNEL, defaulting to the Pallas kernel
         # wherever it can run) and baked into the jitted partials below
@@ -405,16 +443,20 @@ class ContinuousBatchingEngine:
         self._kernel_live_pages = 0
         self._kernel_table_pages = 0
         self._kernel_walked_pages = 0
+        self._kernel_calls = 0
         # window (0 = none) -> how many layers have it: what
         # `_count_kernel_pages` needs of the layer pattern.
         self._layers_by_window = collections.Counter(
             w for _, w in (cfg.layer_kinds() or
-                           ((True, 0),) * cfg.n_layers))
+                           ((True, 0),) * cfg.n_layers) * cfg.loop_passes)
         # Expert layers' counts, summed over ticks and layers: rows
         # routed, (row, held expert) pairs, the fullest expert's rows.
         # None until a tick returns some (a model without experts
         # never does).
         self._moe_counts: Optional[List[int]] = None
+        # A looped stack's (cfg.loop_passes > 1) exit mass by pass,
+        # summed over the ticks read.
+        self._exit_mass = [0.0] * cfg.loop_passes
         self._prefill_chunks = 0
         self._page_deferrals = 0
         self._spec_ticks = 0
@@ -915,8 +957,14 @@ class ContinuousBatchingEngine:
         block table: how much of `max_len` the traffic uses; and
         `walked_pages`, the pages the decode kernel is given to walk
         summed over the layers, a window layer's being those that hold
-        its last `sliding_window` keys).  Expert models add `moe`: the
-        expert layers' counts summed over ticks and layers."""
+        its last `sliding_window` keys; `calls`, the kernel's calls, one
+        a cache layer a tick).  Expert models add `moe`: the expert
+        layers' counts summed over ticks and layers.  A looped stack
+        adds `loop`: `steps` (passes over the stack a token),
+        `cache_layers` (the pool's: layers x steps), `passes` (stack
+        passes run by the ticks read, `steps` a tick) and `exit_mass`
+        (by pass, the share of each token decoded by a live slot that
+        left after that pass, summed: it adds up to the tokens)."""
         from skypilot_tpu.models import decode  # pylint: disable=import-outside-toplevel
         busy = sum(1 for s in self._slots if s.active)
         with self._metrics_lock:
@@ -952,6 +1000,12 @@ class ContinuousBatchingEngine:
                 stats['moe'] = dict(zip(
                     ('tokens', 'held_pairs', 'max_expert_tokens'),
                     self._moe_counts))
+            if self.cfg.loop_passes > 1:
+                stats['loop'] = {
+                    'steps': self.cfg.loop_passes,
+                    'cache_layers': self.cfg.cache_layers,
+                    'passes': self.cfg.loop_passes * self._ticks,
+                    'exit_mass': list(self._exit_mass)}
         stats.update(self._queue.stats())
         stats.update(self._kv.stats())
         with self._metrics_lock:
@@ -961,7 +1015,8 @@ class ContinuousBatchingEngine:
             stats['paged_kernel'] = {
                 'live_pages': self._kernel_live_pages,
                 'table_pages': self._kernel_table_pages,
-                'walked_pages': self._kernel_walked_pages}
+                'walked_pages': self._kernel_walked_pages,
+                'calls': self._kernel_calls}
         rate = round(self._decode_rate(), 3)
         stats['decode_tokens_per_s'] = rate
         # The worker loop's cumulative totals (iterations, seconds by
@@ -1286,10 +1341,11 @@ class ContinuousBatchingEngine:
         """Add one tick to stats()['paged_kernel']: the pages
         that hold each live slot's cache and the tick's `s_q` new
         tokens (`live_pages`) beside the rows of every slot's block
-        table, and the pages the decode kernel is given to walk summed
-        over the layers (`walked_pages`): a window layer's walk starts
-        at the page of the first query's first key.  From the host's
-        own depth of each slot, no device read."""
+        table, the pages the decode kernel is given to walk summed
+        over the cache's layers (`walked_pages`): a window layer's walk
+        starts at the page of the first query's first key; and the
+        kernel's calls, one a cache layer.  From the host's own depth
+        of each slot, no device read."""
         ps = self._kv.page_size
         held = walked = 0
         for i in live:
@@ -1304,16 +1360,26 @@ class ContinuousBatchingEngine:
             self._kernel_live_pages += held
             self._kernel_table_pages += rows
             self._kernel_walked_pages += walked
+            self._kernel_calls += self.cfg.cache_layers
         _M_KERNEL_LIVE_SHARE.set(held / rows)
 
-    def _count_moe(self, counts) -> None:
-        """Add one tick's expert-layer counts (the tick's fourth
-        output, already on the host) to stats()['moe']."""
+    def _count_tick(self, moe, exit_mass) -> None:
+        """Add what one tick counted on the device (its outputs beside
+        `finished`, read with it) to stats(): the expert layers' counts
+        ('moe') and a looped stack's exit mass by pass ('loop'); each
+        None for a model without."""
+        if moe is None and exit_mass is None:
+            return
+        import numpy as np  # pylint: disable=import-outside-toplevel
         with self._metrics_lock:
-            if self._moe_counts is None:
-                self._moe_counts = [0, 0, 0]
-            for j, c in enumerate(counts):
-                self._moe_counts[j] += int(c)
+            if moe is not None:
+                if self._moe_counts is None:
+                    self._moe_counts = [0, 0, 0]
+                for j, c in enumerate(np.asarray(moe)):
+                    self._moe_counts[j] += int(c)
+            if exit_mass is not None:
+                for j, m in enumerate(np.asarray(exit_mass)):
+                    self._exit_mass[j] += float(m)
 
     def _dispatch_step(self):
         """Dispatch one jitted engine tick.  The slice engine
@@ -1363,13 +1429,12 @@ class ContinuousBatchingEngine:
                     drafts_dev,
                     sharding_lib.spec_drafts_sharding(self._mesh))
             (self._state, self._cache, finished, toks_d, counts_d,
-             moe_d) = self._dispatch_spec_step(drafts_dev)
+             moe_d, exit_d) = self._dispatch_spec_step(drafts_dev)
         with prof.phase('device-wait', count=n_live):
             toks = np.asarray(toks_d)
             counts = np.asarray(counts_d)
             fins = np.asarray(finished)
-            if moe_d is not None:
-                self._count_moe(np.asarray(moe_d))
+            self._count_tick(moe_d, exit_d)
         with prof.phase('sample') as phase:
             pushed = 0
             accepted = 0
@@ -1441,9 +1506,10 @@ class ContinuousBatchingEngine:
         import numpy as np  # pylint: disable=import-outside-toplevel
         # One in-flight tick: (state_handles, finished_handle,
         # [(slot_id, request), ...], the expert layers' counts or
-        # None) — read one tick behind.
+        # None, a looped stack's exit mass or None) — read one tick
+        # behind.
         inflight: Optional[Tuple[Any, Any, List[Tuple[int, Any]],
-                                 Any]] = None
+                                 Any, Any]] = None
         pending_prefills: Deque[scheduler.PendingPrefill] = (
             collections.deque())
         live: Dict[int, scheduler.Request] = {}  # slot -> decoding req
@@ -1555,22 +1621,21 @@ class ContinuousBatchingEngine:
                     self._spec_tick(live)
                 elif live:
                     with prof.phase('decode-step', count=len(live)):
-                        self._state, self._cache, finished, moe = (
-                            self._dispatch_step())
+                        (self._state, self._cache, finished, moe,
+                         exit_mass) = self._dispatch_step()
                     self._count_kernel_pages(live, 1)
                     for slot_id in live:
                         self._slots[slot_id].depth += 1
                     dispatched = (self._state, finished,
-                                  list(live.items()), moe)
+                                  list(live.items()), moe, exit_mass)
                 if inflight is not None:
-                    state_t, finished_t, snapshot, moe_t = inflight
+                    state_t, finished_t, snapshot, moe_t, exit_t = inflight
                     # The one place the host waits for the device: the
                     # blocking read of the tick in flight, nothing else.
                     with prof.phase('device-wait', count=len(snapshot)):
                         toks = np.asarray(state_t['tokens'])
                         fins = np.asarray(finished_t)
-                        if moe_t is not None:
-                            self._count_moe(np.asarray(moe_t))
+                        self._count_tick(moe_t, exit_t)
                     with prof.phase('sample') as phase:
                         pushed = 0
                         for slot_id, request in snapshot:

@@ -113,10 +113,12 @@ def model_flops_per_token(cfg, n_params: int, max_len: int) -> float:
     """Forward FLOPs per generated token for the MFU roofline.
 
     Matmul work is ~2 x params (one multiply-add per parameter per
-    token).  On top of that, attention reads the KV cache: per layer
-    and cached position, QK^T and attn x V each cost
-    2 x n_heads x head_dim FLOPs; at the mean decode context
-    (max_len / 2) that adds 2 x n_layers x n_heads x head_dim x
+    token); a looped stack (`cfg.loop_passes`) runs every parameter
+    but the embedding's and the head's once a pass.  On top of that,
+    attention reads the KV cache: per cache layer (`cfg.cache_layers`:
+    one a pass and layer) and cached position, QK^T and attn x V each
+    cost 2 x n_heads x head_dim FLOPs; at the mean decode context
+    (max_len / 2) that adds 2 x cache_layers x n_heads x head_dim x
     max_len.  `SKYTPU_MODEL_FLOPS_PER_TOKEN` overrides the whole
     estimate for imported models whose param tree misleads the count
     (quantized or partially-frozen checkpoints)."""
@@ -127,9 +129,12 @@ def model_flops_per_token(cfg, n_params: int, max_len: int) -> float:
         except ValueError:
             logger.warning('Ignoring non-numeric '
                            f'SKYTPU_MODEL_FLOPS_PER_TOKEN={override!r}')
-    attn = (2.0 * cfg.n_layers * cfg.n_heads * cfg.head_dim
+    attn = (2.0 * cfg.cache_layers * cfg.n_heads * cfg.head_dim
             * float(max_len))
-    return 2.0 * float(n_params) + attn
+    outside = cfg.vocab_size * cfg.d_model * (1 if cfg.tie_embeddings
+                                              else 2)
+    again = (cfg.loop_passes - 1) * max(0.0, float(n_params) - outside)
+    return 2.0 * (float(n_params) + again) + attn
 
 
 class ClientDisconnected(RuntimeError):

@@ -263,10 +263,13 @@ class SliceReplicaEngine(batching_engine_lib.ContinuousBatchingEngine):
                         n_target: int) -> Optional[Dict[str, Any]]:
         """One-shot sequence-parallel prefill of [0, n_target), or None
         when the prompt should take the chunked path (below threshold,
-        a layer pattern or parallel block, or padding does not fit)."""
+        a layer pattern, parallel block, post-norms or more than one
+        pass, or padding does not fit)."""
         import numpy as np  # pylint: disable=import-outside-toplevel
-        if (n_target < self.sp_threshold or self.cfg.layer_pattern or
-                self.cfg.parallel_block):
+        cfg = self.cfg
+        if (n_target < self.sp_threshold or cfg.layer_pattern or
+                cfg.parallel_block or cfg.post_norms or
+                cfg.loop_passes != 1):
             return None
         width = self._sp_padded_width(n_target)
         if width is None:

@@ -247,7 +247,7 @@ class TestEngineStep:
         state, cache = self._setup_state(cfg, params)
         before_tok = int(state['tokens'][1])
         before_len = int(cache['lengths'][1])
-        state, cache, finished, _ = decode.paged_engine_step(
+        state, cache, finished, _, _ = decode.paged_engine_step(
             cfg, params, state, cache)
         assert bool(state['active'][0])
         assert not bool(state['active'][1])
@@ -261,7 +261,7 @@ class TestEngineStep:
         state, cache = self._setup_state(cfg, params)
         fins = []
         for _ in range(4):
-            state, cache, finished, _ = decode.paged_engine_step(
+            state, cache, finished, _, _ = decode.paged_engine_step(
                 cfg, params, state, cache)
             fins.append(bool(finished[0]))
         # remaining=3 -> exactly the third tick finishes the slot, and
@@ -274,12 +274,12 @@ class TestEngineStep:
         state, cache = self._setup_state(cfg, params)
         # Run one step to learn the next token, then rerun with that
         # token as a stop id: the step itself must flag fin.
-        probe_state, _, _, _ = decode.paged_engine_step(
+        probe_state, _, _, _, _ = decode.paged_engine_step(
             cfg, params, dict(state),
             jax.tree.map(jnp.copy, cache))
         stop = int(probe_state['tokens'][0])
         state = dict(state, stop_ids=state['stop_ids'].at[0, 0].set(stop))
-        state, cache, finished, _ = decode.paged_engine_step(
+        state, cache, finished, _, _ = decode.paged_engine_step(
             cfg, params, state, cache)
         assert bool(finished[0])
         assert not bool(state['active'][0])

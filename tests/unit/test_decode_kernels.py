@@ -397,7 +397,8 @@ class TestPallasKernelParity:
         try:
             ps, rows, slots = 8, 64 // 8, 2
             assert eng.stats()['paged_kernel'] == {
-                'live_pages': 0, 'table_pages': 0, 'walked_pages': 0}
+                'live_pages': 0, 'table_pages': 0, 'walked_pages': 0,
+                'calls': 0}
             script = (([3, 1, 4, 1, 5, 9, 2, 6, 5, 3, 5], 6),
                       ([7, 2, 9], 9))
             handles = [eng.submit(p, max_new_tokens=n)

@@ -258,7 +258,7 @@ class TestPoolInPlace:
                                            s_q=s_q)
         want_logits, want_k, want_v = _tick_layer_by_layer(
             cfg, params, tokens, paged)
-        logits, new_k, new_v, _ = jax.jit(
+        logits, new_k, new_v, _, _ = jax.jit(
             lambda t, p: decode._paged_forward(
                 cfg, params, t, p, all_positions=True))(tokens, paged)
         np.testing.assert_allclose(np.asarray(logits),
@@ -355,7 +355,7 @@ class TestInt8KVBound:
                                             first_page=0)
         row = jnp.zeros((4,), jnp.int32).at[:4].set(pages)
         paged = decode.paged_admit_slot(paged, 0, row, 15)
-        logits, _, _ = decode.paged_batched_step(
+        logits, _, _, _ = decode.paged_batched_step(
             cfg, params, prompt[:, -1:], paged)
         ref = np.asarray(ref_logits)[0]
         got = np.asarray(logits)[0]

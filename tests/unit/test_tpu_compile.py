@@ -154,3 +154,92 @@ def test_layer_scan_reads_a_stacked_projection_kernel_in_place(
     else:
         assert any('constant_dynamic-slice_fusion' in name
                    for name, _, _ in whole), whole
+
+
+@pytest.mark.parametrize('by_pages', [True, False],
+                         ids=['by-pages', 'gather'])
+def test_seeding_from_a_pool_leaf_over_2_gib(one_chip, monkeypatch,
+                                             by_pages):
+    """A prefix hit on a looped stack's pool (192 cache layers x 264
+    pages of 16 KV heads: 3.3 GB a leaf).  Copied out page by page,
+    `paged_seed_private` makes nothing but the private cache.  The
+    gather is the control: over an operand of 2 GiB or more the compiler
+    splits it and copies two thirds of the leaf first, 2.2 GB of
+    temporaries, which is why `decode._GATHER_LIMIT_BYTES` exists.
+    Should a later compiler stop copying, this says so, and the
+    page-by-page path can go."""
+    from skypilot_tpu.models import configs
+    cfg = configs.ModelConfig(
+        vocab_size=49152, d_model=2048, n_layers=48, n_heads=16,
+        n_kv_heads=16, d_ff=5632, loop_passes=4, post_norms=True,
+        dtype=jnp.bfloat16)
+    if not by_pages:
+        monkeypatch.setattr(decode, '_GATHER_LIMIT_BYTES', 1 << 62)
+    with_sharding = lambda tree: jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                       sharding=one_chip), tree)
+    paged = with_sharding(jax.eval_shape(
+        lambda: decode.init_paged_cache(cfg, 264, 16, 8, 32)))
+    assert paged['k'].shape[0] == 192
+    ids = jax.ShapeDtypeStruct((3,), jnp.int32, sharding=one_chip)
+    memory = jax.jit(
+        decode.bind(decode.paged_seed_private, cfg),
+        static_argnames=('priv_len',)).lower(
+            paged, ids, priv_len=512).compile().memory_analysis()
+    assert memory.output_size_in_bytes == pytest.approx(0.805e9, rel=0.01)
+    if by_pages:
+        assert memory.temp_size_in_bytes < 0.05e9
+    else:
+        assert memory.temp_size_in_bytes > 2e9
+
+
+def test_looped_tick_keeps_the_pool_in_place(one_chip, monkeypatch):
+    """The decode tick of a looped stack at the benchmark's shapes
+    (48 layers x 4 passes: a pool of 192 cache layers, 6.6 GB): the
+    scan over passes around the layer scan carries the pool as the
+    layer scan alone does, so the tick aliases it to its result and
+    makes no temporary of a pool's or a private cache's size; the
+    kernel is in it, given the whole pool."""
+    from skypilot_tpu.models import configs
+    from skypilot_tpu.ops import attention
+    monkeypatch.delenv('SKYTPU_PALLAS_INTERPRET', raising=False)
+    monkeypatch.setattr(attention, '_on_tpu', lambda: True)
+    cfg = configs.ModelConfig(
+        vocab_size=49152, d_model=2048, n_layers=48, n_heads=16,
+        n_kv_heads=16, d_ff=5632, loop_passes=4, post_norms=True,
+        norm_eps=1e-6, rope_theta=1e6, dtype=jnp.bfloat16,
+        param_dtype=jnp.bfloat16, remat=False)
+    slots, pages, d, f, hd = 8, 264, 2048, 5632, 128
+
+    def arg(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    scale = lambda *lead: {'scale': arg(lead + (d,), jnp.float32)}
+    proj = {'kernel': arg((48, d, 16 * hd))}       # the serving form
+    params = {
+        'embed': {'embedding': arg((49152, d))},
+        'final_norm': scale(),
+        'exit_gate': {'kernel': arg((d, 1)), 'bias': arg((1,))},
+        'lm_head': {'kernel': arg((d, 49152))},
+        'layers': {'layer': {
+            'attn_norm': scale(48), 'attn_post_norm': scale(48),
+            'mlp_norm': scale(48), 'mlp_post_norm': scale(48),
+            'attn': {'q_proj': proj, 'k_proj': proj, 'v_proj': proj,
+                     'o_proj': {'kernel': arg((48, 16, hd, d))}},
+            'mlp': {'gate_proj': {'kernel': arg((48, d, f))},
+                    'up_proj': {'kernel': arg((48, d, f))},
+                    'down_proj': {'kernel': arg((48, f, d))}}}}}
+    with_sharding = lambda tree: jax.tree.map(
+        lambda a: arg(a.shape, a.dtype), tree)
+    paged = with_sharding(jax.eval_shape(
+        lambda: decode.init_paged_cache(cfg, pages, 16, slots, 32)))
+    state = with_sharding(jax.eval_shape(
+        lambda: decode.init_engine_state(slots)))
+    compiled = jax.jit(
+        decode.bind(decode.paged_engine_step, cfg, kernel='pallas'),
+        donate_argnums=(2,)).lower(params, state, paged).compile()
+    memory = compiled.memory_analysis()
+    pool = 2 * 192 * pages * 16 * 16 * hd * 2
+    assert memory.alias_size_in_bytes >= pool
+    assert memory.temp_size_in_bytes < 0.05e9
+    assert 'paged_decode_attention' in compiled.as_text()
