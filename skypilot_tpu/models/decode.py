@@ -23,6 +23,7 @@ Design notes:
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
@@ -261,9 +262,136 @@ def _norm(x, scale, cfg):
 _NO_WINDOW = 1 << 30
 
 
+class _ChunkRows(NamedTuple):
+    """A second group of rows in the layer loop: a prefill chunk that
+    rides a decode tick (`paged_engine_step_with_chunk`).  Its `n` rows
+    follow the slots' in the one joined [1, B + n, d] stream, so every
+    row-wise product reads its weights once for both groups; attention
+    runs per group, the chunk's against its private cache k/v
+    [L, 1, h_kv, max_len, d] (in the scan: the layer's view of it),
+    where its keys are written at `positions` [n].  `x` [1, n, d] is
+    the chunk's embedded tokens, what it enters the loop with; `rows`
+    (int32 scalar, or None: all n) how many of them are the prompt's,
+    the rest padding up to the program's width, whose attention is
+    not worth computing (`_attend`)."""
+    x: Any
+    positions: jax.Array
+    k: Any
+    v: Any
+    use_flash: bool
+    rows: Any = None
+
+    @property
+    def n(self) -> int:
+        return self.positions.shape[0]
+
+
+def _split_rows(t, n: int):
+    """[1, h, B + n, d] of the joined stream -> (the slots' rows as the
+    tick has them, [B, h, 1, d], the chunk's [1, h, n, d])."""
+    b = t.shape[2] - n
+    return t[0, :, :b].transpose(1, 0, 2)[:, :, None], t[:, :, b:]
+
+
+def _join_rows(slots, chunk):
+    """`_split_rows` back: [B, h, 1, d] and [1, h, n, d] ->
+    [1, h, B + n, d]."""
+    return jnp.concatenate(
+        [slots[:, :, 0].transpose(1, 0, 2)[None], chunk], axis=2)
+
+
+# Query rows to a block of a padded chunk's masked attention (`_attend`).
+_ATTEND_BLOCK = 64
+
+
+def _attend(q, cfg, positions, k_cache, v_cache, *, use_flash: bool,
+            mesh, window, dtype, rows=None):
+    """Attention of one group of rows: q [b, h_q, s, hd] (rotated)
+    against its view of the KV cache, which already holds this call's
+    k/v at `positions` -> [b, h_q, s, hd] in `dtype`.  `rows` (a
+    padded chunk's, `_ChunkRows`): only the first `rows` of the s are
+    anyone's; the masked path skips the blocks past them."""
+    if isinstance(k_cache, _PagedView):
+        # Paged-kernel decode: the Pallas kernel copies each slot's
+        # live K/V pages of this layer from the whole pool by (layer,
+        # block-table) index (fused int8 dequant on the loaded
+        # operand); `positions` is implied by the view's lengths —
+        # query token j of slot b sits at lengths[b] + j.
+        with jax.named_scope('paged_attention'):
+            out = paged_attention_ops.paged_attention(
+                q, k_cache.leaf, v_cache.leaf, k_cache.tables,
+                k_cache.lengths, sm_scale=cfg.head_dim ** -0.5,
+                mesh=mesh, window=window, layer=k_cache.layer)
+        return out.astype(dtype)
+    if use_flash:
+        # Prefill from index 0: the valid cache region is exactly the
+        # prompt window [0, s) — a STATIC slice (q.shape[2]), as jit
+        # requires.  (Chunks at index>0 take the masked path instead,
+        # and so does a chunk longer than a layer's window: the caller
+        # sees to it, `_flash_ok`.)
+        s = q.shape[2]
+        with jax.named_scope('flash_attention'):
+            return flash_attention(q, k_cache[:, :, :s],
+                                   v_cache[:, :, :s], causal=True,
+                                   mesh=mesh)
+    if rows is None or q.shape[2] <= _ATTEND_BLOCK:
+        return _masked_attention(q, cfg, positions, k_cache, v_cache,
+                                 window, dtype)
+    # A chunk padded to its program's width: the masked path reads the
+    # whole cache in float32 for every row, so blocks that hold pad
+    # rows only are left at zero (their results are garbage nobody
+    # reads either way: pad positions lie past every real query's
+    # horizon and are overwritten before anything attends them).
+    blocks = []
+    for start in range(0, q.shape[2], _ATTEND_BLOCK):
+        block = slice(start, start + _ATTEND_BLOCK)
+        attend = functools.partial(
+            _masked_attention, q[:, :, block], cfg, positions[block],
+            k_cache, v_cache, window, dtype)
+        blocks.append(attend() if start == 0 else jax.lax.cond(
+            start < rows, attend,
+            lambda block=block: jnp.zeros_like(q[:, :, block], dtype)))
+    return jnp.concatenate(blocks, axis=2)
+
+
+def _masked_attention(q, cfg, positions, k_cache, v_cache, window, dtype):
+    """q [b, h_q, s, hd] at `positions` against a dense cache view
+    [b, h_kv, len, hd], in float32, every key at or before the query's
+    position (and inside its layer's window) -> [b, h_q, s, hd]."""
+    # Masked decode: grouped einsums against the cache — GQA
+    # q-heads fold into a `rep` axis per kv-head, so the repeated
+    # K/V never materialises (8x cache-read savings on llama3-70b).
+    b, h_q, qs, d = q.shape
+    rep = cfg.n_heads // cfg.n_kv_heads
+    qg = q.reshape(b, cfg.n_kv_heads, rep, qs, d).astype(jnp.float32)
+    k32 = k_cache.astype(jnp.float32)
+    s = jnp.einsum('bgrqd,bgkd->bgrqk', qg, k32) * (
+        cfg.head_dim ** -0.5)
+    kpos = jnp.arange(k_cache.shape[2])
+    # Per-query-position causal mask: query at absolute position p
+    # attends keys at kpos <= p.  positions is [s] (single-sequence
+    # prefill continuation), [B, 1] (slot-batched decode — every
+    # slot at its own depth), or [B, s] — so one masked path serves
+    # single-token decode AND multi-token chunked prefill at
+    # index > 0 (where the flash window-from-0 trick is invalid).
+    pos = jnp.asarray(positions)
+    if pos.ndim == 1:
+        pos = pos[None]                               # [1, s]
+    kpos = kpos[None, None, None, None, :]
+    pos = pos[:, None, None, :, None]
+    mask = kpos <= pos
+    if window is not None:
+        mask = mask & (kpos > pos - window)
+    s = jnp.where(mask, s, NEG_INF)
+    p = jax.nn.softmax(s, axis=-1)
+    out = jnp.einsum('bgrqk,bgkd->bgrqd', p,
+                     v_cache.astype(jnp.float32))
+    return out.reshape(b, h_q, qs, d).astype(dtype)
+
+
 def _layer_forward(x, lp, cfg, positions, k_cache, v_cache,
                    *, use_flash: bool, mesh=None, rope_on=None,
-                   window=None, row_mask=None):
+                   window=None, row_mask=None, chunk=None):
     """One decoder layer against its view of the KV cache.
 
     x [b, s, d]; k_cache/v_cache [b, h_kv, max_len, hd] (or the paged
@@ -280,64 +408,26 @@ def _layer_forward(x, lp, cfg, positions, k_cache, v_cache,
     query at position p of a window layer sees keys p - window + 1 .. p.
     `cfg.parallel_block` and `cfg.post_norms` are settings of this one
     body.
+
+    With a `chunk` (`_ChunkRows`, its k/v the layer's views) x is the
+    joined stream [1, B + n, d]: the norms, the projections and the FFN
+    run on it whole, and only attention takes the two groups apart,
+    the slots' rows against `k_cache`/`v_cache` at `positions` [B, 1]
+    and the chunk's against its own.
     """
     h = _norm(x, lp['attn_norm']['scale'], cfg)
     q = _attn_proj(h, lp['attn']['q_proj'], cfg.n_heads, cfg.head_dim)
+    if chunk is not None:
+        q, chunk_q = _split_rows(q, chunk.n)
     q = _rope_if(rope_on, q, positions, cfg)
-
-    if isinstance(k_cache, _PagedView):
-        # Paged-kernel decode: the Pallas kernel copies each slot's
-        # live K/V pages of this layer from the whole pool by (layer,
-        # block-table) index (fused int8 dequant on the loaded
-        # operand); `positions` is implied by the view's lengths —
-        # query token j of slot b sits at lengths[b] + j.
-        with jax.named_scope('paged_attention'):
-            out = paged_attention_ops.paged_attention(
-                q, k_cache.leaf, v_cache.leaf, k_cache.tables,
-                k_cache.lengths, sm_scale=cfg.head_dim ** -0.5,
-                mesh=mesh, window=window, layer=k_cache.layer)
-        out = out.astype(x.dtype)
-    elif use_flash:
-        # Prefill from index 0: the valid cache region is exactly the
-        # prompt window [0, s) — a STATIC slice (q.shape[2]), as jit
-        # requires.  (Chunks at index>0 take the masked path instead,
-        # and so does a chunk longer than a layer's window: the caller
-        # sees to it, `_flash_ok`.)
-        s = q.shape[2]
-        with jax.named_scope('flash_attention'):
-            out = flash_attention(q, k_cache[:, :, :s],
-                                  v_cache[:, :, :s], causal=True,
-                                  mesh=mesh)
-    else:
-        # Masked decode: grouped einsums against the cache — GQA
-        # q-heads fold into a `rep` axis per kv-head, so the repeated
-        # K/V never materialises (8x cache-read savings on llama3-70b).
-        b, h_q, qs, d = q.shape
-        rep = cfg.n_heads // cfg.n_kv_heads
-        qg = q.reshape(b, cfg.n_kv_heads, rep, qs, d).astype(jnp.float32)
-        k32 = k_cache.astype(jnp.float32)
-        s = jnp.einsum('bgrqd,bgkd->bgrqk', qg, k32) * (
-            cfg.head_dim ** -0.5)
-        kpos = jnp.arange(k_cache.shape[2])
-        # Per-query-position causal mask: query at absolute position p
-        # attends keys at kpos <= p.  positions is [s] (single-sequence
-        # prefill continuation), [B, 1] (slot-batched decode — every
-        # slot at its own depth), or [B, s] — so one masked path serves
-        # single-token decode AND multi-token chunked prefill at
-        # index > 0 (where the flash window-from-0 trick is invalid).
-        pos = jnp.asarray(positions)
-        if pos.ndim == 1:
-            pos = pos[None]                               # [1, s]
-        kpos = kpos[None, None, None, None, :]
-        pos = pos[:, None, None, :, None]
-        mask = kpos <= pos
-        if window is not None:
-            mask = mask & (kpos > pos - window)
-        s = jnp.where(mask, s, NEG_INF)
-        p = jax.nn.softmax(s, axis=-1)
-        out = jnp.einsum('bgrqk,bgkd->bgrqd', p,
-                         v_cache.astype(jnp.float32))
-        out = out.reshape(b, h_q, qs, d).astype(x.dtype)
+    out = _attend(q, cfg, positions, k_cache, v_cache, use_flash=use_flash,
+                  mesh=mesh, window=window, dtype=x.dtype)
+    if chunk is not None:
+        chunk_q = _rope_if(rope_on, chunk_q, chunk.positions, cfg)
+        out = _join_rows(out, _attend(
+            chunk_q, cfg, chunk.positions, chunk.k, chunk.v,
+            use_flash=chunk.use_flash, mesh=mesh, window=window,
+            dtype=x.dtype, rows=chunk.rows))
 
     out = jnp.einsum('bhsk,hkd->bsd', out,
                      maybe_dequant(lp['attn']['o_proj']['kernel'],
@@ -390,17 +480,28 @@ def _embed(cfg, params, tokens):
 def _scan_layers_and_unembed(cfg, params, x, positions, cache_k, cache_v,
                              write_fn, *, use_flash: bool,
                              view_fn=None, all_positions: bool = False,
-                             mesh=None, row_mask=None):
+                             mesh=None, row_mask=None, chunk=None):
     """The shared per-layer loop: project+rope k/v, write them into
     layer l of the cache via `write_fn(cache, l, new) -> cache`, run
     the layer against `view_fn(cache, l)`, then final-norm + unembed
     the last position.  Single-sequence decode, slot-batched decode and
     the page pool differ ONLY in write_fn / view_fn / positions shapes.
-    Returns (logits, new_k, new_v, counts, exit_p): counts is None for
-    a model without experts, else the expert layers' int32 [3] counts
-    summed over the layers (`moe.moe_apply`, over the rows `row_mask`
-    marks); exit_p is None for a model of one pass, else the float32
-    [passes, b, s] exit mass of each unembedded position (below).
+    Returns (logits, new_k, new_v, counts, exit_p, chunk_kv): counts is
+    None for a model without experts, else the expert layers' int32 [3]
+    counts summed over the layers (`moe.moe_apply`, over the rows
+    `row_mask` marks); exit_p is None for a model of one pass, else the
+    float32 [passes, b, s] exit mass of each unembedded position
+    (below); chunk_kv is None without a `chunk`.
+
+    `chunk` (`_ChunkRows`) is a second group of rows that rides a
+    slot-batched step (x [B, 1, d]): a prefill chunk's, with a private
+    cache of its own.  Its rows join the slots' in one stream
+    [1, B + n, d], so each layer's weights are read once for both;
+    each group's keys go to its own cache and its queries attend it
+    (`_layer_forward`); `row_mask` [B + n] then marks rows of the
+    stream.  Only the slots' rows reach the head (and the exit gate):
+    the chunk's leave as chunk_kv, its private cache's (k, v) with the
+    chunk's keys of every cache layer written at its positions.
 
     The stacked caches (`[L, ...]` leaves, or int8 {'q','scale'} dicts
     of them) ride the loop as its CARRY beside `x`, whole: the scanned
@@ -441,10 +542,16 @@ def _scan_layers_and_unembed(cfg, params, x, positions, cache_k, cache_v,
     """
     layers = _layer_params(params, cfg)
     if view_fn is None:
-        view_fn = lambda c, l: jax.lax.dynamic_index_in_dim(
-            c, l, axis=0, keepdims=False)
+        view_fn = _layer_of
     kinds = cfg.layer_kinds()
     use_flash = _flash_ok(cfg, use_flash, x.shape[1])
+    slots = x.shape[0]
+    private = ()
+    if chunk is not None:
+        x = jnp.concatenate([x.reshape(1, slots, -1), chunk.x], axis=1)
+        private = (chunk.k, chunk.v)
+        chunk = chunk._replace(
+            x=None, use_flash=_flash_ok(cfg, chunk.use_flash, chunk.n))
     xs = (layers, jnp.arange(cfg.n_layers, dtype=jnp.int32))
     if kinds is not None:
         xs += (jnp.asarray([rope for rope, _ in kinds]),
@@ -456,7 +563,7 @@ def _scan_layers_and_unembed(cfg, params, x, positions, cache_k, cache_v,
         layer (None where there is one pass: the layer's own index)."""
 
         def body(carry, layer_state):
-            x, k_cache, v_cache = carry
+            x, k_cache, v_cache, private = carry
             lp, l = layer_state[:2]
             if first is not None:
                 l = first + l
@@ -466,15 +573,28 @@ def _scan_layers_and_unembed(cfg, params, x, positions, cache_k, cache_v,
                            cfg.head_dim)
             v = _attn_proj(h, lp['attn']['v_proj'], cfg.n_kv_heads,
                            cfg.head_dim)
+            rows = None
+            if chunk is not None:
+                k, chunk_k = _split_rows(k, chunk.n)
+                v, chunk_v = _split_rows(v, chunk.n)
             k = _rope_if(rope_on, k, positions, cfg)
             with jax.named_scope('kv_write'):
                 k_cache = write_fn(k_cache, l, k)
                 v_cache = write_fn(v_cache, l, v)
+                if chunk is not None:
+                    chunk_k = _rope_if(rope_on, chunk_k, chunk.positions,
+                                       cfg)
+                    private = tuple(
+                        _write_private(c, l, new, chunk.positions[0])
+                        for c, new in zip(private, (chunk_k, chunk_v)))
+                    rows = chunk._replace(k=_layer_of(private[0], l),
+                                          v=_layer_of(private[1], l))
             x, counts = _layer_forward(
                 x, lp, cfg, positions, view_fn(k_cache, l),
                 view_fn(v_cache, l), use_flash=use_flash, mesh=mesh,
-                rope_on=rope_on, window=window, row_mask=row_mask)
-            return (x, k_cache, v_cache), counts
+                rope_on=rope_on, window=window, row_mask=row_mask,
+                chunk=rows)
+            return (x, k_cache, v_cache, private), counts
 
         # The caches are in the carry, so nothing cache-sized is sliced
         # or stacked around the body: in a device trace, an op under
@@ -484,28 +604,35 @@ def _scan_layers_and_unembed(cfg, params, x, positions, cache_k, cache_v,
         with jax.named_scope('layer_scan'):
             return jax.lax.scan(body, carry, xs)
 
+    def head_rows(x):
+        """The rows of the stream that the head reads: the slots' own
+        (the chunk's stay behind), each sequence's last unless all are
+        asked for."""
+        if chunk is not None:
+            x = x[0, :slots, None]
+        return x if all_positions else x[:, -1:]
+
     final = params['final_norm']['scale']
     if cfg.loop_passes == 1:
-        (x, new_k, new_v), counts = stack((x, cache_k, cache_v), None)
+        (x, new_k, new_v, private), counts = stack(
+            (x, cache_k, cache_v, private), None)
         if counts is not None:
             counts = jnp.sum(counts, axis=0)
         with jax.named_scope('lm_head'):
-            x = _norm(x if all_positions else x[:, -1:], final, cfg)
+            x = _norm(head_rows(x), final, cfg)
             logits = heads.unembed(x, params, cfg)
         return (logits if all_positions else logits[:, 0], new_k, new_v,
-                counts, None)
+                counts, None, private or None)
 
     def one_pass(carry, t):
         with jax.named_scope('loop_pass'):
-            (x, k_cache, v_cache), counts = stack(carry,
-                                                  t * cfg.n_layers)
+            (x, *caches), counts = stack(carry, t * cfg.n_layers)
         with jax.named_scope('pass_norm'):
             x = _norm(x, final, cfg)
-        return (x, k_cache, v_cache), (
-            x if all_positions else x[:, -1:], counts)
+        return (x, *caches), (head_rows(x), counts)
 
-    (_, new_k, new_v), (hs, counts) = jax.lax.scan(
-        one_pass, (x, cache_k, cache_v),
+    (_, new_k, new_v, private), (hs, counts) = jax.lax.scan(
+        one_pass, (x, cache_k, cache_v, private),
         jnp.arange(cfg.loop_passes, dtype=jnp.int32))
     if counts is not None:
         counts = jnp.sum(counts, axis=(0, 1))
@@ -514,7 +641,19 @@ def _scan_layers_and_unembed(cfg, params, x, positions, cache_k, cache_v,
     with jax.named_scope('lm_head'):
         logits = heads.unembed(x, params, cfg)
     return (logits if all_positions else logits[:, 0], new_k, new_v,
-            counts, exit_p)
+            counts, exit_p, private or None)
+
+
+def _layer_of(cache, l):
+    """Layer `l`'s slice of a dense stacked cache [L, b, h_kv, len, d]."""
+    return jax.lax.dynamic_index_in_dim(cache, l, axis=0, keepdims=False)
+
+
+def _write_private(cache, l, new, start):
+    """new [1, h_kv, s, d] into layer `l` of a single sequence's dense
+    cache [L, 1, h_kv, len, d], at positions start .. start + s - 1."""
+    return jax.lax.dynamic_update_slice(
+        cache, new.astype(cache.dtype)[None], (l, 0, 0, start, 0))
 
 
 def _exit_select(cfg, gate, hs):
@@ -563,13 +702,11 @@ def _forward_with_cache(cfg, params, tokens, cache, *, use_flash: bool,
     positions = start + jnp.arange(s)
     cache_len = start + s
 
-    def write(c, l, new):
-        return jax.lax.dynamic_update_slice(
-            c, new.astype(c.dtype)[None], (l, 0, 0, start, 0))
-
-    logits, new_k, new_v, _, _ = _scan_layers_and_unembed(
+    logits, new_k, new_v, _, _, _ = _scan_layers_and_unembed(
         cfg, params, _embed(cfg, params, tokens), positions,
-        cache['k'], cache['v'], write, use_flash=use_flash, mesh=mesh)
+        cache['k'], cache['v'],
+        lambda c, l, new: _write_private(c, l, new, start),
+        use_flash=use_flash, mesh=mesh)
     return logits, {'k': new_k, 'v': new_v, 'index': cache_len}
 
 
@@ -931,15 +1068,17 @@ def _dequant_kv(leaf_slice, dtype):
 
 def _paged_forward(cfg: ModelConfig, params, tokens, paged, *,
                    kernel=None, all_positions: bool = False, mesh=None,
-                   active=None):
+                   active=None, chunk=None):
     """Shared write-then-attend body for paged decode: tokens [B, S]
     land at positions lengths..lengths+S-1, then every query attends
-    through the pool.  Returns (logits, new_k, new_v, counts, exit_p)
-    WITHOUT advancing lengths — callers own the bookkeeping (the
-    speculative step only advances by the accepted count).  counts:
+    through the pool.  Returns (logits, new_k, new_v, counts, exit_p,
+    chunk_kv) WITHOUT advancing lengths — callers own the bookkeeping
+    (the speculative step only advances by the accepted count).  counts:
     the expert layers' over the rows of the `active` [B] slots (None
     without experts); exit_p: a looped stack's exit mass [passes, B, S
-    or 1] of each unembedded position (None for one pass).
+    or 1] of each unembedded position (None for one pass); chunk_kv:
+    the private cache's (k, v) of a prefill `chunk` (`_ChunkRows`) that
+    rode the step (S = 1), whose rows no count covers; None without.
 
     The pool leaves are handed to the layer loop whole and come back
     whole (its carry): layer l's writes scatter each (slot, token, kv
@@ -1010,11 +1149,15 @@ def _paged_forward(cfg: ModelConfig, params, tokens, paged, *,
             bb, p, h, s, d = arr.shape
             return arr.transpose(0, 2, 1, 3, 4).reshape(bb, h, p * s, d)
 
+    x = _embed(cfg, params, tokens)
+    row_mask = None if active is None else jnp.repeat(active, s_q)
+    if chunk is not None and row_mask is not None:
+        row_mask = jnp.pad(row_mask, (0, chunk.n))   # none of its rows
     return _scan_layers_and_unembed(
-        cfg, params, _embed(cfg, params, tokens), positions,
+        cfg, params, x, positions,
         paged['k'], paged['v'], write, use_flash=False, view_fn=view,
-        all_positions=all_positions, mesh=mesh,
-        row_mask=None if active is None else jnp.repeat(active, s_q))
+        all_positions=all_positions, mesh=mesh, row_mask=row_mask,
+        chunk=chunk)
 
 
 def paged_batched_step(cfg: ModelConfig, params, tokens, paged,
@@ -1031,16 +1174,24 @@ def paged_batched_step(cfg: ModelConfig, params, tokens, paged,
     only active slots advance — inactive slots' writes land at their
     frozen length (garbage that is overwritten by the next admission)
     and their logits are garbage the caller masks out."""
-    logits, new_k, new_v, counts, exit_p = _paged_forward(
+    return _paged_tick(cfg, params, tokens, paged, active, kernel=kernel,
+                       mesh=mesh)[:4]
+
+
+def _paged_tick(cfg, params, tokens, paged, active, *, kernel, mesh,
+                chunk=None):
+    """`paged_batched_step` and, fifth, the private cache's (k, v) of a
+    prefill `chunk` that rode it (None without one)."""
+    logits, new_k, new_v, counts, exit_p, chunk_kv = _paged_forward(
         cfg, params, tokens, paged, kernel=kernel, mesh=mesh,
-        active=active)
+        active=active, chunk=chunk)
     lengths = paged['lengths']
     advance = (jnp.ones_like(lengths) if active is None
                else active.astype(lengths.dtype))
     return (logits, dict(paged, k=new_k, v=new_v,
                          lengths=lengths + advance), counts,
             _exit_mass(exit_p, None if active is None
-                       else active[:, None]))
+                       else active[:, None]), chunk_kv)
 
 
 def _exit_mass(exit_p, decoded):
@@ -1081,6 +1232,44 @@ def paged_engine_step(cfg: ModelConfig, params, state, paged, *,
         max_top_k=max_top_k)
 
 
+def paged_engine_step_with_chunk(cfg: ModelConfig, params, state, paged,
+                                 tokens, cache=None, rows=None, *,
+                                 max_len: int, max_top_k: int = 64,
+                                 kernel=None, mesh=None):
+    """`paged_engine_step` with one prefill chunk riding it: one
+    program in which every layer's weights are read once, for the live
+    slots' tokens and the chunk's rows together.  Between two ticks a
+    standalone chunk streams the very weights the tick has just
+    streamed, for rows that fit under the same read.
+
+    tokens [1, c] is the chunk; `cache` its prompt's private cache
+    ({'k', 'v', 'index'}, k/v [L, 1, h_kv, max_len, d]; jit with it and
+    `paged` donated), or None for a prompt's first chunk, which starts
+    a fresh one of `max_len` and attends by the flash kernel, as
+    `prefill` does; a later chunk attends by the masked path at
+    cache['index'], as `prefill_chunk` does, and where `rows` (int32
+    scalar) says how many of the c tokens are the prompt's and not
+    padding, only over the blocks of rows that hold any.  Returns
+    `paged_engine_step`'s five results and the private cache with index
+    advanced by c.  The tick's results are what the plain tick's would
+    be: the head, sampling and the bookkeeping see the slots' rows
+    only, and the counts (`moe`, `exit_mass`) cover the live slots and
+    nothing of the chunk, whose rows are not unembedded (the prompt's
+    last token rides a later tick, as after a standalone chunk)."""
+    fresh = cache is None
+    if fresh:
+        cache = init_cache(cfg, 1, max_len)
+    start = cache['index']
+    chunk = _ChunkRows(_embed(cfg, params, tokens),
+                       start + jnp.arange(tokens.shape[1]), cache['k'],
+                       cache['v'], use_flash=fresh, rows=rows)
+    *tick, (new_k, new_v) = _paged_tick(
+        cfg, params, state['tokens'][:, None], paged, state['active'],
+        kernel=kernel, mesh=mesh, chunk=chunk)
+    return _select_and_bookkeep(state, *tick, max_top_k=max_top_k) + (
+        {'k': new_k, 'v': new_v, 'index': start + tokens.shape[1]},)
+
+
 def paged_spec_engine_step(cfg: ModelConfig, params, state, paged,
                            drafts, *, max_top_k: int = 64, kernel=None,
                            mesh=None):
@@ -1113,7 +1302,7 @@ def paged_spec_engine_step(cfg: ModelConfig, params, state, paged,
     tokens = jnp.concatenate(
         [state['tokens'][:, None], jnp.asarray(drafts, jnp.int32)],
         axis=1)                                    # [B, S]
-    logits, new_k, new_v, moe_counts, exit_p = _paged_forward(
+    logits, new_k, new_v, moe_counts, exit_p, _ = _paged_forward(
         cfg, params, tokens, paged, kernel=kernel, all_positions=True,
         mesh=mesh, active=active)
 

@@ -358,11 +358,58 @@ def _stacked(k_leaf, v_leaf, layer):
     return lift(k_leaf), lift(v_leaf), jnp.zeros((), jnp.int32)
 
 
-def _paged_attention_pallas(q, k_leaf, v_leaf, tables, lengths, *,
-                            sm_scale: float, window=None, layer=None):
+@functools.lru_cache(maxsize=None)
+def _decode_call(b, h_kv, r_pad, d, ps, pps, s_q, sm_scale, quantized,
+                 windowed, pool_dtype, q_dtype, interpret):
+    """The decode kernel's `pallas_call` for one set of shapes, built
+    once a process.  The call is a jitted function that traces the
+    kernel's body on its first use; every program of an engine that
+    holds the kernel (the tick, the verify tick, each width of the tick
+    with a chunk riding it) calls it with the same shapes, and one
+    object lets them share that trace, a third of a second a program
+    on a serving host, where a fresh call would trace the body anew
+    each time.  What the call lowers to is the same either way."""
     from jax.experimental import pallas as pl  # pylint: disable=import-outside-toplevel
     from jax.experimental.pallas import tpu as pltpu  # pylint: disable=import-outside-toplevel
 
+    row_spec = pl.BlockSpec(
+        (1, h_kv, r_pad, d), lambda bb, *_: (bb, 0, 0, 0),
+        memory_space=pltpu.VMEM)
+    # The pools never enter VMEM whole, nor is a layer's share sliced
+    # out of them: the kernel copies the pages of `layer` that a slot's
+    # table names, and only the live ones.
+    pool_spec = pl.BlockSpec(memory_space=pl.ANY)
+    kv_buf = pltpu.VMEM((2, pps, h_kv, ps, d), pool_dtype)
+    buffers = [kv_buf, kv_buf]
+    if quantized:
+        width = -(-h_kv * ps // _LANES) * _LANES
+        scale_buf = pltpu.VMEM((2, pps, width), jnp.float32)
+        buffers += [scale_buf, scale_buf]
+    scratch = buffers + [pltpu.SemaphoreType.DMA((2, 2)),
+                         pltpu.SMEM((1,), jnp.int32),
+                         pltpu.VMEM((h_kv * r_pad, d), jnp.float32),
+                         pltpu.VMEM((h_kv * r_pad, _LANES), jnp.float32),
+                         pltpu.VMEM((h_kv * r_pad, _LANES), jnp.float32)]
+    return pl.pallas_call(
+        functools.partial(_paged_decode_kernel, page_size=ps, s_q=s_q,
+                          pages_per_step=pps, sm_scale=sm_scale,
+                          quantized=quantized, windowed=windowed),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3 + windowed,
+            grid=(b,),
+            in_specs=[row_spec] + [pool_spec] * (4 if quantized else 2),
+            out_specs=row_spec,
+            scratch_shapes=scratch),
+        out_shape=jax.ShapeDtypeStruct((b, h_kv, r_pad, d), q_dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=('arbitrary',)),
+        interpret=interpret,
+        name='paged_decode_attention',
+    )
+
+
+def _paged_attention_pallas(q, k_leaf, v_leaf, tables, lengths, *,
+                            sm_scale: float, window=None, layer=None):
     b, h_q, s_q, d = q.shape
     k_leaf, v_leaf, layer = _stacked(k_leaf, v_leaf, layer)
     quantized = isinstance(k_leaf, dict)
@@ -383,14 +430,6 @@ def _paged_attention_pallas(q, k_leaf, v_leaf, tables, lengths, *,
     qr = jnp.pad(q.reshape(b, h_kv, rep, s_q, d).reshape(b, h_kv, r, d),
                  ((0, 0), (0, 0), (0, r_pad - r), (0, 0)))
 
-    row_spec = pl.BlockSpec(
-        (1, h_kv, r_pad, d), lambda bb, *_: (bb, 0, 0, 0),
-        memory_space=pltpu.VMEM)
-    # The pools never enter VMEM whole, nor is a layer's share sliced
-    # out of them: the kernel copies the pages of `layer` that a slot's
-    # table names, and only the live ones.
-    pool_spec = pl.BlockSpec(memory_space=pl.ANY)
-    kv_buf = pltpu.VMEM((2, pps, h_kv, ps, d), pool.dtype)
     if quantized:
         # The layer's scales, a page's [h_kv, ps] as one row, padded to
         # whole lane tiles: what a DMA can slice by page index.
@@ -402,40 +441,19 @@ def _paged_attention_pallas(q, k_leaf, v_leaf, tables, lengths, *,
                     scale.shape[1], h_kv * ps)
             return jnp.pad(flat, ((0, 0), (0, width - h_kv * ps)))
 
-        scale_buf = pltpu.VMEM((2, pps, width), jnp.float32)
         operands = (qr, k_leaf['q'], rows(k_leaf['scale']), v_leaf['q'],
                     rows(v_leaf['scale']))
-        buffers = [kv_buf, kv_buf, scale_buf, scale_buf]
     else:
         operands = (qr, k_leaf, v_leaf)
-        buffers = [kv_buf, kv_buf]
-    scratch = buffers + [pltpu.SemaphoreType.DMA((2, 2)),
-                         pltpu.SMEM((1,), jnp.int32),
-                         pltpu.VMEM((h_kv * r_pad, d), jnp.float32),
-                         pltpu.VMEM((h_kv * r_pad, _LANES), jnp.float32),
-                         pltpu.VMEM((h_kv * r_pad, _LANES), jnp.float32)]
     # Tables, lengths and the layer's index ride in scalar-prefetch
     # memory, and the layer's window after them where it has one.
     scalars = (tables, lengths, layer.reshape(1)) + (
         () if window is None else (
             jnp.asarray(window, jnp.int32).reshape(1),))
-    out = pl.pallas_call(
-        functools.partial(_paged_decode_kernel, page_size=ps, s_q=s_q,
-                          pages_per_step=pps, sm_scale=sm_scale,
-                          quantized=quantized,
-                          windowed=window is not None),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=len(scalars),
-            grid=(b,),
-            in_specs=[row_spec] + [pool_spec] * (len(operands) - 1),
-            out_specs=row_spec,
-            scratch_shapes=scratch),
-        out_shape=jax.ShapeDtypeStruct((b, h_kv, r_pad, d), q.dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=('arbitrary',)),
-        interpret=interpret_mode(),
-        name='paged_decode_attention',
-    )(*scalars, *operands)
+    out = _decode_call(
+        b, h_kv, r_pad, d, ps, pps, s_q, float(sm_scale), quantized,
+        window is not None, jnp.dtype(pool.dtype), jnp.dtype(q.dtype),
+        interpret_mode())(*scalars, *operands)
     return out[:, :, :r].reshape(b, h_kv, rep, s_q, d).reshape(
         b, h_q, s_q, d)
 

@@ -52,10 +52,21 @@ Decode hot loop (the device never waits on Python):
   stream/stop bookkeeping, so host work overlaps device compute.  A
   slot that stops at tick t is already inactive on device when tick
   t+1 runs — the pipeline never decodes past a stop.
-- Prompt prefill is CHUNKED: `_admit` splits a long prompt into
-  fixed-size chunks interleaved with decode ticks (at most one chunk
-  between ticks), so the worst ITL stall any admission can impose on
-  running requests is one chunk's compute, not one prompt's.
+- Prompt prefill is CHUNKED: an admission splits a long prompt into
+  fixed-size chunks, at most one an iteration of the loop, so the worst
+  ITL stall any admission can impose on running requests is one chunk's
+  compute, not one prompt's.
+- A chunk RIDES the tick: an iteration that has a chunk to run
+  dispatches one program (`decode.paged_engine_step_with_chunk`) that
+  is the live slots' tick and the chunk together, every layer's weights
+  read once for both, where a chunk between two ticks would stream the
+  weights the tick has just streamed.  Whether a chunk is pending, its
+  kind (first or later) and its width choose the program, nothing else:
+  a pending chunk takes the fused step also when no slot is live (the
+  frozen slots' results are not read), so the programs a warm-up
+  compiles are the ones traffic meets.  The speculative engine, whose
+  verify ticks are synchronous, the slice engine and `export_prefill`
+  run the standalone programs (`decode.prefill`, `prefill_chunk`).
 
 Self-speculative decoding (`spec_tokens=k > 0`): a per-slot host-side
 n-gram/prompt-lookup drafter (`serve/sampler.NgramDrafter`) proposes
@@ -152,6 +163,16 @@ validate_sampling = sampler_lib.validate_sampling
 validate_stop_ids = sampler_lib.validate_stop_ids
 
 _PREFILL_BUCKETS = (16, 32, 64, 128, 256, 512, 1024, 2048, 4096)
+# The least width of a chunk that rides the tick (where `prefill_chunk`
+# allows it).  Every width is a program of the tick's own size, and a
+# serving host spends about a second of set-up on each even when the
+# compile cache holds it (tracing and lowering, four times a standalone
+# chunk's: chip runs, PR 36), while rows under the one read of the
+# weights cost the products next to nothing until some 240 of them
+# reach a v5e's ridge.  So every piece up to this width shares one
+# program a kind, which is told how many of its rows are the prompt's
+# and leaves the attention of the rest undone.
+_FUSED_MIN_WIDTH = 256
 
 # Process-global registry instruments (observability/metrics.py) —
 # what `GET /metrics` on the serving fronts exposes.  Counters are
@@ -168,6 +189,10 @@ _M_TOKENS = metrics_lib.counter(
 _M_PREFILL_CHUNKS = metrics_lib.counter(
     'skytpu_engine_prefill_chunks_total',
     'Prompt prefill chunks executed.')
+_M_PREFILL_CHUNKS_FUSED = metrics_lib.counter(
+    'skytpu_engine_prefill_chunks_fused_total',
+    'Prompt prefill chunks that shared their read of the weights with '
+    'the decode tick of at least one live slot (one program for both).')
 _M_BUSY_SLOTS = metrics_lib.gauge(
     'skytpu_engine_busy_slots', 'KV slots currently decoding.')
 _M_SLOTS = metrics_lib.gauge(
@@ -263,6 +288,12 @@ def _maybe_page_journal():
 
 class ContinuousBatchingEngine:
     """Submit() from any thread; one worker thread owns the device."""
+
+    # Whether a prefill chunk rides the decode tick, one program for
+    # both.  An engine whose tick is not `_step` alone keeps the
+    # standalone chunk programs: the slice engine (its ranks run the
+    # tick on a broadcast command) and any engine with `spec_tokens`.
+    _FUSES_CHUNKS = True
 
     def __init__(self, cfg, params, *, max_len: int = 512,
                  slots: int = 4, prefill_chunk: int = 512,
@@ -416,6 +447,16 @@ class ContinuousBatchingEngine:
         # is donated so XLA extends it in place.
         self._prefill_chunk = jax.jit(
             decode.bind(decode.prefill_chunk, cfg), donate_argnums=(2,))
+        # The tick with a chunk riding it (first chunk: no private
+        # cache goes in; later: the prompt's, donated like the pool).
+        # One compile per chunk kind and width, as the two above.
+        self._chunk_step = None
+        if self._FUSES_CHUNKS and not self.spec_tokens:
+            self._chunk_step = jax.jit(
+                decode.bind(decode.paged_engine_step_with_chunk, cfg,
+                            max_len=max_len, max_top_k=self.max_top_k,
+                            kernel=self.decode_kernel, mesh=mesh),
+                donate_argnums=(2, 4))
         # ---- continuous profiling plane (observability/profiling.py).
         # Tick-phase spans + recompile sentinel; both collapse to no-ops
         # under SKYTPU_PROFILE_DISABLE.  Every resolved jit entry above
@@ -428,7 +469,7 @@ class ContinuousBatchingEngine:
         for attr in ('_step', '_spec_step', '_admit_paged',
                      '_release_paged', '_insert_pages', '_seed_private',
                      '_write_pages', '_write_pages_q', '_prefill',
-                     '_prefill_chunk'):
+                     '_prefill_chunk', '_chunk_step'):
             setattr(self, attr, self._sentinel.wrap(
                 attr.lstrip('_'), getattr(self, attr)))
         self._failed: Optional[Exception] = None
@@ -458,6 +499,7 @@ class ContinuousBatchingEngine:
         # summed over the ticks read.
         self._exit_mass = [0.0] * cfg.loop_passes
         self._prefill_chunks = 0
+        self._prefill_chunks_fused = 0
         self._page_deferrals = 0
         self._spec_ticks = 0
         self._spec_slot_ticks = 0   # (live slot, verify tick) pairs
@@ -975,6 +1017,8 @@ class ContinuousBatchingEngine:
                 'failed': self._failed is not None,
                 'ticks': self._ticks,
                 'prefill_chunks': self._prefill_chunks,
+                # ... of which rode the tick of at least one live slot.
+                'prefill_chunks_fused': self._prefill_chunks_fused,
                 'prefill_chunk': self.prefill_chunk,
                 'decode_kernel': self.decode_kernel,
                 'spec_tokens': self.spec_tokens,
@@ -1097,10 +1141,13 @@ class ContinuousBatchingEngine:
         _M_TOKENS.inc(n)
         _M_DECODE_RATE.set(round(self._decode_rate(), 3))
 
-    def _record_chunk(self) -> None:
+    def _record_chunk(self, fused: bool = False) -> None:
         _M_PREFILL_CHUNKS.inc()
+        if fused:
+            _M_PREFILL_CHUNKS_FUSED.inc()
         with self._metrics_lock:
             self._prefill_chunks += 1
+            self._prefill_chunks_fused += fused
 
     # ------------------------------------------------------------ worker
 
@@ -1109,6 +1156,15 @@ class ContinuousBatchingEngine:
             if n <= b:
                 return b
         return n
+
+    def _chunk_width(self, take: int, chunk: int) -> int:
+        """The width `take` prompt tokens are padded to for one chunk
+        of at most `chunk`: their power-of-two bucket, and no less than
+        `_FUSED_MIN_WIDTH` where the chunk rides the tick."""
+        width = self._bucket(take)
+        if self._chunk_step is not None:
+            width = max(width, min(_FUSED_MIN_WIDTH, self._bucket(chunk)))
+        return width
 
     # --------------------------------------------------------- admission
 
@@ -1165,11 +1221,16 @@ class ContinuousBatchingEngine:
         slot.request = request
         return scheduler.PendingPrefill(slot_id, request, n - 1, plan)
 
-    def _advance_prefill(self, pending: scheduler.PendingPrefill
-                         ) -> bool:
+    def _advance_prefill(self, pending: scheduler.PendingPrefill,
+                         riders: int = 0
+                         ) -> Tuple[bool, Optional[Tuple[Any, ...]]]:
         """Run ONE chunk of a pending prefill (this is the whole point:
         an admission stalls running decodes by at most one chunk).
-        Returns True when the prefill completed and the slot went live.
+        Returns (done, tick): done when the prefill completed and the
+        slot went live (or its request was dropped and the slot freed);
+        tick, where the chunk rode the decode tick (`_dispatch_chunk`),
+        that tick's (state, finished, counts, exit mass), the tick of
+        the `riders` slots live at the call; None where no tick ran.
         """
         jnp = self._jnp
         request = pending.request
@@ -1183,7 +1244,7 @@ class ContinuousBatchingEngine:
                         'request deadline passed mid-prefill'))
             self._slots[pending.slot_id].request = None
             self._release_slot_pages(pending.slot_id)
-            return True  # pending is finished (slot freed)
+            return True, None  # pending is finished (slot freed)
         import numpy as np  # pylint: disable=import-outside-toplevel
         n_target = pending.n_target
         # Fractional-role clamp: a decode-heavy budget shrinks the
@@ -1192,6 +1253,7 @@ class ContinuousBatchingEngine:
         plan = pending.plan
         reuse_tokens = plan.n_reuse_tokens
         seeding = pending.cache is None and reuse_tokens > 0
+        tick = None
         # The phase and `span.prefill_s` time the host's DISPATCH of
         # the program (asynchronous), not the program.
         with self._profiler.phase(
@@ -1219,11 +1281,11 @@ class ContinuousBatchingEngine:
                 # `.at[:n].set` would compile a tiny scatter per
                 # distinct prompt length, right on the admission path.
                 take = min(n_target, chunk)
-                bucket = min(self._bucket(take), self.max_len)
+                bucket = min(self._chunk_width(take, chunk), self.max_len)
                 padded = np.zeros((1, bucket), np.int32)
                 padded[0, :take] = request.prompt_ids[:take]
-                _, pending.cache = self._prefill(self.params,
-                                                 jnp.asarray(padded))
+                pending.cache, tick = self._dispatch_chunk(
+                    jnp.asarray(padded), None, take, riders)
                 # The padded flash cache advanced index to `bucket`;
                 # chunk continuations must write at the REAL consumed
                 # length.
@@ -1246,12 +1308,12 @@ class ContinuousBatchingEngine:
                 # step that reaches it.
                 start = pending.consumed
                 take = min(n_target - start, chunk)
-                width = min(self._bucket(take), chunk,
+                width = min(self._chunk_width(take, chunk), chunk,
                             self.max_len - start)
                 piece = np.zeros((1, width), np.int32)
                 piece[0, :take] = request.prompt_ids[start:start + take]
-                _, pending.cache = self._prefill_chunk(
-                    self.params, jnp.asarray(piece), pending.cache)
+                pending.cache, tick = self._dispatch_chunk(
+                    jnp.asarray(piece), pending.cache, take, riders)
                 pending.cache = dict(
                     pending.cache,
                     index=jnp.asarray(start + take, jnp.int32))
@@ -1259,11 +1321,36 @@ class ContinuousBatchingEngine:
                 phase.count = width
             request.span.mark_prefill_chunk(time.monotonic() - t_chunk0)
         if seeding:
-            return False
-        self._record_chunk()
+            return False, None
+        self._record_chunk(fused=tick is not None and riders > 0)
         if pending.consumed < n_target:
-            return False
-        return self._finish_prefill(pending)
+            return False, tick
+        return self._finish_prefill(pending), tick
+
+    def _dispatch_chunk(self, piece, cache, take: int, riders: int):
+        """Dispatch one prefill chunk, `piece` [1, width] holding `take`
+        prompt tokens, of the prompt whose private cache is `cache`
+        (None: its first chunk) -> (the private cache with the chunk in
+        it, tick).  Where the engine
+        fuses, the chunk rides the decode tick, one program for both
+        (`decode.paged_engine_step_with_chunk`), and tick is that
+        tick's (state, finished, counts, exit mass); it runs whether or
+        not a slot is live (`riders` 0: over frozen slots, a tick
+        nobody reads), so which program a (kind, width) of chunk takes
+        never hangs on what else the engine is doing.  Otherwise the
+        standalone programs run and tick is None."""
+        if self._chunk_step is None:
+            if cache is None:
+                return self._prefill(self.params, piece)[1], None
+            return self._prefill_chunk(self.params, piece, cache)[1], None
+        # `decode-step` inside the chunk's own phase: this dispatch is
+        # the iteration's tick too.
+        with self._profiler.phase('decode-step', count=riders):
+            (self._state, self._cache, finished, moe, exit_mass,
+             cache) = self._chunk_step(
+                 self.params, self._state, self._cache, piece, cache,
+                 self._jnp.asarray(take, self._jnp.int32))
+        return cache, (self._state, finished, moe, exit_mass)
 
     def _finish_prefill(self, pending: scheduler.PendingPrefill) -> bool:
         """All chunks in: adopt the private cache into the page pool
@@ -1600,11 +1687,17 @@ class ContinuousBatchingEngine:
                 # left the device with nothing queued.
                 if inflight is not None and (pending_prefills or live):
                     prof.probe_starved(inflight[1])
-                # At most ONE prefill chunk between ticks — the bound
-                # on the ITL stall an admission can impose.
+                # At most ONE prefill chunk an iteration — the bound
+                # on the ITL stall an admission can impose.  Where it
+                # rides the tick (`_dispatch_chunk`), `tick` is the tick
+                # of the `riders`, the slots live before it; a prompt
+                # it finishes is adopted behind it and joins the next.
+                tick, riders = None, []
                 if pending_prefills:
+                    riders = list(live.items())
                     pending = pending_prefills.popleft()
-                    done = self._advance_prefill(pending)
+                    done, tick = self._advance_prefill(pending,
+                                                       len(riders))
                     if done:
                         if self._slots[pending.slot_id].request is not None:
                             live[pending.slot_id] = pending.request
@@ -1619,15 +1712,23 @@ class ContinuousBatchingEngine:
                     # Speculative mode: synchronous multi-token verify
                     # ticks (see _spec_tick); `inflight` stays empty.
                     self._spec_tick(live)
+                elif tick is not None and riders:
+                    # The chunk's step was this iteration's tick.
+                    state, finished, moe, exit_mass = tick
+                    dispatched = (state, finished, riders, moe, exit_mass)
                 elif live:
+                    # (Also behind a chunk that rode over frozen slots
+                    # only: a slot it made live gets its tick now.)
                     with prof.phase('decode-step', count=len(live)):
                         (self._state, self._cache, finished, moe,
                          exit_mass) = self._dispatch_step()
-                    self._count_kernel_pages(live, 1)
-                    for slot_id in live:
-                        self._slots[slot_id].depth += 1
                     dispatched = (self._state, finished,
                                   list(live.items()), moe, exit_mass)
+                if dispatched is not None:
+                    ticked = [slot_id for slot_id, _ in dispatched[2]]
+                    self._count_kernel_pages(ticked, 1)
+                    for slot_id in ticked:
+                        self._slots[slot_id].depth += 1
                 if inflight is not None:
                     state_t, finished_t, snapshot, moe_t, exit_t = inflight
                     # The one place the host waits for the device: the

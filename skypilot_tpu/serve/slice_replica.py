@@ -152,6 +152,10 @@ class SliceReplicaEngine(batching_engine_lib.ContinuousBatchingEngine):
     the replica as a unit; (c) sequence-parallel prefill for prompts at
     or above `sp_threshold` tokens."""
 
+    # The ranks run a tick on the coordinator's TICK command, so a
+    # prefill chunk stays a program of its own between two ticks.
+    _FUSES_CHUNKS = False
+
     def __init__(self, cfg, params, *, num_hosts: int,
                  sp_threshold: Optional[int] = None,
                  sequence: Optional[int] = None,
@@ -282,7 +286,7 @@ class SliceReplicaEngine(batching_engine_lib.ContinuousBatchingEngine):
             self._sp_prefills += 1
         return dict(cache, index=jnp.asarray(n_target, jnp.int32))
 
-    def _advance_prefill(self, pending) -> bool:
+    def _advance_prefill(self, pending, riders: int = 0):
         request = pending.request
         if (pending.cache is None and
                 pending.plan.n_reuse_tokens == 0 and
@@ -304,8 +308,8 @@ class SliceReplicaEngine(batching_engine_lib.ContinuousBatchingEngine):
                     coordinator_lib.CMD_PREFILL,
                     slot=pending.slot_id, tokens=pending.n_target,
                     sp=self._sp_degree)
-                return self._finish_prefill(pending)
-        return super()._advance_prefill(pending)
+                return self._finish_prefill(pending), None
+        return super()._advance_prefill(pending, riders)
 
     def _prefill_private(self, prompt_ids: List[int],
                          n_target: int) -> Dict[str, Any]:

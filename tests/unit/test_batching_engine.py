@@ -324,6 +324,143 @@ class TestChunkedPrefill:
             eng.stop()
 
 
+class TestChunkRidesTheTick:
+    """An iteration with a prefill chunk to run dispatches one program,
+    the live slots' tick and the chunk together
+    (`decode.paged_engine_step_with_chunk`)."""
+
+    @pytest.mark.parametrize('model', ['tiny', 'tiny-moe'])
+    def test_arrivals_beside_a_live_slot_stay_exact(self, model):
+        """With a slot decoding, prompts of one, two and four chunks
+        arrive; every request's greedy tokens are `decode.generate`'s,
+        and every chunk but the first request's (which met an empty
+        engine) shared its weight read with a live slot's tick."""
+        cfg = configs.get_config(model)
+        params = nn.meta.unbox(Transformer(cfg).init(
+            jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))['params'])
+        eng = batching_engine.ContinuousBatchingEngine(
+            cfg, params, max_len=64, slots=4, prefill_chunk=4)
+        prompts = [([2, 7, 1, 8], 52),            # live throughout
+                   (list(range(3, 8)), 3),        # 4 to prefill: 1 chunk
+                   (list(range(11, 20)), 4),      # 8: 2 chunks
+                   (list(range(20, 36)), 5)]      # 15: 4, the last padded
+        try:
+            handles = [eng.submit(p, n) for p, n in prompts]
+            for (p, n), handle in zip(prompts, handles):
+                assert handle.result(timeout=240) == _reference(
+                    cfg, params, p, n), p
+            stats = eng.stats()
+            assert stats['prefill_chunks'] == 8
+            assert stats['prefill_chunks_fused'] == 7
+            assert stats['ticks'] >= 52
+            if cfg.n_experts:
+                # The ticks' counts hold the live slots' tokens and
+                # nothing of the chunks that rode them.
+                assert stats['moe']['tokens'] == (
+                    sum(n for _, n in prompts) * cfg.n_layers)
+            # The chunk's phase holds the tick's dispatch: one
+            # `decode-step` in such an iteration, inside
+            # `prefill-chunk`, counting the slots that rode.
+            fused = [rec['phases'] for rec in eng.profile()['ring']
+                     if any(p[0] == 'prefill-chunk' for p in rec['phases'])]
+            assert len(fused) == 8
+            for phases in fused[1:]:
+                steps = [p for p in phases if p[0] == 'decode-step']
+                assert len(steps) == 1 and steps[0][3] >= 1
+            # The first met frozen slots only: its own tick follows it.
+            assert [p[3] for p in fused[0] if p[0] == 'decode-step'] == [
+                0, 1]
+        finally:
+            eng.stop()
+
+    @pytest.mark.parametrize('how', ['cancel', 'deadline'])
+    def test_dropped_mid_prefill_beside_a_live_slot(self, setup, how):
+        """A cancel or a passed deadline between two chunks that ride
+        a live slot's ticks frees the slot; the live request's tokens
+        stay exact."""
+        cfg, params = setup
+        eng = batching_engine.ContinuousBatchingEngine(
+            cfg, params, max_len=64, slots=2, prefill_chunk=2)
+        try:
+            running = eng.submit([2, 7, 1, 8], 40)
+            victim = eng.submit(list(range(1, 50)), 8, deadline_ms=6e5)
+            while eng.stats()['prefill_chunks_fused'] < 2:
+                assert not victim.done.is_set()
+                victim.done.wait(0.002)
+            if how == 'cancel':
+                victim.cancel()
+            else:
+                victim.deadline = victim.submit_time   # it has passed
+            assert victim.done.wait(120)
+            if how == 'cancel':
+                assert victim.error is None
+            else:
+                assert isinstance(victim.error,
+                                  batching_engine.DeadlineExceeded)
+                assert 'mid-prefill' in str(victim.error)
+            assert running.result(timeout=180) == _reference(
+                cfg, params, [2, 7, 1, 8], 40)
+            stats = eng.stats()
+            assert 2 <= stats['prefill_chunks_fused'] < 24
+            assert stats['busy_slots'] == 0
+            assert stats['kv_pages_used'] == stats['kv_pages_pinned']
+            # The slot is reusable afterwards.
+            assert eng.generate([4, 5], 3, timeout=120) == _reference(
+                cfg, params, [4, 5], 3)
+        finally:
+            eng.stop()
+
+    def test_short_pieces_share_one_width(self, setup):
+        """Every width of the fused step is a program of the tick's
+        size, so pieces under `_FUSED_MIN_WIDTH` rows are padded to it
+        (where `prefill_chunk` allows): prompts of 20, 70 and 200
+        tokens take one program between them; the speculative engine's
+        standalone chunks keep their buckets."""
+        cfg, params = setup
+        widths = {}
+        for spec_tokens in (0, 2):
+            eng = batching_engine.ContinuousBatchingEngine(
+                cfg, params, max_len=256, slots=2, prefill_chunk=256,
+                prefix_caching=False, spec_tokens=spec_tokens)
+            try:
+                for n in (20, 70, 200):
+                    prompt = [1 + i % 250 for i in range(n)]
+                    assert eng.generate(prompt, 3, timeout=240) == [
+                        int(t) for t in np.asarray(decode.generate(
+                            cfg, params, jnp.asarray([prompt], jnp.int32),
+                            max_new_tokens=3, max_len=256)[1])[0]], n
+                widths[spec_tokens] = [
+                    p[3] for rec in eng.profile()['ring']
+                    for p in rec['phases'] if p[0] == 'prefill-chunk']
+                compiles = eng.profile()['recompiles']['fns']
+                if not spec_tokens:
+                    assert compiles['chunk_step']['compiles'] == 1
+            finally:
+                eng.stop()
+        assert widths == {0: [256, 256, 256], 2: [32, 128, 256]}
+
+    def test_speculative_engine_runs_no_fused_step(self, setup):
+        """The speculative engine's verify ticks are synchronous: its
+        chunks stay programs of their own between them."""
+        cfg, params = setup
+        eng = batching_engine.ContinuousBatchingEngine(
+            cfg, params, max_len=64, slots=2, prefill_chunk=4,
+            spec_tokens=2)
+        try:
+            running = eng.submit([2, 7, 1, 8], 30)
+            late = eng.submit(list(range(1, 20)), 4)
+            assert running.result(timeout=180) == _reference(
+                cfg, params, [2, 7, 1, 8], 30)
+            assert late.result(timeout=180) == _reference(
+                cfg, params, list(range(1, 20)), 4)
+            stats = eng.stats()
+            assert stats['prefill_chunks'] == 6
+            assert stats['prefill_chunks_fused'] == 0
+            assert 'chunk_step' not in eng.profile()['recompiles']['fns']
+        finally:
+            eng.stop()
+
+
 class TestSampling:
 
     def test_sampled_deterministic_per_seed(self, setup):

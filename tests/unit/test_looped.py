@@ -198,8 +198,8 @@ def test_paged_tick_writes_every_cache_layer(monkeypatch, setup):
     run = lambda kernel: jax.jit(lambda t, p: decode._paged_forward(
         cfg, params, t, p, kernel=kernel, all_positions=True))(
             tokens, paged)
-    logits_g, k_g, _, _, exit_g = run('gather')
-    logits_p, k_p, _, _, exit_p = run('pallas')
+    logits_g, k_g, _, _, exit_g, _ = run('gather')
+    logits_p, k_p, _, _, exit_p, _ = run('pallas')
     np.testing.assert_allclose(np.asarray(logits_p), np.asarray(logits_g),
                                atol=_TOL, rtol=0)
     np.testing.assert_allclose(np.asarray(exit_p), np.asarray(exit_g),
@@ -274,7 +274,7 @@ def test_selection_agrees_with_reference(setup_half):
     write = lambda c, l, new: jax.lax.dynamic_update_slice(
         c, new[None], (l, 0, 0, 0, 0))
     for all_positions in (True, False):
-        logits, _, _, _, exit_p = decode._scan_layers_and_unembed(
+        logits, _, _, _, exit_p, _ = decode._scan_layers_and_unembed(
             cfg, params, decode._embed(cfg, params,
                                        jnp.asarray([tokens[:n]])),
             jnp.arange(n), cache['k'], cache['v'], write,

@@ -335,7 +335,8 @@ def test_every_jitted_entry_is_a_named_function(tiny_paged_engine):
     assert names['_seed_private'] == 'paged_seed_private'
     assert names['_insert_pages'] == 'insert_prefill_pages'
     assert names['_admit_paged'] == 'paged_admit_slot'
-    assert len(names) == 10
+    assert names['_chunk_step'] == 'paged_engine_step_with_chunk'
+    assert len(names) == 11
 
 
 def test_stats_tick_loop_counts_the_loop(tiny_paged_engine):

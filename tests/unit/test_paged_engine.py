@@ -258,7 +258,7 @@ class TestPoolInPlace:
                                            s_q=s_q)
         want_logits, want_k, want_v = _tick_layer_by_layer(
             cfg, params, tokens, paged)
-        logits, new_k, new_v, _, _ = jax.jit(
+        logits, new_k, new_v, _, _, _ = jax.jit(
             lambda t, p: decode._paged_forward(
                 cfg, params, t, p, all_positions=True))(tokens, paged)
         np.testing.assert_allclose(np.asarray(logits),
@@ -419,6 +419,40 @@ class TestPrefixReuse:
         assert got == _reference(cfg, params, prompt, 4)
         assert eng.stats()['prefill_chunks'] == chunks0
         assert eng.span(handle.request_id)['prefix_hit_pages'] == 4
+
+    @pytest.mark.parametrize('quantize_kv', [False, True],
+                             ids=['float', 'int8'])
+    def test_hits_ride_a_live_slots_ticks(self, setup, quantize_kv):
+        """Beside a decoding slot, a document is asked about twice: the
+        first prompt prefills in four chunks, the second seeds its
+        private cache from the cached pages (an iteration of its own,
+        with a plain tick) and runs its tail as one chunk.  Every
+        chunk rides the live slot's tick, and every answer is
+        `decode.generate`'s (int8 pages: the first's only, its keys
+        were never read back from the pool)."""
+        cfg, params = setup
+        eng = _paged_engine(cfg, params, slots=3,
+                            quantize_kv=quantize_kv)
+        try:
+            document = list(range(200, 224))        # 24 tokens: 3 pages
+            first, second = document + [5, 6, 7], document + [8, 9, 1, 2]
+            running = eng.submit([2, 7, 1, 8], 50)
+            a = eng.submit(first, 4)
+            got_a = a.result(timeout=240)
+            b = eng.submit(second, 4)   # at once: 50 ticks pass quickly
+            got = b.result(timeout=240)
+            assert got_a == _reference(cfg, params, first, 4)
+            if not quantize_kv:
+                assert got == _reference(cfg, params, second, 4)
+                assert running.result(timeout=240) == _reference(
+                    cfg, params, [2, 7, 1, 8], 50)
+            assert b.span.prefix_hit_pages == 3
+            stats = eng.stats()
+            # 26 tokens in chunks of 8: four; the hit's tail of 3: one.
+            assert stats['prefill_chunks'] == 1 + 4 + 1
+            assert stats['prefill_chunks_fused'] == 4 + 1
+        finally:
+            eng.stop()
 
     def test_hit_tail_shorter_than_chunk(self, setup):
         """Regression: a prefix hit seeds the private cache near the

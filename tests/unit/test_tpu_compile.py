@@ -193,53 +193,155 @@ def test_seeding_from_a_pool_leaf_over_2_gib(one_chip, monkeypatch,
         assert memory.temp_size_in_bytes > 2e9
 
 
-def test_looped_tick_keeps_the_pool_in_place(one_chip, monkeypatch):
+# The benchmark's dense stacks: Mistral-7B's 16 layers, and Ouro-2.6B's
+# 48 layers run 4 times (sandwich norms, exit gate); with each the
+# engine's slots, pool pages and max_len.
+_STACKS = {
+    'mistral': (dict(vocab_size=32768, d_model=4096, n_layers=16,
+                     n_heads=32, n_kv_heads=8, d_ff=14336), 16, 2432, 2560),
+    'looped': (dict(vocab_size=49152, d_model=2048, n_layers=48,
+                    n_heads=16, n_kv_heads=16, d_ff=5632, loop_passes=4,
+                    post_norms=True, norm_eps=1e-6), 8, 264, 512),
+}
+
+
+def _engine_shapes(stack, one_chip):
+    """(cfg, params, state, paged, private cache) of a benchmark stack
+    as shapes on the described chip: the weights in bf16 with the q/k/v
+    kernels in the serving form, the engine's state and page pool, one
+    prompt's private prefill cache."""
+    from skypilot_tpu.models import configs
+    keys, slots, pages, max_len = _STACKS[stack]
+    cfg = configs.ModelConfig(rope_theta=1e6, dtype=jnp.bfloat16,
+                              param_dtype=jnp.bfloat16, remat=False,
+                              **keys)
+    layers, d, f, hd = cfg.n_layers, cfg.d_model, cfg.d_ff, cfg.head_dim
+
+    def arg(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    scale = lambda *lead: {'scale': arg(lead + (d,), jnp.float32)}
+    proj = lambda heads: {'kernel': arg((layers, d, heads * hd))}
+    layer = {
+        'attn_norm': scale(layers), 'mlp_norm': scale(layers),
+        'attn': {'q_proj': proj(cfg.n_heads), 'k_proj': proj(cfg.n_kv_heads),
+                 'v_proj': proj(cfg.n_kv_heads),
+                 'o_proj': {'kernel': arg((layers, cfg.n_heads, hd, d))}},
+        'mlp': {'gate_proj': {'kernel': arg((layers, d, f))},
+                'up_proj': {'kernel': arg((layers, d, f))},
+                'down_proj': {'kernel': arg((layers, f, d))}}}
+    params = {'embed': {'embedding': arg((cfg.vocab_size, d))},
+              'final_norm': scale(),
+              'lm_head': {'kernel': arg((d, cfg.vocab_size))},
+              'layers': {'layer': layer}}
+    if cfg.post_norms:
+        layer.update(attn_post_norm=scale(layers),
+                     mlp_post_norm=scale(layers))
+    if cfg.loop_passes > 1:
+        params['exit_gate'] = {'kernel': arg((d, 1)), 'bias': arg((1,))}
+    shapes = lambda make: jax.tree.map(
+        lambda a: arg(a.shape, a.dtype), jax.eval_shape(make))
+    return (cfg, params,
+            shapes(lambda: decode.init_engine_state(slots)),
+            shapes(lambda: decode.init_paged_cache(cfg, pages, 16, slots,
+                                                   max_len // 16)),
+            shapes(lambda: decode.init_cache(cfg, 1, max_len)))
+
+
+@pytest.fixture()
+def kernel_on_tpu(monkeypatch):
+    """The paged tick takes the Pallas kernel, as on the chip."""
+    from skypilot_tpu.ops import attention
+    monkeypatch.delenv('SKYTPU_PALLAS_INTERPRET', raising=False)
+    monkeypatch.setattr(attention, '_on_tpu', lambda: True)
+
+
+def test_looped_tick_keeps_the_pool_in_place(one_chip, kernel_on_tpu):
     """The decode tick of a looped stack at the benchmark's shapes
     (48 layers x 4 passes: a pool of 192 cache layers, 6.6 GB): the
     scan over passes around the layer scan carries the pool as the
     layer scan alone does, so the tick aliases it to its result and
     makes no temporary of a pool's or a private cache's size; the
     kernel is in it, given the whole pool."""
-    from skypilot_tpu.models import configs
-    from skypilot_tpu.ops import attention
-    monkeypatch.delenv('SKYTPU_PALLAS_INTERPRET', raising=False)
-    monkeypatch.setattr(attention, '_on_tpu', lambda: True)
-    cfg = configs.ModelConfig(
-        vocab_size=49152, d_model=2048, n_layers=48, n_heads=16,
-        n_kv_heads=16, d_ff=5632, loop_passes=4, post_norms=True,
-        norm_eps=1e-6, rope_theta=1e6, dtype=jnp.bfloat16,
-        param_dtype=jnp.bfloat16, remat=False)
-    slots, pages, d, f, hd = 8, 264, 2048, 5632, 128
-
-    def arg(shape, dtype=jnp.bfloat16):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-
-    scale = lambda *lead: {'scale': arg(lead + (d,), jnp.float32)}
-    proj = {'kernel': arg((48, d, 16 * hd))}       # the serving form
-    params = {
-        'embed': {'embedding': arg((49152, d))},
-        'final_norm': scale(),
-        'exit_gate': {'kernel': arg((d, 1)), 'bias': arg((1,))},
-        'lm_head': {'kernel': arg((d, 49152))},
-        'layers': {'layer': {
-            'attn_norm': scale(48), 'attn_post_norm': scale(48),
-            'mlp_norm': scale(48), 'mlp_post_norm': scale(48),
-            'attn': {'q_proj': proj, 'k_proj': proj, 'v_proj': proj,
-                     'o_proj': {'kernel': arg((48, 16, hd, d))}},
-            'mlp': {'gate_proj': {'kernel': arg((48, d, f))},
-                    'up_proj': {'kernel': arg((48, d, f))},
-                    'down_proj': {'kernel': arg((48, f, d))}}}}}
-    with_sharding = lambda tree: jax.tree.map(
-        lambda a: arg(a.shape, a.dtype), tree)
-    paged = with_sharding(jax.eval_shape(
-        lambda: decode.init_paged_cache(cfg, pages, 16, slots, 32)))
-    state = with_sharding(jax.eval_shape(
-        lambda: decode.init_engine_state(slots)))
+    cfg, params, state, paged, _ = _engine_shapes('looped', one_chip)
     compiled = jax.jit(
         decode.bind(decode.paged_engine_step, cfg, kernel='pallas'),
         donate_argnums=(2,)).lower(params, state, paged).compile()
     memory = compiled.memory_analysis()
-    pool = 2 * 192 * pages * 16 * 16 * hd * 2
+    pool = 2 * 192 * 264 * 16 * 16 * 128 * 2
     assert memory.alias_size_in_bytes >= pool
     assert memory.temp_size_in_bytes < 0.05e9
     assert 'paged_decode_attention' in compiled.as_text()
+
+
+@pytest.mark.parametrize('stack,later', [
+    ('mistral', False), ('mistral', True), ('looped', False),
+    ('looped', True)], ids=['mistral-first', 'mistral-later',
+                            'looped-first', 'looped-later'])
+def test_fused_step_keeps_pool_and_kernels_in_place(one_chip,
+                                                    kernel_on_tpu, stack,
+                                                    later):
+    """A chunk of 128 rows (padded: so many of them the prompt's)
+    riding the tick (`paged_engine_step_with_chunk`), a prompt's first
+    and a later one:
+    the pool (and a later chunk's private cache) is aliased to the
+    result as in the plain tick; the temporaries are no more than the
+    standalone chunk's; the paged kernel is in it; and no operation of
+    the layer loop results in a layer's whole q, k or v kernel: the one
+    product for both groups of rows still reads the stacked kernel in
+    place (`_attn_proj`)."""
+    cfg, params, state, paged, cache = _engine_shapes(stack, one_chip)
+    tokens = jax.ShapeDtypeStruct((1, 128), jnp.int32, sharding=one_chip)
+    max_len = cache['k'].shape[3]
+    compiled = jax.jit(
+        decode.bind(decode.paged_engine_step_with_chunk, cfg,
+                    max_len=max_len, kernel='pallas'),
+        donate_argnums=(2, 4)).lower(
+            params, state, paged, tokens, cache if later else None,
+            jax.ShapeDtypeStruct((), jnp.int32,
+                                 sharding=one_chip)).compile()
+    if later:
+        alone = jax.jit(decode.bind(decode.prefill_chunk, cfg),
+                        donate_argnums=(2,)).lower(params, tokens, cache)
+    else:
+        alone = jax.jit(decode.bind(decode.prefill, cfg,
+                                    max_len=max_len)).lower(params, tokens)
+    nbytes = lambda tree: sum(
+        math.prod(a.shape) * a.dtype.itemsize
+        for a in jax.tree.leaves(tree))
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= nbytes(
+        (paged['k'], paged['v'])) + later * nbytes(
+            (cache['k'], cache['v']))
+    assert memory.temp_size_in_bytes <= (
+        alone.compile().memory_analysis().temp_size_in_bytes + 0.05e9)
+    hlo = compiled.as_text()
+    assert 'paged_decode_attention' in hlo
+    kernels = {cfg.d_model * heads * cfg.head_dim
+               for heads in (cfg.n_heads, cfg.n_kv_heads)}
+    whole = [op for op in _own_operations(hlo)
+             if math.prod(op[1]) in kernels]
+    assert not whole, whole
+
+
+@pytest.mark.parametrize('stack,text_sha256', [
+    ('mistral',
+     'bdba0ff0b5eae2effe32aac0cfae9646d8ec60f0fe7d27fe9e403a237e0596af'),
+    ('looped',
+     '887f3c4edc6f595b800fcb327a868e3bda9dfaeda4a95b68d25136fc390dc346'),
+])
+def test_plain_tick_lowers_to_the_text_it_had(one_chip, kernel_on_tpu,
+                                              stack, text_sha256):
+    """A tick without a chunk is the program it was before a chunk
+    could ride it (PR 36): its lowered text at the benchmark's shapes,
+    less the Mosaic kernel's payload (which holds the checkout's path),
+    hashes to what the parent commit's did.  A change that means to
+    alter the tick lowers it at the commit before, sees that this
+    held, and pins its own text here."""
+    import hashlib
+    cfg, params, state, paged, _ = _engine_shapes(stack, one_chip)
+    text = jax.jit(
+        decode.bind(decode.paged_engine_step, cfg, kernel='pallas'),
+        donate_argnums=(2,)).lower(params, state, paged).as_text()
+    text = re.sub(r'backend_config = "[^"]*"', 'backend_config = ""', text)
+    assert hashlib.sha256(text.encode()).hexdigest() == text_sha256

@@ -259,8 +259,8 @@ def test_tick_on_the_whole_pool_with_two_kinds_of_layer(monkeypatch, setup,
     run = lambda kernel: jax.jit(lambda t, p: decode._paged_forward(
         cfg, params, t, p, kernel=kernel, all_positions=True))(
             tokens, paged)
-    logits_g, k_g, v_g, counts_g, _ = run('gather')
-    logits_p, k_p, v_p, counts_p, _ = run('pallas')
+    logits_g, k_g, v_g, counts_g, _, _ = run('gather')
+    logits_p, k_p, v_p, counts_p, _, _ = run('pallas')
     np.testing.assert_allclose(np.asarray(logits_p), np.asarray(logits_g),
                                atol=_TOL, rtol=0)
     np.testing.assert_array_equal(np.asarray(counts_p),
