@@ -28,7 +28,19 @@ Two always-on, low-overhead instruments:
   The **starvation probe** (`probe_starved`) is asked right before an
   iteration's first dispatch with a tick in flight: if that tick has
   already finished, the device has run dry.  The count is exact; the
-  seconds are an estimate (see `probe_starved`).
+  seconds are an estimate, also made where the read finds its tick
+  finished at entry (see `_wait_returned`).
+
+  Outside the iterations the profiler names what else holds the
+  worker, so that every idle gap of the device has a host span open at
+  its start: `skytpu/idle` (stat `reason`: `empty`, parked waiting for
+  work; `pages`, the pool-exhausted retry throttle) and `skytpu/gc`
+  (stats `gen`, `collected`: a collector pause, from a `gc.callbacks`
+  hook installed by `start()` and removed by `close()`).  Neither is a
+  phase: they never enter the ring or `loop_s`, and are counted in
+  `idle_s`, `deferred_s`, `gc_s` and `gc_pauses`.  `device_wait()`
+  asks the tick it reads whether it is already finished (stat `ready`):
+  such a read is host time, counted in `late_reads` and `late_read_s`.
 
 - **RecompileSentinel** -- wraps the engine's resolved jit entries
   (incl. the Pallas kernel path, a closure constant of the wrapped
@@ -45,6 +57,7 @@ Knobs: `SKYTPU_PROFILE_RING_TICKS` (ring capacity, default 512),
 from __future__ import annotations
 
 import collections
+import gc
 import os
 import threading
 import time
@@ -60,9 +73,17 @@ PHASES = ('admit', 'prefill-chunk', 'decode-step', 'spec-verify',
           'device-wait', 'sample', 'page-scatter', 'handoff',
           'slice-sync')
 DEVICE_WAIT = 'device-wait'
+# Phases that hand the device its next program: the first of them to
+# end in an iteration is when the device has work again.
+DISPATCHES = ('decode-step', 'prefill-chunk', 'spec-verify')
 # Names on the profiler trace's host plane: `skytpu/tick` (stat `n`)
-# around `skytpu/<phase>` (stats `n`, `count`, `request_id`).
+# around `skytpu/<phase>` (stats `n`, `count`, `request_id`; `ready` on
+# `device-wait`); between iterations `skytpu/idle` (stats `n`,
+# `reason`), and `skytpu/gc` (stats `gen`, `collected`) on whichever
+# thread collected.
 TRACE_PREFIX = 'skytpu/'
+# Why the worker parked, and the `tick_loop` key its seconds go to.
+IDLE_KEYS = {'empty': 'idle_s', 'pages': 'deferred_s'}
 
 DEFAULT_RING_TICKS = 512
 # Steady-state threshold: a compile after this many quiet calls of the
@@ -150,19 +171,26 @@ class _Phase:
     iteration's record and closes the `skytpu/<name>` annotation.  Set
     `record = False` before leaving a phase whose machinery ran but did
     no work (the trace still shows it; the ring does not), and `count`
-    once what it worked on is known."""
+    once what it worked on is known.  A `device-wait` from
+    `device_wait()` carries `ready` (the tick read was finished at
+    entry) and, set by the collector hook, `gc_t0` (when the first
+    collection inside it began)."""
 
     __slots__ = ('_prof', 'name', 'request_id', 'count', 'record',
-                 'dur_s', '_t0', '_inner_s', '_outer', '_span', '_count0')
+                 'dur_s', '_t0', '_inner_s', '_outer', '_span', '_count0',
+                 'ready', 'gc_t0')
 
     def __init__(self, prof: 'TickProfiler', name: str,
-                 request_id: Optional[str], count: Optional[int]):
+                 request_id: Optional[str], count: Optional[int],
+                 ready: Optional[bool] = None):
         self._prof = prof
         self.name = name
         self.request_id = request_id
         self.count = count
         self.record = True
         self.dur_s = 0.0
+        self.ready = ready
+        self.gc_t0: Optional[float] = None
 
     def __enter__(self):
         prof = self._prof
@@ -171,6 +199,8 @@ class _Phase:
             args['count'] = self.count
         if self.request_id is not None:
             args['request_id'] = self.request_id
+        if self.ready is not None:
+            args['ready'] = int(self.ready)
         self._count0 = self.count
         self._span = prof._annotate(TRACE_PREFIX + self.name, **args)  # pylint: disable=protected-access
         self._outer = prof._open  # pylint: disable=protected-access
@@ -192,7 +222,9 @@ class _Phase:
         if self._outer is not None:
             self._outer._inner_s += whole  # pylint: disable=protected-access
         if self.name == DEVICE_WAIT:
-            prof._wait_returned(now)  # pylint: disable=protected-access
+            prof._wait_returned(self, now)  # pylint: disable=protected-access
+        elif self.name in DISPATCHES and prof._t_dispatched is None:  # pylint: disable=protected-access
+            prof._t_dispatched = now  # pylint: disable=protected-access
         if self.record:
             prof._cur.append(  # pylint: disable=protected-access
                 [self.name, self._t0 - prof._t_tick0, self.dur_s,  # pylint: disable=protected-access
@@ -201,6 +233,35 @@ class _Phase:
 
 
 _NULL_PHASE = _NullPhase()
+
+
+class _Idle:
+    """The worker parked between iterations: the `skytpu/idle` span and
+    its seconds under `IDLE_KEYS[reason]`, from one pair of clock
+    reads."""
+
+    __slots__ = ('_prof', 'reason', '_span', '_t0')
+
+    def __init__(self, prof: 'TickProfiler', reason: str):
+        self._prof = prof
+        self.reason = reason
+
+    def __enter__(self):
+        prof = self._prof
+        self._span = prof._annotate(TRACE_PREFIX + 'idle',  # pylint: disable=protected-access
+                                    n=prof.iteration, reason=self.reason)
+        self._span.__enter__()
+        self._t0 = prof._clock()  # pylint: disable=protected-access
+        return self
+
+    def __exit__(self, *exc):
+        prof = self._prof
+        seconds = prof._clock() - self._t0  # pylint: disable=protected-access
+        self._span.__exit__(*exc)
+        key = IDLE_KEYS[self.reason]
+        with prof._lock:  # pylint: disable=protected-access
+            prof._parked[key] += seconds  # pylint: disable=protected-access
+        return False
 
 
 class TickProfiler:
@@ -243,20 +304,38 @@ class TickProfiler:
         self._phase_counts: Dict[str, int] = {}
         self._starved_ticks = 0
         self._starved_s = 0.0
+        self._late_reads = 0
+        self._late_read_s = 0.0
+        self._parked = {key: 0.0 for key in IDLE_KEYS.values()}
+        self._t_born = clock()   # `elapsed_s` counts from here
         self._mem_watermark: Optional[int] = None
+        # Collector pauses.  Written by the hook alone, without the
+        # lock: CPython runs one collection at a time, and one may
+        # start while this or any thread holds the lock.
+        self._gc_s = 0.0
+        self._gc_pauses = 0
+        self._gc_t0 = 0.0
+        self._gc_span: Any = None
+        self._gc_hook: Optional[Callable[[str, Dict[str, Any]], None]] = None
         # Worker-thread state for the iteration in progress.
         self.iteration = 0       # numbers every begin_tick, idle or not
         self._t_tick0 = 0.0
         self._cur: List[List[Any]] = []
         self._open: Optional[_Phase] = None
         self._tick_span: Any = None
-        # Starvation probe: when the last device-wait returned, the
-        # last interval between two returns of an unstarved iteration
-        # (the running tick length), and this iteration's findings.
-        self._t_wait_ret: Optional[float] = None
+        # Starvation probe: the reference point the last device-wait
+        # left (see `_wait_returned`) and whether a stall moved it off
+        # the return, the last interval between two clean returns of an
+        # unstarved iteration (the running tick length), and this
+        # iteration's findings: when the probe found the tick in flight
+        # finished, when the first dispatch ended, the dry estimate.
+        self._t_ref: Optional[float] = None
+        self._ref_stalled = False
         self._tick_len: Optional[float] = None
         self._waited = False
         self._starved_now: Optional[float] = None
+        self._t_dispatched: Optional[float] = None
+        self._dry_s = 0.0
         # Self-overhead model: one phase's clock reads and annotation,
         # measured once, multiplied by the cumulative phase count in
         # snapshot().
@@ -273,6 +352,45 @@ class TickProfiler:
         # on the list append and the bookkeeping around them.
         return (time.perf_counter() - t0) / n * 2.0
 
+    # ------------------------------------------------------- lifecycle
+
+    def start(self) -> None:
+        """Install this profiler's `gc.callbacks` hook (once; never
+        when disabled).  The engine's worker calls it as it starts and
+        `close()` as it exits."""
+        if self.disabled or self._gc_hook is not None:
+            return
+        self._gc_hook = self._on_gc
+        gc.callbacks.append(self._gc_hook)
+
+    def close(self) -> None:
+        """Remove the collector hook; the profiler's totals stay."""
+        if self._gc_hook is None:
+            return
+        gc.callbacks.remove(self._gc_hook)
+        self._gc_hook = None
+
+    def _on_gc(self, phase: str, info: Dict[str, Any]) -> None:
+        """Every collection, on any thread, stops the worker: `skytpu/gc`
+        from `start` to `stop`, and `gc_s`, `gc_pauses`.  A collection
+        that begins inside a `device-wait` marks it for the probe."""
+        now = self._clock()
+        if phase == 'start':
+            self._gc_t0 = now
+            wait = self._open
+            if (wait is not None and wait.name == DEVICE_WAIT and
+                    wait.gc_t0 is None):
+                wait.gc_t0 = now
+            self._gc_span = self._annotate(TRACE_PREFIX + 'gc',
+                                           gen=info['generation'])
+            self._gc_span.__enter__()
+        elif self._gc_span is not None:
+            span, self._gc_span = self._gc_span, None
+            span.set_metadata(collected=info['collected'])
+            span.__exit__(None, None, None)
+            self._gc_s += now - self._gc_t0
+            self._gc_pauses += 1
+
     # ---------------------------------------------- worker-thread API
 
     def begin_tick(self) -> None:
@@ -283,6 +401,8 @@ class TickProfiler:
         self._open = None
         self._waited = False
         self._starved_now = None
+        self._t_dispatched = None
+        self._dry_s = 0.0
         self._tick_span = self._annotate(TRACE_PREFIX + 'tick',
                                          n=self.iteration)
         self._tick_span.__enter__()
@@ -299,29 +419,73 @@ class TickProfiler:
             return _NULL_PHASE
         return _Phase(self, name, request_id, count)
 
+    def device_wait(self, tick, *, count: Optional[int] = None):
+        """The `device-wait` phase around the blocking read of `tick`
+        (a handle of the tick's outputs).  Asks `tick.is_ready()` once
+        on entry, onto the span as stat `ready`: a read of a tick that
+        had already finished is host time (transfer, runtime, the GIL),
+        counted in `late_reads` and `late_read_s`."""
+        if self.disabled:
+            return _NULL_PHASE
+        return _Phase(self, DEVICE_WAIT, None, count,
+                      ready=bool(tick.is_ready()))
+
+    def idle(self, reason: str):
+        """The worker parked between iterations (a context manager):
+        `reason` 'empty' waits for work, 'pages' throttles the retry of
+        an admission the exhausted pool deferred.  Not an iteration."""
+        if self.disabled:
+            return _NULL_PHASE
+        return _Idle(self, reason)
+
     def probe_starved(self, inflight_finished) -> bool:
         """Ask, right before an iteration's first dispatch, whether the
         tick in flight has already finished (`is_ready()`, no
         blocking): if so nothing is queued behind it and the device
-        has run dry.  Counted exactly in `starved_ticks`.  `starved_s`
-        gets an ESTIMATE of for how long: host time since the previous
-        device-wait returned (when the tick in flight started on the
-        device) less the running tick length, floored at 0 -- good to
-        the tick-to-tick variation, and 0 until one unstarved interval
-        has been seen."""
+        has run dry.  Counted exactly in `starved_ticks`; the seconds
+        are estimated when the iteration's read returns
+        (`_wait_returned`)."""
         if self.disabled or not inflight_finished.is_ready():
             return False
-        dry = 0.0
-        if self._t_wait_ret is not None and self._tick_len is not None:
-            dry = max(0.0, self._clock() - self._t_wait_ret -
-                      self._tick_len)
-        self._starved_now = dry
+        self._starved_now = self._clock()
         return True
 
-    def _wait_returned(self, now: float) -> None:
-        if self._t_wait_ret is not None and self._starved_now is None:
-            self._tick_len = now - self._t_wait_ret
-        self._t_wait_ret = now
+    def _wait_returned(self, wait: _Phase, now: float) -> None:
+        """Two jobs.  First, where the tick this read waited for had
+        finished before the iteration's first dispatch ended -- the
+        probe found it so, or the read found it so at entry (a stall
+        inside the dispatch) -- `starved_s` gets an ESTIMATE of how
+        long the device ran dry: from the last reference point plus the
+        running tick length (when that tick should have finished) to
+        the end of the first dispatch, floored at 0; good to the
+        tick-to-tick variation, and 0 until one clean interval has been
+        seen.  Second, the reference point this read leaves: the
+        earliest of its return, its entry where the tick was already
+        finished there, and the start of a collection inside it.  A
+        host stalled inside the read then counts toward `starved_s`
+        and not as device time.  Only an interval between two clean
+        returns (no stall in either) teaches the tick length."""
+        if self._starved_now is not None or wait.ready:
+            end = self._t_dispatched
+            if end is None:
+                end = (self._starved_now if self._starved_now is not None
+                       else wait._t0)  # pylint: disable=protected-access
+            if self._t_ref is not None and self._tick_len is not None:
+                self._dry_s = max(0.0, end - self._t_ref - self._tick_len)
+        ref = now
+        if wait.ready:
+            ref = wait._t0  # pylint: disable=protected-access
+            with self._lock:
+                self._late_reads += 1
+                self._late_read_s += now - ref
+        if wait.gc_t0 is not None:
+            ref = min(ref, wait.gc_t0)
+        stalled = bool(wait.ready) or wait.gc_t0 is not None
+        if (self._t_ref is not None and self._starved_now is None and
+                not stalled and not self._ref_stalled):
+            self._tick_len = now - self._t_ref
+        self._t_ref = ref
+        self._ref_stalled = stalled
         self._waited = True
 
     def end_tick(self) -> None:
@@ -334,7 +498,7 @@ class TickProfiler:
         if not self._waited:
             # No tick was in flight: the next device-wait return does
             # not follow the last one by a tick.
-            self._t_wait_ret = None
+            self._t_ref = None
         cur = self._cur
         self._cur = []
         if not cur:
@@ -359,7 +523,7 @@ class TickProfiler:
                     self._phase_counts.get(name, 0) + 1)
             if self._starved_now is not None:
                 self._starved_ticks += 1
-                self._starved_s += self._starved_now
+            self._starved_s += self._dry_s
         for name, _, seconds, _, _ in cur:
             obs = _PHASE_OBSERVERS.get(name)
             if obs is None:
@@ -376,14 +540,25 @@ class TickProfiler:
     def tick_loop(self) -> Dict[str, Any]:
         """Cumulative totals since the engine started, all monotone (a
         reader takes a difference): iterations that did work, their
-        summed durations, seconds by phase, and the starvation probe's
-        count and estimated seconds."""
+        summed durations, seconds by phase, the starvation probe's
+        count and estimated seconds, the late reads, the seconds parked
+        (`idle_s` waiting for work, `deferred_s` throttled by the
+        pool), collector pauses (every thread's), and `elapsed_s`,
+        seconds on the profiler's clock since it was made: the
+        denominator of a share over a reader's window."""
+        elapsed = self._clock() - self._t_born
         with self._lock:
             return {'iterations': self._ticks,
                     'loop_s': self._loop_s,
                     'phase_s': dict(self._phase_totals),
                     'starved_ticks': self._starved_ticks,
-                    'starved_s': self._starved_s}
+                    'starved_s': self._starved_s,
+                    'late_reads': self._late_reads,
+                    'late_read_s': self._late_read_s,
+                    **self._parked,
+                    'gc_pauses': self._gc_pauses,
+                    'gc_s': self._gc_s,
+                    'elapsed_s': elapsed}
 
     def _read_memory(self) -> Optional[int]:
         """One read of the backend's watermark, on the reader's

@@ -1614,7 +1614,7 @@ class ContinuousBatchingEngine:
                     sharding_lib.spec_drafts_sharding(self._mesh))
             (self._state, self._cache, finished, toks_d, counts_d,
              moe_d, exit_d) = self._dispatch_spec_step(drafts_dev)
-        with prof.phase('device-wait', count=n_live):
+        with prof.device_wait(finished, count=n_live):
             toks = np.asarray(toks_d)
             counts = np.asarray(counts_d)
             fins = np.asarray(finished)
@@ -1668,6 +1668,7 @@ class ContinuousBatchingEngine:
         # Profiling lifecycle: one start/end pair brackets the worker's
         # whole run so journal replay can attribute the ring's ticks to
         # an engine incarnation (and see whether it died or drained).
+        # The profiler's collector hook lives exactly as long.
         prof = self._profiler
         try:
             journal = profiling.serve_journal()
@@ -1677,9 +1678,11 @@ class ContinuousBatchingEngine:
             journal.append('tick_profile_start',
                            ring_ticks=prof.ring_ticks,
                            enabled=not prof.disabled)
+        prof.start()
         try:
             self._run_pipelined(prof)
         finally:
+            prof.close()
             if journal is not None:
                 journal.append(
                     'tick_profile_end',
@@ -1830,7 +1833,8 @@ class ContinuousBatchingEngine:
                     state_t, finished_t, snapshot, moe_t, exit_t = inflight
                     # The one place the host waits for the device: the
                     # blocking read of the tick in flight, nothing else.
-                    with prof.phase('device-wait', count=len(snapshot)):
+                    with prof.device_wait(finished_t,
+                                          count=len(snapshot)):
                         toks = np.asarray(state_t['tokens'])
                         fins = np.asarray(finished_t)
                         self._count_tick(moe_t, exit_t)
@@ -1866,7 +1870,8 @@ class ContinuousBatchingEngine:
                         # pages soon: throttle the retry loop (TTL
                         # expiry / cancel / submit backpressure are
                         # what resolve this state).
-                        time.sleep(0.005)
+                        with prof.idle('pages'):
+                            time.sleep(0.005)
                     else:
                         with self._cond:
                             with self._host_ops_lock:
@@ -1874,7 +1879,8 @@ class ContinuousBatchingEngine:
                             if (not len(self._queue) and
                                     not ops_waiting and
                                     not self._stop.is_set()):
-                                self._cond.wait(timeout=0.05)
+                                with prof.idle('empty'):
+                                    self._cond.wait(timeout=0.05)
             except Exception as e:  # pylint: disable=broad-except
                 logger.exception('batching engine tick failed')
                 # The jit'd step donates the page pool — after a

@@ -1,13 +1,16 @@
 """Continuous profiling plane tests (ISSUE 18 tentpole).
 
 TickProfiler (exclusive phase laps, bounded ring, idle-tick skip,
-disable gate), the recompile sentinel (warm-up compiles free,
+disable gate), what holds the worker besides an iteration (the parked
+engine, collector pauses, late reads) and the starvation probe's
+reading of them, the recompile sentinel (warm-up compiles free,
 steady-state recompiles journaled + counted exactly once), the
 collapsed-stack / Chrome-trace exports, `/profile` on BOTH HTTP
 fronts, the sharpened MFU numerator, and the ≤3% overhead budget.
 """
 from __future__ import annotations
 
+import gc
 import json
 import time
 
@@ -220,6 +223,7 @@ class TestTickProfiler:
         for a, b in zip(seen, seen[1:]):
             assert b['iterations'] == a['iterations'] + 1
             assert b['loop_s'] > a['loop_s']
+            assert b['elapsed_s'] > a['elapsed_s']
             for name, total in a['phase_s'].items():
                 assert b['phase_s'][name] >= total
         snap = prof.snapshot()
@@ -230,7 +234,12 @@ class TestTickProfiler:
         assert seen[-1]['phase_s'] == pytest.approx(ring_s)
         assert seen[-1]['loop_s'] == pytest.approx(
             sum(rec['dur_s'] for rec in snap['ring']))
-        assert snap['tick_loop'] == seen[-1]
+        # The snapshot's totals are the last reader's; only the clock
+        # has moved on.
+        later = dict(snap['tick_loop'])
+        assert later.pop('elapsed_s') > seen[-1]['elapsed_s']
+        assert later == {k: v for k, v in seen[-1].items()
+                         if k != 'elapsed_s'}
 
     def test_disable_gate_is_a_noop(self):
         prof = _profiler(disabled=True, annotate=None)
@@ -377,6 +386,303 @@ class TestStarvationProbe:
         loop = prof.tick_loop()
         assert loop['starved_ticks'] == 1
         assert loop['starved_s'] == 0.0
+
+
+class RaisingInflight:
+    def is_ready(self) -> bool:
+        raise AssertionError('is_ready() asked of a disabled profiler')
+
+
+@pytest.fixture
+def quiet_collector():
+    """Only the test's own `gc.collect()` calls collect, so a fake
+    clock's queued reads go where the test puts them."""
+    was = gc.isenabled()
+    gc.disable()
+    yield
+    if was:
+        gc.enable()
+
+
+class TestHostStalls:
+    """What holds the worker outside an iteration (`skytpu/idle`,
+    `skytpu/gc`) and inside the blocking read (`ready`), as spans and
+    as `tick_loop` counters, and what the probe makes of a stall."""
+
+    def test_parked_worker_is_a_span_and_no_iteration(self):
+        clock = FakeClock(step=0.0)
+        ann = RecordingAnnotation()
+        prof = _profiler(clock=clock, annotate=ann)
+        del ann.opened[:]
+        seen = [prof.tick_loop()]
+        clock.queued = [0.0, 0.250]
+        with prof.idle('empty'):
+            pass
+        seen.append(prof.tick_loop())
+        clock.queued = [0.0, 0.005]
+        with prof.idle('pages'):
+            pass
+        clock.queued = [0.1]          # the reader's own clock read
+        seen.append(prof.tick_loop())
+        assert ann.opened == [('skytpu/idle', {'n': 0, 'reason': 'empty'}),
+                              ('skytpu/idle', {'n': 0, 'reason': 'pages'})]
+        assert ann.depth == 0
+        loop = seen[-1]
+        assert loop['idle_s'] == pytest.approx(0.250)
+        assert loop['deferred_s'] == pytest.approx(0.005)
+        assert loop['iterations'] == 0 and loop['loop_s'] == 0.0
+        assert prof.snapshot()['ring'] == []
+        assert [s['elapsed_s'] for s in seen] == pytest.approx(
+            [0.0, 0.250, 0.355])
+
+    def test_a_collection_is_a_span_and_a_counter(self, quiet_collector):
+        ann = RecordingAnnotation()
+        prof = _profiler(clock=FakeClock(step=1.0), annotate=ann)
+        del ann.opened[:]
+        prof.start()
+        try:
+            before = prof.tick_loop()
+            gc.collect()
+            gc.collect(0)
+            after = prof.tick_loop()
+        finally:
+            prof.close()
+        assert after['gc_pauses'] == before['gc_pauses'] + 2
+        # Two clock reads a collection, each 1 s on this clock.
+        assert after['gc_s'] == pytest.approx(before['gc_s'] + 2.0)
+        spans = [(name, kw) for name, kw in ann.opened
+                 if name == 'skytpu/gc']
+        assert [kw['gen'] for _, kw in spans] == [2, 0]
+        assert all(kw['collected'] >= 0 for _, kw in spans)
+        assert ann.depth == 0
+        # Gone with close(): a later collection counts nowhere.
+        gc.collect()
+        assert prof.tick_loop()['gc_pauses'] == after['gc_pauses']
+
+    def test_collections_from_many_threads_all_count(self):
+        """The hook's counters take no lock (a collection can start
+        while any thread holds it); collections are one at a time, so
+        none is lost under many collecting threads and a fast switch."""
+        import sys
+        import threading
+        prof = _profiler(clock=time.monotonic)
+        stops = []
+        lock = threading.Lock()
+
+        def reference(phase, info):
+            if phase == 'stop':
+                with lock:
+                    stops.append(info['generation'])
+
+        def collect():
+            for _ in range(100):
+                gc.collect(0)
+                prof.tick_loop()
+
+        switch = sys.getswitchinterval()
+        prof.start()
+        gc.callbacks.append(reference)
+        try:
+            sys.setswitchinterval(1e-6)
+            workers = [threading.Thread(target=collect) for _ in range(16)]
+            for w in workers:
+                w.start()
+            for w in workers:
+                w.join(timeout=60)
+            assert not any(w.is_alive() for w in workers)
+        finally:
+            sys.setswitchinterval(switch)
+            gc.callbacks.remove(reference)
+            prof.close()
+        # A call made while another thread collects returns at once, so
+        # there are fewer collections than calls; each one counts once.
+        loop = prof.tick_loop()
+        assert loop['gc_pauses'] == len(stops) > 0
+        assert loop['gc_s'] > 0.0
+
+    def test_hook_lifecycle_leaves_the_collector_as_it_was(self):
+        before = list(gc.callbacks)
+        for _ in range(20):
+            prof = _profiler()
+            prof.start()
+            prof.start()                      # once a profiler
+            assert len(gc.callbacks) == len(before) + 1
+            prof.close()
+            prof.close()
+        held = [_profiler() for _ in range(20)]
+        for prof in held:
+            prof.start()
+        assert len(gc.callbacks) == len(before) + 20
+        for prof in held:
+            prof.close()
+        assert gc.callbacks == before
+
+    def test_disabled_installs_nothing_and_asks_nothing(self):
+        before = list(gc.callbacks)
+        prof = _profiler(disabled=True, annotate=None)
+        prof.start()
+        assert gc.callbacks == before
+        prof.begin_tick()
+        with prof.device_wait(RaisingInflight(), count=3):
+            pass
+        with prof.idle('empty'):
+            pass
+        prof.end_tick()
+        prof.close()
+        loop = prof.tick_loop()
+        assert (loop['late_reads'], loop['idle_s'], loop['gc_pauses']) == \
+            (0, 0.0, 0)
+
+    @staticmethod
+    def _iteration(prof, clock, *, host_s, probe_ready, wait_ready,
+                   wait_s, inside=None, in_dispatch=None):
+        """One pipelined iteration as `TestStarvationProbe` runs it, the
+        read through `device_wait`; `inside` runs within the read,
+        `in_dispatch` within the dispatch."""
+        clock.queued = [0.0]
+        prof.begin_tick()
+        clock.queued = [host_s]
+        if not prof.probe_starved(FakeInflight(probe_ready)):
+            clock.queued = []
+            clock.now += host_s
+        clock.queued = [0.0, 0.0]
+        with prof.phase('decode-step'):
+            if in_dispatch is not None:
+                in_dispatch()
+        clock.queued = [0.0, wait_s]
+        with prof.device_wait(FakeInflight(wait_ready), count=4):
+            if inside is not None:
+                inside()
+        clock.queued = [0.0]
+        prof.end_tick()
+
+    def _stall(self, prof, clock, *, late, inside=None):
+        """Two clean 100 ms ticks, then a read that takes 400 ms,
+        then a probe 5 ms later."""
+        for _ in range(2):
+            self._iteration(prof, clock, host_s=0.005, probe_ready=False,
+                            wait_ready=False, wait_s=0.095)
+        self._iteration(prof, clock, host_s=0.005, probe_ready=False,
+                        wait_ready=late, wait_s=0.400, inside=inside)
+        self._iteration(prof, clock, host_s=0.005, probe_ready=True,
+                        wait_ready=False, wait_s=0.0)
+        return prof.tick_loop()
+
+    def test_a_late_read_is_counted_and_starves_the_next_probe(self):
+        clock = FakeClock(step=0.0)
+        ann = RecordingAnnotation()
+        prof = _profiler(clock=clock, annotate=ann)
+        loop = self._stall(prof, clock, late=True)
+        assert loop['late_reads'] == 1
+        assert loop['late_read_s'] == pytest.approx(0.400)
+        # The tick in flight started by the late read's entry at the
+        # latest: 405 ms of host time less a 100 ms tick.
+        assert loop['starved_ticks'] == 1
+        assert loop['starved_s'] == pytest.approx(0.305)
+        waits = [kw for name, kw in ann.opened
+                 if name == 'skytpu/device-wait']
+        assert [kw['ready'] for kw in waits] == [0, 0, 1, 0]
+
+    def test_a_read_not_ready_at_entry_changes_nothing(self):
+        """The same stall with the tick still running at the read's
+        entry: no late read, and the probe's estimate is the parent's,
+        step for step the plain `device-wait` phase's."""
+        runs = []
+        for plain in (False, True):
+            clock = FakeClock(step=0.0)
+            prof = _profiler(clock=clock)
+            if plain:      # what the probe made of the same loop before
+                prof.device_wait = lambda tick, count: prof.phase(
+                    'device-wait', count=count)
+            runs.append(self._stall(prof, clock, late=False))
+        for loop in runs:
+            loop.pop('elapsed_s')
+        assert runs[0] == runs[1]
+        assert runs[0]['late_reads'] == 0
+        assert runs[0]['starved_ticks'] == 1
+        assert runs[0]['starved_s'] == 0.0
+
+    def test_a_collection_inside_the_read_moves_the_reference(
+            self, quiet_collector):
+        clock = FakeClock(step=0.0)
+        ann = RecordingAnnotation()
+        prof = _profiler(clock=clock, annotate=ann)
+        prof.start()
+
+        def collect():   # starts 10 ms into the read, lasts 300 ms
+            clock.queued = [0.010, 0.300]
+            gc.collect()
+            clock.queued = [0.090]     # the read returns 400 ms in
+
+        try:
+            loop = self._stall(prof, clock, late=False, inside=collect)
+        finally:
+            prof.close()
+        assert loop['gc_pauses'] == 1
+        assert loop['gc_s'] == pytest.approx(0.300)
+        assert loop['late_reads'] == 0
+        # From the collection's start (10 ms into the read) to the
+        # probe: 395 ms, less the 100 ms tick.
+        assert loop['starved_s'] == pytest.approx(0.295)
+
+    def test_a_collection_inside_the_dispatch_starves_the_device(
+            self, quiet_collector):
+        """The probe asks before the dispatch; a pause inside it lets the
+        tick in flight finish to an idle device.  The read then finds
+        its tick finished at entry, and the device counts as dry from
+        when that tick should have ended to the dispatch's end."""
+        clock = FakeClock(step=0.0)
+        prof = _profiler(clock=clock)
+        prof.start()
+
+        def collect():   # 300 ms, begun as the dispatch began
+            clock.queued = [0.0, 0.300]
+            gc.collect()
+            clock.queued = [0.0]
+
+        try:
+            for _ in range(2):
+                self._iteration(prof, clock, host_s=0.005,
+                                probe_ready=False, wait_ready=False,
+                                wait_s=0.095)
+            self._iteration(prof, clock, host_s=0.005, probe_ready=False,
+                            wait_ready=True, wait_s=0.0001,
+                            in_dispatch=collect)
+        finally:
+            prof.close()
+        loop = prof.tick_loop()
+        assert (loop['gc_pauses'], loop['late_reads']) == (1, 1)
+        assert loop['starved_ticks'] == 0        # the probe saw nothing
+        # The tick in flight should have ended 100 ms after the last
+        # return; the dispatch ended 305 ms after it.
+        assert loop['starved_s'] == pytest.approx(0.205)
+
+    def test_a_parked_engine_counts_idle_and_drops_its_hook(self):
+        import flax.linen as nn
+
+        from skypilot_tpu.models.transformer import Transformer
+        from skypilot_tpu.serve import batching_engine
+        cfg = configs.get_config('tiny')
+        params = nn.meta.unbox(Transformer(cfg).init(
+            jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))['params'])
+        eng = batching_engine.ContinuousBatchingEngine(
+            cfg, params, max_len=64, slots=2, prefill_chunk=8,
+            kv_pages=48, page_size=8)
+        prof = eng._profiler
+        try:
+            first = eng.stats()['tick_loop']
+            time.sleep(0.3)                 # nothing to do: parked
+            later = eng.stats()['tick_loop']
+            assert prof._gc_hook in gc.callbacks
+        finally:
+            eng.stop()
+        assert later['idle_s'] > first['idle_s']
+        assert later['elapsed_s'] - first['elapsed_s'] >= \
+            later['idle_s'] - first['idle_s']
+        assert later['iterations'] == first['iterations'] == 0
+        assert prof._gc_hook is None
+        assert all(getattr(cb, '__self__', None) is not prof
+                   for cb in gc.callbacks)
 
 
 def _counter_value(name, **labels):
@@ -650,8 +956,8 @@ class TestOverheadBudget:
     def _per_tick_cost(cls, prof):
         """Seconds per tick of the instrumentation calls alone, at the
         real call pattern of a steady pipelined iteration (the probe,
-        three phases, begin/end; the real `TraceAnnotation`, with no
-        profiler session open)."""
+        three phases, the read's own readiness check, begin/end; the
+        real `TraceAnnotation`, with no profiler session open)."""
         inflight = FakeInflight(False)
         t0 = time.perf_counter()
         for _ in range(cls.TICKS):
@@ -659,7 +965,7 @@ class TestOverheadBudget:
             prof.probe_starved(inflight)
             with prof.phase('decode-step', count=16):
                 pass
-            with prof.phase('device-wait', count=16):
+            with prof.device_wait(inflight, count=16):
                 pass
             with prof.phase('sample') as phase:
                 phase.count = 16

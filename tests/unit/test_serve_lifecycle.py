@@ -732,7 +732,19 @@ class TestDisconnectReap:
             time.sleep(0.05)
         assert server._engine.stats()['busy_slots'] == 0  # pylint: disable=protected-access
 
-    def _hang_up(self, server, port, rid, headers):
+    def _hang_up(self, server, port, rid, headers, monkeypatch):
+        # Ticks of at least 3 ms (the tiny model's take well under one
+        # on an idle host): the 220 tokens then outlast the fronts'
+        # disconnect poll (0.1 s), so the request cannot finish before
+        # the hang-up is seen.
+        engine = server._engine  # pylint: disable=protected-access
+        step = engine._dispatch_step  # pylint: disable=protected-access
+
+        def slow_step():
+            time.sleep(0.003)
+            return step()
+
+        monkeypatch.setattr(engine, '_dispatch_step', slow_step)
         sock = _raw_post(port, '/generate',
                          {'prompt_ids': [[11, 12, 13, 14]],
                           'max_new_tokens': 220},
@@ -746,18 +758,19 @@ class TestDisconnectReap:
         sock.close()
         self._assert_reaped(server, rid)
 
-    def test_threaded_front_reaps_on_hangup(self, served):
+    def test_threaded_front_reaps_on_hangup(self, served, monkeypatch):
         server, t_url, _ = served
         port = int(t_url.rsplit(':', 1)[1])
         self._hang_up(server, port, 'disc-threaded-1',
-                      {'X-SkyTPU-Request-Id': 'disc-threaded-1'})
+                      {'X-SkyTPU-Request-Id': 'disc-threaded-1'},
+                      monkeypatch)
 
-    def test_async_front_reaps_on_hangup(self, served):
+    def test_async_front_reaps_on_hangup(self, served, monkeypatch):
         server, _, a_url = served
         port = int(a_url.rsplit(':', 1)[1])
         self._hang_up(server, port, 'disc-async-1',
                       {'X-SkyTPU-Request-Id': 'disc-async-1',
-                       'Connection': 'close'})
+                       'Connection': 'close'}, monkeypatch)
 
 
 # ------------------------------------------------------------ role morph
