@@ -353,7 +353,8 @@ def _attend(q, cfg, positions, k_cache, v_cache, *, use_flash: bool,
                 q, k_cache.leaf, v_cache.leaf, k_cache.tables,
                 k_cache.lengths, sm_scale=cfg.head_dim ** -0.5,
                 mesh=mesh, window=window, layer=k_cache.layer,
-                new_k=k_cache.new, new_v=v_cache.new)
+                new_k=k_cache.new, new_v=v_cache.new,
+                heads_per_row=heads_per_row(cfg, k_cache.leaf))
         if k_cache.new is None:
             return out.astype(dtype), None
         return out[0].astype(dtype), out[1:]
@@ -1363,23 +1364,53 @@ def _select_and_bookkeep(state, logits, new_cache, counts, exit_mass,
 
 
 def _page_size_of(paged: Dict[str, Any]) -> int:
-    leaf = paged['k']['q'] if isinstance(paged['k'], dict) else paged['k']
-    return leaf.shape[3]
+    return _pool_array(paged['k']).shape[3]
 
 
-def _to_rows(x, leaf):
-    """x [..., d] padded with zeros to the pool `leaf`'s row width (its
-    last axis: `init_paged_cache`'s `head_lanes`)."""
-    width = (leaf['q'] if isinstance(leaf, dict) else leaf).shape[-1]
-    pad = width - x.shape[-1]
+def _pool_array(leaf):
+    return leaf['q'] if isinstance(leaf, dict) else leaf
+
+
+def heads_per_row(cfg: ModelConfig, leaf) -> int:
+    """The kv heads one row of the pool `leaf` holds side by side
+    (`init_paged_cache`): 1 but where a narrow head shares a row."""
+    return cfg.n_kv_heads // _pool_array(leaf).shape[-3]
+
+
+def to_rows(x, leaf, axis: int):
+    """x [..., d] with its h_kv heads on `axis`, laid out as rows of the
+    pool `leaf` (`init_paged_cache`): where the pool's rows hold g heads,
+    each g adjacent heads side by side in one row (the head axis
+    becomes h_kv / g); the row then padded with zeros to the pool's
+    width.  Only the piece written moves, never the pool."""
+    pool = _pool_array(leaf)
+    g = x.shape[axis] // pool.shape[-3]
+    if g > 1:
+        x = jnp.moveaxis(x, axis, -2)
+        x = jnp.moveaxis(x.reshape(x.shape[:-2] + (
+            x.shape[-2] // g, g * x.shape[-1])), -2, axis)
+    pad = pool.shape[-1] - x.shape[-1]
     return x if not pad else jnp.pad(x, [(0, 0)] * (x.ndim - 1) +
                                      [(0, pad)])
+
+
+def from_rows(x, cfg: ModelConfig, axis: int):
+    """`to_rows` undone: x, pool rows with their heads on `axis` ->
+    [..., d] with the model's h_kv heads there."""
+    d = cfg.head_dim
+    g = cfg.n_kv_heads // x.shape[axis]
+    if g == 1:
+        return x[..., :d]
+    x = jnp.moveaxis(x, axis, -2)[..., :g * d]
+    return jnp.moveaxis(x.reshape(x.shape[:-2] + (x.shape[-2] * g, d)),
+                        -2, axis)
 
 
 def init_paged_cache(cfg: ModelConfig, n_pages: int, page_size: int,
                      slots: int, max_pages_per_slot: int,
                      quantize_kv: bool = False,
-                     head_lanes: int = 0) -> Dict[str, Any]:
+                     head_lanes: int = 0,
+                     sharded_heads: bool = False) -> Dict[str, Any]:
     """Zeroed page-pool cache.  k/v are [L, n_pages, h_kv, ps, d], L
     the cache's layers (`cfg.cache_layers`: a page holds its tokens'
     keys of every layer and pass; int8 {'q','scale'} leaves when
@@ -1406,19 +1437,31 @@ def init_paged_cache(cfg: ModelConfig, n_pages: int, page_size: int,
     The null page's stay zero: the tails before position 0.
 
     `head_lanes` (the engine's, where the paged kernel runs on a TPU: 128)
-    pads each head's row of d to a whole number of that many lanes.  The
-    kernel copies a page of a layer, [h_kv, ps, d], by one DMA, and
-    Mosaic slices a DMA's last axis by whole 128-lane tiles only; a TPU's
-    tiled layout pads a narrower row to 128 lanes in HBM anyway, so the
-    padded pool holds no byte more than the unpadded one did.  Whatever
-    writes rows pads them (`_to_rows`), whatever reads them takes the
-    first d back, and the kernel pads the query (`ops/paged_attention`).
-    A head of 128 (every model before a head of 64) pads nothing."""
-    width = cfg.head_dim
+    makes a row a whole number of that many lanes.  The kernel copies a
+    page of a layer by one DMA, and Mosaic slices a DMA's last axis by
+    whole 128-lane tiles only.  A head of d < 128 that divides the row
+    shares it: g = 128 / d adjacent kv heads side by side
+    (`heads_per_row`), a page [h_kv / g, ps, 128], so the kernel copies
+    the keys' own bytes and no padding (LFM2's 8 heads of 64: 4 KiB a
+    token of keys where padded rows read 8).  Where the heads cannot
+    share rows they are padded with zeros: an int8 pool (its scales are
+    a head's), `sharded_heads` (the engine's under a mesh of more than
+    one device, which may split the kv heads by a tensor degree that
+    divides h_kv but not h_kv / g; a slice's follower mirrors its rank
+    0), h_kv not a multiple of g, d not dividing 128.  The layout is read from these arguments
+    alone, so every rank given them builds the same pool.  Whatever
+    writes rows lays them out so (`to_rows`), whatever reads them takes
+    the heads back (`from_rows`), and the kernel spreads the query over
+    the row's lanes (`ops/paged_attention`).  A head of 128 is its own
+    row."""
+    width, h_kv = cfg.head_dim, cfg.n_kv_heads
     if head_lanes:
         width = -(-width // head_lanes) * head_lanes
-    kv_shape = (cfg.cache_layers, n_pages, cfg.n_kv_heads, page_size,
-                width)
+        g = width // cfg.head_dim
+        if (width % cfg.head_dim == 0 and h_kv % g == 0 and
+                not quantize_kv and not sharded_heads):
+            h_kv //= g
+    kv_shape = (cfg.cache_layers, n_pages, h_kv, page_size, width)
 
     def kv_leaf():
         if quantize_kv:
@@ -1539,12 +1582,12 @@ def _paged_forward(cfg: ModelConfig, params, tokens, paged, *,
     flat_off = offsets.reshape(-1)
 
     def write(c, l, new):
-        # new [B, h_kv, S, d] -> one [d] row per (slot, token, head)
-        # at (layer, page, head, offset) of the whole pool (why the
-        # head is indexed too: the docstring).
-        tok = _to_rows(new.transpose(0, 2, 1, 3).reshape(
-            b * s_q, new.shape[1], new.shape[3]), c)   # [B*S, h_kv, d]
-        at = (l, flat_pages[:, None], jnp.arange(new.shape[1])[None, :],
+        # new [B, h_kv, S, d] -> one pool row per (slot, token, pool
+        # head) at (layer, page, head, offset) of the whole pool (why
+        # the head is indexed too: the docstring).
+        tok = to_rows(new.transpose(0, 2, 1, 3).reshape(
+            b * s_q, new.shape[1], new.shape[3]), c, 1)  # [B*S, h, W]
+        at = (l, flat_pages[:, None], jnp.arange(tok.shape[1])[None, :],
               flat_off[:, None])
         if isinstance(c, dict):
             q, scale = _quant_kv(tok)
@@ -1566,9 +1609,9 @@ def _paged_forward(cfg: ModelConfig, params, tokens, paged, *,
                                   cfg.dtype)
             else:
                 arr = c[l, tables]
-            bb, p, h, s, d = arr.shape
-            return arr.transpose(0, 2, 1, 3, 4).reshape(
-                bb, h, p * s, d)[..., :cfg.head_dim]
+            bb, p, h, s, w = arr.shape
+            return from_rows(arr.transpose(0, 2, 1, 3, 4).reshape(
+                bb, h, p * s, w), cfg, 1)
 
     x = _embed(cfg, params, tokens)
     row_mask = None if active is None else jnp.repeat(active, s_q)
@@ -1886,8 +1929,8 @@ def insert_prefill_pages(paged, private_cache, pages_row, *,
     ids = jnp.asarray(pages_row, jnp.int32)
 
     def leaf(pool_leaf, private_leaf):
-        piece = _to_rows(_private_as_pages(private_leaf, ps)[
-            :, first_page:first_page + n], pool_leaf)  # [L, n, h_kv, ps, d]
+        piece = to_rows(_private_as_pages(private_leaf, ps)[
+            :, first_page:first_page + n], pool_leaf, 2)  # [L, n, h, ps, W]
         if isinstance(pool_leaf, dict):
             q, scale = _quant_kv(piece)
             return {'q': pool_leaf['q'].at[:, ids].set(q),
@@ -1941,8 +1984,8 @@ def paged_seed_private(cfg: ModelConfig, paged, pages_row, *,
                                'scale': pool_leaf['scale'][:, ids]},
                               cfg.dtype)
         else:
-            arr = pool_leaf[:, ids]            # [L, r, h_kv, ps, d]
-        arr = arr[..., :cfg.head_dim]
+            arr = pool_leaf[:, ids]            # [L, r, h, ps, W]
+        arr = from_rows(arr, cfg, 2)          # [L, r, h_kv, ps, d]
         l, _, h, _, d = arr.shape
         dense = arr.transpose(0, 2, 1, 3, 4).reshape(
             l, 1, h, r * ps, d)               # [L, 1, h_kv, r*ps, d]
@@ -1954,14 +1997,15 @@ def paged_seed_private(cfg: ModelConfig, paged, pages_row, *,
             page = _dequant_kv(jax.tree.map(
                 lambda a: jax.lax.dynamic_index_in_dim(
                     a, ids[i], axis=1, keepdims=False), pool_leaf),
-                cfg.dtype)[..., :cfg.head_dim]  # [L, h_kv, ps, d]
+                cfg.dtype)
+            page = from_rows(page, cfg, 1)    # [L, h_kv, ps, d]
             return jax.lax.dynamic_update_slice(
                 out, page[:, None], (0, 0, 0, i * ps, 0)), None
 
-        l, _, h, _, _ = jax.tree.leaves(pool_leaf)[0].shape
-        d = cfg.head_dim
+        l = jax.tree.leaves(pool_leaf)[0].shape[0]
         return jax.lax.scan(
-            put, jnp.zeros((l, 1, h, priv_len, d), cfg.dtype),
+            put, jnp.zeros((l, 1, cfg.n_kv_heads, priv_len, cfg.head_dim),
+                           cfg.dtype),
             jnp.arange(r))[0]
 
     cache = {'k': leaf(paged['k']), 'v': leaf(paged['v']),
@@ -1990,7 +2034,7 @@ def write_pages(paged, k_pages, v_pages, pages_row):
     ids = jnp.asarray(pages_row, jnp.int32)
 
     def leaf(pool_leaf, piece):
-        piece = _to_rows(piece, pool_leaf)
+        piece = to_rows(piece, pool_leaf, 2)
         if isinstance(pool_leaf, dict):
             q, scale = _quant_kv(piece)
             return {'q': pool_leaf['q'].at[:, ids].set(q),
@@ -2011,7 +2055,7 @@ def write_pages_quantized(paged, k_q, v_q, k_scale, v_scale,
     ids = jnp.asarray(pages_row, jnp.int32)
 
     def leaf(pool_leaf, q, scale):
-        q = _to_rows(q, pool_leaf)
+        q = to_rows(q, pool_leaf, 2)
         return {'q': pool_leaf['q'].at[:, ids].set(q),
                 'scale': pool_leaf['scale'].at[:, ids].set(scale)}
 

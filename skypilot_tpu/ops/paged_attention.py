@@ -70,7 +70,12 @@ an int32 scalar, or one layer's [n_pages, h_kv, ps, d] without it;
 tables [B, P]; lengths [B] (pre-write depths: the S new tokens sit at
 positions lengths..lengths+S-1, written there by the caller before
 the call, or given as `new_k`/`new_v` [B, h_kv, 1, d] for the kernel
-to write, as `models/decode._paged_forward` chooses).
+to write, as `models/decode._paged_forward` chooses).  A head narrower
+than the pool's rows (`models/decode.init_paged_cache`) sits in rows
+of W lanes: g = `heads_per_row` adjacent heads side by side, the
+leaves [L, n_pages, h_kv / g, ps, W], W = g * d where the heads fill
+the row, else one head a row padded with zeros; `paged_attention`
+presents either to the kernel as rows of W.
 """
 from __future__ import annotations
 
@@ -106,9 +111,11 @@ def decode_kernel_choice() -> str:
 
 
 def pool_head_lanes(kernel: str) -> int:
-    """The lanes a pool's head row is padded to for `kernel` (the
-    engine's choice): 128 where this kernel runs on a TPU, whose DMA
-    copies whole 128-lane tiles of a row; 0 (no padding) elsewhere
+    """The lanes a pool row is made whole to for `kernel` (the engine's
+    choice): 128 where this kernel runs on a TPU, whose DMA copies
+    whole 128-lane tiles of a row; 0 (rows of one head's d) elsewhere.
+    A narrower head shares a row with its neighbours where it can, and
+    is padded with zeros where it cannot
     (`models/decode.init_paged_cache`)."""
     return 128 if kernel == 'pallas' and not interpret_mode() else 0
 
@@ -684,7 +691,8 @@ def writes_in_kernel(quantized: bool, s_q: int, mesh=None) -> bool:
 
 def paged_attention(q, k_leaf: Any, v_leaf: Any, tables, lengths, *,
                     sm_scale: Optional[float] = None, mesh=None,
-                    window=None, layer=None, new_k=None, new_v=None):
+                    window=None, layer=None, new_k=None, new_v=None,
+                    heads_per_row: int = 1):
     """Paged decode attention over one layer of the page pool.
 
     q [B, h_q, S, d] (query token j of slot b at absolute position
@@ -696,6 +704,10 @@ def paged_attention(q, k_leaf: Any, v_leaf: Any, tables, lengths, *,
     are one layer's, [n_pages, h_kv, ps, d].  Returns [B, h_q, S, d] in
     q's dtype.  `window` (an int32 scalar, traced or not; None = no
     window): a query at position p sees keys p - window + 1 .. p only.
+
+    Rows wider than d (`models/decode.init_paged_cache`): the leaves
+    are [..., h_kv / heads_per_row, ps, W], `heads_per_row` adjacent kv
+    heads side by side in a row of W lanes and zeros after them.
 
     Under a `mesh` of more than one device each device runs the kernel
     on its own heads (ops/sp_common.py says why): q heads and the
@@ -716,21 +728,38 @@ def paged_attention(q, k_leaf: Any, v_leaf: Any, tables, lengths, *,
     if sm_scale is None:
         sm_scale = float(q.shape[-1]) ** -0.5
     pool = k_leaf['q'] if isinstance(k_leaf, dict) else k_leaf
-    d = q.shape[-1]
-    if pool.shape[-1] > d:
-        # A pool of lane-padded rows (`decode.init_paged_cache`'s
-        # `head_lanes`): the query takes zeros in the pad lanes, which
-        # add nothing to a score, and the output's are dropped; new
-        # rows are padded as `decode._to_rows` pads them.
-        pad = lambda x: None if x is None else jnp.pad(
-            x, ((0, 0),) * 3 + ((0, pool.shape[-1] - d),))
-        out = paged_attention(pad(q), k_leaf, v_leaf, tables, lengths,
-                              sm_scale=sm_scale, mesh=mesh, window=window,
-                              layer=layer, new_k=pad(new_k),
-                              new_v=pad(new_v))
-        if new_k is None:
-            return out[..., :d]
-        return (out[0][..., :d],) + tuple(out[1:])
+    b, h_q, s_q, d = q.shape
+    width = pool.shape[-1]
+    if width > d:
+        # The kernel attends the rows as they are, a pool head being a
+        # group of g kv heads: the query of kv head g * j + p takes its
+        # d channels at lanes p * d of group j's rows and zeros in
+        # every other lane, so a score adds only exact zeros from lanes
+        # not its head's (the padded pool's score, bit for bit), and
+        # each output row keeps its own head's lanes.  New rows go in
+        # as `decode.to_rows` lays them out: adjacent heads, one row.
+        g, groups = heads_per_row, pool.shape[-3]
+        rep = h_q // (groups * g)
+        lanes = lambda p: ((0, 0),) * 4 + ((p * d, width - (p + 1) * d),)
+        by_head = q.reshape(b, groups, g, rep, s_q, d)
+        spread = jnp.stack([jnp.pad(by_head[:, :, p], lanes(p))
+                            for p in range(g)], axis=2)
+        rows = lambda x: None if x is None else jnp.pad(
+            x.reshape(b, groups, 1, g * d),
+            ((0, 0),) * 3 + ((0, width - g * d),))
+        out = paged_attention(
+            spread.reshape(b, h_q, s_q, width), k_leaf, v_leaf, tables,
+            lengths, sm_scale=sm_scale, mesh=mesh, window=window,
+            layer=layer, new_k=rows(new_k), new_v=rows(new_v))
+        wide = (out if new_k is None else out[0]).reshape(
+            b, groups, g, rep, s_q, width)
+        # Head p's lanes are rolled down to lane 0 before the cut: XLA
+        # for TPU folds a stack of plain cuts at lanes p * d into one
+        # bitcast that reads every head's output from lanes 0..d-1
+        # (a wrong program, found on a v5e; the interpreter is right).
+        own = jnp.stack([jnp.roll(wide[:, :, p], -p * d, axis=-1)[..., :d]
+                         for p in range(g)], axis=2).reshape(b, h_q, s_q, d)
+        return own if new_k is None else (own,) + tuple(out[1:])
     if new_k is not None and not writes_in_kernel(
             isinstance(k_leaf, dict), q.shape[2], mesh):
         raise ValueError('paged_attention: new rows are written only by '

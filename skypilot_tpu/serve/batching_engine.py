@@ -241,6 +241,10 @@ _M_KV_KERNEL_WRITES = metrics_lib.counter(
     'skytpu_engine_kv_kernel_writes_total',
     'Cache-layer writes of a tick\'s new K/V rows that the paged decode '
     'kernel made itself, with no scatter (one a cache layer a tick).')
+_M_KV_HEADS_PER_ROW = metrics_lib.gauge(
+    'skytpu_engine_kv_heads_per_row',
+    'KV heads one row of the page pool holds side by side: 1, or 128 / '
+    'head_dim where heads narrower than a lane tile share a row.')
 _M_KERNEL_PALLAS = metrics_lib.gauge(
     'skytpu_engine_decode_kernel_pallas',
     'Whether the paged decode attention runs the Pallas kernel '
@@ -384,12 +388,17 @@ class ContinuousBatchingEngine:
         # environment.
         self.decode_kernel = paged_attention_lib.decode_kernel_choice()
         # On a TPU the kernel copies whole 128-lane rows: a narrower
-        # head's rows are padded in the pool (`init_paged_cache`).
+        # head shares its row with its neighbours where it can, and is
+        # padded where it cannot (an int8 pool, a mesh of more than one
+        # device; `init_paged_cache`).
         self._cache = decode.init_paged_cache(
             cfg, n_pages, page_size, slots, max_len // page_size,
             quantize_kv=quantize_kv,
             head_lanes=paged_attention_lib.pool_head_lanes(
-                self.decode_kernel))
+                self.decode_kernel),
+            sharded_heads=mesh is not None and mesh.size > 1)
+        self._heads_per_row = decode.heads_per_row(cfg, self._cache['k'])
+        _M_KV_HEADS_PER_ROW.set(self._heads_per_row)
         # Prompts that may be mid-prefill at once.  Each holds a private
         # cache of max_len (over every cache layer) until it joins the
         # engine's cache; past the bound a request waits in the queue
@@ -891,6 +900,8 @@ class ContinuousBatchingEngine:
         JSON/base64 dict; raises HandoffError when this engine has no
         exportable prefixes (prefix caching off, empty cache)."""
         import numpy as np  # pylint: disable=import-outside-toplevel
+
+        from skypilot_tpu.models import decode  # pylint: disable=import-outside-toplevel
         handoff_lib.check_model(self.cfg)
         if not self._kv.prefix_caching:
             raise HandoffError('prefix export needs the prefix cache')
@@ -917,19 +928,21 @@ class ContinuousBatchingEngine:
                 ids = np.asarray([p for _, p in entries], np.int32)
                 k = self._cache['k']
                 v = self._cache['v']
-                d = self.cfg.head_dim   # the pool's rows may be padded
+                # The wire holds [L, n, h_kv, ps, d] whatever the
+                # pool's rows are (`decode.from_rows`).
+                heads = lambda pages: decode.from_rows(pages, self.cfg, 2)
                 if self.quantize_kv:
                     payload = encode(
                         hashes, self._kv.page_size,
-                        np.asarray(k['q'][:, ids, ..., :d]),
-                        np.asarray(v['q'][:, ids, ..., :d]),
+                        np.asarray(heads(k['q'][:, ids])),
+                        np.asarray(heads(v['q'][:, ids])),
                         np.asarray(k['scale'][:, ids]),
                         np.asarray(v['scale'][:, ids]))
                 else:
                     payload = encode(
                         hashes, self._kv.page_size,
-                        np.asarray(k[:, ids, ..., :d], np.float32),
-                        np.asarray(v[:, ids, ..., :d], np.float32))
+                        np.asarray(heads(k[:, ids]), np.float32),
+                        np.asarray(heads(v[:, ids]), np.float32))
                 holder['result'] = payload
             except BaseException as e:  # pylint: disable=broad-except
                 holder['error'] = e
@@ -1121,7 +1134,8 @@ class ContinuousBatchingEngine:
                 'live_pages': self._kernel_live_pages,
                 'table_pages': self._kernel_table_pages,
                 'walked_pages': self._kernel_walked_pages,
-                'calls': self._kernel_calls, **self._kv_writes}
+                'calls': self._kernel_calls,
+                'heads_per_row': self._heads_per_row, **self._kv_writes}
         rate = round(self._decode_rate(), 3)
         stats['decode_tokens_per_s'] = rate
         # The worker loop's cumulative totals (iterations, seconds by

@@ -422,14 +422,16 @@ class FollowerExecutor:
         self._insert_pages = jax.jit(
             decode.insert_prefill_pages,
             static_argnames=('first_page',), donate_argnums=(0,))
-        # The pool rank 0's engine builds from the same geometry.
+        # The pool rank 0's engine builds from the same geometry, under
+        # a slice mesh of two or more devices (`sharded_heads`).
         self._cache = decode.init_paged_cache(
             cfg, batching_engine_lib.PagedKVManager.pool_pages(
                 kv_pages, int(slots), self.max_len, self._page_size),
             self._page_size, int(slots),
             self.max_len // self._page_size,
             quantize_kv=bool(quantize_kv),
-            head_lanes=paged_attention_lib.pool_head_lanes(kernel))
+            head_lanes=paged_attention_lib.pool_head_lanes(kernel),
+            sharded_heads=True)
         self._state = decode.init_engine_state(int(slots),
                                                int(max_stop_ids))
         self._prefill = jax.jit(

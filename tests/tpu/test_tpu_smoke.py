@@ -256,8 +256,17 @@ def test_paged_attention_matches_reference_at_llama_widths(quantized,
                                np.asarray(ref, np.float32), atol=2e-2)
 
 
-@pytest.mark.parametrize('d', [128, 64], ids=['head128', 'head64-padded'])
-def test_paged_kernel_writes_the_rows_the_scatter_wrote(d):
+def _packed_on_host(x, g, axis=-3):
+    """x [..., h_kv (at `axis`), ..., d] with g adjacent heads side by
+    side in one row, by numpy: what `decode.to_rows` must give."""
+    x = np.moveaxis(np.asarray(x), axis, -2)
+    x = x.reshape(x.shape[:-2] + (x.shape[-2] // g, g * x.shape[-1]))
+    return np.moveaxis(x, -2, axis)
+
+
+@pytest.mark.parametrize('d,g', [(128, 1), (64, 1), (64, 2)],
+                         ids=['head128', 'head64-padded', 'head64-packed'])
+def test_paged_kernel_writes_the_rows_the_scatter_wrote(d, g):
     """The kernel given each slot's new row (the tick's, PR 38) at
     Mistral's heads, 16-token pages, 3 layers stacked and the middle one
     a traced index: slots mid-page, on a fresh page's first row, on the
@@ -265,7 +274,9 @@ def test_paged_kernel_writes_the_rows_the_scatter_wrote(d):
     the null page.  The pools after the call are, byte for byte, a
     scatter of the rows followed by the reading kernel's, and so is the
     output of every slot a caller reads (all but the parked).  A head
-    of 64 sits in rows padded to 128 lanes, as the engine holds it."""
+    of 64 sits in rows padded to 128 lanes, or (`head64-packed`, as the
+    engine holds LFM2's) two heads side by side in one row of 128; the
+    packed call's output is also the padded one's, bit for bit."""
     from skypilot_tpu.ops import paged_attention as pa
     h_kv, ps, rows, lanes = 8, 16, 40, 128
     lengths = jnp.asarray([37, 32 * ps, rows * ps - 1, rows * ps, 5, 9],
@@ -277,9 +288,15 @@ def test_paged_kernel_writes_the_rows_the_scatter_wrote(d):
         parked[:, None], 0, jax.random.permutation(
             ks[5], jnp.arange(1, n_pages))[:6 * rows].reshape(6, rows))
     pad = lambda x: jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, lanes - d)])
+
+    def as_rows(x):
+        """The pool's rows, made on the host: a head padded to 128
+        lanes, or heads 2j and 2j + 1 side by side in row j."""
+        return jnp.asarray(_packed_on_host(x, g) if g > 1 else pad(x))
+
     normal = lambda key, shape: jax.random.normal(key, shape, jnp.bfloat16)
-    k = pad(normal(ks[0], (3, n_pages, h_kv, ps, d)))
-    v = pad(normal(ks[1], (3, n_pages, h_kv, ps, d)))
+    k = normal(ks[0], (3, n_pages, h_kv, ps, d))
+    v = normal(ks[1], (3, n_pages, h_kv, ps, d))
     q = normal(ks[2], (6, 32, 1, d))
     new_k, new_v = (normal(key, (6, h_kv, 1, d)) for key in ks[3:5])
     layer = jnp.asarray(1, jnp.int32)
@@ -288,18 +305,75 @@ def test_paged_kernel_writes_the_rows_the_scatter_wrote(d):
     page = jnp.where(row < rows, jnp.take_along_axis(
         tables, jnp.minimum(row, rows - 1)[:, None], 1)[:, 0], 0)
     at = (layer, page[:, None], jnp.arange(h_kv)[None], (lengths % ps)[:, None])
-    k_ref = k.at[at].set(pad(new_k)[:, :, 0])
-    v_ref = v.at[at].set(pad(new_v)[:, :, 0])
-    ref = jax.jit(lambda *a: pa.paged_attention(*a, layer=layer))(
-        q, k_ref, v_ref, tables, lengths)
+    k_heads = k.at[at].set(new_k[:, :, 0])
+    v_heads = v.at[at].set(new_v[:, :, 0])
+    k_ref, v_ref = as_rows(k_heads), as_rows(v_heads)
+    ref = jax.jit(lambda *a: pa.paged_attention(
+        *a, layer=layer, heads_per_row=g))(q, k_ref, v_ref, tables, lengths)
     out, k_out, v_out = jax.jit(lambda *a: pa.paged_attention(
-        *a[:5], layer=layer, new_k=a[5], new_v=a[6]))(
-            q, k, v, tables, lengths, new_k, new_v)
+        *a[:5], layer=layer, new_k=a[5], new_v=a[6], heads_per_row=g))(
+            q, as_rows(k), as_rows(v), tables, lengths, new_k, new_v)
+    padded = jax.jit(lambda *a: pa.paged_attention(*a, layer=layer))(
+        q, pad(k_heads), pad(v_heads), tables, lengths)
+    np.testing.assert_array_equal(np.asarray(ref, np.float32),
+                                  np.asarray(padded, np.float32))
     np.testing.assert_array_equal(np.asarray(out, np.float32)[~parked],
                                   np.asarray(ref, np.float32)[~parked])
     for got, want in ((k_out, k_ref), (v_out, v_ref)):
         np.testing.assert_array_equal(np.asarray(got, np.float32),
                                       np.asarray(want, np.float32))
+
+
+def test_packed_pages_go_in_and_come_out_as_they_were():
+    """LFM2-24B-A2B's pool on the chip (8 kv heads of 64, two to a
+    128-lane row), each program jitted as the engine jits it: a prompt's
+    pages scattered in (`insert_prefill_pages`) are the host's packing
+    of them; seeding a private cache from them (`paged_seed_private`,
+    gathered and page by page), the handoff's export of them
+    (`decode.from_rows`) and the tick's scatter rows (`decode.to_rows`)
+    are what went in, byte for byte."""
+    from skypilot_tpu.models import configs
+    from skypilot_tpu.models import decode
+    cfg = configs.ModelConfig(vocab_size=256, d_model=2048, n_layers=2,
+                              n_heads=32, n_kv_heads=8, d_ff=64,
+                              dtype=jnp.bfloat16)
+    ps, rows = 16, 32
+    paged = decode.init_paged_cache(cfg, 2 * rows + 1, ps, 2, rows,
+                                    head_lanes=128)
+    assert paged['k'].shape == (2, 2 * rows + 1, 4, ps, 128)
+    ks = jax.random.split(jax.random.PRNGKey(3), 3)
+    private = {key: jax.random.normal(s, (2, 1, 8, rows * ps, 64),
+                                      jnp.bfloat16)
+               for key, s in zip('kv', ks)}
+    ids = jnp.asarray(np.random.default_rng(0).permutation(
+        np.arange(1, 2 * rows + 1))[:rows], jnp.int32)
+    paged = jax.jit(decode.insert_prefill_pages, static_argnames=(
+        'first_page',), donate_argnums=(0,))(paged, private, ids,
+                                             first_page=0)
+    host = lambda a: np.asarray(a, np.float32)
+    for key in 'kv':
+        pages = host(private[key])[:, 0].reshape(2, 8, rows, ps, 64)
+        np.testing.assert_array_equal(
+            host(paged[key])[:, np.asarray(ids)],
+            _packed_on_host(pages.transpose(0, 2, 1, 3, 4), 2))
+        np.testing.assert_array_equal(
+            host(jax.jit(lambda a: decode.from_rows(a[:, ids], cfg, 2))(
+                paged[key])), pages.transpose(0, 2, 1, 3, 4))
+    for limit in (1 << 31, 0):
+        decode._GATHER_LIMIT_BYTES = limit  # pylint: disable=protected-access
+        try:
+            seeded = jax.jit(decode.bind(decode.paged_seed_private, cfg),
+                             static_argnames=('priv_len',))(
+                                 paged, ids, priv_len=rows * ps)
+        finally:
+            decode._GATHER_LIMIT_BYTES = 1 << 31  # pylint: disable=protected-access
+        for key in 'kv':
+            np.testing.assert_array_equal(host(seeded[key]),
+                                          host(private[key]))
+    tok = jax.random.normal(ks[2], (64, 8, 64), jnp.bfloat16)
+    np.testing.assert_array_equal(
+        host(jax.jit(lambda t: decode.to_rows(t, paged['k'], 1))(tok)),
+        _packed_on_host(host(tok), 2, axis=1))
 
 
 @pytest.mark.parametrize('writes', [False, True],

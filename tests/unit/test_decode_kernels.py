@@ -460,6 +460,193 @@ class TestKernelWritesRows:
         assert paged_attention.writes_in_kernel(False, 1)
 
 
+# LFM2-24B-A2B's attention, whose pool rows a TPU's engine packs two
+# heads to 128 lanes: 32 query heads on 8 kv heads of 64, pages of 16;
+# slots on tables of 4 rows, in a stacked pool of 3 layers read at 1.
+_LFM2 = dict(vocab_size=256, d_model=2048, n_layers=2, n_heads=32,
+             n_kv_heads=8, d_ff=64, dtype=jnp.bfloat16)
+_LANES, _PS, _ROWS = 128, 16, 4
+
+
+def _lfm2_case(lengths, parked=()):
+    """(q, k, v, new_k, new_v, tables, lengths): bf16 keys and values of
+    every head [3, n_pages, 8, 16, 64], each slot on pages of its own (a
+    parked slot's table the null page), one new row a slot."""
+    b = len(lengths)
+    tables = np.zeros((b, _ROWS), np.int32)
+    for i in range(b):
+        if i not in parked:
+            tables[i] = 1 + i * _ROWS + np.arange(_ROWS)
+    ks = jax.random.split(jax.random.PRNGKey(5), 5)
+    normal = lambda key, *shape: jax.random.normal(
+        key, shape, jnp.float32).astype(jnp.bfloat16)
+    pool = (3, 1 + b * _ROWS + 2, 8, _PS, 64)
+    return (normal(ks[0], b, 32, 1, 64), normal(ks[1], *pool),
+            normal(ks[2], *pool), normal(ks[3], b, 8, 1, 64),
+            normal(ks[4], b, 8, 1, 64), jnp.asarray(tables),
+            jnp.asarray(lengths, jnp.int32))
+
+
+def _padded(x):
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, _LANES - x.shape[-1])])
+
+
+def _packed(x, axis=2):
+    """Two adjacent heads of x (on `axis`) a row of 128 lanes
+    (`decode.to_rows`, given a pool of that many groups of rows)."""
+    return decode.to_rows(x, jax.ShapeDtypeStruct(
+        (1, 1, x.shape[axis] // 2, 1, _LANES), x.dtype), axis)
+
+
+def _packed_read(window=None, traced=False, one_layer=False):
+    """The packed pool read by the kernel is the padded pool read by
+    it, bit for bit; the packed row of group j holds heads 2j and
+    2j + 1 side by side."""
+    q, k, v, _, _, tables, lengths = _lfm2_case([13, 37, 63, 5])
+    packed = _packed(k)
+    assert packed.shape == (3, k.shape[1], 4, _PS, _LANES)
+    np.testing.assert_array_equal(
+        np.asarray(packed[:, :, 1, :, 64:], np.float32),
+        np.asarray(k[:, :, 3], np.float32))
+    kw = dict(window=window)
+    if one_layer:
+        k, v = k[1], v[1]
+    else:
+        kw['layer'] = jnp.asarray(1, jnp.int32) if traced else 1
+
+    def read(*a, **more):
+        return paged_attention.paged_attention(*a, tables, lengths, **kw,
+                                               **more)
+
+    run = jax.jit(read, static_argnames=('heads_per_row',)) if traced \
+        else read
+    want = run(q, _padded(k), _padded(v))
+    got = run(q, _packed(k, 1 if one_layer else 2),
+              _packed(v, 1 if one_layer else 2), heads_per_row=2)
+    assert got.shape == q.shape
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(want, np.float32))
+
+
+def _packed_write():
+    """Given the new rows, the kernel leaves the packed pools a packed
+    scatter of them makes (a row mid-page, a fresh page's first, the
+    table's last, one past the table: the null page, two parked slots),
+    and every read slot's output is the padded kernel's."""
+    kinds = ('mid', 'fresh', 'deep', 'past', 'parked', 'parked')
+    q, k, v, nk, nv, tables, lengths = _lfm2_case(
+        [37, 2 * _PS, _ROWS * _PS - 1, _ROWS * _PS, 5, 9], parked=(4, 5))
+    layer = jnp.asarray(1, jnp.int32)
+    write = jax.jit(lambda *a, g=1: paged_attention.paged_attention(
+        *a[:3], tables, lengths, layer=layer, new_k=a[3], new_v=a[4],
+        heads_per_row=g), static_argnames=('g',))
+    out, k_out, v_out = write(q, _packed(k), _packed(v), nk, nv, g=2)
+    want, _, _ = write(q, _padded(k), _padded(v), nk, nv)
+    for got, pool, new in ((k_out, k, nk), (v_out, v, nv)):
+        np.testing.assert_array_equal(
+            np.asarray(got, np.float32),
+            np.asarray(_scatter_rows(_packed(pool), layer, tables, lengths,
+                                     _packed(new, 1)), np.float32))
+    read = np.asarray([kind != 'parked' for kind in kinds])
+    np.testing.assert_array_equal(np.asarray(out[read], np.float32),
+                                  np.asarray(want[read], np.float32))
+
+
+def _packed_seed(monkeypatch):
+    """A private cache put into packed pages and seeded back, gathered
+    and page by page, is the cache it was."""
+    cfg = configs.ModelConfig(**_LFM2)
+    paged = decode.init_paged_cache(cfg, 9, _PS, 2, _ROWS,
+                                    head_lanes=_LANES)
+    assert paged['k'].shape == (2, 9, 4, _PS, _LANES)
+    ks = jax.random.split(jax.random.PRNGKey(2), 2)
+    private = {key: jax.random.normal(
+        s, (2, 1, 8, _ROWS * _PS, 64), jnp.float32).astype(jnp.bfloat16)
+        for key, s in zip('kv', ks)}
+    pages = jnp.asarray([3, 5, 8, 2], jnp.int32)
+    paged = decode.insert_prefill_pages(paged, private, pages, first_page=0)
+    for limit in (decode._GATHER_LIMIT_BYTES, 0):  # pylint: disable=protected-access
+        monkeypatch.setattr(decode, '_GATHER_LIMIT_BYTES', limit)
+        seeded = decode.paged_seed_private(cfg, paged, pages,
+                                           priv_len=_ROWS * _PS)
+        for key in 'kv':
+            np.testing.assert_array_equal(
+                np.asarray(seeded[key], np.float32),
+                np.asarray(private[key], np.float32))
+
+
+def _packed_handoff():
+    """The prefix export's pages of a packed pool are the wire's
+    [L, n, h_kv, ps, d], byte for byte what an unpadded pool gives, and
+    importing them into a packed pool restores it."""
+    cfg = configs.ModelConfig(**_LFM2)
+    wire = jax.random.normal(jax.random.PRNGKey(4), (2, 3, 8, _PS, 64),
+                             jnp.float32).astype(jnp.bfloat16)
+    ids = jnp.asarray([4, 1, 6], jnp.int32)
+    pools = {lanes: decode.write_pages(
+        decode.init_paged_cache(cfg, 8, _PS, 2, _ROWS, head_lanes=lanes),
+        wire.astype(jnp.float32), wire.astype(jnp.float32), ids)
+        for lanes in (0, _LANES)}
+    assert decode.heads_per_row(cfg, pools[_LANES]['k']) == 2
+    exported = {lanes: np.asarray(decode.from_rows(
+        paged['k'][:, ids], cfg, 2), np.float32)
+        for lanes, paged in pools.items()}
+    assert exported[_LANES].tobytes() == exported[0].tobytes()
+    assert exported[_LANES].tobytes() == np.asarray(
+        wire, np.float32).tobytes()
+    np.testing.assert_array_equal(
+        np.asarray(pools[_LANES]['v'], np.float32),
+        np.asarray(decode.write_pages(
+            pools[_LANES], exported[_LANES], exported[_LANES], ids)['v'],
+            np.float32))
+
+
+def _packed_layouts():
+    """Heads share a row only where they fill it and the pool is float
+    and not split over devices: an int8 pool, a head of 128, an odd
+    h_kv, sharded heads and no `head_lanes` keep a head a row."""
+    cases = [
+        (dict(), {}, (4, _PS, _LANES)),
+        (dict(), dict(quantize_kv=True), (8, _PS, _LANES)),
+        (dict(d_model=4096), {}, (8, _PS, _LANES)),
+        (dict(d_model=1920, n_heads=30, n_kv_heads=3), {}, (3, _PS, _LANES)),
+        (dict(), dict(sharded_heads=True), (8, _PS, _LANES)),
+        (dict(), dict(head_lanes=0), (8, _PS, 64)),
+    ]
+    for keys, kw, rows in cases:
+        cfg = configs.ModelConfig(**dict(_LFM2, **keys))
+        kw = dict(dict(head_lanes=_LANES), **kw)
+        paged = jax.eval_shape(lambda: decode.init_paged_cache(  # pylint: disable=cell-var-from-loop
+            cfg, 9, _PS, 2, _ROWS, **kw))
+        leaf = decode._pool_array(paged['k'])  # pylint: disable=protected-access
+        assert leaf.shape[2:] == rows, (keys, kw)
+        assert decode.heads_per_row(cfg, paged['k']) == (
+            cfg.n_kv_heads // rows[0])
+
+
+_PACKED_CASES = {
+    'read': lambda _: _packed_read(),
+    'read-one-layer': lambda _: _packed_read(one_layer=True),
+    'read-traced-layer': lambda _: _packed_read(traced=True),
+    'read-window': lambda _: _packed_read(window=20, traced=True),
+    'write': lambda _: _packed_write(),
+    'seed-round-trip': _packed_seed,
+    'handoff': lambda _: _packed_handoff(),
+    'layouts': lambda _: _packed_layouts(),
+}
+
+
+@pytest.mark.parametrize('case', list(_PACKED_CASES))
+def test_packed_rows_are_the_padded_rows(monkeypatch, case):
+    """A pool whose rows hold two 64-wide heads side by side (what a
+    TPU's engine holds for LFM2-24B-A2B) against the pool of one head a
+    row padded to 128 lanes, in the interpreter: the kernel's reads and
+    writes, the prefix hit's seeding, the handoff's pages and which
+    pools pack at all."""
+    monkeypatch.setenv('SKYTPU_PALLAS_INTERPRET', '1')
+    _PACKED_CASES[case](monkeypatch)
+
+
 class TestPallasKernelParity:
 
     def test_greedy_parity_vs_dense_reference(self, setup,
@@ -564,7 +751,8 @@ class TestPallasKernelParity:
             ps, rows, slots = 8, 64 // 8, 2
             assert eng.stats()['paged_kernel'] == {
                 'live_pages': 0, 'table_pages': 0, 'walked_pages': 0,
-                'calls': 0, 'kernel_writes': 0, 'scatter_writes': 0}
+                'calls': 0, 'heads_per_row': 1, 'kernel_writes': 0,
+                'scatter_writes': 0}
             script = (([3, 1, 4, 1, 5, 9, 2, 6, 5, 3, 5], 6),
                       ([7, 2, 9], 9))
             handles = [eng.submit(p, max_new_tokens=n)

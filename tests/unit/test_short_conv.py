@@ -162,19 +162,26 @@ def _reference_row(model, params, seq):
 
 
 @pytest.mark.parametrize('kernel,lanes', [('gather', 0), ('pallas', 0),
-                                          ('pallas', 128)],
-                         ids=['gather', 'pallas', 'pallas-lanes'])
+                                          ('pallas', 128), ('pallas', 32),
+                                          ('gather', 32)],
+                         ids=['gather', 'pallas', 'pallas-lanes',
+                              'pallas-packed', 'gather-packed'])
 def test_paged_tick_matches_reference(setup, monkeypatch, kernel, lanes):
     """Prefill, then ticks through the pool with each slot's tails
-    carried: the gather path, the kernel (interpret mode) and the
-    kernel on a pool of lane-padded rows (what a TPU's engine holds
-    for a head of 16 or 64) give the reference's logits."""
+    carried: the gather path, the kernel (interpret mode), the kernel
+    on a pool of lane-padded rows (what a TPU's engine holds where the
+    heads cannot fill a row: 2 heads of 16 in 128 lanes) and both on a
+    pool whose rows hold two heads side by side (rows of 32 lanes here,
+    as a TPU's engine packs two heads of 64 to 128) give the
+    reference's logits."""
     monkeypatch.setenv('SKYTPU_PALLAS_INTERPRET', '1')
     model, cfg, params, _, _ = setup
     state, paged, prompts = _slots(cfg, params, {0: 9, 2: 21},
                                    head_lanes=lanes)
     if lanes:
-        assert paged['k'].shape[-1] == 128
+        # 2 kv heads of 16: padded to 128 lanes a head, or one row.
+        assert paged['k'].shape[2:] == ((2, _PAGE, 128) if lanes == 128
+                                        else (1, _PAGE, 32))
     step = jax.jit(lambda s, p: decode.paged_batched_step(
         cfg, params, s['tokens'][:, None], p, s['active'],
         kernel=kernel))
@@ -289,6 +296,41 @@ def test_engine_prefix_hit_restores_the_tails(setup):
         assert [cold.generate(p, 10, timeout=300) for p in prompts] == outs
     finally:
         cold.stop()
+
+
+def test_engine_on_packed_rows_gives_the_unpadded_tokens(setup,
+                                                         monkeypatch):
+    """The engine through the kernel (interpret mode) on a pool whose
+    rows hold both kv heads side by side, as a TPU's holds LFM2's heads
+    of 64 two to a row: requests on one document, the later ones prefix
+    hits, give the tokens of the pool of one head a row, and say so."""
+    from skypilot_tpu.ops import paged_attention
+    model, cfg, params, _, _ = setup
+    monkeypatch.setenv('SKYTPU_PALLAS_INTERPRET', '1')
+    rng = np.random.default_rng(8)
+    doc = rng.integers(1, 256, size=21).tolist()
+    prompts = [doc + rng.integers(1, 256, size=n).tolist() for n in (3, 6)]
+    outs, stats = {}, {}
+    for lanes in (0, 32):
+        monkeypatch.setattr(paged_attention, 'pool_head_lanes',
+                            lambda kernel, lanes=lanes: lanes)
+        eng = _engine(cfg, params, slots=2)
+        try:
+            outs[lanes] = [eng.generate(prompts[0], 8, timeout=300)]
+            outs[lanes] += [r.result(timeout=300) for r in
+                            [eng.submit(p, 8) for p in prompts]]
+            stats[lanes] = eng.stats()
+            assert eng._cache['k'].shape[2:] == (  # pylint: disable=protected-access
+                (2, 4, 16) if lanes == 0 else (1, 4, 32))
+        finally:
+            eng.stop()
+    assert outs[32] == outs[0]
+    for p, o in zip(prompts[:1] + prompts, outs[32]):
+        assert _gap(model, params, p, o) <= _TOL, len(p)
+    assert [stats[lanes]['paged_kernel']['heads_per_row']
+            for lanes in (0, 32)] == [1, 2]
+    assert stats[32]['paged_kernel']['kernel_writes'] > 0
+    assert stats[32]['conv']['snapshots_restored'] == 2
 
 
 def test_engine_reuses_a_released_slot_without_its_tails(setup):

@@ -698,6 +698,40 @@ class TestFollowerExecutor:
             max_new_tokens=6, max_len=geom['max_len'])
         assert got == [int(t) for t in np.asarray(want)[0]]
 
+    def test_follower_builds_the_pool_rank_0_builds(self, monkeypatch):
+        """Where the kernel's rows are 128 lanes (a TPU), two heads of
+        64 share a row on one device but not under the slice mesh: the
+        follower's pool takes rank 0's layout, one head a row."""
+        import flax.linen as nn
+        import jax
+        import jax.numpy as jnp
+
+        from skypilot_tpu.models import configs
+        from skypilot_tpu.models import decode
+        from skypilot_tpu.models.transformer import Transformer
+        from skypilot_tpu.ops import paged_attention as paged_attention_lib
+        cfg = configs.get_config('tiny', d_model=128, n_heads=2)
+        assert (cfg.head_dim, cfg.n_kv_heads) == (64, 2)
+        params = nn.meta.unbox(Transformer(cfg).init(
+            jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))['params'])
+        monkeypatch.setattr(paged_attention_lib, 'pool_head_lanes',
+                            lambda kernel: 128)
+        alone = decode.init_paged_cache(cfg, 48, 8, 2, 8, head_lanes=128)
+        assert decode.heads_per_row(cfg, alone['k']) == 2
+        follower = slice_replica.FollowerExecutor(cfg, params,
+                                                  **self.GEOM)
+        eng = slice_replica.SliceReplicaEngine(
+            cfg, params, num_hosts=2,
+            rank_channels=[coordinator_lib.LocalRank(1, follower)],
+            **self.GEOM)
+        try:
+            assert eng._cache['k'].shape == follower._cache['k'].shape == (
+                cfg.n_layers, self.GEOM['kv_pages'], 2, 8, 128)
+            assert decode.heads_per_row(cfg, follower._cache['k']) == 1
+            assert eng.stats()['paged_kernel']['heads_per_row'] == 1
+        finally:
+            eng.stop()
+
     def test_follower_release_parks_tables(self, tiny):
         import numpy as np
         cfg, params = tiny

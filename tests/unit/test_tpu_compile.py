@@ -100,6 +100,53 @@ def test_windowed_paged_decode_kernel_compiles_for_v5e(
     assert 'tpu_custom_call' in compiled.as_text()
 
 
+@pytest.mark.parametrize('writes', [False, True], ids=['reads', 'writes'])
+def test_packed_rows_reach_the_kernel_and_back_by_moves(one_chip,
+                                                        kernel_on_tpu, writes):
+    """LFM2-24B-A2B's heads, two of 64 to a 128-lane row, through
+    `paged_attention` at the benchmark's shapes (64 slots, tables of 544
+    rows, 2 layers of 6,144 pages): no bitcast in the program changes an
+    array's element count.  One did (a bf16[64,4,8,128] kernel result
+    read as [64,4,2,4,1,64], every head's output taken from lanes 0-63)
+    where the wrapper cut each head's lanes out with a plain slice: the
+    compiler folded the stack of cuts into that bitcast, and the chip
+    computed the second head of each row wrong."""
+    slots, pages, rows = 64, 6144, 544
+
+    def arg(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool = arg((2, pages, 4, 16, 128))
+    new = (arg((slots, 8, 1, 64)),) * 2 if writes else ()
+    hlo = jax.jit(lambda q, k, v, t, n, layer, *new: paged_attention.
+                  paged_attention(q, k, v, t, n, layer=layer,
+                                  heads_per_row=2,
+                                  **dict(zip(('new_k', 'new_v'), new)))
+                  ).lower(arg((slots, 32, 1, 64)), pool, pool,
+                          arg((slots, rows), jnp.int32),
+                          arg((slots,), jnp.int32), arg((), jnp.int32),
+                          *new).compile().as_text()
+    assert 'paged_decode_attention' in hlo
+    assert not _resizing_bitcasts(hlo)
+
+
+def _resizing_bitcasts(hlo: str):
+    """The bitcasts whose result holds another number of elements than
+    their operand: a read of an array's padding as data."""
+    size, out = {}, []
+    for line in hlo.splitlines():
+        m = re.match(r'^\s+(?:ROOT )?(%[\w.\-]+) = \w+\[([\d,]*)\]\S* '
+                     r'([\w\-]+)\((%[\w.\-]+)?', line)
+        if not m:
+            continue
+        size[m.group(1)] = math.prod(int(n) for n in m.group(2).split(',')
+                                     if n)
+        if m.group(3) == 'bitcast' and size.get(m.group(4), size[
+                m.group(1)]) != size[m.group(1)]:
+            out.append(line.strip())
+    return out
+
+
 def _own_operations(hlo: str):
     """(name, result dims, opcode) of every instruction that runs as an
     operation of its own: those of the computations no fusion calls."""
@@ -359,15 +406,17 @@ def test_layers_stacked_by_kind_read_weights_in_place(one_chip,
     """A model stacked by kind at the benchmark's shapes (LFM2-24B-A2B's
     layers 0-9: two dense conv layers, then two periods of attention
     and three conv layers, 64 experts of 2048 x 1536 a layer; 64 slots,
-    6,144 pages, `max_len` 8,704; head rows padded to 128 lanes, as the
-    engine holds them for the kernel): the plain tick and a 256-row
-    chunk riding it keep the pool, the slots' conv tails and the pages'
-    snapshots in place (aliased to the result), make no temporary of a
-    weight stack's size, and no operation results in a layer's experts
-    or a period's: each layer's weights are one dynamic index of its
-    kind's stack, which the product reads in place.  A slice of a
-    period read by its four layers was copied out first (4.9 GB of
-    temporaries, PR 37)."""
+    6,144 pages, `max_len` 8,704; two heads of 64 side by side in a
+    row of 128 lanes, as the engine holds them for the kernel): the
+    plain tick and a 256-row chunk riding it keep the pool, the slots'
+    conv tails and the pages' snapshots in place (aliased to the
+    result), make no temporary of a weight stack's size, no operation
+    results in a layer's experts or a period's (each layer's weights
+    are one dynamic index of its kind's stack, which the product reads
+    in place; a slice of a period read by its four layers was copied out
+    first, 4.9 GB of temporaries), and none but the kernel's
+    results in the pool's shape: the packed rows are not unpacked or
+    moved around it; no bitcast reads an array's padding as data."""
     from skypilot_tpu.models import configs
     cfg = configs.ModelConfig(
         vocab_size=65536, d_model=2048, n_layers=10, n_heads=32,
@@ -393,7 +442,7 @@ def test_layers_stacked_by_kind_read_weights_in_place(one_chip,
     state = shapes(lambda: decode.init_engine_state(slots))
     paged = shapes(lambda: decode.init_paged_cache(
         cfg, pages, 16, slots, max_len // 16, head_lanes=128))
-    assert paged['k'].shape == (2, pages, 8, 16, 128)
+    assert paged['k'].shape == (2, pages, 4, 16, 128)
     if step == 'tick':
         compiled = jax.jit(
             decode.bind(decode.paged_engine_step, cfg, kernel='pallas'),
@@ -421,3 +470,9 @@ def test_layers_stacked_by_kind_read_weights_in_place(one_chip,
                                'bitcast', 'while', 'slice-done',
                                'custom-call')]
     assert not copied, copied
+    pool = [op for op in _own_operations(hlo)
+            if tuple(op[1]) == paged['k'].shape
+            if op[2] not in ('parameter', 'get-tuple-element', 'tuple',
+                             'bitcast', 'while', 'custom-call')]
+    assert not pool, pool
+    assert not _resizing_bitcasts(hlo)
