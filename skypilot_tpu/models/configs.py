@@ -2,24 +2,62 @@
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional, Tuple
+from typing import List, Optional, Tuple
 
 import jax.numpy as jnp
 
 # Layer kinds a `layer_pattern` may name, with what each kind sets: does
 # the layer rotate q and k (rotary embedding), does a query see only
-# the last `sliding_window` keys, and is its mixer a gated short
-# convolution in place of attention.  A new kind is a row here, read by
-# `ModelConfig.layer_kinds`; the layer body takes the settings as
-# per-layer data and never switches on a kind's name.  A 'conv' layer
-# (LFM2's) keeps no keys and no pages: a sequence carries the last
-# `conv_kernel - 1` rows of the layer's gated input from step to step.
+# the last `sliding_window` keys, which mixer it has ('attn', 'conv': a
+# gated short convolution, 'mamba': a Mamba-2 state-space mixer, or
+# None) and whether an FFN follows the mixer.  A new kind is a row
+# here, read by `ModelConfig.layer_kinds`; the layer body takes the
+# settings as per-layer data and never switches on a kind's name.  A
+# 'conv' layer (LFM2's) keeps no keys and no pages: a sequence carries
+# the last `conv_kernel - 1` rows of the layer's gated input from step
+# to step.  A 'mamba' layer (Nemotron-H's) is its mixer alone and
+# carries a matrix of state a head and the last `conv_kernel - 1` rows
+# of its convolved input; an 'experts' layer is an FFN alone and an
+# 'attn_nope' layer position-free attention alone.
 LAYER_KINDS = {
-    'full': {'rope': True, 'window': False, 'conv': False},
-    'window': {'rope': True, 'window': True, 'conv': False},
-    'full_nope': {'rope': False, 'window': False, 'conv': False},
-    'conv': {'rope': False, 'window': False, 'conv': True},
+    'full': {'rope': True, 'window': False, 'mixer': 'attn', 'ffn': True},
+    'window': {'rope': True, 'window': True, 'mixer': 'attn', 'ffn': True},
+    'full_nope': {'rope': False, 'window': False, 'mixer': 'attn',
+                  'ffn': True},
+    'conv': {'rope': False, 'window': False, 'mixer': 'conv', 'ffn': True},
+    'mamba': {'rope': False, 'window': False, 'mixer': 'mamba',
+              'ffn': False},
+    'experts': {'rope': False, 'window': False, 'mixer': None, 'ffn': True},
+    'attn_nope': {'rope': False, 'window': False, 'mixer': 'attn',
+                  'ffn': False},
 }
+
+
+def count_mixer(kinds, mixer) -> int:
+    """How many of the layer kinds `kinds` have the mixer `mixer`."""
+    return sum(LAYER_KINDS[k]['mixer'] == mixer for k in kinds)
+
+
+def runs_of(names) -> Tuple[Tuple[Tuple[str, ...], int], ...]:
+    """Layer kind names as runs of repeated periods: cut after each
+    layer with attention and no FFN, equal neighbours merged
+    (Nemotron-H's `MEMEM*`, `EMEMEM*` four times, ...)."""
+    pieces, piece = [], []
+    for name in names:
+        piece.append(name)
+        if LAYER_KINDS[name]['mixer'] == 'attn' and not LAYER_KINDS[name][
+                'ffn']:
+            pieces.append(tuple(piece))
+            piece = []
+    if piece:
+        pieces.append(tuple(piece))
+    runs: List[Tuple[Tuple[str, ...], int]] = []
+    for piece in pieces:
+        if runs and runs[-1][0] == piece:
+            runs[-1] = (piece, runs[-1][1] + 1)
+        else:
+            runs.append((piece, 1))
+    return tuple(runs)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -129,6 +167,21 @@ class ModelConfig:
     # them; the gates are the chosen scores over their sum + this eps.
     expert_bias: bool = False
     expert_gate_eps: float = 0.0
+    # The routed gates times this (`routed_scaling_factor`).
+    expert_scale: float = 1.0
+    # A shared expert's width where it is not `d_ff`.
+    shared_d_ff: int = 0
+    # 'mamba' layers (Mamba-2): `ssm_heads` heads of `ssm_head_dim`
+    # channels, B and C in `ssm_groups` groups of `ssm_state` (a head
+    # reads group head // (ssm_heads / ssm_groups)); a causal depthwise
+    # convolution of `conv_kernel` over x, B and C; prefill scans in
+    # chunks of `ssm_chunk` positions.  The gated output norm is an
+    # RMSNorm over each of `ssm_groups` groups of channels.
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_groups: int = 1
+    ssm_state: int = 0
+    ssm_chunk: int = 128
 
     @property
     def head_dim(self) -> int:
@@ -148,53 +201,106 @@ class ModelConfig:
             if self.conv_layers and self.conv_kernel < 2:
                 raise ValueError('conv layers need conv_kernel >= 2, '
                                  f'got {self.conv_kernel}')
+            if self.mamba_layers and (
+                    self.conv_kernel < 2 or self.ssm_state <= 0 or
+                    self.ssm_head_dim <= 0 or self.ssm_groups <= 0 or
+                    self.ssm_heads % self.ssm_groups):
+                raise ValueError(
+                    'mamba layers need conv_kernel >= 2, ssm_state and '
+                    'ssm_head_dim > 0, and ssm_heads a multiple of '
+                    'ssm_groups')
+            if self.conv_layers and self.mamba_layers:
+                raise ValueError('a model carries one kind of recurrent '
+                                 'state: conv or mamba layers, not both')
             if self.dense_layers and self.dense_d_ff <= 0:
                 raise ValueError('dense_layers need dense_d_ff > 0')
             if (self.loop_passes != 1 or self.parallel_block or
                     self.post_norms or not self.scan_layers):
                 raise ValueError(
-                    'layers stacked by kind (conv layers or leading '
-                    'dense layers) are served once a token, in sequence '
-                    '(no loop_passes, parallel_block or post_norms), '
-                    'from the scanned layout')
+                    'layers stacked by kind (conv or mamba layers, '
+                    'leading dense layers or runs of periods) are served '
+                    'once a token, in sequence (no loop_passes, '
+                    'parallel_block or post_norms), from the scanned '
+                    'layout')
 
     @property
     def stacked_by_kind(self) -> bool:
         """Whether the weights are stacked by layer kind and the layers
-        run as periods (models/decode.py): a model with 'conv' layers or
-        leading dense layers.  Every other model is one stack of alike
+        run as periods (models/decode.py): a model with leading dense
+        layers, runs of periods, or a kind whose mixer is not attention
+        or that has no FFN.  Every other model is one stack of alike
         layers, and takes the code it took before kinds were stacked."""
-        return bool(self.dense_layers) or 'conv' in self.layer_pattern
+        return (bool(self.dense_layers) or any(
+            LAYER_KINDS.get(k, LAYER_KINDS['full'])['mixer'] != 'attn' or
+            not LAYER_KINDS.get(k, LAYER_KINDS['full'])['ffn']
+            for k in self.layer_pattern))
 
-    def layer_names(self) -> Tuple[str, ...]:
-        """Every layer's kind name, in order: the leading dense layers',
-        then `layer_pattern` repeated over the rest."""
+    def runs(self) -> Tuple[Tuple[Tuple[str, ...], int], ...]:
+        """The layers after the leading dense ones as runs of repeated
+        periods: `layer_pattern` repeated whole, or, where it names
+        each of those layers once, its own runs (`runs_of`: a pattern
+        that is no whole number of periods, Nemotron-H's)."""
         pattern = self.layer_pattern or ('full',)
-        unknown = set(pattern + self.dense_layers) - set(LAYER_KINDS)
-        if unknown:
-            raise ValueError(f'Unknown layer kinds {sorted(unknown)}; '
-                             f'have {sorted(LAYER_KINDS)}')
         rest = self.n_layers - len(self.dense_layers)
         if rest < 0 or rest % len(pattern):
             raise ValueError(
                 f'n_layers {self.n_layers} less {len(self.dense_layers)} '
                 f'dense layers is not whole periods of layer_pattern '
                 f'{pattern}')
-        return self.dense_layers + pattern * (rest // len(pattern))
+        if rest == len(pattern) and set(pattern) <= set(LAYER_KINDS):
+            return runs_of(pattern)
+        return ((pattern, rest // len(pattern)),)
+
+    def layer_names(self) -> Tuple[str, ...]:
+        """Every layer's kind name, in order: the leading dense layers',
+        then each run's period repeated."""
+        runs = self.runs()
+        unknown = set(self.dense_layers).union(
+            *(period for period, _ in runs)) - set(LAYER_KINDS)
+        if unknown:
+            raise ValueError(f'Unknown layer kinds {sorted(unknown)}; '
+                             f'have {sorted(LAYER_KINDS)}')
+        names = self.dense_layers + sum(
+            (period * n for period, n in runs), ())
+        if len(names) != self.n_layers:
+            raise ValueError(
+                f'the layer kinds give {len(names)} layers, n_layers is '
+                f'{self.n_layers}')
+        return names
 
     @property
     def conv_layers(self) -> int:
         """Layers whose mixer is the short convolution: no keys, no
         pages, a tail of rows a sequence."""
-        if 'conv' not in self.layer_pattern + self.dense_layers:
+        if not self.stacked_by_kind:
             return 0
-        return self.layer_names().count('conv')
+        return count_mixer(self.layer_names(), 'conv')
+
+    @property
+    def mamba_layers(self) -> int:
+        """Layers whose mixer is Mamba-2: no keys, no pages, a matrix of
+        state a head and a tail of rows a sequence."""
+        if not self.stacked_by_kind:
+            return 0
+        return count_mixer(self.layer_names(), 'mamba')
+
+    @property
+    def ssm_inner(self) -> int:
+        """A mamba layer's channels of x (and of z, and of y)."""
+        return self.ssm_heads * self.ssm_head_dim
+
+    @property
+    def ssm_conv_dim(self) -> int:
+        """A mamba layer's convolved channels: x, B and C."""
+        return self.ssm_inner + 2 * self.ssm_groups * self.ssm_state
 
     @property
     def cache_layers(self) -> int:
         """Layers of the KV caches: one for every (pass, attention
         layer)."""
-        return (self.n_layers - self.conv_layers) * self.loop_passes
+        if not self.stacked_by_kind:
+            return self.n_layers * self.loop_passes
+        return count_mixer(self.layer_names(), 'attn') * self.loop_passes
 
     @property
     def held_experts(self) -> Tuple[int, int]:
@@ -205,14 +311,14 @@ class ModelConfig:
         """Per attention layer (rope, window): whether q and k are
         rotated, and how many keys a query sees (0 = all).  None where
         every layer is 'full', so a model of one kind of layer takes the
-        code it took before kinds existed.  A conv layer has neither and
-        is left out (`stacked_by_kind`)."""
+        code it took before kinds existed.  A layer without attention
+        has neither and is left out (`stacked_by_kind`)."""
         if self.stacked_by_kind:
             return tuple(
                 (LAYER_KINDS[name]['rope'],
                  self.sliding_window if LAYER_KINDS[name]['window'] else 0)
                 for name in self.layer_names()
-                if not LAYER_KINDS[name]['conv'])
+                if LAYER_KINDS[name]['mixer'] == 'attn')
         if not self.layer_pattern:
             return None
         unknown = set(self.layer_pattern) - set(LAYER_KINDS)

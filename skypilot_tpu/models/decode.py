@@ -30,15 +30,18 @@ import jax
 import jax.numpy as jnp
 
 from skypilot_tpu.models import heads
+from skypilot_tpu.models import mamba
 from skypilot_tpu.models import moe
 from skypilot_tpu.models.configs import LAYER_KINDS
 from skypilot_tpu.models.configs import ModelConfig
+from skypilot_tpu.models.configs import count_mixer
 from skypilot_tpu.models.quantize import is_quantized_leaf
 from skypilot_tpu.models.quantize import maybe_dequant
 from skypilot_tpu.models.transformer import _rope
 from skypilot_tpu.ops import paged_attention as paged_attention_ops
 from skypilot_tpu.ops.attention import NEG_INF
 from skypilot_tpu.ops.attention import flash_attention
+from skypilot_tpu.ops.ssm_decode import ssm_decode_step
 
 
 class _PagedView(NamedTuple):
@@ -90,7 +93,8 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int
     """Zeroed KV cache pytree (stacked over the cache's layers, one a
     (pass, attention layer) of a looped stack: `cfg.cache_layers`).  A
     model with conv layers adds their tails, 'conv' [conv layers,
-    batch, conv_kernel - 1, d]: the rows each sequence carries."""
+    batch, conv_kernel - 1, d]: the rows each sequence carries; a model
+    with mamba layers their states (`_ssm_states`)."""
     shape = (cfg.cache_layers, batch, cfg.n_kv_heads, max_len,
              cfg.head_dim)
     cache = {
@@ -100,6 +104,8 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int
     }
     if cfg.conv_layers:
         cache['conv'] = _conv_tails(cfg, batch)
+    if cfg.mamba_layers:
+        cache.update(_ssm_states(cfg, batch))
     return cache
 
 
@@ -108,6 +114,36 @@ def _conv_tails(cfg: ModelConfig, n: int):
     sequences' (or pages') rows before position 0."""
     return jnp.zeros((cfg.conv_layers, n, cfg.conv_kernel - 1, cfg.d_model),
                      cfg.dtype)
+
+
+def _ssm_states(cfg: ModelConfig, n: int, store: bool = False):
+    """Zeroed states of the mamba layers for n sequences: 'ssm' [mamba
+    layers, n, H, P, N] float32 (a matrix of state a head) and 'xbc'
+    [mamba layers, n, K - 1, conv channels] (the rows before the
+    convolution that the next row's taps read); `store`: n entries of
+    every layer's state, [n, mamba layers, ...]."""
+    lead = (n, cfg.mamba_layers) if store else (cfg.mamba_layers, n)
+    return {'ssm': jnp.zeros(lead + (cfg.ssm_heads, cfg.ssm_head_dim,
+                                     cfg.ssm_state), jnp.float32),
+            'xbc': jnp.zeros(lead + (cfg.conv_kernel - 1,
+                                     cfg.ssm_conv_dim), cfg.dtype)}
+
+
+def _recurrent(cache):
+    """The per-sequence state of a cache or pool beside its keys: the
+    conv tails (an array), the mamba states ({'ssm', 'xbc'}) or None."""
+    if 'conv' in cache:
+        return cache['conv']
+    if 'ssm' in cache:
+        return {'ssm': cache['ssm'], 'xbc': cache['xbc']}
+    return None
+
+
+def _recurrent_items(state) -> Dict[str, Any]:
+    """`_recurrent` back: the cache keys that hold `state`."""
+    if state is None:
+        return {}
+    return dict(state) if isinstance(state, dict) else {'conv': state}
 
 
 def _layer_params(params: Dict[str, Any], cfg: ModelConfig):
@@ -296,7 +332,11 @@ class _ChunkRows(NamedTuple):
     the chunk's embedded tokens, what it enters the loop with; `rows`
     (int32 scalar, or None: all n) how many of them are the prompt's,
     the rest padding up to the program's width, whose attention is
-    not worth computing (`_attend`) and which no conv tail takes."""
+    not worth computing (`_attend`) and which no conv tail takes.
+    A model with mamba layers: `conv` the chunk's own states
+    ({'ssm', 'xbc'}, `_ssm_states` of one), `snap` the states after
+    the first `snap_at` (int32 scalar) of its rows, a snapshot
+    (`_mamba_layer`)."""
     x: Any
     positions: jax.Array
     k: Any
@@ -309,6 +349,7 @@ class _ChunkRows(NamedTuple):
     conv: Any = None
     snap: Any = None
     page_size: int = 0
+    snap_at: Any = None
 
     @property
     def n(self) -> int:
@@ -426,7 +467,7 @@ def _masked_attention(q, cfg, positions, k_cache, v_cache, window, dtype):
 
 def _layer_forward(x, lp, cfg, positions, k_cache, v_cache,
                    *, use_flash: bool, mesh=None, rope_on=None,
-                   window=None, row_mask=None, chunk=None):
+                   window=None, row_mask=None, chunk=None, ffn=True):
     """One decoder layer against its view of the KV cache.
 
     x [b, s, d]; k_cache/v_cache [b, h_kv, max_len, hd] (or the paged
@@ -451,6 +492,8 @@ def _layer_forward(x, lp, cfg, positions, k_cache, v_cache,
     run on it whole, and only attention takes the two groups apart,
     the slots' rows against `k_cache`/`v_cache` at `positions` [B, 1]
     and the chunk's against its own.
+
+    `ffn` False: attention alone, no FFN after it ('attn_nope').
     """
     h = _norm(x, lp['attn_norm']['scale'], cfg)
     q = _qk_norm(_attn_proj(h, lp['attn']['q_proj'], cfg.n_heads,
@@ -479,6 +522,8 @@ def _layer_forward(x, lp, cfg, positions, k_cache, v_cache,
             m, counts = _mlp(h, lp, cfg, row_mask)
         return x + out + _post_norm(m.astype(x.dtype), lp,
                                     'mlp_post_norm', cfg), counts, written
+    if not ffn:
+        return x + out, None, written
     return (*_ffn_residual(x + out, lp, cfg, row_mask), written)
 
 
@@ -691,7 +736,7 @@ def _scan_layers_and_unembed(cfg, params, x, positions, cache_k, cache_v,
 
 def _attention_layer(x, lp, l, k_cache, v_cache, private, *, cfg,
                      positions, write_fn, view_fn, use_flash, mesh,
-                     rope_on, window, row_mask, chunk):
+                     rope_on, window, row_mask, chunk, ffn=True):
     """One attention layer of the layer loop: project (and norm) k and
     v, rotate k, write both into cache layer `l` (`write_fn`), a riding
     chunk's into its private cache `private` (k, v), and run the layer
@@ -727,35 +772,43 @@ def _attention_layer(x, lp, l, k_cache, v_cache, private, *, cfg,
         views = view_fn(k_cache, l), view_fn(v_cache, l)
     x, counts, written = _layer_forward(
         x, lp, cfg, positions, *views, use_flash=use_flash, mesh=mesh,
-        rope_on=rope_on, window=window, row_mask=row_mask, chunk=rows)
+        rope_on=rope_on, window=window, row_mask=row_mask, chunk=rows,
+        ffn=ffn)
     if written is not None:
         k_cache, v_cache = written
     return x, k_cache, v_cache, private, counts
 
 
 # ---------------------------------------- layers stacked by their kind
-# A model with conv layers or leading dense layers (`cfg.stacked_by_kind`)
-# holds its weights by kind: params[group]['attn'] (the attention layers'
-# norm and q/k/v/o, [n, ...]), params[group]['conv'] (the conv layers'
-# norm, in/out projections and kernel, [n, ...]) and params[group]['ffn']
-# (every layer's FFN norm and its dense FFN or experts, [n, ...]), in the
-# group 'lead' (the leading dense layers) and 'layers' (the periods of
-# `layer_pattern`).  The page pool holds the attention layers only: an
-# attention layer's cache layer is its index among them.  A conv layer's
-# state is a tail of `conv_kernel - 1` rows a sequence, carried beside
-# the pool ('conv' [conv layers, slots, K - 1, d]).
+# A model stacked by kind (`cfg.stacked_by_kind`) holds its weights by
+# kind: params[group]['attn'] (the attention layers' norm and q/k/v/o,
+# [n, ...]), params[group]['conv'] (the conv layers' norm, in/out
+# projections and kernel, [n, ...]), params[group]['mamba'] (the mamba
+# layers' norm and mixer, [n, ...]) and params[group]['ffn'] (the FFN
+# norm and dense FFN or experts of every layer that has one, [n, ...]),
+# in the group 'lead' (the leading dense layers) and 'layers' (the
+# runs of periods).  The page pool holds the attention layers only: an
+# attention layer's cache layer is its index among them.  A conv
+# layer's state is a tail of `conv_kernel - 1` rows a sequence, carried
+# beside the pool ('conv' [conv layers, slots, K - 1, d]); a mamba
+# layer's a matrix a head and a tail ('ssm', 'xbc': `_ssm_states`).
 
 
 def stacked_param_shapes(cfg: ModelConfig) -> Dict[str, Any]:
     """The tree a model stacked by kind is read from, as shapes in
     `cfg.param_dtype`: per group ('lead': the leading dense layers,
-    'layers': the periods) the conv layers' stack under 'conv' (the
-    mixer's norm 'conv_norm', in/out projections, the depthwise kernel
-    [K, d]), the attention layers' under 'attn' (as every model's, with
-    'q_norm'/'k_norm' scales of hd under `cfg.qk_norm`) and every
-    layer's FFN under 'ffn' ('mlp_norm' and a dense 'mlp', or the
-    experts 'moe_mlp' with the router, the selection bias under
-    `cfg.expert_bias` and the held experts' stacks)."""
+    'layers': the runs of periods) the conv layers' stack under 'conv'
+    (the mixer's norm 'conv_norm', in/out projections, the depthwise
+    kernel [K, d]), the mamba layers' under 'mamba' (the norm
+    'mamba_norm'; in_proj, the depthwise 'conv_kernel' [K, C] and its
+    'conv_bias', 'A_log', 'dt_bias', 'D' of a head each, the gated
+    norm's scale and out_proj), the attention layers' under 'attn' (as
+    every model's, with 'q_norm'/'k_norm' scales of hd under
+    `cfg.qk_norm`) and the FFN of every layer that has one under 'ffn'
+    ('mlp_norm' and a dense 'mlp', or the experts 'moe_mlp' with the
+    router, the selection bias under `cfg.expert_bias`, the held
+    experts' stacks and the shared experts'; no 'gate_proj' where
+    `cfg.mlp_act` is 'relu2')."""
     d, hd, dtype = cfg.d_model, cfg.head_dim, cfg.param_dtype
     sds = lambda *shape: jax.ShapeDtypeStruct(shape, dtype)
     tree: Dict[str, Any] = {'embed': {'embedding': sds(cfg.vocab_size, d)},
@@ -764,11 +817,14 @@ def stacked_param_shapes(cfg: ModelConfig) -> Dict[str, Any]:
         tree['lm_head'] = {'kernel': sds(d, cfg.vocab_size)}
     names = cfg.layer_names()
     lead = len(cfg.dense_layers)
+    gated = cfg.mlp_act != 'relu2'
     for key, kinds in (('lead', names[:lead]), ('layers', names[lead:])):
         if not kinds:
             continue
-        n_conv = sum(LAYER_KINDS[k]['conv'] for k in kinds)
-        n_attn, n = len(kinds) - n_conv, len(kinds)
+        n_conv, n_attn = count_mixer(kinds, 'conv'), count_mixer(kinds,
+                                                                 'attn')
+        n_mamba = count_mixer(kinds, 'mamba')
+        n = sum(LAYER_KINDS[k]['ffn'] for k in kinds)
         group = tree[key] = {}
         if n_conv:
             group['conv'] = {
@@ -776,6 +832,21 @@ def stacked_param_shapes(cfg: ModelConfig) -> Dict[str, Any]:
                 'conv': {'in_proj': {'kernel': sds(n_conv, d, 3 * d)},
                          'kernel': sds(n_conv, cfg.conv_kernel, d),
                          'out_proj': {'kernel': sds(n_conv, d, d)}}}
+        if n_mamba:
+            heads, inner = cfg.ssm_heads, cfg.ssm_inner
+            conv_dim = cfg.ssm_conv_dim
+            group['mamba'] = {
+                'mamba_norm': {'scale': sds(n_mamba, d)},
+                'mamba': {
+                    'in_proj': {'kernel': sds(n_mamba, d,
+                                              inner + conv_dim + heads)},
+                    'conv_kernel': sds(n_mamba, cfg.conv_kernel, conv_dim),
+                    'conv_bias': sds(n_mamba, conv_dim),
+                    'A_log': sds(n_mamba, heads),
+                    'dt_bias': sds(n_mamba, heads),
+                    'D': sds(n_mamba, heads),
+                    'norm': {'scale': sds(n_mamba, inner)},
+                    'out_proj': {'kernel': sds(n_mamba, inner, d)}}}
         if n_attn:
             attn = {name: {'kernel': sds(n_attn, d, heads, hd)}
                     for name, heads in (('q_proj', cfg.n_heads),
@@ -787,15 +858,24 @@ def stacked_param_shapes(cfg: ModelConfig) -> Dict[str, Any]:
                             k_norm={'scale': sds(n_attn, hd)})
             group['attn'] = {'attn_norm': {'scale': sds(n_attn, d)},
                              'attn': attn}
+        if not n:
+            continue
         ffn = group['ffn'] = {'mlp_norm': {'scale': sds(n, d)}}
         if key == 'layers' and cfg.n_experts:
             e, f = cfg.held_experts[1], cfg.d_ff
             moe_mlp = ffn['moe_mlp'] = {
                 'router': {'kernel': sds(n, d, cfg.n_experts)},
-                'gate_proj': sds(n, e, d, f), 'up_proj': sds(n, e, d, f),
-                'down_proj': sds(n, e, f, d)}
+                'up_proj': sds(n, e, d, f), 'down_proj': sds(n, e, f, d)}
+            if gated:
+                moe_mlp['gate_proj'] = sds(n, e, d, f)
             if cfg.expert_bias:
                 moe_mlp['expert_bias'] = sds(n, cfg.n_experts)
+            if cfg.n_shared_experts:
+                e, f = cfg.n_shared_experts, cfg.shared_d_ff or cfg.d_ff
+                moe_mlp.update(shared_up_proj=sds(n, e, d, f),
+                               shared_down_proj=sds(n, e, f, d))
+                if gated:
+                    moe_mlp['shared_gate_proj'] = sds(n, e, d, f)
         else:
             f = cfg.dense_d_ff if key == 'lead' else cfg.d_ff
             ffn['mlp'] = {'gate_proj': {'kernel': sds(n, d, f)},
@@ -808,12 +888,12 @@ def _scan_by_kind(cfg, params, x, positions, cache_k, cache_v, conv,
                   write_fn, *, use_flash, view_fn, all_positions, mesh,
                   row_mask, chunk):
     """`_scan_layers_and_unembed` for a model stacked by kind: the
-    leading dense layers, then the periods, each group one scan over
-    whole periods whose body runs the period's layers in order (every
-    weight read in place from its kind's stack, `_periods`); then the
-    head.  The carry is the stream, the pool's k/v, the sequences'
-    conv tails and, with a riding chunk, its private k/v, tails and
-    page snapshots."""
+    leading dense layers, then each run of periods, each one scan over
+    its whole periods whose body runs the period's layers in order
+    (every weight read in place from its kind's stack, `_periods`);
+    then the head.  The carry is the stream, the pool's k/v, the
+    sequences' recurrent state (conv tails, or mamba states) and, with
+    a riding chunk, its private k/v, its own state and its snapshots."""
     use_flash = _flash_ok(cfg, use_flash, x.shape[1])
     slots = x.shape[0]
     private, own = (), ()
@@ -824,23 +904,30 @@ def _scan_by_kind(cfg, params, x, positions, cache_k, cache_v, conv,
             x=None, k=None, v=None, conv=None, snap=None,
             use_flash=_flash_ok(cfg, chunk.use_flash, chunk.n))
     carry = (x, cache_k, cache_v, conv, private, own)
-    names = cfg.layer_names()
     lead = len(cfg.dense_layers)
     groups = [('lead', cfg.dense_layers, 1)] if lead else []
-    period = cfg.layer_pattern or ('full',)
-    groups.append(('layers', period, (len(names) - lead) // len(period)))
-    first, counts = [0, 0], None
+    groups += [('layers', period, n) for period, n in cfg.runs()]
+    # The first layer of each mixer's state (attention: its cache
+    # layer; conv, mamba: its tail and state), over the whole model;
+    # and, within each group, where the run's weights start in each
+    # kind's stack (attention, conv, mamba, FFN).
+    first, starts, counts = [0, 0, 0], {}, None
     for key, kinds, n_periods in groups:
+        at = starts.setdefault(key, [0, 0, 0, 0])
         carry, c = _periods(
-            cfg, params[key], kinds, n_periods, tuple(first), carry,
-            positions=positions, write_fn=write_fn, view_fn=view_fn,
-            use_flash=use_flash, mesh=mesh, row_mask=row_mask,
-            chunk=chunk)
+            cfg, params[key], kinds, n_periods, tuple(first), tuple(at),
+            carry, positions=positions, write_fn=write_fn,
+            view_fn=view_fn, use_flash=use_flash, mesh=mesh,
+            row_mask=row_mask, chunk=chunk)
         if c is not None:
             counts = c if counts is None else counts + c
-        n_conv = sum(LAYER_KINDS[k]['conv'] for k in kinds)
-        first[0] += n_periods * (len(kinds) - n_conv)
-        first[1] += n_periods * n_conv
+        mixers = [count_mixer(kinds, mixer)
+                  for mixer in ('attn', 'conv', 'mamba')]
+        for i, n in enumerate(mixers):
+            first[i] += n_periods * n
+        for i, n in enumerate(mixers +
+                              [sum(LAYER_KINDS[k]['ffn'] for k in kinds)]):
+            at[i] += n_periods * n
     x, new_k, new_v, conv, private, own = carry
     if chunk is not None:
         x = x[0, :slots, None]
@@ -850,46 +937,66 @@ def _scan_by_kind(cfg, params, x, positions, cache_k, cache_v, conv,
         logits = heads.unembed(x, params, cfg)
     chunk_kv = None
     if chunk is not None:
-        chunk_kv = dict(zip(('k', 'v', 'conv', 'snap'), private + own))
+        chunk_kv = dict(zip(('k', 'v'), private), snap=own[1],
+                        **_recurrent_items(own[0]))
     return (logits if all_positions else logits[:, 0], new_k, new_v,
             counts, None, chunk_kv, conv)
 
 
-def _periods(cfg, group, kinds, n_periods, first, carry, *, positions,
-             write_fn, view_fn, use_flash, mesh, row_mask, chunk):
-    """One group's layers: `n_periods` periods of `kinds`, a scan over
+def _periods(cfg, group, kinds, n_periods, first, at, carry, *,
+             positions, write_fn, view_fn, use_flash, mesh, row_mask,
+             chunk):
+    """One run's layers: `n_periods` periods of `kinds`, a scan over
     the periods whose body runs a period's layers in order.  The
     group's stacks ride the loop whole, and layer j of period p reads
-    its weights at index p * (its kind's layers a period) + (its place
-    among them) of its kind's stack, one dynamic index a leaf that the
-    product reading it takes in place: a slice of a period's weights,
-    read by more than one layer, would be copied out of the stack
-    first (compiled for a v5e, PR 37).  `first` = (attention, conv)
-    index of the group's first layer of each kind: a layer's cache
-    layer, or its tail's.  Returns (carry, the expert layers' counts
-    summed or None)."""
-    n_conv = sum(LAYER_KINDS[k]['conv'] for k in kinds)
-    n_attn = len(kinds) - n_conv
+    its weights at index (the run's start in its kind's stack) + p *
+    (its kind's layers a period) + (its place among them) of its kind's
+    stack, one dynamic index a leaf that the product reading it takes
+    in place: a slice of a period's weights, read by more than one
+    layer, would be copied out of the stack first (as compiled for a
+    v5e).  `first` = (attention, conv, mamba) index of the run's
+    first layer of each mixer over the model: a layer's cache layer, or
+    its state's; `at` = (attention, conv, mamba, FFN) the run's start
+    in each stack of the group.  Returns (carry, the expert layers'
+    counts summed or None)."""
+    n_attn = count_mixer(kinds, 'attn')
+    n_conv = count_mixer(kinds, 'conv')
+    n_mamba = count_mixer(kinds, 'mamba')
+    n_ffn = sum(LAYER_KINDS[k]['ffn'] for k in kinds)
 
     def layer(tree, i):
         return jax.tree.map(
             lambda a: jax.lax.dynamic_index_in_dim(a, i, keepdims=False),
             tree)
 
+    def index(p, n, i, start):
+        idx = p * n + i
+        return idx + start if start else idx
+
     def body(carry, p):
         x, k_cache, v_cache, conv, private, own = carry
-        a, c, counts = 0, 0, None
-        for j, name in enumerate(kinds):
+        a, c, m, f, counts = 0, 0, 0, 0, None
+        for name in kinds:
             kind = LAYER_KINDS[name]
-            lp = layer(group['ffn'], p * len(kinds) + j)
-            if kind['conv']:
-                lp.update(layer(group['conv'], p * n_conv + c))
+            lp, cnt = {}, None
+            if kind['ffn']:
+                lp = layer(group['ffn'], index(p, n_ffn, f, at[3]))
+                f += 1
+            if kind['mixer'] == 'conv':
+                lp.update(layer(group['conv'], index(p, n_conv, c, at[1])))
                 x, conv, own, cnt = _conv_layer(
                     x, lp, first[1] + p * n_conv + c, conv, own, cfg=cfg,
                     row_mask=row_mask, chunk=chunk)
                 c += 1
-            else:
-                lp.update(layer(group['attn'], p * n_attn + a))
+            elif kind['mixer'] == 'mamba':
+                lp.update(layer(group['mamba'],
+                                index(p, n_mamba, m, at[2])))
+                x, conv, own = _mamba_layer(
+                    x, lp, first[2] + p * n_mamba + m, conv, own, cfg=cfg,
+                    row_mask=row_mask, chunk=chunk)
+                m += 1
+            elif kind['mixer'] == 'attn':
+                lp.update(layer(group['attn'], index(p, n_attn, a, at[0])))
                 x, k_cache, v_cache, private, cnt = _attention_layer(
                     x, lp, first[0] + p * n_attn + a, k_cache, v_cache,
                     private, cfg=cfg, positions=positions,
@@ -897,8 +1004,10 @@ def _periods(cfg, group, kinds, n_periods, first, carry, *, positions,
                     use_flash=use_flash, mesh=mesh,
                     rope_on=None if kind['rope'] else False,
                     window=cfg.sliding_window if kind['window'] else None,
-                    row_mask=row_mask, chunk=chunk)
+                    row_mask=row_mask, chunk=chunk, ffn=kind['ffn'])
                 a += 1
+            else:
+                x, cnt = _ffn_residual(x, lp, cfg, row_mask)
             if cnt is not None:
                 counts = cnt if counts is None else counts + cnt
         return (x, k_cache, v_cache, conv, private, own), counts
@@ -907,6 +1016,113 @@ def _periods(cfg, group, kinds, n_periods, first, carry, *, positions,
         carry, counts = jax.lax.scan(
             body, carry, jnp.arange(n_periods, dtype=jnp.int32))
     return carry, None if counts is None else jnp.sum(counts, axis=0)
+
+
+def _mamba_layer(x, lp, m, state, own, *, cfg, row_mask, chunk):
+    """One Mamba-2 layer, its mixer alone (`models/mamba.py` has the
+    equations): x + mixer(norm(x)).  `state` ({'ssm', 'xbc'}, each
+    [mamba layers, b, ...]) holds the states of the b sequences whose
+    rows x [b, s, d] carries, `m` the layer's index among the mamba
+    layers.  One row a sequence (the decode tick) advances each state
+    in place through the kernel; more rows scan in the chunked form.
+    With a riding `chunk` x is the joined stream [1, B + n, d]: the
+    slots' rows take the kernel against their states and the chunk's
+    the chunked scan from its own, `own` = (its states, its snapshot
+    {'ssm', 'xbc'} after `chunk.snap_at` rows), so no row reads another
+    sequence's state.  Returns (x, state, own)."""
+    mp = lp['mamba']
+    inner = cfg.ssm_inner
+    with jax.named_scope('mamba_mixer'):
+        h = _norm(x, lp['mamba_norm']['scale'], cfg)
+        proj = jnp.einsum('bsd,de->bse', h, maybe_dequant(
+            mp['in_proj']['kernel'], h.dtype))
+        z, xbc, dt = jnp.split(proj, [inner, inner + cfg.ssm_conv_dim],
+                               axis=-1)
+        dt = jax.nn.softplus(dt.astype(jnp.float32) +
+                             mp['dt_bias'].astype(jnp.float32))
+        if chunk is None and x.shape[1] == 1:
+            y, state = _ssm_step(xbc[:, 0], dt[:, 0], mp, m, state, cfg,
+                                 row_mask)
+            y = y[:, None]
+        elif chunk is None:
+            y, state, _ = _ssm_rows(xbc, dt, mp, m, state, cfg)
+        else:
+            n = chunk.n
+            y, state = _ssm_step(
+                xbc[0, :-n], dt[0, :-n], mp, m, state, cfg,
+                None if row_mask is None else row_mask[:-n])
+            y_chunk, mine, snap = _ssm_rows(
+                xbc[:, -n:], dt[:, -n:], mp, m, own[0], cfg, chunk.rows,
+                chunk.snap_at)
+            own = (mine, jax.tree.map(
+                lambda leaf, new: _set_layer(leaf, m, new), own[1], snap))
+            y = jnp.concatenate([y[None], y_chunk], axis=1)
+        out = mamba.gated_norm(y.reshape(y.shape[:2] + (inner,)), z,
+                               mp['norm']['scale'], cfg.ssm_groups,
+                               cfg.norm_eps, x.dtype)
+        out = jnp.einsum('bse,ed->bsd', out, maybe_dequant(
+            mp['out_proj']['kernel'], x.dtype))
+    return x + out, state, own
+
+
+def _ssm_step(xbc, dt, mp, m, state, cfg, active):
+    """One row a sequence: xbc [b, C] and dt [b, H] of the b sequences
+    whose states `state` holds -> (y [b, H, P] float32 with the D x
+    term, the states advanced).  A sequence that `active` [b] (None:
+    all) leaves out keeps its state."""
+    with jax.named_scope('ssm_state'):
+        tail = _layer_of(state['xbc'], m)                  # [b, K - 1, C]
+        cat = jnp.concatenate([tail, xbc[:, None].astype(tail.dtype)],
+                              axis=1)
+        u = mamba.conv_rows(cat, mp['conv_kernel'], mp['conv_bias'], 1)
+        xs, bs, cs = mamba.split_xbc(u[:, 0], cfg.ssm_heads,
+                                     cfg.ssm_groups, cfg.ssm_state)
+        decay = jnp.exp(dt * -jnp.exp(mp['A_log'].astype(jnp.float32)))
+        live = (jnp.ones(dt.shape[:1], jnp.int32) if active is None
+                else active.astype(jnp.int32))
+        ssm, y = ssm_decode_step(
+            state['ssm'], m, jnp.swapaxes(dt[..., None] * xs, 1, 2),
+            decay[:, None], bs, cs, live)
+        y = (jnp.swapaxes(y, 1, 2) +
+             mp['D'].astype(jnp.float32)[:, None] * xs)
+        new_tail = cat[:, 1:]
+        if active is not None:
+            new_tail = jnp.where(active[:, None, None], new_tail, tail)
+        return y, {'ssm': ssm, 'xbc': _set_layer(state['xbc'], m,
+                                                 new_tail)}
+
+
+def _ssm_rows(xbc, dt, mp, m, state, cfg, rows=None, snap_at=None):
+    """Rows of b sequences in the chunked form: xbc [b, s, C], dt
+    [b, s, H] -> (y [b, s, H, P] float32 with the D x term, the states
+    after the first `rows` rows (int32 scalar; None: all s), the states
+    after the first `snap_at` rows or None)."""
+    s = xbc.shape[1]
+    tail = _layer_of(state['xbc'], m)
+    cat = jnp.concatenate([tail, xbc.astype(tail.dtype)], axis=1)
+    u = mamba.conv_rows(cat, mp['conv_kernel'], mp['conv_bias'], s)
+    xs, bs, cs = mamba.split_xbc(u, cfg.ssm_heads, cfg.ssm_groups,
+                                 cfg.ssm_state)
+    if rows is not None:
+        dt = jnp.where(jnp.arange(s)[None, :, None] < rows, dt, 0.0)
+    a_rate = -jnp.exp(mp['A_log'].astype(jnp.float32))
+    s0 = _layer_of(state['ssm'], m)
+    with jax.named_scope('ssm_chunk_scan'):
+        y, s_end = mamba.chunk_scan(xs, dt, a_rate, bs, cs, s0,
+                                    cfg.ssm_chunk)
+    y = y + mp['D'].astype(jnp.float32)[:, None] * xs
+    width = tail.shape[1]
+    new_tail = (cat[:, s:] if rows is None else
+                jax.lax.dynamic_slice_in_dim(cat, rows, width, axis=1))
+    state = {'ssm': _set_layer(state['ssm'], m, s_end),
+             'xbc': _set_layer(state['xbc'], m, new_tail)}
+    snap = None
+    if snap_at is not None:
+        with jax.named_scope('ssm_chunk_scan'):
+            snap = {'ssm': mamba.state_at(xs, dt, a_rate, bs, s0, snap_at),
+                    'xbc': jax.lax.dynamic_slice_in_dim(cat, snap_at, width,
+                                                        axis=1)}
+    return y, state, snap
 
 
 def _conv_layer(x, lp, c, conv, own, *, cfg, row_mask, chunk):
@@ -1056,12 +1272,13 @@ def _forward_with_cache(cfg, params, tokens, cache, *, use_flash: bool,
         cfg, params, _embed(cfg, params, tokens), positions,
         cache['k'], cache['v'],
         lambda c, l, new: _write_private(c, l, new, start),
-        use_flash=use_flash, mesh=mesh, conv=cache.get('conv'))
+        use_flash=use_flash, mesh=mesh, conv=_recurrent(cache))
     new = {'k': new_k, 'v': new_v, 'index': cache_len}
     if conv is not None:
-        # The tails after every row given (the standalone programs are
-        # given no padding that a tail could take).
-        new = dict(cache, **new, conv=conv)
+        # The tails (or mamba states) after every row given (the
+        # standalone programs are given no padding that a state could
+        # take).
+        new = dict(cache, **new, **_recurrent_items(conv))
     return logits, new
 
 
@@ -1113,10 +1330,10 @@ def prefill_sp(cfg: ModelConfig, params, tokens, *, mesh, max_len: int,
     window, and the body below is the one block of a model whose layers
     are all alike, run once.
     """
-    if cfg.conv_layers:
-        raise ValueError('sequence-parallel prefill cannot serve conv '
-                         'layers: their tails run through the sequence, '
-                         'and the ring splits it')
+    if cfg.conv_layers or cfg.mamba_layers:
+        raise ValueError('sequence-parallel prefill cannot serve layers '
+                         'with recurrent state (conv or mamba): it runs '
+                         'through the sequence, and the ring splits it')
     if (cfg.layer_pattern or cfg.parallel_block or cfg.post_norms or
             cfg.loop_passes != 1 or cfg.stacked_by_kind):
         raise ValueError('sequence-parallel prefill serves one kind of '
@@ -1410,7 +1627,8 @@ def init_paged_cache(cfg: ModelConfig, n_pages: int, page_size: int,
                      slots: int, max_pages_per_slot: int,
                      quantize_kv: bool = False,
                      head_lanes: int = 0,
-                     sharded_heads: bool = False) -> Dict[str, Any]:
+                     sharded_heads: bool = False,
+                     snapshots: int = 0) -> Dict[str, Any]:
     """Zeroed page-pool cache.  k/v are [L, n_pages, h_kv, ps, d], L
     the cache's layers (`cfg.cache_layers`: a page holds its tokens'
     keys of every layer and pass; int8 {'q','scale'} leaves when
@@ -1435,6 +1653,15 @@ def init_paged_cache(cfg: ModelConfig, n_pages: int, page_size: int,
     which a prefix hit on the page restores.  So a cached page carries
     its tails wherever the prefix cache's hashing and eviction take it.
     The null page's stay zero: the tails before position 0.
+
+    A model with mamba layers holds beside the pool each slot's states
+    ('ssm', 'xbc': `_ssm_states` of `slots`; the tick advances them in
+    place) and a store of `snapshots` entries of every mamba layer's
+    states ('ssm_store', 'xbc_store'), which the engine fills at chosen
+    page boundaries of prompts (`save_snapshot`) and a prefix hit
+    restores (`paged_seed_private`, `paged_admit_slot`): a page's
+    snapshot would be a sequence's whole state, so pages do not carry
+    one.
 
     `head_lanes` (the engine's, where the paged kernel runs on a TPU: 128)
     makes a row a whole number of that many lanes.  The kernel copies a
@@ -1487,6 +1714,10 @@ def init_paged_cache(cfg: ModelConfig, n_pages: int, page_size: int,
         # chip trace, PR 37).
         paged['conv_snap'] = _conv_tails(cfg, n_pages).transpose(
             1, 2, 0, 3)
+    if cfg.mamba_layers:
+        paged.update(_ssm_states(cfg, slots))
+        paged.update({f'{key}_store': value for key, value in _ssm_states(
+            cfg, snapshots, store=True).items()})
     return paged
 
 
@@ -1622,7 +1853,7 @@ def _paged_forward(cfg: ModelConfig, params, tokens, paged, *,
         paged['k'], paged['v'], None if in_kernel else write,
         use_flash=False, view_fn=view,
         all_positions=all_positions, mesh=mesh, row_mask=row_mask,
-        chunk=chunk, conv=paged.get('conv'))
+        chunk=chunk, conv=_recurrent(paged))
 
 
 def kernel_writes_rows(kernel, quantized: bool, s_q: int,
@@ -1665,8 +1896,7 @@ def _paged_tick(cfg, params, tokens, paged, active, *, kernel, mesh,
     advance = (jnp.ones_like(lengths) if active is None
                else active.astype(lengths.dtype))
     new = dict(paged, k=new_k, v=new_v, lengths=lengths + advance)
-    if conv is not None:
-        new['conv'] = conv
+    new.update(_recurrent_items(conv))
     return (logits, new, counts,
             _exit_mass(exit_p, None if active is None
                        else active[:, None]), chunk_kv)
@@ -1711,9 +1941,10 @@ def paged_engine_step(cfg: ModelConfig, params, state, paged, *,
 
 
 def paged_engine_step_with_chunk(cfg: ModelConfig, params, state, paged,
-                                 tokens, cache=None, rows=None, *,
-                                 max_len: int, max_top_k: int = 64,
-                                 kernel=None, mesh=None):
+                                 tokens, cache=None, rows=None,
+                                 snap_at=None, *, max_len: int,
+                                 max_top_k: int = 64, kernel=None,
+                                 mesh=None):
     """`paged_engine_step` with one prefill chunk riding it: one
     program in which every layer's weights are read once, for the live
     slots' tokens and the chunk's rows together.  Between two ticks a
@@ -1731,7 +1962,9 @@ def paged_engine_step_with_chunk(cfg: ModelConfig, params, state, paged,
     `paged_engine_step`'s five results and the private cache with index
     advanced by c (a model with conv layers: its tails after the
     chunk's `rows`, and the tails at the end of each page the chunk
-    completed, `_snapshots`).  The tick's results are what the plain
+    completed, `_snapshots`; a model with mamba layers: its states after
+    the chunk's `rows` and, under 'snap', after its first `snap_at`
+    rows, int32 scalar).  The tick's results are what the plain
     tick's would be: the head, sampling and the bookkeeping see the slots' rows
     only, and the counts (`moe`, `exit_mass`) cover the live slots and
     nothing of the chunk, whose rows are not unembedded (the prompt's
@@ -1744,8 +1977,8 @@ def paged_engine_step_with_chunk(cfg: ModelConfig, params, state, paged,
     chunk = _ChunkRows(_embed(cfg, params, tokens),
                        start + jnp.arange(tokens.shape[1]), cache['k'],
                        cache['v'], use_flash=fresh, rows=rows,
-                       conv=cache.get('conv'), snap=cache.get('snap'),
-                       page_size=ps)
+                       conv=_recurrent(cache), snap=cache.get('snap'),
+                       page_size=ps, snap_at=snap_at)
     *tick, private = _paged_tick(
         cfg, params, state['tokens'][:, None], paged, state['active'],
         kernel=kernel, mesh=mesh, chunk=chunk)
@@ -1758,10 +1991,13 @@ def paged_engine_step_with_chunk(cfg: ModelConfig, params, state, paged,
 def _private_cache(cfg: ModelConfig, max_len: int, page_size: int):
     """A prompt's fresh private prefill cache: `init_cache` of one
     sequence and, for a model with conv layers, room for the tails at
-    the end of each of its pages ('snap', `_snapshots`)."""
+    the end of each of its pages ('snap', `_snapshots`); with mamba
+    layers, for one snapshot of its states ('snap', `_mamba_layer`)."""
     cache = init_cache(cfg, 1, max_len)
     if cfg.conv_layers:
         cache['snap'] = _conv_tails(cfg, max_len // page_size)
+    if cfg.mamba_layers:
+        cache['snap'] = _ssm_states(cfg, 1)
     return cache
 
 
@@ -1791,11 +2027,11 @@ def paged_spec_engine_step(cfg: ModelConfig, params, state, paged,
     pushes toks[b, :counts[b]] per live slot.  Inactive slots emit
     nothing (counts 0).
     """
-    if cfg.conv_layers:
+    if cfg.conv_layers or cfg.mamba_layers:
         raise ValueError(
-            'the speculative tick cannot serve conv layers: a rejected '
-            'draft would leave its rows in the conv tails, which keep no '
-            'position to roll back to')
+            'the speculative tick cannot serve layers with recurrent '
+            'state (conv or mamba): a rejected draft would leave its rows '
+            'in the state, which keeps no position to roll back to')
     active = state['active']
     b, _ = drafts.shape
     s_q = drafts.shape[1] + 1
@@ -1871,12 +2107,16 @@ def paged_spec_engine_step(cfg: ModelConfig, params, state, paged,
 
 
 def paged_admit_slot(paged, slot, pages_row, length, tail=None,
-                     snap_page=None):
+                     snap_page=None, states=None, snapshot=None):
     """Point `slot` at its pages and depth (jit with paged donated).  A
     model with conv layers also sets the slot's tails: `tail` [conv
     layers, K - 1, d] (a prefill's), else the snapshot of pool page
     `snap_page` (the last page of a prefix hit; the null page's is the
-    tails before position 0)."""
+    tails before position 0).  A model with mamba layers sets the
+    slot's states: `states` (a prefill's private {'ssm', 'xbc'}, each
+    [mamba layers, 1, ...]), else entry `snapshot` of the snapshot store
+    (a prefix hit's), else zeros: a slot never keeps another request's
+    state."""
     new = dict(
         paged,
         block_tables=paged['block_tables'].at[slot].set(
@@ -1890,7 +2130,35 @@ def paged_admit_slot(paged, slot, pages_row, length, tail=None,
                 0 if snap_page is None else snap_page].transpose(1, 0, 2)
         new['conv'] = paged['conv'].at[:, slot].set(
             tail.astype(paged['conv'].dtype))
+    # skytpu: lint-ok[tracer-safety] reason=a key of the pool's dict, known when traced
+    if 'ssm' in paged:
+        with jax.named_scope('ssm_state'):
+            for key in ('ssm', 'xbc'):
+                if states is not None:
+                    value = states[key][:, 0]
+                elif snapshot is not None:
+                    value = jax.lax.dynamic_index_in_dim(
+                        paged[f'{key}_store'], snapshot, keepdims=False)
+                else:
+                    value = jnp.zeros_like(paged[key][:, 0])
+                new[key] = jax.lax.dynamic_update_index_in_dim(
+                    paged[key], value.astype(paged[key].dtype), slot,
+                    axis=1)
     return new
+
+
+def save_snapshot(paged, states, entry):
+    """Entry `entry` of the pool's snapshot store ('ssm_store',
+    'xbc_store' [entries, mamba layers, ...]) set to `states` ({'ssm',
+    'xbc'}, each [mamba layers, 1, ...]: a prefill's snapshot).  Jit
+    with paged donated: the store is written in place."""
+    with jax.named_scope('ssm_state'):
+        return dict(paged, **{
+            f'{key}_store': jax.lax.dynamic_update_index_in_dim(
+                paged[f'{key}_store'],
+                states[key][:, 0].astype(paged[f'{key}_store'].dtype),
+                entry, axis=0)
+            for key in ('ssm', 'xbc')})
 
 
 def paged_release_slot(paged, slot):
@@ -1955,8 +2223,8 @@ def insert_prefill_pages(paged, private_cache, pages_row, *,
 _GATHER_LIMIT_BYTES = 1 << 31
 
 
-def paged_seed_private(cfg: ModelConfig, paged, pages_row, *,
-                       priv_len: int):
+def paged_seed_private(cfg: ModelConfig, paged, pages_row, snapshot=None,
+                       *, priv_len: int):
     """Build a private prefill cache whose leading positions are the
     dequantized contents of cached pages `pages_row` — the prefix-hit
     admission path: the remaining prompt tokens then chunk-prefill
@@ -1969,7 +2237,11 @@ def paged_seed_private(cfg: ModelConfig, paged, pages_row, *,
     compiler splits a gather over such an operand and copies two
     thirds of the leaf first (2.2 GB of temporaries beside a 3.3 GB
     leaf, compiled for a v5e, PR 35), so there the pages are sliced
-    out one at a time under a scan, which copies nothing else."""
+    out one at a time under a scan, which copies nothing else.
+
+    A model with mamba layers takes its states from entry `snapshot` of
+    the snapshot store: the states after the hit's last page (no page
+    of keys could give them again)."""
     ps = _page_size_of(paged)
     r = pages_row.shape[0]
     ids = jnp.asarray(pages_row, jnp.int32)
@@ -2018,6 +2290,12 @@ def paged_seed_private(cfg: ModelConfig, paged, pages_row, *,
             cache['conv'] = paged['conv_snap'][ids[-1]].transpose(
                 1, 0, 2)[:, None]
             cache['snap'] = _conv_tails(cfg, priv_len // ps)
+    if 'ssm_store' in paged:
+        with jax.named_scope('ssm_state'):
+            cache.update({key: jax.lax.dynamic_index_in_dim(
+                paged[f'{key}_store'], snapshot, keepdims=False)[:, None]
+                for key in ('ssm', 'xbc')})
+            cache['snap'] = _ssm_states(cfg, 1)
     return cache
 
 

@@ -173,7 +173,8 @@ class CheckpointReader:
 # HF config.json -> ModelConfig
 # --------------------------------------------------------------------------
 
-_FAMILIES = ('llama', 'qwen2', 'gemma', 'mixtral', 'ouro', 'lfm2_moe')
+_FAMILIES = ('llama', 'qwen2', 'gemma', 'mixtral', 'ouro', 'lfm2_moe',
+             'nemotron_h')
 
 
 def config_from_hf(hf: Dict[str, Any]) -> Tuple[configs.ModelConfig, str]:
@@ -188,6 +189,8 @@ def config_from_hf(hf: Dict[str, Any]) -> Tuple[configs.ModelConfig, str]:
     head_dim = hf.get('head_dim') or d_model // n_heads
     if family == 'lfm2_moe':
         return _lfm2_moe_config(hf), family
+    if family == 'nemotron_h':
+        return _nemotron_h_config(hf), family
     common = dict(
         vocab_size=hf['vocab_size'],
         d_model=d_model,
@@ -332,6 +335,49 @@ def _lfm2_moe_config(hf: Dict[str, Any]) -> configs.ModelConfig:
         param_dtype=jnp.float32)
 
 
+# Nemotron-H's letters of `hybrid_override_pattern` -> layer kinds.
+_NEMOTRON_H_KINDS = {'M': 'mamba', 'E': 'experts', '*': 'attn_nope'}
+
+
+def _nemotron_h_config(hf: Dict[str, Any]) -> configs.ModelConfig:
+    """Nemotron-H: Mamba-2 layers, expert layers (non-gated relu^2
+    experts chosen by sigmoid score + bias, a shared expert) and
+    position-free attention layers, one sub-block a layer, named by
+    `hybrid_override_pattern` (the program's layers stacked by kind, in
+    runs of periods; the training module does not build them)."""
+    import jax.numpy as jnp  # pylint: disable=import-outside-toplevel
+    for key, want in (('n_group', 1), ('topk_group', 1),
+                      ('norm_topk_prob', True), ('mlp_hidden_act', 'relu2'),
+                      ('mamba_hidden_act', 'silu'), ('use_conv_bias', True),
+                      ('n_shared_experts', 1)):
+        if hf.get(key, want) != want:
+            raise ValueError(f'nemotron_h with {key}={hf[key]!r} is not '
+                             f'served (needs {want!r})')
+    names = [_NEMOTRON_H_KINDS[c] for c in hf['hybrid_override_pattern']]
+    return configs.ModelConfig(
+        vocab_size=hf['vocab_size'], d_model=hf['hidden_size'],
+        n_layers=hf['num_hidden_layers'],
+        n_heads=hf['num_attention_heads'],
+        n_kv_heads=hf['num_key_value_heads'],
+        head_dim_override=hf['head_dim'],
+        d_ff=hf['moe_intermediate_size'],
+        shared_d_ff=hf['moe_shared_expert_intermediate_size'],
+        layer_pattern=tuple(names), conv_kernel=hf['conv_kernel'],
+        ssm_heads=hf['mamba_num_heads'], ssm_head_dim=hf['mamba_head_dim'],
+        ssm_groups=hf['n_groups'], ssm_state=hf['ssm_state_size'],
+        ssm_chunk=hf['chunk_size'],
+        max_seq_len=hf.get('max_position_embeddings', 8192),
+        norm_eps=float(hf.get('norm_eps', 1e-5)), mlp_act='relu2',
+        tie_embeddings=bool(hf.get('tie_word_embeddings', False)),
+        n_experts=hf['n_routed_experts'],
+        expert_top_k=hf['num_experts_per_tok'],
+        expert_score_fn='sigmoid', expert_bias=True,
+        expert_gate_eps=1e-20,
+        expert_scale=float(hf.get('routed_scaling_factor', 1.0)),
+        n_shared_experts=hf['n_shared_experts'],
+        dtype=jnp.bfloat16, param_dtype=jnp.float32)
+
+
 # --------------------------------------------------------------------------
 # Name mapping + tensor transforms
 # --------------------------------------------------------------------------
@@ -369,6 +415,8 @@ def _plan_for(cfg: configs.ModelConfig, family: str):
     full paths of its tree (`_plan_by_kind`).
     """
     if cfg.stacked_by_kind:
+        if family == 'nemotron_h':
+            return _plan_nemotron_h(cfg)
         return _plan_by_kind(cfg)
     hd, nh, nkv = cfg.head_dim, cfg.n_heads, cfg.n_kv_heads
     d, dff = cfg.d_model, cfg.d_ff
@@ -534,6 +582,77 @@ def _plan_by_kind(cfg: configs.ModelConfig):
     return plan
 
 
+def _plan_nemotron_h(cfg: configs.ModelConfig):
+    """The plan of Nemotron-H (the `nemotron_h` modeling code's names:
+    `backbone.layers.{i}.norm` and `.mixer.*` for every layer's one
+    sub-block, `backbone.embeddings`, `backbone.norm_f`, `lm_head`):
+    full path in the tree -> (HF name template, transform, the
+    checkpoint's layers stacked there, in order).  No rotation, so q
+    and k are taken as they are."""
+    hd, nh, nkv, d = cfg.head_dim, cfg.n_heads, cfg.n_kv_heads, cfg.d_model
+    names = cfg.layer_names()
+    mixer = 'backbone.layers.{i}.mixer.'
+    norm = 'backbone.layers.{i}.norm.weight'
+    of = {kind: [i for i, n in enumerate(names) if n == kind]
+          for kind in ('mamba', 'attn_nope', 'experts')}
+    same = lambda w: w
+    plan: Dict[Tuple[str, ...], Tuple[Any, ...]] = {
+        ('embed', 'embedding'): ('backbone.embeddings.weight', same),
+        ('final_norm', 'scale'): ('backbone.norm_f.weight', same),
+        ('lm_head', 'kernel'): ('lm_head.weight', _t)}
+    m, a, e = ('layers', 'mamba'), ('layers', 'attn'), ('layers', 'ffn')
+    for path, (name, fn, layers) in {
+            m + ('mamba_norm', 'scale'): (norm, same, of['mamba']),
+            m + ('mamba', 'in_proj', 'kernel'): (mixer + 'in_proj.weight',
+                                                 _t, of['mamba']),
+            # torch Conv1d(C, C, K, groups=C): [C, 1, K] -> [K, C].
+            m + ('mamba', 'conv_kernel'): (mixer + 'conv1d.weight',
+                                           lambda w: _t(w[:, 0, :]),
+                                           of['mamba']),
+            m + ('mamba', 'conv_bias'): (mixer + 'conv1d.bias', same,
+                                         of['mamba']),
+            m + ('mamba', 'A_log'): (mixer + 'A_log', same, of['mamba']),
+            m + ('mamba', 'dt_bias'): (mixer + 'dt_bias', same,
+                                       of['mamba']),
+            m + ('mamba', 'D'): (mixer + 'D', same, of['mamba']),
+            m + ('mamba', 'norm', 'scale'): (mixer + 'norm.weight', same,
+                                             of['mamba']),
+            m + ('mamba', 'out_proj', 'kernel'): (mixer + 'out_proj.weight',
+                                                  _t, of['mamba']),
+            a + ('attn_norm', 'scale'): (norm, same, of['attn_nope']),
+            a + ('attn', 'q_proj', 'kernel'): (
+                mixer + 'q_proj.weight',
+                lambda w: _t(w).reshape(d, nh, hd), of['attn_nope']),
+            a + ('attn', 'k_proj', 'kernel'): (
+                mixer + 'k_proj.weight',
+                lambda w: _t(w).reshape(d, nkv, hd), of['attn_nope']),
+            a + ('attn', 'v_proj', 'kernel'): (
+                mixer + 'v_proj.weight',
+                lambda w: _t(w).reshape(d, nkv, hd), of['attn_nope']),
+            a + ('attn', 'o_proj', 'kernel'): (
+                mixer + 'o_proj.weight',
+                lambda w: _t(w).reshape(nh, hd, d), of['attn_nope']),
+            e + ('mlp_norm', 'scale'): (norm, same, of['experts']),
+            e + ('moe_mlp', 'router', 'kernel'): (mixer + 'gate.weight', _t,
+                                                  of['experts']),
+            e + ('moe_mlp', 'expert_bias'): (
+                mixer + 'gate.e_score_correction_bias', same,
+                of['experts']),
+            e + ('moe_mlp', 'up_proj'): (
+                mixer + 'experts.{e}.up_proj.weight', _t, of['experts']),
+            e + ('moe_mlp', 'down_proj'): (
+                mixer + 'experts.{e}.down_proj.weight', _t, of['experts']),
+            # The one shared expert: a stack of one.
+            e + ('moe_mlp', 'shared_up_proj'): (
+                mixer + 'shared_experts.up_proj.weight',
+                lambda w: _t(w)[None], of['experts']),
+            e + ('moe_mlp', 'shared_down_proj'): (
+                mixer + 'shared_experts.down_proj.weight',
+                lambda w: _t(w)[None], of['experts'])}.items():
+        plan[path] = (name, fn, layers)
+    return plan
+
+
 def expected_tree(cfg: configs.ModelConfig) -> Dict[str, Any]:
     """Shape/dtype skeleton of the model's param tree (eval_shape —
     nothing is materialized).  A model stacked by kind is served by
@@ -645,10 +764,11 @@ def load_params(src_dir: str,
             stacked = alloc(want.shape, tgt_path)
             for j, i in enumerate(layers):
                 if '{e}' in template:
+                    lo, held = cfg.held_experts
                     layer = np.stack([
                         transform(reader.get(
                             template.format(i=i, e=e)))
-                        for e in range(cfg.n_experts)
+                        for e in range(lo, lo + held)
                     ])
                 else:
                     layer = transform(reader.get(template.format(i=i)))

@@ -74,21 +74,38 @@ def route(tokens, router_kernel, cfg: ModelConfig, bias=None
     if cfg.expert_gate_eps:
         total = total + cfg.expert_gate_eps
     gate_vals = gate_vals / total
+    if cfg.expert_scale != 1.0:
+        gate_vals = gate_vals * cfg.expert_scale
     return scores, gate_vals, gate_idx
+
+
+# An expert's activation by `cfg.mlp_act`.  'relu2' (Nemotron-H's
+# experts) is relu(h)^2 of an expert without a gate matrix.
+ACTIVATIONS = {'silu': jax.nn.silu, 'gelu': jax.nn.gelu,
+               'relu2': lambda h: jnp.square(jax.nn.relu(h))}
 
 
 def _expert_products(x, gates, w_gate, w_up, w_down, cfg: ModelConfig):
     """sum_e gates[n, e] * (act(x G_e) * (x U_e)) D_e for stacks
     [E, d, f], [E, d, f], [E, f, d]: x [N, d], gates [N, E] float32 ->
-    [N, d] float32.  The hidden activation is x's dtype.  The gate
-    scales the hidden activation, so the down projection contracts
+    [N, d] float32; without a gate stack (`w_gate` None: a non-gated
+    expert) act(x U_e) D_e.  The hidden activation is x's dtype.  The
+    gate scales the hidden activation, so the down projection contracts
     expert and width at once and no [E, N, d] tensor is built."""
-    act = {'silu': jax.nn.silu, 'gelu': jax.nn.gelu}[cfg.mlp_act]
+    act = ACTIVATIONS[cfg.mlp_act]
     kw = dict(preferred_element_type=jnp.float32)
-    g = jnp.einsum('nd,edf->enf', x, maybe_dequant(w_gate, x.dtype), **kw)
-    u = jnp.einsum('nd,edf->enf', x, maybe_dequant(w_up, x.dtype), **kw)
+    u = None
+    if w_gate is not None:
+        g = jnp.einsum('nd,edf->enf', x, maybe_dequant(w_gate, x.dtype),
+                       **kw)
+        u = jnp.einsum('nd,edf->enf', x, maybe_dequant(w_up, x.dtype),
+                       **kw)
+        hidden = act(g) * u
+    else:
+        hidden = act(jnp.einsum('nd,edf->enf', x,
+                                maybe_dequant(w_up, x.dtype), **kw))
     h = nn.with_logical_constraint(
-        (act(g) * u * gates.T[:, :, None]).astype(x.dtype),
+        (hidden * gates.T[:, :, None]).astype(x.dtype),
         ('expert', None, 'mlp'))
     return jnp.einsum('enf,efd->nd', h, maybe_dequant(w_down, x.dtype),
                       **kw)
@@ -101,7 +118,9 @@ def moe_apply(tokens, mp: Dict[str, Any], cfg: ModelConfig,
     [d, n_experts], the held experts' `gate_proj`, `up_proj` [n_held,
     d, f] and `down_proj` [n_held, f, d] (int8 leaves pass through
     `maybe_dequant`) and, with shared experts, `shared_gate_proj`,
-    `shared_up_proj`, `shared_down_proj` over [n_shared, ...].
+    `shared_up_proj`, `shared_down_proj` over [n_shared, ...] (of their
+    own width).  `cfg.mlp_act` 'relu2' experts are not gated and have
+    no `gate_proj` leaves: act(x U) D.
 
     Returns (out [N, d] float32, the load-balancing auxiliary loss,
     counts int32 [4]): rows routed, (row, held expert) pairs computed,
@@ -130,9 +149,11 @@ def moe_apply(tokens, mp: Dict[str, Any], cfg: ModelConfig,
             else jnp.sum(row_mask.astype(jnp.float32)),
             jnp.sum(per_expert), jnp.max(per_expert),
             jnp.sum(per_expert > 0)]).astype(jnp.int32)
+    gated = cfg.mlp_act != 'relu2'
     with jax.named_scope('moe_experts'):
-        out = _expert_products(x, gates, mp['gate_proj'], mp['up_proj'],
-                               mp['down_proj'], cfg)
+        out = _expert_products(x, gates,
+                               mp['gate_proj'] if gated else None,
+                               mp['up_proj'], mp['down_proj'], cfg)
     if cfg.n_shared_experts:
         if cfg.shared_expert_combine not in ('sum', 'average'):
             raise ValueError(
@@ -144,7 +165,8 @@ def moe_apply(tokens, mp: Dict[str, Any], cfg: ModelConfig,
             out = out + _expert_products(
                 x, jnp.full((tokens.shape[0], cfg.n_shared_experts),
                             weight, jnp.float32),
-                mp['shared_gate_proj'], mp['shared_up_proj'],
+                mp['shared_gate_proj'] if gated else None,
+                mp['shared_up_proj'],
                 mp['shared_down_proj'], cfg)
 
     # Load-balancing auxiliary loss (Switch Transformer eq. 4) over the
@@ -197,9 +219,10 @@ class MoEMLP(nn.Module):
                           ('shared_', cfg.n_shared_experts)):
             if not n:
                 continue
-            mp[f'{prefix}gate_proj'] = param(
-                f'{prefix}gate_proj', (n, d, cfg.d_ff),
-                ('expert', 'embed', 'mlp'))
+            if cfg.mlp_act != 'relu2':
+                mp[f'{prefix}gate_proj'] = param(
+                    f'{prefix}gate_proj', (n, d, cfg.d_ff),
+                    ('expert', 'embed', 'mlp'))
             mp[f'{prefix}up_proj'] = param(
                 f'{prefix}up_proj', (n, d, cfg.d_ff),
                 ('expert', 'embed', 'mlp'))

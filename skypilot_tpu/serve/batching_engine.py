@@ -104,7 +104,11 @@ the expert layers' counts beside `finished`, read one tick behind
 run several times a token) takes the same path too: a page holds its
 tokens' keys of every cache layer (`cfg.cache_layers`, one a pass and
 layer), a tick still yields one token a slot, and returns the exit
-gate's mass by pass the same way (stats()['loop']).
+gate's mass by pass the same way (stats()['loop']).  A model with
+mamba layers takes it too: each slot's states sit beside the pool and
+the tick advances them in place; a prefix hit restores them from a
+store of snapshots saved at chosen page boundaries, and goes only as
+deep as a snapshot does (stats()['ssm']).
 
 Admission is BOUNDED: `max_queue` rejects new submits when the backlog
 is full (`QueueFull` -> HTTP 429) and `queue_ttl` expires requests
@@ -114,6 +118,7 @@ spike degrades with fast, honest rejections instead of unbounded TTFT.
 from __future__ import annotations
 
 import collections
+import math
 import os
 import threading
 import time
@@ -156,6 +161,7 @@ NULL_PAGE = cache_manager.NULL_PAGE
 PagePool = cache_manager.PagePool
 PagedKVManager = cache_manager.PagedKVManager
 PrefixCache = cache_manager.PrefixCache
+StateSnapshots = cache_manager.StateSnapshots
 chunk_hashes = cache_manager.chunk_hashes
 NgramDrafter = sampler_lib.NgramDrafter
 SlotSampler = sampler_lib.SlotSampler
@@ -201,6 +207,30 @@ _M_CONV_SNAPSHOTS_RESTORED = metrics_lib.counter(
     'skytpu_engine_conv_snapshots_restored_total',
     'Prefix hits whose conv tails came from the snapshot of their last '
     'cached page (models with conv layers).')
+_M_SSM_SNAPSHOTS_SAVED = metrics_lib.counter(
+    'skytpu_engine_ssm_snapshots_saved_total',
+    'Snapshots of every mamba layer\'s state saved to the store at a '
+    'prompt\'s page boundary (models with mamba layers).')
+_M_SSM_SNAPSHOTS_RESTORED = metrics_lib.counter(
+    'skytpu_engine_ssm_snapshots_restored_total',
+    'Prefix hits whose mamba states came from a snapshot in the store.')
+_M_SSM_PROMPT_TOKENS = metrics_lib.counter(
+    'skytpu_engine_ssm_prompt_tokens_total',
+    'Prompt tokens admitted (models with mamba layers).')
+_M_SSM_RESTORED_TOKENS = metrics_lib.counter(
+    'skytpu_engine_ssm_restored_tokens_total',
+    'Prompt tokens whose pages and mamba states a prefix hit restored.')
+_M_SSM_RECOMPUTED_TOKENS = metrics_lib.counter(
+    'skytpu_engine_ssm_recomputed_tokens_total',
+    'Prompt tokens whose pages were cached but whose mamba states were '
+    'not (no snapshot that deep): prefilled again.')
+_M_SSM_STATE_BYTES = metrics_lib.gauge(
+    'skytpu_engine_ssm_state_bytes',
+    'Bytes of the slots\' mamba states held beside the page pool.')
+_M_SSM_SNAPSHOT_ROWS = metrics_lib.gauge(
+    'skytpu_engine_ssm_snapshot_rows',
+    'Entries of the store of mamba state snapshots (each every mamba '
+    'layer\'s state of one prefix).')
 _M_BUSY_SLOTS = metrics_lib.gauge(
     'skytpu_engine_busy_slots', 'KV slots currently decoding.')
 _M_SLOTS = metrics_lib.gauge(
@@ -259,6 +289,31 @@ def _device_memory_left(device) -> Optional[int]:
     if 'bytes_limit' not in stats or 'bytes_in_use' not in stats:
         return None
     return int(stats['bytes_limit']) - int(stats['bytes_in_use'])
+
+
+# The share of the device memory left, with weights, pool and slot
+# states resident, that a model with mamba layers gives its store of
+# state snapshots; the rest is for private prefill caches and the
+# programs' temporaries.
+_SNAPSHOT_SHARE = 0.25
+
+
+def _snapshot_entries(cfg, slots: int, pool_bytes: int, device) -> int:
+    """Entries of the store of state snapshots (a model with mamba
+    layers): `_SNAPSHOT_SHARE` of what `device` has left once the pool
+    (`pool_bytes`) and the slots' states are placed, at least one, at
+    most two a slot; two a slot where the backend reports no memory
+    statistics."""
+    import jax.numpy as jnp  # pylint: disable=import-outside-toplevel
+    one = cfg.mamba_layers * (
+        4 * cfg.ssm_heads * cfg.ssm_head_dim * cfg.ssm_state +
+        (cfg.conv_kernel - 1) * cfg.ssm_conv_dim *
+        jnp.dtype(cfg.dtype).itemsize)
+    left = _device_memory_left(device)
+    if left is None:
+        return 2 * slots
+    left -= pool_bytes + slots * one
+    return max(1, min(2 * slots, int(left * _SNAPSHOT_SHARE) // one))
 
 
 def _prefill_bound(slots: int, private: int, pool) -> int:
@@ -369,12 +424,13 @@ class ContinuousBatchingEngine:
         if self.spec_tokens < 0:
             raise ValueError(
                 f'spec_tokens must be >= 0, got {spec_tokens}')
-        if cfg.conv_layers and (self.spec_tokens or mesh is not None):
+        if ((cfg.conv_layers or cfg.mamba_layers) and
+                (self.spec_tokens or mesh is not None)):
             raise ValueError(
-                'a model with conv layers is served without speculation '
-                '(a rejected draft\'s rows would stay in the conv tails) '
-                'and on one device (the tails have no placement over a '
-                'mesh)')
+                'a model with recurrent state (conv or mamba layers) is '
+                'served without speculation (a rejected draft\'s rows '
+                'would stay in the state) and on one device (the state '
+                'has no placement over a mesh)')
         page_size = int(page_size)
         n_pages = cache_manager.PagedKVManager.pool_pages(
             kv_pages, slots, max_len, page_size)
@@ -387,6 +443,17 @@ class ContinuousBatchingEngine:
         # as a closure constant, so the hot loop never re-reads the
         # environment.
         self.decode_kernel = paged_attention_lib.decode_kernel_choice()
+        # A model with mamba layers: a store of snapshots of the slots'
+        # states, sized from the memory left (`_snapshot_entries`).
+        snapshots = 0
+        if cfg.mamba_layers:
+            snapshots = _snapshot_entries(
+                cfg, slots, 2 * cfg.cache_layers * n_pages * page_size *
+                cfg.n_kv_heads * cfg.head_dim *
+                jnp.dtype(cfg.dtype).itemsize,
+                next(iter(jax.tree.leaves(self.params)[0].devices())))
+        self._snapshots = (cache_manager.StateSnapshots(snapshots)
+                           if cfg.mamba_layers else None)
         # On a TPU the kernel copies whole 128-lane rows: a narrower
         # head shares its row with its neighbours where it can, and is
         # padded where it cannot (an int8 pool, a mesh of more than one
@@ -396,7 +463,8 @@ class ContinuousBatchingEngine:
             quantize_kv=quantize_kv,
             head_lanes=paged_attention_lib.pool_head_lanes(
                 self.decode_kernel),
-            sharded_heads=mesh is not None and mesh.size > 1)
+            sharded_heads=mesh is not None and mesh.size > 1,
+            snapshots=snapshots)
         self._heads_per_row = decode.heads_per_row(cfg, self._cache['k'])
         _M_KV_HEADS_PER_ROW.set(self._heads_per_row)
         # Prompts that may be mid-prefill at once.  Each holds a private
@@ -404,11 +472,15 @@ class ContinuousBatchingEngine:
         # engine's cache; past the bound a request waits in the queue
         # for a prefill to finish.
         # (A model with conv layers: and its tails, and those at the end
-        # of each of its pages.)
+        # of each of its pages; with mamba layers: its states and one
+        # snapshot of them.)
         private = (2 * cfg.cache_layers * cfg.n_kv_heads * cfg.head_dim *
                    max_len + cfg.conv_layers * (max_len // page_size + 1) *
                    (cfg.conv_kernel - 1) * cfg.d_model
-                   ) * jnp.dtype(cfg.dtype).itemsize
+                   ) * jnp.dtype(cfg.dtype).itemsize + 2 * sum(
+                       leaf.nbytes // slots for key in ('ssm', 'xbc')
+                       if key in self._cache
+                       for leaf in (self._cache[key],))
         self._max_prefills = _prefill_bound(
             slots, private, tuple(self._cache[key] for key in
                                   ('k', 'v', 'conv_snap')
@@ -474,6 +546,10 @@ class ContinuousBatchingEngine:
                                     donate_argnums=(0,))
         self._write_pages_q = jax.jit(decode.write_pages_quantized,
                                       donate_argnums=(0,))
+        # A prefill's snapshot of its mamba states -> an entry of the
+        # store (pool donated: written in place).
+        self._save_snapshot = jax.jit(decode.save_snapshot,
+                                      donate_argnums=(0,))
         # Jitted prefill: one compile per prompt-length bucket (the
         # whole point of the bucket padding), not eager per-op dispatch
         # per admission.
@@ -506,7 +582,7 @@ class ContinuousBatchingEngine:
         for attr in ('_step', '_spec_step', '_admit_paged',
                      '_release_paged', '_insert_pages', '_seed_private',
                      '_write_pages', '_write_pages_q', '_prefill',
-                     '_prefill_chunk', '_chunk_step'):
+                     '_prefill_chunk', '_chunk_step', '_save_snapshot'):
             setattr(self, attr, self._sentinel.wrap(
                 attr.lstrip('_'), getattr(self, attr)))
         self._failed: Optional[Exception] = None
@@ -548,6 +624,17 @@ class ContinuousBatchingEngine:
         self._conv_counts = dict.fromkeys(
             ('snapshots_saved', 'snapshots_restored', 'restored_tokens',
              'prompt_tokens'), 0)
+        # A model with mamba layers: snapshots of the states saved to
+        # the store, restored by prefix hits; the prompt tokens
+        # admitted, those a hit restored (pages and states), and those
+        # whose pages were cached but whose states were not.
+        self._ssm_counts = dict.fromkeys(
+            ('snapshots_saved', 'snapshots_restored', 'prompt_tokens',
+             'restored_tokens', 'recomputed_tokens'), 0)
+        if cfg.mamba_layers:
+            _M_SSM_STATE_BYTES.set(self._cache['ssm'].nbytes +
+                                   self._cache['xbc'].nbytes)
+            _M_SSM_SNAPSHOT_ROWS.set(snapshots)
         # A looped stack's (cfg.loop_passes > 1) exit mass by pass,
         # summed over the ticks read.
         self._exit_mass = [0.0] * cfg.loop_passes
@@ -1072,6 +1159,12 @@ class ContinuousBatchingEngine:
         `snapshots_restored` (prefix hits that took their tails from
         their last page's), `restored_tokens` (the prompt tokens those
         hits restored, pages and tails) and `prompt_tokens` (admitted).
+        A model with mamba layers adds `ssm`: `state_bytes` (the slots'
+        states), `snapshot_rows` (entries of the snapshot store),
+        `snapshots_saved`, `snapshots_restored`, `snapshots_evicted`,
+        `prompt_tokens` (admitted), `restored_tokens` (those a hit
+        restored, pages and states) and `recomputed_tokens` (those whose
+        pages were cached but whose states were not: prefilled again).
         A looped stack adds `loop`: `steps` (passes over the stack a token),
         `cache_layers` (the pool's: layers x steps), `passes` (stack
         passes run by the ticks read, `steps` a tick) and `exit_mass`
@@ -1118,6 +1211,13 @@ class ContinuousBatchingEngine:
                 stats['conv'] = dict(
                     self._conv_counts, layers=self.cfg.conv_layers,
                     state_bytes=self._cache['conv'].nbytes)
+            if self._snapshots is not None:
+                stats['ssm'] = dict(
+                    self._ssm_counts,
+                    state_bytes=(self._cache['ssm'].nbytes +
+                                 self._cache['xbc'].nbytes),
+                    snapshot_rows=self._snapshots.entries,
+                    snapshots_evicted=self._snapshots.evicted)
             if self.cfg.loop_passes > 1:
                 stats['loop'] = {
                     'steps': self.cfg.loop_passes,
@@ -1226,6 +1326,18 @@ class ContinuousBatchingEngine:
         _M_CONV_SNAPSHOTS_SAVED.inc(counts.get('snapshots_saved', 0))
         _M_CONV_SNAPSHOTS_RESTORED.inc(counts.get('snapshots_restored', 0))
 
+    def _count_ssm(self, **counts: int) -> None:
+        """Add to stats()['ssm'] (a model with mamba layers only)."""
+        with self._metrics_lock:
+            for key, n in counts.items():
+                self._ssm_counts[key] += n
+        for key, metric in (('snapshots_saved', _M_SSM_SNAPSHOTS_SAVED),
+                            ('snapshots_restored', _M_SSM_SNAPSHOTS_RESTORED),
+                            ('prompt_tokens', _M_SSM_PROMPT_TOKENS),
+                            ('restored_tokens', _M_SSM_RESTORED_TOKENS),
+                            ('recomputed_tokens', _M_SSM_RECOMPUTED_TOKENS)):
+            metric.inc(counts.get(key, 0))
+
     def _record_chunk(self, fused: bool = False) -> None:
         _M_PREFILL_CHUNKS.inc()
         if fused:
@@ -1271,10 +1383,14 @@ class ContinuousBatchingEngine:
         slot = self._slots[slot_id]
         prompt = request.prompt_ids
         n = len(prompt)
-        # Match the prefix cache and allocate the request's pages.
+        # Match the prefix cache and allocate the request's pages (a
+        # model with mamba layers: a hit as deep as a state snapshot).
         plan = self._kv.plan_admission(    # may raise PagesExhausted
-            prompt, request.max_new_tokens)
-        request.span.prefix_hit_pages = plan.prefix_hit_pages
+            prompt, request.max_new_tokens,
+            hit_depth=(None if self._snapshots is None
+                       else self._snapshots.depth))
+        request.span.prefix_hit_pages = (plan.n_reuse_tokens //
+                                         self._kv.page_size)
         self._kv.commit(slot_id, plan)
         self._queue.record_admission(request, self._profiler.iteration)
         # A model with conv layers: the slot's tails come from the
@@ -1284,6 +1400,14 @@ class ContinuousBatchingEngine:
             tails['snap_page'] = (plan.reuse_pages or
                                   [cache_manager.NULL_PAGE])[-1]
         self._count_conv(prompt_tokens=n)
+        # A model with mamba layers: the hit's states come from its
+        # snapshot (pinned until the restore is dispatched), and the
+        # boundaries the prefill will save snapshots at.
+        restore, save = None, None
+        if self._snapshots is not None:
+            restore, save = self._plan_snapshots(plan, n)
+            if restore is not None:
+                tails['snapshot'] = restore[1]
         if n <= 1:
             # Single-token prompt: empty slot; stale keys are masked
             # (per-position causal mask) and position 0 is overwritten
@@ -1304,6 +1428,7 @@ class ContinuousBatchingEngine:
                 **tails)
             self._count_conv(snapshots_restored=1,
                              restored_tokens=plan.n_reuse_tokens)
+            self._restored(restore)
             slot.request = request
             self._activate(slot_id, request, int(prompt[-1]), n - 1,
                            remaining=request.max_new_tokens,
@@ -1314,7 +1439,48 @@ class ContinuousBatchingEngine:
         # overwrites the first pad position and attends only real
         # keys, so logits match unpadded decode exactly).
         slot.request = request
-        return scheduler.PendingPrefill(slot_id, request, n - 1, plan)
+        pending = scheduler.PendingPrefill(slot_id, request, n - 1, plan)
+        pending.restore, pending.save = restore, save
+        return pending
+
+    def _plan_snapshots(self, plan, n: int):
+        """A model with mamba layers: (the snapshot a hit restores, as
+        (prefix hash, store entry) pinned, or None; the page boundary,
+        as (tokens, prefix hash), at which the prefill saves the state,
+        or None).  Saved: the branch point, the deepest boundary the
+        prompt shares with the prefix cache, where the hit stops short
+        of it for want of a snapshot; and a prompt that shares nothing
+        has the state at its deepest boundary that is a whole number of
+        prefill chunks (and pages) saved.  A prompt that shares nothing
+        cannot tell where the part a later prompt will share ends (a
+        document, a system prompt, the turns so far): a branch inside
+        its last chunk resumes from there and recomputes less than a
+        chunk, and the branch point's own snapshot is saved then.
+        Counts the prompt's tokens."""
+        ps = self._kv.page_size
+        hashes = plan.page_hashes
+        depth, shared = plan.n_reuse_tokens // ps, len(plan.reuse_pages)
+        restore = None
+        if depth:
+            restore = (hashes[depth - 1],
+                       self._snapshots.acquire(hashes[depth - 1]))
+        save = None
+        if shared > depth:
+            save = (shared * ps, hashes[shared - 1])
+        elif not shared and self._kv.prefix_caching:
+            align = math.lcm(self.prefill_chunk, ps) // ps   # pages
+            at = len(hashes) // align * align
+            if at:
+                save = (at * ps, hashes[at - 1])
+        self._count_ssm(prompt_tokens=n, restored_tokens=depth * ps,
+                        recomputed_tokens=(shared - depth) * ps)
+        return restore, save
+
+    def _restored(self, restore) -> None:
+        """A hit's snapshot was read (its restore is dispatched)."""
+        if restore is not None:
+            self._snapshots.release(*restore)
+            self._count_ssm(snapshots_restored=1)
 
     def _advance_prefill(self, pending: scheduler.PendingPrefill,
                          riders: int = 0
@@ -1339,6 +1505,9 @@ class ContinuousBatchingEngine:
                         'request deadline passed mid-prefill'))
             self._slots[pending.slot_id].request = None
             self._release_slot_pages(pending.slot_id)
+            if pending.cache is None and pending.restore is not None:
+                # Its snapshot was never read: unpin it.
+                self._snapshots.release(*pending.restore)
             return True, None  # pending is finished (slot freed)
         import numpy as np  # pylint: disable=import-outside-toplevel
         n_target = pending.n_target
@@ -1359,13 +1528,19 @@ class ContinuousBatchingEngine:
                 # pages — positions [0, reuse_tokens) appear exactly as
                 # if they had been prefilled here; only the tail chunks
                 # run (count 0: no prompt token is computed).
+                snapshot = ()
+                if self._snapshots is not None:
+                    snapshot = (pending.restore[1],)
                 pending.cache = self._seed_private(
                     self._cache,
-                    np.asarray(plan.reuse_pages, np.int32),
-                    priv_len=self.max_len)
+                    np.asarray(plan.reuse_pages[
+                        :reuse_tokens // self._kv.page_size], np.int32),
+                    *snapshot, priv_len=self.max_len)
                 pending.consumed = reuse_tokens
                 self._count_conv(snapshots_restored=1,
                                  restored_tokens=reuse_tokens)
+                if self._snapshots is not None:
+                    self._restored(pending.restore)
                 phase.count = 0
             elif pending.cache is None:
                 # Chunk 0: flash prefill from index 0 into a fresh
@@ -1378,11 +1553,13 @@ class ContinuousBatchingEngine:
                 # `.at[:n].set` would compile a tiny scatter per
                 # distinct prompt length, right on the admission path.
                 take = min(n_target, chunk)
+                snap = self._snapshot_in(pending, 0, take)
                 bucket = min(self._chunk_width(take, chunk), self.max_len)
                 padded = np.zeros((1, bucket), np.int32)
                 padded[0, :take] = request.prompt_ids[:take]
                 pending.cache, tick = self._dispatch_chunk(
-                    jnp.asarray(padded), None, take, riders)
+                    jnp.asarray(padded), None, take, riders, snap)
+                self._save_snapshot_of(pending, snap)
                 # The padded flash cache advanced index to `bucket`;
                 # chunk continuations must write at the REAL consumed
                 # length.
@@ -1405,12 +1582,14 @@ class ContinuousBatchingEngine:
                 # step that reaches it.
                 start = pending.consumed
                 take = min(n_target - start, chunk)
+                snap = self._snapshot_in(pending, start, take)
                 width = min(self._chunk_width(take, chunk), chunk,
                             self.max_len - start)
                 piece = np.zeros((1, width), np.int32)
                 piece[0, :take] = request.prompt_ids[start:start + take]
                 pending.cache, tick = self._dispatch_chunk(
-                    jnp.asarray(piece), pending.cache, take, riders)
+                    jnp.asarray(piece), pending.cache, take, riders, snap)
+                self._save_snapshot_of(pending, snap)
                 pending.cache = dict(
                     pending.cache,
                     index=jnp.asarray(start + take, jnp.int32))
@@ -1424,7 +1603,33 @@ class ContinuousBatchingEngine:
             return False, tick
         return self._finish_prefill(pending), tick
 
-    def _dispatch_chunk(self, piece, cache, take: int, riders: int):
+    @staticmethod
+    def _snapshot_in(pending, start: int, take: int):
+        """A model with mamba layers: the prompt's save boundary as (rows
+        into the chunk, prefix hash) where it falls inside the chunk's
+        (start, start + take], else None."""
+        if pending.save is None or not start < pending.save[0] <= (
+                start + take):
+            return None
+        return pending.save[0] - start, pending.save[1]
+
+    def _save_snapshot_of(self, pending, snap) -> None:
+        """The chunk just dispatched computed the prompt's state at a
+        save boundary (`snap`): into a store entry, if one is free or
+        can be freed."""
+        if snap is None:
+            return
+        _, h = snap
+        pending.save = None
+        entry = self._snapshots.reserve(h)
+        if entry is None:
+            return
+        self._cache = self._save_snapshot(self._cache,
+                                          pending.cache['snap'], entry)
+        self._count_ssm(snapshots_saved=1)
+
+    def _dispatch_chunk(self, piece, cache, take: int, riders: int,
+                        snap=None):
         """Dispatch one prefill chunk, `piece` [1, width] holding `take`
         prompt tokens, of the prompt whose private cache is `cache`
         (None: its first chunk) -> (the private cache with the chunk in
@@ -1442,11 +1647,18 @@ class ContinuousBatchingEngine:
             return self._prefill_chunk(self.params, piece, cache)[1], None
         # `decode-step` inside the chunk's own phase: this dispatch is
         # the iteration's tick too.
+        extra = ()
+        if self._snapshots is not None:
+            # The states after the first `snap_at` rows come back as
+            # the cache's snapshot (the chunk's end where no boundary
+            # falls inside it).
+            extra = (self._jnp.asarray(take if snap is None else snap[0],
+                                       self._jnp.int32),)
         with self._profiler.phase('decode-step', count=riders):
             (self._state, self._cache, finished, moe, exit_mass,
              cache) = self._chunk_step(
                  self.params, self._state, self._cache, piece, cache,
-                 self._jnp.asarray(take, self._jnp.int32))
+                 self._jnp.asarray(take, self._jnp.int32), *extra)
         return cache, (self._state, finished, moe, exit_mass)
 
     def _finish_prefill(self, pending: scheduler.PendingPrefill) -> bool:
@@ -1476,10 +1688,13 @@ class ContinuousBatchingEngine:
                 self._cache, pending.cache,
                 np.asarray(plan.row[r:n_prompt_pages], np.int32),
                 first_page=r)
-            # The slot's conv tails are the prompt's, after its last
-            # prefilled token.
+            # The slot's conv tails (or mamba states) are the prompt's,
+            # after its last prefilled token.
             tails = ({'tail': pending.cache['conv'][:, 0]}
                      if self.cfg.conv_layers else {})
+            if self._snapshots is not None:
+                tails['states'] = {key: pending.cache[key]
+                                   for key in ('ssm', 'xbc')}
             pending.cache = None   # donated to the scatter
             self._cache = self._admit_paged(
                 self._cache, pending.slot_id,

@@ -27,6 +27,12 @@ host-side decision about those pages:
   admission (prefix match + allocation + block-table row), track which
   slot owns which pages, and release them on completion/cancel/TTL so
   the pool can never leak.
+- :class:`StateSnapshots` — for a model whose layers carry recurrent
+  state (Mamba-2's, a matrix a head): which page boundaries of which
+  prefixes have a snapshot of every layer's state in the engine's
+  fixed store, so that a prefix hit can restore it.  A page cannot
+  carry its own snapshot (one is a sequence's whole state, 47 MiB for
+  Nemotron-H), so a hit goes only as deep as a snapshot does.
 
 Why pages: a dense per-slot cache reserves `max_len` positions per
 slot, so replica concurrency is bounded by the WORST-CASE sequence
@@ -67,6 +73,10 @@ _M_PREFIX_HITS = metrics_lib.counter(
 _M_PREFIX_MISSES = metrics_lib.counter(
     'skytpu_engine_prefix_cache_misses_total',
     'Prompt pages that had to be prefilled (no cached prefix).')
+_M_SSM_SNAPSHOTS_EVICTED = metrics_lib.counter(
+    'skytpu_engine_ssm_snapshots_evicted_total',
+    'State snapshots dropped from the store to make room for a newer one '
+    '(models with mamba layers).')
 
 
 class PagesExhausted(RuntimeError):
@@ -362,7 +372,9 @@ class AdmissionPlan:
     row: List[int]            # block-table row: reused + fresh pages
     reuse_pages: List[int]    # cached pages adopted (prefix hit)
     fresh_pages: List[int]    # newly allocated pages
-    n_reuse_tokens: int       # positions [0, n_reuse_tokens) are cached
+    # Positions [0, n_reuse_tokens) need no prefill: every adopted
+    # page's, or (`plan_admission`'s hit_depth) fewer.
+    n_reuse_tokens: int
     page_hashes: List[int]    # chain hashes of the prompt's full pages
 
     @property
@@ -425,10 +437,16 @@ class PagedKVManager:
 
     def plan_admission(self, prompt_ids: Sequence[int],
                        max_new_tokens: int, *,
-                       prefix_ok: bool = True) -> AdmissionPlan:
+                       prefix_ok: bool = True,
+                       hit_depth=None) -> AdmissionPlan:
         """Match the prompt against the prefix cache and allocate the
         fresh remainder; raises PagesExhausted (with any matched pages
-        released) when the pool cannot cover it."""
+        released) when the pool cannot cover it.  `hit_depth(hashes,
+        matched) -> pages` (a model with recurrent state:
+        `StateSnapshots.depth`) says after how many of the matched
+        pages a prefill can resume: the matched pages are adopted all
+        the same (their keys are the prompt's), and the positions past
+        that depth are prefilled again for the state they leave."""
         ps = self.page_size
         n = len(prompt_ids)
         total_pages = self.pages_needed(n, max_new_tokens)
@@ -438,6 +456,9 @@ class PagedKVManager:
                   if (prefix_ok and self.prefix_caching and n > 1)
                   else [])
         reuse = self.prefix.match(hashes)
+        resume = len(reuse)
+        if hit_depth is not None and reuse:
+            resume = hit_depth(hashes, len(reuse))
         fresh_needed = total_pages - len(reuse)
         try:
             fresh = self._alloc_with_eviction(fresh_needed)
@@ -447,7 +468,7 @@ class PagedKVManager:
             raise
         return AdmissionPlan(row=reuse + fresh, reuse_pages=reuse,
                              fresh_pages=fresh,
-                             n_reuse_tokens=len(reuse) * ps,
+                             n_reuse_tokens=resume * ps,
                              page_hashes=hashes)
 
     def _alloc_with_eviction(self, n: int) -> List[int]:
@@ -535,3 +556,95 @@ class PagedKVManager:
         _M_PAGES_USED.set(stats['kv_pages_used'])
         _M_PAGES_PINNED.set(stats['kv_pages_pinned'])
         return stats
+
+
+class StateSnapshots:
+    """Which prefixes have a snapshot of every layer's recurrent state
+    in the engine's store of `entries` fixed slots (the device arrays
+    are `models/decode.init_paged_cache`'s 'ssm_store' and
+    'xbc_store'), keyed by the chain hash of the prefix's last page
+    (`chunk_hashes`): entry i holds the state after exactly those pages.
+
+    What is saved (the engine's policy, `serve/batching_engine.py`): the
+    state at a prompt's branch point, the deepest page boundary it
+    shares with the prefix cache where no snapshot is, when its
+    prefill passes it; and, for a prompt that shares nothing, the state
+    at its deepest boundary of whole prefill chunks.  A hit resumes
+    prefill no deeper than a snapshot (`depth`).
+
+    Eviction is segmented.  A snapshot saved and not yet restored waits
+    in probation, oldest first out; one restored at least once is kept
+    apart and leaves only when probation is empty, the least recently
+    restored first.  So snapshots that hits keep using are not pushed
+    out by new ones that nobody may ever ask for.  An entry being
+    restored (`acquire` until `release`) is never evicted.  Worker
+    thread only."""
+
+    def __init__(self, entries: int) -> None:
+        self.entries = int(entries)
+        self._free = list(range(self.entries))
+        # hash -> entry, oldest first.
+        self._probation: 'collections.OrderedDict[int, int]' = (
+            collections.OrderedDict())
+        self._protected: 'collections.OrderedDict[int, int]' = (
+            collections.OrderedDict())
+        self._pins: Dict[int, int] = {}
+        self.evicted = 0
+
+    def __len__(self) -> int:
+        return len(self._probation) + len(self._protected)
+
+    def entry(self, h: int) -> Optional[int]:
+        return self._probation.get(h, self._protected.get(h))
+
+    def depth(self, hashes: Sequence[int], matched: int) -> int:
+        """The deepest page count j <= matched whose prefix (pages
+        0 .. j - 1, named by hashes[j - 1]) has a snapshot; 0 where
+        none has."""
+        for j in range(matched, 0, -1):
+            if self.entry(hashes[j - 1]) is not None:
+                return j
+        return 0
+
+    def acquire(self, h: int) -> int:
+        """Pin the snapshot of prefix `h` for a restore; its entry."""
+        e = self.entry(h)
+        if e is None:
+            raise KeyError(f'no state snapshot for prefix {h}')
+        self._pins[e] = self._pins.get(e, 0) + 1
+        return e
+
+    def release(self, h: int, e: int) -> None:
+        """The restore from entry `e` was dispatched: unpin it, and the
+        snapshot is the most recently restored."""
+        self._pins[e] -= 1
+        if not self._pins[e]:
+            del self._pins[e]
+        if self._probation.pop(h, None) is not None or h in self._protected:
+            self._protected[h] = e
+            self._protected.move_to_end(h)
+
+    def reserve(self, h: int) -> Optional[int]:
+        """An entry for a new snapshot of prefix `h`: a free one, else
+        the first to evict; None where `h` has one already or every
+        entry is pinned."""
+        if self.entry(h) is not None:
+            return None
+        if self._free:
+            e = self._free.pop()
+        else:
+            e = None
+            for segment in (self._probation, self._protected):
+                for key, held in segment.items():
+                    if held not in self._pins:
+                        del segment[key]
+                        e = held
+                        break
+                if e is not None:
+                    break
+            if e is None:
+                return None
+            self.evicted += 1
+            _M_SSM_SNAPSHOTS_EVICTED.inc()
+        self._probation[h] = e
+        return e

@@ -72,14 +72,16 @@ class HandoffRejected(HandoffError):
 
 def check_model(cfg) -> None:
     """The wire carries pages of keys and values and nothing else: a
-    model with conv layers keeps, beside its pages, tails that a prompt
-    page's snapshot restores (`models/decode.init_paged_cache`), and a
-    page without them would be a prefix hit that restores no state.
-    Such a model is refused, on both sides of a handoff."""
-    if cfg.conv_layers:
+    model with conv or mamba layers keeps, beside its pages, state that
+    a prefix hit restores from a snapshot (`models/decode.
+    init_paged_cache`), and a page without it would be a prefix hit
+    that restores no state.  Such a model is refused, on both sides of
+    a handoff."""
+    if cfg.conv_layers or cfg.mamba_layers:
         raise HandoffError(
-            'KV handoff cannot carry conv layers\' state: the wire holds '
-            'pages of keys and values, not the conv tails at their end')
+            'KV handoff cannot carry conv or mamba layers\' state: the '
+            'wire holds pages of keys and values, not the state at their '
+            'end')
 
 
 def _b64(arr: np.ndarray) -> str:

@@ -32,7 +32,7 @@ import dataclasses
 import queue
 import threading
 import time
-from typing import Any, Callable, Deque, Dict, List, Optional
+from typing import Any, Callable, Deque, Dict, List, Optional, Tuple
 
 from skypilot_tpu.serve import roles as roles_lib
 
@@ -305,6 +305,12 @@ class PendingPrefill:
         # The cache_manager.AdmissionPlan holding this request's pages
         # (reuse + fresh) until activation/abandon.
         self.plan = plan
+        # A model with mamba layers: the state snapshot a prefix hit
+        # restores, (prefix hash, store entry) pinned until it is read;
+        # the page boundary, (tokens, prefix hash), at which the
+        # prefill saves one.
+        self.restore: Optional[Tuple[int, int]] = None
+        self.save: Optional[Tuple[int, int]] = None
 
 
 @dataclasses.dataclass

@@ -166,12 +166,12 @@ class SliceReplicaEngine(batching_engine_lib.ContinuousBatchingEngine):
         import jax  # pylint: disable=import-outside-toplevel
 
         from skypilot_tpu.models import decode  # pylint: disable=import-outside-toplevel
-        if cfg.conv_layers:
+        if cfg.conv_layers or cfg.mamba_layers:
             raise ValueError(
-                'a slice replica cannot serve conv layers: its ring '
-                'prefill splits the sequence their tails run through, and '
-                'its ranks\' standalone chunk programs keep no page '
-                'snapshots')
+                'a slice replica cannot serve conv or mamba layers: its '
+                'ring prefill splits the sequence their state runs '
+                'through, and its ranks\' standalone chunk programs keep '
+                'no snapshots of it')
         self.num_hosts = int(num_hosts)
         self.sp_threshold = (sp_threshold_default()
                              if sp_threshold is None

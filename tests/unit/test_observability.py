@@ -336,7 +336,8 @@ def test_every_jitted_entry_is_a_named_function(tiny_paged_engine):
     assert names['_insert_pages'] == 'insert_prefill_pages'
     assert names['_admit_paged'] == 'paged_admit_slot'
     assert names['_chunk_step'] == 'paged_engine_step_with_chunk'
-    assert len(names) == 11
+    assert names['_save_snapshot'] == 'save_snapshot'
+    assert len(names) == 12
 
 
 def test_stats_tick_loop_counts_the_loop(tiny_paged_engine):

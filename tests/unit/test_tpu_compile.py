@@ -476,3 +476,27 @@ def test_layers_stacked_by_kind_read_weights_in_place(one_chip,
                              'bitcast', 'while', 'custom-call')]
     assert not pool, pool
     assert not _resizing_bitcasts(hlo)
+
+
+def test_ssm_decode_kernel_compiles_for_v5e_in_place(one_chip, monkeypatch):
+    """The decode-state kernel at the benchmark's shapes (Nemotron-3-
+    Nano: 23 mamba layers, 64 slots, 64 heads of a [64, 128] float32
+    state, 8 groups): every layer's state in one operand and the layer
+    a scalar, the state aliased to the result (3.1 GB written in place)
+    and nothing else of its size made around the call."""
+    from skypilot_tpu.ops import ssm_decode
+    monkeypatch.delenv('SKYTPU_PALLAS_INTERPRET', raising=False)
+
+    def arg(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    m, b, h, p, n, g = 23, 64, 64, 64, 128, 8
+    compiled = jax.jit(ssm_decode._decode_call(  # pylint: disable=protected-access
+        (m, b, h, p, n), g, False), donate_argnums=(2,)).lower(
+            arg((1,), jnp.int32), arg((b,), jnp.int32),
+            arg((m, b, h, p, n)), arg((b, p, h)), arg((b, 1, h)),
+            arg((b, g, n)), arg((b, g, n))).compile()
+    assert 'ssm_decode_step' in compiled.as_text()
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= m * b * h * p * n * 4
+    assert memory.temp_size_in_bytes < 1e6
